@@ -81,12 +81,8 @@ from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:                                      # jax >= 0.8
-    from jax import shard_map
-except ImportError:                       # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
 
 from ..ops.paged_attention import paged_decode_attention
 from ..ops.paged_prefill import (paged_prefill_attention,
@@ -355,7 +351,8 @@ def _tp_attention_decode_paged(layer, config: LlamaConfig, tp: int,
     k = llama.apply_rope(k, cos, sin)
     new_pool = llama._paged_write_rows(pool_layer, k, v, tables,
                                        positions)
-    use_kernel, interpret = llama.decode_kernel_mode()
+    use_kernel, interpret = llama.decode_dispatch(
+        hd, kv, new_pool["k"].dtype)
     q_g = q.reshape(batch, seq, kv, h // kv, hd)
     if use_kernel:
         out = paged_decode_attention(
@@ -419,7 +416,8 @@ def _tp_prefill_append_core(params, tokens, pool, tables, start_index,
     chunk_lens = jnp.full((batch,), K, jnp.int32)
     cos, sin = llama._rope_freqs(config, positions_b)
     x = _tp_embed(params, tokens, config, axis)
-    use_kernel, interpret = llama.prefill_kernel_mode()
+    use_kernel, interpret = llama.prefill_dispatch(
+        hd, kv, pool[0]["k"].dtype, pool[0]["k"].shape[1], K)
     new_pool = []
     lora_layers = _lora_layers(lora, len(pool))
     for layer, pool_layer, lora_layer in zip(params["layers"], pool,
@@ -481,10 +479,9 @@ def _tp_sp_prefill_core(params, tokens, pool, tables, start_index,
       dispatch of the single-chip server (invariant 19) — the
       sp window just runs all ``sp`` chunk programs at once.
 
-    The in-kernel int8 writer is bit-identical to the aligned slab
-    writer's per-row absmax (see ops/paged_prefill), so the kernel
-    path re-writing this shard's own chunk leaves every sp copy
-    byte-identical too."""
+    The kernel path quantizes int8 rows with the slab writer's own
+    per-row absmax (see ops/paged_prefill), so re-writing this shard's
+    own chunk leaves every sp copy byte-identical too."""
     batch, W = tokens.shape
     h, kv = config.n_heads // tp, config.n_kv_heads // tp
     hd = config.head_dim
@@ -500,7 +497,8 @@ def _tp_sp_prefill_core(params, tokens, pool, tables, start_index,
     chunk_lens = jnp.full((batch,), W, jnp.int32)
     cos, sin = llama._rope_freqs(config, positions_b)
     x = _tp_embed(params, tokens, config, axis)
-    use_kernel, interpret = llama.prefill_kernel_mode()
+    use_kernel, interpret = llama.prefill_dispatch(
+        hd, kv, pool[0]["k"].dtype, pool[0]["k"].shape[1], W)
     new_pool = []
     lora_layers = _lora_layers(lora, len(pool))
     for layer, pool_layer, lora_layer in zip(params["layers"], pool,
@@ -561,7 +559,8 @@ def _tp_verify_core(params, tokens, pool, tables, positions, active,
                              jnp.zeros_like(tables))
     cos, sin = llama._rope_freqs(config, positions_b)
     x = _tp_embed(params, tokens, config, axis)
-    use_kernel, interpret = llama.prefill_kernel_mode()
+    use_kernel, interpret = llama.verify_dispatch(
+        hd, kv, pool[0]["k"].dtype, K)
     new_pool = []
     lora_layers = _lora_layers(lora, len(pool))
     for layer, pool_layer, lora_layer in zip(params["layers"], pool,
@@ -664,7 +663,7 @@ class TPEngine:
 
     def _shard_map(self, body, in_specs, out_specs):
         return shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+                         out_specs=out_specs, check_vma=False)
 
     def _core_kwargs(self):
         """Second-axis / overlap context threaded into every mirror

@@ -1,8 +1,9 @@
 """Compile ledger: every XLA compilation, observed and attributed.
 
 The whole serving stack leans on one unmeasured invariant: pow2 shape
-bucketing keeps compile counts log-bounded because "every compile is a
-relay risk" (``orchestration/continuous.py`` prefill loop,
+bucketing keeps compile counts log-bounded, because a compile in the
+serving loop stalls every slot for seconds
+(``orchestration/continuous.py`` prefill loop,
 ``kvstore/transfer.py``).  This module makes that invariant observable
 at runtime instead of only in jaxpr tests:
 
@@ -17,15 +18,21 @@ at runtime instead of only in jaxpr tests:
   ``aiko_compiles_steady_state_total`` and fires a flight capture
   (trigger ``"compile"``) with the ledger attached, so the pathology
   is caught in production, not just in tests.
-* :func:`enable_persistent_cache` wires JAX's persistent compilation
-  cache to a per-replica directory so a warm restart skips
-  recompilation entirely; the ledger's hit/miss/saved-ms counters
-  quantify it (``tools/loadgen.run_compile_cache_ab`` gates on it).
+* :func:`enable_persistent_cache` turns on JAX's persistent
+  compilation cache — in the directory ``JAX_COMPILATION_CACHE_DIR``
+  names when the environment sets it, else a caller's directory or
+  the fixed in-checkout :func:`default_cache_dir` — so a warm restart
+  skips recompilation entirely; the ledger's hit/miss/saved-ms
+  counters quantify it (``tools/loadgen.run_compile_cache_ab`` gates
+  on it).
 
-Event semantics (measured, jax 0.4.x): ``jax.monitoring`` events carry
-NO program name (empty kwargs), so attribution uses a **per-thread
-label** set by the engine at each dispatch site
-(:func:`label` / :func:`set_label`).  On a persistent-cache HIT the
+Event semantics (jax 0.9.0; re-checked on CPU and on a TPU v5e by
+``chip_smoke.py``'s two-run cache check): the duration events carry
+only the jitted function's name (``fun_name=``) and the hit/miss
+events carry nothing, so attribution — which also needs the shape
+bucket — uses a **per-thread label** set by the engine at each
+dispatch site (:func:`label` / :func:`set_label`).  On a
+persistent-cache HIT the
 ``…/backend_compile_duration`` event STILL fires (it times the ~ms
 cache retrieval, not a real compile) — the ledger pairs a same-thread
 preceding ``cache_hits`` event with the next duration event and books
@@ -50,6 +57,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -58,7 +66,8 @@ from typing import Dict, List, Optional, Tuple
 from .metrics import REGISTRY
 
 __all__ = ["CompileLedger", "LEDGER", "install", "uninstall",
-           "enable_persistent_cache", "disable_persistent_cache",
+           "enable_persistent_cache", "persistent_cache",
+           "entry_point_cache", "default_cache_dir",
            "set_label", "clear_label", "current_label", "label"]
 
 #: Process-wide switchboard.  ``None`` (the default) means compile
@@ -322,44 +331,92 @@ def uninstall():
 # Persistent compilation cache wiring.
 # --------------------------------------------------------------------------- #
 
-def enable_persistent_cache(cache_dir: str,
+#: The environment's placement of the persistent cache.  JAX reads it
+#: into ``jax_compilation_cache_dir`` at import; where it is set, no
+#: code here sets another directory.
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def default_cache_dir() -> str:
+    """The fixed, git-ignored cache directory of this checkout
+    (``<repo>/.jax_cache``), resolved from the package's location —
+    never from a temp name, pid or time: the path is part of the cache
+    key's neighbourhood, and a directory that moves never hits."""
+    package = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(package), ".jax_cache")
+
+
+def entry_point_cache() -> str:
+    """Place the persistent cache for a process that STARTS the program
+    (``chip_smoke.py``, ``bench.py`` section children, the pipeline and
+    registrar CLIs); returns the directory.  Call it first thing in
+    ``main``: it exports the placement — the directory the environment
+    already names, else :func:`default_cache_dir` — together with the
+    cache-everything thresholds, so JAX reads them when it is imported
+    and every child this process launches (``ProcessManager`` replicas
+    included) inherits the same directory.  A control-plane process
+    that never imports JAX pays nothing.  Importing the package and
+    running the tests call this nowhere."""
+    cache_dir = os.environ.setdefault(CACHE_DIR_ENV, default_cache_dir())
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                          "0")
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES",
+                          "-1")
+    if "jax" in sys.modules:
+        # JAX has already read its environment: configure it directly.
+        enable_persistent_cache()
+    return cache_dir
+
+
+def enable_persistent_cache(cache_dir: Optional[str] = None,
                             min_compile_time_secs: float = 0.0,
                             min_entry_size_bytes: int = -1) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+    """Turn JAX's persistent compilation cache on; returns the
+    directory in use.
 
-    Per-replica opt-in (the ``compilation_cache_dir`` engine kwarg
-    routes here).  The aggressive thresholds default to "cache
-    everything" because serving programs are few and warm-restart
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set that directory is the
+    cache and ``cache_dir`` is ignored (the machine's owner placed the
+    cache; an engine's ``compilation_cache_dir=`` may not move it).
+    Otherwise ``cache_dir``, or :func:`default_cache_dir`.
+
+    The aggressive thresholds default to "cache everything" because
+    serving programs are few and warm-restart
     time-to-first-compiled-step is the metric that matters
     (``SERVING.md`` warm-restart story; the loadgen A/B gates on it).
-    Returns the directory (created if missing).
     """
-    cache_dir = str(cache_dir)
-    os.makedirs(cache_dir, exist_ok=True)
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    cache_dir = os.environ.get(CACHE_DIR_ENV) or str(
+        cache_dir or default_cache_dir())
+    os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_time_secs))
     jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                       int(min_entry_size_bytes))
-    try:
-        # jax initializes its cache singleton on first compile and
-        # ignores later config changes; reset so a mid-process enable
-        # (replica constructed after other engines compiled) works.
-        from jax.experimental.compilation_cache import compilation_cache
-        compilation_cache.reset_cache()
-    except Exception:  # noqa: BLE001 - older jax: dir read per compile
-        pass
+    # jax initializes its cache singleton on first compile and ignores
+    # later config changes; reset so a mid-process enable (replica
+    # constructed after other engines compiled) works.
+    compilation_cache.reset_cache()
     return cache_dir
 
 
-def disable_persistent_cache():
-    """Un-configure the persistent cache (harness cleanup: a temp
-    cache directory must not stay configured after it is deleted)."""
+@contextlib.contextmanager
+def persistent_cache(cache_dir: str):
+    """Test-rig scope: the cache in ``cache_dir`` for the block, then
+    the setting that was found — a rig's temp directory must not stay
+    configured after it is deleted, and must not erase a cache that
+    was on before it.  Yields the directory in use, which is the
+    environment's when ``JAX_COMPILATION_CACHE_DIR`` pins it."""
     import jax
-    jax.config.update("jax_compilation_cache_dir", None)
+    from jax.experimental.compilation_cache import compilation_cache
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    found = {name: getattr(jax.config, name) for name in names}
     try:
-        from jax.experimental.compilation_cache import compilation_cache
+        yield enable_persistent_cache(cache_dir)
+    finally:
+        for name, value in found.items():
+            jax.config.update(name, value)
         compilation_cache.reset_cache()
-    except Exception:  # noqa: BLE001 - see enable_persistent_cache
-        pass

@@ -16,20 +16,16 @@ lane dimension, query blocks ride sublanes.
 from __future__ import annotations
 
 import functools
+import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas TPU backend only exists on TPU-enabled jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-    _PALLAS_TPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _PALLAS_TPU = False
-
-__all__ = ["flash_attention", "attention_reference", "NEG_INF"]
+__all__ = ["flash_attention", "flash_tiles", "attention_reference",
+           "NEG_INF"]
 
 NEG_INF = -1e30
 # NEG_INF must stay FINITE (never -inf): with sliding-window masking a
@@ -71,6 +67,19 @@ def attention_reference(q, k, v, causal: bool = True,
     weights = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd",
                       weights.astype(v.dtype), v).astype(q.dtype)
+
+
+def flash_tiles(q_len: int, k_len: int, causal: bool = True,
+                block_q: int = DEFAULT_BLOCK_Q,
+                block_k: int = DEFAULT_BLOCK_K) -> bool:
+    """Can the flash kernel tile this shape?  Both lengths must be
+    whole blocks (a length below the block size is its own block), and
+    a causal query may not outrun the keys — rows with no visible key
+    make the block-skip index map go negative, and the jnp reference
+    defines the semantics there."""
+    return (q_len % min(block_q, q_len) == 0
+            and k_len % min(block_k, k_len) == 0
+            and not (causal and q_len > k_len))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref,
@@ -163,7 +172,9 @@ def flash_attention(q, k, v, causal: bool = True,
                     interpret: bool = False,
                     window: Optional[int] = None):
     """Flash attention; dispatches to the Pallas kernel on TPU (or in
-    interpret mode), else the jnp reference.
+    interpret mode), else the jnp reference.  A shape the kernel
+    cannot tile (:func:`flash_tiles`) also takes the reference; on the
+    TPU backend that is warned about at trace time, never silent.
 
     Grouped-query attention is native: ``k``/``v`` may carry fewer heads
     than ``q`` (``heads % kv_heads == 0``) — query-head grid steps index
@@ -186,17 +197,17 @@ def flash_attention(q, k, v, causal: bool = True,
         return attention_reference(q, k_full, v_full, causal=causal,
                                    sm_scale=sm_scale, window=window)
 
-    on_tpu = jax.default_backend() == "tpu"
-    if not (_PALLAS_TPU and (on_tpu or interpret)):
+    if not (jax.default_backend() == "tpu" or interpret):
+        return fallback()
+    if not flash_tiles(q_len, k_len, causal, block_q, block_k):
+        if not interpret:
+            warnings.warn(
+                f"flash_attention: q_len={q_len} k_len={k_len} does not "
+                "tile; running the jnp reference on the TPU",
+                stacklevel=2)
         return fallback()
     block_q = min(block_q, q_len)
     block_k = min(block_k, k_len)
-    if q_len % block_q or k_len % block_k:
-        return fallback()
-    if causal and q_len > k_len:
-        # Rows with no visible keys make the block-skip index map go
-        # negative; the jnp reference defines the semantics here.
-        return fallback()
 
     bh = batch * heads
     q3 = q.reshape(bh, q_len, head_dim)

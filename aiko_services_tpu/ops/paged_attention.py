@@ -9,11 +9,18 @@ HBM reads per row per step no matter how short the row really is, and
 the paged layout must first gather its blocks into a contiguous bucket.
 The kernel here reads only the blocks a row actually occupies:
 
-* grid ``(batch-row, kv-head, block)``; the block axis is
-  fastest-varying, so one program instance sweeps one row × kv-head
-  through its live blocks carrying online-softmax state in VMEM scratch
-  (flash-decoding style — running max ``m``, denominator ``l``,
-  accumulator ``acc`` in f32).
+* grid ``(batch-row, block)``; the block axis is fastest-varying, so
+  one program instance sweeps one row through its live blocks carrying
+  online-softmax state for every head in VMEM scratch (flash-decoding
+  style — running max ``m``, denominator ``l``, accumulator ``acc`` in
+  f32).
+* a program's K/V tile is one WHOLE pool block ``(block_size,
+  kv_heads, head_dim)``: the tile's last two dimensions are the
+  array's own, which is the only cut of this pool layout Mosaic's
+  (sublane, lane) tiling accepts (a one-head tile is refused: 1 is
+  neither a multiple of 8 nor ``kv_heads``), and the DMA is one
+  contiguous copy.  Heads are split in-kernel
+  (:func:`load_head_rows`).
 * the block table and per-row positions ride scalar prefetch
   (``PrefetchScalarGridSpec``), so the K/V BlockSpec index maps resolve
   ``tables[row, j]`` into a pool block id BEFORE the body runs — the
@@ -21,14 +28,13 @@ The kernel here reads only the blocks a row actually occupies:
 * dead grid steps (``j`` past the row's last live block, or wholly
   below the sliding window) clamp their index map to a resident block
   and skip compute via ``pl.when`` — no HBM traffic, (almost) no work.
-* all ``group = n_heads // n_kv_heads`` query heads of a kv head run in
-  ONE program, so the MXU sees a (group, head_dim) × (head_dim,
-  block_size) matmul per block instead of ``group`` skinny dot
-  products.
-* int8 KV dequantizes in-kernel: per-(token, head) scales load as a
-  (block_size, 1) column and broadcast-multiply the int8 block right
-  after the load — the cache is read at 1 byte/element and no bf16
-  copy of it ever exists.
+* all ``group = n_heads // n_kv_heads`` query heads of a kv head share
+  one (group, head_dim) × (head_dim, block_size) matmul per block
+  instead of ``group`` skinny dot products.
+* int8 KV dequantizes in-kernel: the block's per-(token, head) scale
+  plane loads whole, the head's column broadcast-multiplies its rows
+  right after the load — the cache is read at 1 byte/element and no
+  bf16 copy of it ever exists.
 
 The contiguous ragged cache is the degenerate case: reshape
 ``(batch, S, kv, hd)`` to ``(batch·S/bs, bs, kv, hd)`` with iota block
@@ -46,17 +52,15 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .attention import NEG_INF, _PALLAS_TPU
-
-if _PALLAS_TPU:
-    from jax.experimental.pallas import tpu as pltpu
-else:  # pragma: no cover
-    pltpu = None
+from .attention import NEG_INF
 
 __all__ = ["paged_decode_attention", "paged_decode_reference",
-           "cached_gqa_attention", "decode_kernel_mode",
-           "decode_attention_path", "contiguous_block_size"]
+           "cached_gqa_attention", "kernel_mode", "runs_kernel",
+           "decode_kernel_mode",
+           "decode_dispatch", "decode_attention_path",
+           "contiguous_block_size", "kernel_serves", "load_head_rows"]
 
 #: Maximum pool block size the degenerate contiguous view uses — small
 #: enough that short rows skip most of the cache, large enough for the
@@ -71,11 +75,10 @@ DEQUANT_BLOCK_CAP = 512
 # Dispatch policy
 
 
-def decode_kernel_mode() -> Tuple[bool, bool]:
-    """``(use_kernel, interpret)`` for the decode-attention dispatch.
-
-    Controlled by ``AIKO_DECODE_ATTENTION`` (read at TRACE time — set it
-    before the first decode call of a given shape, jit caches traces):
+def kernel_mode(env_var: str) -> Tuple[bool, bool]:
+    """``(use_kernel, interpret)`` from one of the attention mode
+    variables (read at TRACE time — set it before the first call of a
+    given shape, jit caches traces):
 
     * ``auto`` (default): kernel on TPU, jnp reference elsewhere.
     * ``kernel``: force the kernel; off-TPU it runs in interpret mode
@@ -83,20 +86,73 @@ def decode_kernel_mode() -> Tuple[bool, bool]:
     * ``interpret``: kernel in interpret mode everywhere.
     * ``reference`` / ``off`` / ``0``: always the jnp reference.
     """
-    mode = os.environ.get("AIKO_DECODE_ATTENTION", "auto").lower()
+    mode = os.environ.get(env_var, "auto").lower()
     if mode in ("reference", "fallback", "off", "0"):
         return False, False
     on_tpu = jax.default_backend() == "tpu"
     if mode in ("kernel", "force"):
-        return _PALLAS_TPU, not on_tpu
+        return True, not on_tpu
     if mode == "interpret":
-        return _PALLAS_TPU, True
-    return _PALLAS_TPU and on_tpu, False
+        return True, True
+    return on_tpu, False
 
 
-def decode_attention_path() -> str:
-    """``"kernel"`` or ``"reference"`` — the serving-counter path tag."""
-    return "kernel" if decode_kernel_mode()[0] else "reference"
+def decode_kernel_mode() -> Tuple[bool, bool]:
+    """:func:`kernel_mode` of ``AIKO_DECODE_ATTENTION``."""
+    return kernel_mode("AIKO_DECODE_ATTENTION")
+
+
+def runs_kernel(interpret: bool) -> bool:
+    """Entry rule of the public kernels when called directly:
+    interpreting, or on the TPU backend, the kernel runs (and refuses,
+    loudly, a geometry it cannot serve — callers that want the
+    reference there ask the ``*_dispatch`` functions first, which is
+    also what the serving path tags report); anywhere else the jnp
+    oracle IS the path."""
+    return interpret or jax.default_backend() == "tpu"
+
+
+def kernel_serves(head_dim: int, kv_heads: int, pool_dtype,
+                  interpret: bool) -> bool:
+    """Can the paged kernels (decode, append, verify) serve a pool of
+    this geometry?  ``kv_heads`` is the LOCAL count under tensor
+    parallelism.  One head's row rides the lane axis, so ``head_dim``
+    may not exceed 128 anywhere.  Compiled, the whole-block tile
+    ``(block_size, kv_heads, head_dim)`` must also sit on Mosaic's
+    tiling, which refuses everything else (TPU v5e, PR 21):
+
+    * lanes: ``head_dim`` a multiple of 128 — 64 fails with "Slice
+      shape along dimension 3 must be aligned to tiling (128)";
+    * sublanes: 32-bit words, so ``kv_heads`` a multiple of the rows a
+      word packs (1 f32, 2 bf16, 4 int8) — two int8 heads, TP=4 of an
+      8-kv-head model with int8 KV, fail with "Slice shape along
+      dimension 2 must be aligned to tiling (4), but is 2"."""
+    if head_dim > 128:
+        return False
+    if interpret:
+        return True
+    packing = 4 // jnp.dtype(pool_dtype).itemsize
+    return head_dim == 128 and kv_heads % packing == 0
+
+
+def decode_dispatch(head_dim: int, kv_heads: int,
+                    pool_dtype) -> Tuple[bool, bool]:
+    """``(use_kernel, interpret)`` for one pool geometry: the mode from
+    :func:`decode_kernel_mode`, and the reference for a geometry the
+    kernel cannot serve (:func:`kernel_serves`) — so nothing else runs
+    under the kernel's name.  The serving path tag is this same
+    answer."""
+    use_kernel, interpret = decode_kernel_mode()
+    return (use_kernel and kernel_serves(head_dim, kv_heads, pool_dtype,
+                                         interpret)), interpret
+
+
+def decode_attention_path(head_dim: int, kv_heads: int,
+                          pool_dtype) -> str:
+    """``"kernel"`` or ``"reference"`` — the serving-counter path tag,
+    decided by :func:`decode_dispatch` at the server's real geometry."""
+    use_kernel, _ = decode_dispatch(head_dim, kv_heads, pool_dtype)
+    return "kernel" if use_kernel else "reference"
 
 
 def contiguous_block_size(max_seq: int) -> int:
@@ -264,25 +320,46 @@ def paged_decode_reference(q, k_pool, v_pool, tables, positions,
 # The kernel
 
 
+#: The kernels' matmuls contract at f32 precision.  The MXU's default
+#: rounds f32 operands to bf16, which on the chip put the compiled
+#: kernels 3e-3..1e-2 from their oracles where the interpreter sits at
+#: 1e-6 (PERF.md, PR 21); what precision the serving dtype can afford
+#: is a decision for a measurement against a logits tolerance
+#: (ROADMAP C3), not a default to inherit unseen.
+MXU_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def load_head_rows(block_ref, head: int):
+    """One kv head of a ``(block_size, kv_heads, head_dim)`` pool tile
+    as f32 ``(block_size, head_dim)`` rows — shared by the decode and
+    append-attention kernels.  The tile's sublane axis is ``kv_heads``,
+    so this is a strided read (every ``kv_heads``-th row, sub-word rows
+    for bf16 and int8), which Mosaic lowers for f32, bf16 and int8
+    tiles (compiled and compared on a v5e, PR 21)."""
+    return block_ref[:, head, :].astype(jnp.float32)
+
+
 def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
                          q_ref, k_ref, v_ref, *rest,
-                         block_size: int, sm_scale: float,
+                         block_size: int, group: int, sm_scale: float,
                          window: Optional[int], quantized: bool):
-    """Grid: (batch, kv_heads, blocks); blocks fastest-varying.
+    """Grid: (batch, blocks); blocks fastest-varying.
 
-    One program = one (row, kv-head) × one pool block.  Scratch carries
-    the online-softmax state across the block sweep.  ``tables_ref`` /
-    ``positions_ref`` are the scalar-prefetched block table and per-row
-    positions (also consumed by the K/V index maps in
-    :func:`paged_decode_attention`)."""
+    One program = one row × one pool block, every head.  Scratch
+    carries the online-softmax state of all ``kv_heads · group`` query
+    heads (one scratch row each, kv-head major) across the block
+    sweep.  ``tables_ref`` / ``positions_ref`` are the scalar-prefetched
+    block table and per-row positions (also consumed by the K/V index
+    maps in :func:`paged_decode_attention`)."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     row = pl.program_id(0)
-    j = pl.program_id(2)
-    num_j = pl.num_programs(2)
+    j = pl.program_id(1)
+    num_j = pl.num_programs(1)
     pos = positions_ref[row]
+    kv_heads = k_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -303,40 +380,47 @@ def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
 
     @pl.when(block_live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)           # (group, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)        # (bs, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)        # (bs, hd)
-        if quantized:
-            # Per-(token, head) scales load as a (bs, 1) column and
-            # broadcast along hd — dequantization never leaves VMEM.
-            k = k * ks_ref[0]
-            v = v * vs_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (group, bs)
-
         key_ids = jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1) + j * block_size
+            jnp.int32, (group, block_size), 1) + j * block_size
         visible = key_ids <= pos
         if window is not None:
             visible &= key_ids > pos - window
-        s = jnp.where(visible, s, NEG_INF)
+        if quantized:
+            k_scales = ks_ref[0]                       # (bs, kv_heads)
+            v_scales = vs_ref[0]
+        for head in range(kv_heads):
+            rows = slice(head * group, (head + 1) * group)
+            q = q_ref[0, rows, :]                      # (group, hd) f32
+            k = load_head_rows(k_ref.at[0], head)      # (bs, hd) f32
+            v = load_head_rows(v_ref.at[0], head)
+            if quantized:
+                # The head's scale column broadcasts along hd —
+                # dequantization never leaves VMEM.
+                k = k * k_scales[:, head:head + 1]
+                v = v * v_scales[:, head:head + 1]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), precision=MXU_PRECISION,
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(visible, s, NEG_INF)         # (group, bs)
 
-        m_prev = m_scr[:]                              # (group, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                         # (group, bs)
-        correction = jnp.exp(m_prev - m_new)
-        l_scr[:] = correction * l_scr[:] + jnp.sum(p, axis=-1,
-                                                   keepdims=True)
-        acc_scr[:] = acc_scr[:] * correction + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
+            m_prev = m_scr[rows, :]                    # (group, 1)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            correction = jnp.exp(m_prev - m_new)
+            l_scr[rows, :] = correction * l_scr[rows, :] + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_scr[rows, :] = (
+                acc_scr[rows, :] * correction + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    precision=MXU_PRECISION,
+                    preferred_element_type=jnp.float32))
+            m_scr[rows, :] = m_new
 
     @pl.when(j == num_j - 1)
     def _finish():
         denom = jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
-        o_ref[0, 0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, positions,
@@ -364,27 +448,32 @@ def paged_decode_attention(q, k_pool, v_pool, tables, positions,
       interpret: run the Pallas kernel in interpret mode (CPU testing).
 
     Returns ``(batch, kv_heads, group, head_dim)`` in ``q.dtype``.
-    Dispatches to :func:`paged_decode_reference` when Pallas TPU is
-    unavailable (and not interpreting) or the shape is unsupported.
+    Off the TPU backend (and not interpreting) this IS
+    :func:`paged_decode_reference`, the CPU path; see
+    :func:`runs_kernel`.
     """
     batch, kv_heads, group, head_dim = q.shape
-    n_blocks, block_size = k_pool.shape[0], k_pool.shape[1]
+    block_size = k_pool.shape[1]
     max_blocks = tables.shape[1]
     quantized = ks is not None
     if sm_scale is None:
         sm_scale = head_dim ** -0.5
 
-    on_tpu = jax.default_backend() == "tpu"
-    if not (_PALLAS_TPU and (on_tpu or interpret)) or head_dim > 128:
+    if not runs_kernel(interpret):
         return paged_decode_reference(q, k_pool, v_pool, tables,
                                       positions, ks=ks, vs=vs,
                                       window=window)
+    if not kernel_serves(head_dim, kv_heads, k_pool.dtype, interpret):
+        raise ValueError(
+            f"paged decode kernel cannot serve head_dim={head_dim} "
+            f"kv_heads={kv_heads} {k_pool.dtype} pools")
 
     tables = tables.astype(jnp.int32)
     positions = positions.astype(jnp.int32)
-    grid = (batch, kv_heads, max_blocks)
+    heads = kv_heads * group
+    grid = (batch, max_blocks)
 
-    def kv_index(row, head, j, tables_ref, positions_ref):
+    def kv_index(row, j, tables_ref, positions_ref):
         # Clamp dead steps into the live band [first_live, last_live]:
         # an unchanged block index means Pallas reuses the resident
         # VMEM tile instead of issuing a fresh HBM copy, so a row's
@@ -394,42 +483,48 @@ def paged_decode_attention(q, k_pool, v_pool, tables, positions,
         if window is not None:
             first_live = jnp.maximum(pos - window + 1, 0) // block_size
             j_c = jnp.maximum(j_c, first_live)
-        return (tables_ref[row, j_c], 0, head, 0)
+        return (tables_ref[row, j_c], 0, 0, 0)
 
-    def scale_index(row, head, j, tables_ref, positions_ref):
-        return kv_index(row, head, j, tables_ref, positions_ref)[:3]
+    def scale_index(row, j, tables_ref, positions_ref):
+        return kv_index(row, j, tables_ref, positions_ref)[:3]
 
-    def q_index(row, head, j, tables_ref, positions_ref):
-        return (row, head, 0, 0)
+    def q_index(row, j, tables_ref, positions_ref):
+        return (row, 0, 0)
 
+    # Queries ride as f32 rows, kv-head major (row = kv_head·group + g):
+    # a few KB that XLA widens once, so a head's rows are a plain
+    # sublane slice of an unpacked tile.
+    q_rows = q.reshape(batch, heads, head_dim).astype(jnp.float32)
+    block = (1, block_size, kv_heads, head_dim)
     in_specs = [
-        pl.BlockSpec((1, 1, group, head_dim), q_index),
-        pl.BlockSpec((1, block_size, 1, head_dim), kv_index),
-        pl.BlockSpec((1, block_size, 1, head_dim), kv_index),
+        pl.BlockSpec((1, heads, head_dim), q_index),
+        pl.BlockSpec(block, kv_index),
+        pl.BlockSpec(block, kv_index),
     ]
-    operands = [q, k_pool, v_pool]
+    operands = [q_rows, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, block_size, 1), scale_index),
-                     pl.BlockSpec((1, block_size, 1), scale_index)]
+        in_specs += [pl.BlockSpec(block[:3], scale_index),
+                     pl.BlockSpec(block[:3], scale_index)]
         operands += [ks, vs]
 
     kernel = functools.partial(
-        _paged_decode_kernel, block_size=block_size,
+        _paged_decode_kernel, block_size=block_size, group=group,
         sm_scale=sm_scale, window=window, quantized=quantized)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, group, head_dim), q_index),
+        out_specs=pl.BlockSpec((1, heads, head_dim), q_index),
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, head_dim), jnp.float32),
+            pltpu.VMEM((heads, 1), jnp.float32),
+            pltpu.VMEM((heads, 1), jnp.float32),
+            pltpu.VMEM((heads, head_dim), jnp.float32),
         ])
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_rows.shape, q.dtype),
         interpret=interpret,
     )(tables, positions, *operands)
+    return out.reshape(q.shape)

@@ -9,21 +9,24 @@ it, then scatters the result back into the pool
 before the first decode step, and a prefix-cache hit still pays the
 full gather.  The append kernel here removes both copies:
 
-* a **write kernel** (grid ``(row, kv-head, chunk-block)``) quantizes
-  (int8 layout) and lands the chunk's new K/V rows directly in the
-  row's pool blocks — the block table rides scalar prefetch, so the
-  output BlockSpec index map targets ``tables[row, cached//bs + cb]``
-  and the flush IS the pool write.  Blocks past ``chunk_len`` retarget
-  the allocator's reserved scratch block 0 (never attendable, the same
-  contract inactive decode lanes rely on).
-* an **attention kernel** (grid ``(row, kv-head, query-tile,
-  kv-block)``, kv fastest) runs flash-style online softmax for the
-  chunk's queries over the row's cached prefix blocks plus the
-  causally-visible part of the chunk itself, reading K/V straight from
-  the pool.  Per-row ``(cached_len, chunk_len)`` metadata rides scalar
-  prefetch; dead steps (blocks past the tile's last query, or wholly
-  below its sliding window) clamp their index map to a resident block
-  and skip compute, so a row's HBM traffic is O(its real history).
+* a **write kernel** (grid ``(row, chunk-block)``) lands the chunk's
+  new K/V rows — quantized by XLA with the cache writer's own
+  quantizer for int8 layouts — directly in the row's pool blocks, one
+  whole ``(block_size, kv_heads, head_dim)`` block per program: the
+  block table rides scalar prefetch, so the output BlockSpec index map
+  targets ``tables[row, cached//bs + cb]`` and the flush IS the pool
+  write.  Blocks past ``chunk_len`` retarget the allocator's reserved
+  scratch block 0 (never attendable, the same contract inactive decode
+  lanes rely on).
+* an **attention kernel** (grid ``(row, query-tile, kv-block)``, kv
+  fastest) runs flash-style online softmax for the chunk's queries
+  over the row's cached prefix blocks plus the causally-visible part
+  of the chunk itself, reading whole pool blocks and splitting heads
+  in-kernel (:func:`~.paged_attention.load_head_rows`).  Per-row
+  ``(cached_len, chunk_len)`` metadata rides scalar prefetch; dead
+  steps (blocks past the tile's last query, or wholly below its
+  sliding window) clamp their index map to a resident block and skip
+  compute, so a row's HBM traffic is O(its real history).
 * all ``group`` query heads of a kv head stack into the tile's row
   axis (``(q_tile·group, head_dim)``), so masking is per-row by
   absolute ids and every matmul is MXU-shaped 2D.
@@ -48,28 +51,32 @@ docs/KERNELS.md.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .attention import NEG_INF, _PALLAS_TPU
-from .paged_attention import cached_gqa_attention
-
-if _PALLAS_TPU:
-    from jax.experimental.pallas import tpu as pltpu
-else:  # pragma: no cover
-    pltpu = None
+from .attention import NEG_INF
+from .paged_attention import (MXU_PRECISION, cached_gqa_attention,
+                              kernel_mode, kernel_serves,
+                              load_head_rows, runs_kernel)
 
 __all__ = ["paged_prefill_attention", "paged_prefill_reference",
            "paged_verify_attention",
-           "prefill_kernel_mode", "prefill_attention_path"]
+           "prefill_kernel_mode", "prefill_dispatch",
+           "verify_dispatch", "prefill_attention_path"]
 
 #: Largest query tile (tokens) one attention program carries; the tile
 #: row axis is ``q_tile * group`` so this also bounds scratch size.
 Q_TILE_CAP = 128
+
+#: VMEM budget for one attention program's per-tile state.  Mosaic's
+#: default scoped limit is 16 MiB: at Llama-3-8B width a 128-query f32
+#: tile needed 19.2 MiB and was refused on the chip (PR 21), the bf16
+#: one (10 MiB of state) fits with its block buffers and temporaries.
+TILE_STATE_BYTES = 12 * 2**20
 
 
 # ---------------------------------------------------------------------------
@@ -77,37 +84,56 @@ Q_TILE_CAP = 128
 
 
 def prefill_kernel_mode() -> Tuple[bool, bool]:
-    """``(use_kernel, interpret)`` for the append-attention dispatch.
-
-    Controlled by ``AIKO_PREFILL_ATTENTION`` (read at TRACE time — set
-    it before the first admission of a given shape, jit caches traces):
-
-    * ``auto`` (default): kernel on TPU, jnp reference elsewhere.
-    * ``kernel``: force the kernel; off-TPU it runs in interpret mode
-      (slow — testing only).
-    * ``interpret``: kernel in interpret mode everywhere.
-    * ``reference`` / ``off`` / ``0``: always the jnp reference.
-    """
-    mode = os.environ.get("AIKO_PREFILL_ATTENTION", "auto").lower()
-    if mode in ("reference", "fallback", "off", "0"):
-        return False, False
-    on_tpu = jax.default_backend() == "tpu"
-    if mode in ("kernel", "force"):
-        return _PALLAS_TPU, not on_tpu
-    if mode == "interpret":
-        return _PALLAS_TPU, True
-    return _PALLAS_TPU and on_tpu, False
+    """:func:`~.paged_attention.kernel_mode` of
+    ``AIKO_PREFILL_ATTENTION`` — the append-attention twin of the
+    decode knob."""
+    return kernel_mode("AIKO_PREFILL_ATTENTION")
 
 
-def prefill_attention_path() -> str:
-    """``"kernel"`` or ``"reference"`` — the serving-counter path tag."""
-    return "kernel" if prefill_kernel_mode()[0] else "reference"
+def prefill_dispatch(head_dim: int, kv_heads: int, pool_dtype,
+                     block_size: int, chunk: int) -> Tuple[bool, bool]:
+    """``(use_kernel, interpret)`` for one admission geometry: the mode
+    from :func:`prefill_kernel_mode`, and the reference for what the
+    append kernels cannot serve — a pool
+    :func:`~.paged_attention.kernel_serves` refuses, or a ``chunk``
+    width that is not whole blocks — so nothing else runs under the
+    kernels' name.  The serving path tag is this same answer at the
+    server's admission slice."""
+    use_kernel, interpret = prefill_kernel_mode()
+    return (use_kernel and chunk % block_size == 0
+            and kernel_serves(head_dim, kv_heads, pool_dtype,
+                              interpret)), interpret
 
 
-def _q_tile_size(chunk: int) -> int:
-    """Default query tile: largest power-of-two divisor of ``chunk``,
-    capped at :data:`Q_TILE_CAP`."""
-    return min(chunk & -chunk, Q_TILE_CAP)
+def verify_dispatch(head_dim: int, kv_heads: int, pool_dtype,
+                    window_tokens: int) -> Tuple[bool, bool]:
+    """:func:`prefill_dispatch` for the speculative verify window: any
+    start position, at most :data:`Q_TILE_CAP` tokens."""
+    use_kernel, interpret = prefill_kernel_mode()
+    return (use_kernel and window_tokens <= Q_TILE_CAP
+            and kernel_serves(head_dim, kv_heads, pool_dtype,
+                              interpret)), interpret
+
+
+def prefill_attention_path(head_dim: int, kv_heads: int, pool_dtype,
+                           block_size: int, chunk: int) -> str:
+    """``"kernel"`` or ``"reference"`` — the serving-counter path tag,
+    decided by :func:`prefill_dispatch` at the server's real geometry."""
+    use_kernel, _ = prefill_dispatch(head_dim, kv_heads, pool_dtype,
+                                     block_size, chunk)
+    return "kernel" if use_kernel else "reference"
+
+
+def _q_tile_size(chunk: int, heads: int, itemsize: int) -> int:
+    """Default query tile: the largest power-of-two divisor of
+    ``chunk``, capped at :data:`Q_TILE_CAP` and at what
+    :data:`TILE_STATE_BYTES` holds.  A tile keeps, per query and query
+    head, one lane-padded 128-wide row of q and of the output (both
+    double-buffered, ``itemsize`` bytes) and of the f32 accumulator,
+    running max and denominator."""
+    per_query = heads * 128 * (4 * itemsize + 3 * 4)
+    fits = max(TILE_STATE_BYTES // per_query, 1)
+    return min(chunk & -chunk, Q_TILE_CAP, 1 << (fits.bit_length() - 1))
 
 
 # ---------------------------------------------------------------------------
@@ -126,23 +152,33 @@ def _kv_quantize_rows(rows):
     return q, scale
 
 
-def _write_rows_reference(pool, k_new, v_new, tables, positions):
-    """Scatter the chunk rows (every padded row — pad keys land past
-    every real query's visibility) into the pool at their absolute
-    positions; int8 layouts quantize exactly like the cache writer."""
-    block_size = pool["k"].shape[1]
-    block_ids = jnp.take_along_axis(tables, positions // block_size,
-                                    axis=1)
-    offsets = positions % block_size
+def _pool_rows(pool, k_new, v_new):
+    """The chunk's rows as the pool stores them: int8 layouts quantize
+    exactly like the cache writer, others cast to the pool dtype."""
     if "ks" in pool:
         kq, ks = _kv_quantize_rows(k_new)
         vq, vs = _kv_quantize_rows(v_new)
-        sources = {"k": kq, "v": vq, "ks": ks, "vs": vs}
-    else:
-        sources = {"k": k_new, "v": v_new}
-    return {key: pool[key].at[block_ids, offsets].set(
-                src.astype(pool[key].dtype))
-            for key, src in sources.items()}
+        return {"k": kq, "v": vq, "ks": ks, "vs": vs}
+    return {"k": k_new.astype(pool["k"].dtype),
+            "v": v_new.astype(pool["v"].dtype)}
+
+
+def _write_rows(pool, k_new, v_new, tables, positions, chunk_lens=None):
+    """Scatter the chunk rows into the pool at their absolute positions
+    — one ``(kv_heads, head_dim)`` row per token, the pool's own tile.
+    Without ``chunk_lens`` every padded row lands (pad keys sit past
+    every real query's visibility); with it, rows at or past a row's
+    chunk length retarget reserved scratch block 0."""
+    block_size = pool["k"].shape[1]
+    block_ids = jnp.take_along_axis(tables, positions // block_size,
+                                    axis=1)
+    if chunk_lens is not None:
+        real = (jnp.arange(positions.shape[1], dtype=jnp.int32)[None, :]
+                < chunk_lens[:, None])
+        block_ids = jnp.where(real, block_ids, 0)
+    offsets = positions % block_size
+    return {key: pool[key].at[block_ids, offsets].set(src)
+            for key, src in _pool_rows(pool, k_new, v_new).items()}
 
 
 def paged_prefill_reference(q, k_new, v_new, pool, tables, cached_lens,
@@ -161,8 +197,7 @@ def paged_prefill_reference(q, k_new, v_new, pool, tables, cached_lens,
     hd = q.shape[-1]
     positions = (cached_lens.astype(jnp.int32)[:, None]
                  + jnp.arange(T, dtype=jnp.int32)[None, :])
-    new_pool = _write_rows_reference(pool, k_new, v_new, tables,
-                                     positions)
+    new_pool = _write_rows(pool, k_new, v_new, tables, positions)
 
     def view(buf):
         gathered = buf[tables]
@@ -181,50 +216,38 @@ def paged_prefill_reference(q, k_new, v_new, pool, tables, cached_lens,
 
 
 def _append_kv_kernel(tables_ref, meta_ref,        # scalar prefetch
-                      k_new_ref, v_new_ref, k_in_ref, v_in_ref, *rest,
-                      quantized: bool):
-    """Grid: (batch, kv_heads, chunk_blocks).  One program moves one
-    (row, kv-head) chunk block from the activation slab into the pool
-    block the index map resolved from the prefetched table — the
-    output flush IS the pool write.  Dead steps (block past
-    ``chunk_len``) still flush, but the index map retargeted them at
-    reserved scratch block 0, which is never attendable."""
-    if quantized:
-        _ks_in, _vs_in, k_out, v_out, ks_out, vs_out = rest
-    else:
-        k_out, v_out = rest
-    k = k_new_ref[0, :, 0]                      # (bs, hd)
-    v = v_new_ref[0, :, 0]
-    if quantized:
-        for new, out, scale_out in ((k, k_out, ks_out),
-                                    (v, v_out, vs_out)):
-            r32 = new.astype(jnp.float32)
-            amax = jnp.max(jnp.abs(r32), axis=-1, keepdims=True)
-            scale = jnp.where(amax == 0, 1.0, amax / 127.0)  # (bs, 1)
-            out[0, :, 0] = jnp.clip(jnp.round(r32 / scale),
-                                    -127, 127).astype(out.dtype)
-            scale_out[0] = scale
-    else:
-        k_out[0, :, 0] = k.astype(k_out.dtype)
-        v_out[0, :, 0] = v.astype(v_out.dtype)
+                      *refs):
+    """Grid: (batch, chunk_blocks).  One program moves one row's chunk
+    block — every kv head, and the scale planes of an int8 layout —
+    from the activation slabs into the pool block the index map
+    resolved from the prefetched table: the output flush IS the pool
+    write.  Dead steps (block past ``chunk_len``) still flush, but the
+    index map retargeted them at reserved scratch block 0, which is
+    never attendable.  ``refs`` is (slabs…, aliased pools…, outputs…);
+    the aliased pool inputs stay in HBM untouched."""
+    n = len(refs) // 3
+    for src, dst in zip(refs[:n], refs[2 * n:]):
+        dst[...] = src[...]
 
 
 def _append_kv(k_new, v_new, pool, tables, meta, interpret: bool):
     """Write the (batch, T, kv, hd) chunk slabs into the pool blocks
     named by ``tables`` starting at block ``cached // bs`` — in-kernel,
     via aliased pool outputs whose index maps resolve the target block
-    from the scalar-prefetched table."""
-    batch, T, kv_heads, head_dim = k_new.shape
+    from the scalar-prefetched table.  The tile is one whole pool
+    block ``(block_size, kv_heads, head_dim)``: its last two dimensions
+    are the array's own, the only cut of this layout Mosaic's tiling
+    accepts, and int8 rows arrive already quantized so the body is a
+    copy."""
+    batch, T = k_new.shape[:2]
     block_size = pool["k"].shape[1]
     max_blocks = tables.shape[1]
-    quantized = "ks" in pool
-    chunk_blocks = T // block_size
-    grid = (batch, kv_heads, chunk_blocks)
+    grid = (batch, T // block_size)
 
-    def new_index(b, h, cb, tables_ref, meta_ref):
-        return (b, cb, h, 0)
+    def new_index(b, cb, tables_ref, meta_ref):
+        return (b, cb, 0, 0)
 
-    def pool_index(b, h, cb, tables_ref, meta_ref):
+    def pool_index(b, cb, tables_ref, meta_ref):
         # Blocks past the row's real chunk length flush garbage — but
         # into reserved scratch block 0, exactly like inactive decode
         # lanes.  The live-block table lookup is clamped so dead steps
@@ -232,46 +255,36 @@ def _append_kv(k_new, v_new, pool, tables, meta, interpret: bool):
         live = cb * block_size < meta_ref[b, 1]
         entry = jnp.minimum(meta_ref[b, 0] // block_size + cb,
                             max_blocks - 1)
-        return (jnp.where(live, tables_ref[b, entry], 0), 0, h, 0)
+        return (jnp.where(live, tables_ref[b, entry], 0), 0, 0, 0)
 
-    def scale_index(b, h, cb, tables_ref, meta_ref):
-        return pool_index(b, h, cb, tables_ref, meta_ref)[:3]
+    def block_spec(index_map, buf):
+        # k/v tiles are whole blocks, scale planes the same minus the
+        # head_dim axis: one index map, cut to the buffer's rank.
+        tile = (1, block_size) + buf.shape[2:]
+        return pl.BlockSpec(
+            tile, lambda *args: index_map(*args)[:len(tile)])
 
-    kv_spec = pl.BlockSpec((1, block_size, 1, head_dim), new_index)
-    pool_spec = pl.BlockSpec((1, block_size, 1, head_dim), pool_index)
-    scale_spec = pl.BlockSpec((1, block_size, 1), scale_index)
-
-    in_specs = [kv_spec, kv_spec, pool_spec, pool_spec]
-    operands = [k_new, v_new, pool["k"], pool["v"]]
-    out_specs = [pool_spec, pool_spec]
-    out_shape = [jax.ShapeDtypeStruct(pool["k"].shape, pool["k"].dtype),
-                 jax.ShapeDtypeStruct(pool["v"].shape, pool["v"].dtype)]
-    # Aliased pool operands: positions count scalar-prefetch args, so
-    # (tables, meta, k_new, v_new, k, v[, ks, vs]) puts the pools at 4+.
-    aliases = {4: 0, 5: 1}
-    if quantized:
-        in_specs += [scale_spec, scale_spec]
-        operands += [pool["ks"], pool["vs"]]
-        out_specs += [scale_spec, scale_spec]
-        out_shape += [
-            jax.ShapeDtypeStruct(pool["ks"].shape, pool["ks"].dtype),
-            jax.ShapeDtypeStruct(pool["vs"].shape, pool["vs"].dtype)]
-        aliases.update({6: 2, 7: 3})
-
+    rows = _pool_rows(pool, k_new, v_new)
+    keys = list(rows)
+    slab_specs = [block_spec(new_index, pool[key]) for key in keys]
+    pool_specs = [block_spec(pool_index, pool[key]) for key in keys]
+    n = len(keys)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=grid,
-        in_specs=in_specs, out_specs=out_specs)
+        in_specs=slab_specs + [pl.BlockSpec(memory_space=pl.ANY)] * n,
+        out_specs=pool_specs)
     outs = pl.pallas_call(
-        functools.partial(_append_kv_kernel, quantized=quantized),
+        _append_kv_kernel,
         grid_spec=grid_spec,
-        out_shape=out_shape,
-        input_output_aliases=aliases,
+        out_shape=[jax.ShapeDtypeStruct(pool[key].shape, pool[key].dtype)
+                   for key in keys],
+        # Operand positions count the scalar-prefetch args: (tables,
+        # meta, slabs…, pools…) puts pool i at 2 + n + i.
+        input_output_aliases={2 + n + i: i for i in range(n)},
         interpret=interpret,
-    )(tables, meta, *operands)
-    new_pool = {"k": outs[0], "v": outs[1]}
-    if quantized:
-        new_pool["ks"], new_pool["vs"] = outs[2], outs[3]
-    return new_pool
+    )(tables, meta, *(rows[key] for key in keys),
+      *(pool[key] for key in keys))
+    return dict(zip(keys, outs))
 
 
 # ---------------------------------------------------------------------------
@@ -283,24 +296,27 @@ def _prefill_attention_kernel(tables_ref, meta_ref,   # scalar prefetch
                               block_size: int, q_tile: int, group: int,
                               sm_scale: float, window: Optional[int],
                               quantized: bool):
-    """Grid: (batch, kv_heads, q_tiles, kv_blocks); kv fastest.
+    """Grid: (batch, q_tiles, kv_blocks); kv fastest.
 
-    One program sweeps one (row, kv-head, query-tile) through the
-    row's pool blocks carrying online-softmax state in VMEM scratch.
-    The tile's row axis interleaves queries and their group heads
-    (``row = token·group + head``), so per-row masking by absolute ids
-    covers ragged causality AND the sliding window in one 2D tile."""
+    One program sweeps one (row, query-tile), every kv head, through
+    the row's pool blocks carrying online-softmax state in VMEM
+    scratch.  The tile's row axis interleaves queries and their group
+    heads (``row = token·group + head``), so per-row masking by
+    absolute ids covers ragged causality AND the sliding window in one
+    2D tile."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    qt = pl.program_id(2)
-    j = pl.program_id(3)
-    num_j = pl.num_programs(3)
+    qt = pl.program_id(1)
+    j = pl.program_id(2)
+    num_j = pl.num_programs(2)
     cached = meta_ref[b, 0]
     q_min = cached + qt * q_tile          # tile's first query position
     q_max = q_min + q_tile - 1            # tile's last query position
+    kv_heads = k_ref.shape[2]
+    rows = q_tile * group
 
     @pl.when(j == 0)
     def _init():
@@ -319,46 +335,53 @@ def _prefill_attention_kernel(tables_ref, meta_ref,   # scalar prefetch
 
     @pl.when(block_live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)       # (q_tile*group, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)    # (bs, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0]
-            v = v * vs_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-
         q_ids = q_min + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0) // group
+            jnp.int32, (rows, block_size), 0) // group
         key_ids = jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1) + j * block_size
+            jnp.int32, (rows, block_size), 1) + j * block_size
         visible = key_ids <= q_ids
         if window is not None:
             visible &= key_ids > q_ids - window
-        s = jnp.where(visible, s, NEG_INF)
+        if quantized:
+            k_scales = ks_ref[0]                   # (bs, kv_heads)
+            v_scales = vs_ref[0]
+        for head in range(kv_heads):
+            q = q_ref[0, head].astype(jnp.float32)    # (rows, hd)
+            k = load_head_rows(k_ref.at[0], head)     # (bs, hd) f32
+            v = load_head_rows(v_ref.at[0], head)
+            if quantized:
+                k = k * k_scales[:, head:head + 1]
+                v = v * v_scales[:, head:head + 1]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), precision=MXU_PRECISION,
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(visible, s, NEG_INF)
 
-        m_prev = m_scr[:]                         # (q_tile*group, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # A live block can hold rows with NO visible key (later chunk
-        # rows, or a window that slid past): their m stays NEG_INF and
-        # exp(NEG_INF - NEG_INF) = 1 would be bogus mass — zero masked
-        # probabilities explicitly (the single-query decode kernel's
-        # every-live-block-has-a-visible-key invariant does not extend
-        # to multi-query tiles).
-        p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
-        correction = jnp.exp(m_prev - m_new)
-        l_scr[:] = correction * l_scr[:] + jnp.sum(p, axis=-1,
-                                                   keepdims=True)
-        acc_scr[:] = acc_scr[:] * correction + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
+            m_prev = m_scr[head]                      # (rows, 1)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=-1, keepdims=True))
+            # A live block can hold rows with NO visible key (later
+            # chunk rows, or a window that slid past): their m stays
+            # NEG_INF and exp(NEG_INF - NEG_INF) = 1 would be bogus
+            # mass — zero masked probabilities explicitly (the
+            # single-query decode kernel's
+            # every-live-block-has-a-visible-key invariant does not
+            # extend to multi-query tiles).
+            p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+            correction = jnp.exp(m_prev - m_new)
+            l_scr[head] = correction * l_scr[head] + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_scr[head] = (
+                acc_scr[head] * correction + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    precision=MXU_PRECISION,
+                    preferred_element_type=jnp.float32))
+            m_scr[head] = m_new
 
     @pl.when(j == num_j - 1)
     def _finish():
         denom = jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
-        o_ref[0, 0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
 
 
 def _chunk_attention(q, pool, tables, meta, window: Optional[int],
@@ -371,16 +394,16 @@ def _chunk_attention(q, pool, tables, meta, window: Optional[int],
     quantized = "ks" in pool
     # All group heads of a query stack into the tile row axis: 2D tiles
     # everywhere in-kernel, one (q_tile*group, hd) x (hd, bs) matmul
-    # per block.
+    # per head per block.
     q_r = q.transpose(0, 2, 1, 3, 4).reshape(batch, kv_heads,
                                              T * group, head_dim)
-    grid = (batch, kv_heads, T // q_tile, kv_blocks)
+    grid = (batch, T // q_tile, kv_blocks)
     rows = q_tile * group
 
-    def q_index(b, h, qt, j, tables_ref, meta_ref):
-        return (b, h, qt, 0)
+    def q_index(b, qt, j, tables_ref, meta_ref):
+        return (b, 0, qt, 0)
 
-    def kv_index(b, h, qt, j, tables_ref, meta_ref):
+    def kv_index(b, qt, j, tables_ref, meta_ref):
         # Clamp dead steps into the tile's live band: an unchanged
         # block index makes Pallas reuse the resident VMEM tile
         # instead of issuing a fresh HBM copy.
@@ -391,20 +414,22 @@ def _chunk_attention(q, pool, tables, meta, window: Optional[int],
             first_live = jnp.maximum(
                 cached + qt * q_tile - window + 1, 0) // block_size
             j_c = jnp.maximum(j_c, first_live)
-        return (tables_ref[b, j_c], 0, h, 0)
+        return (tables_ref[b, j_c], 0, 0, 0)
 
-    def scale_index(b, h, qt, j, tables_ref, meta_ref):
-        return kv_index(b, h, qt, j, tables_ref, meta_ref)[:3]
+    def scale_index(b, qt, j, tables_ref, meta_ref):
+        return kv_index(b, qt, j, tables_ref, meta_ref)[:3]
 
+    block = (1, block_size, kv_heads, head_dim)
+    q_block = (1, kv_heads, rows, head_dim)
     in_specs = [
-        pl.BlockSpec((1, 1, rows, head_dim), q_index),
-        pl.BlockSpec((1, block_size, 1, head_dim), kv_index),
-        pl.BlockSpec((1, block_size, 1, head_dim), kv_index),
+        pl.BlockSpec(q_block, q_index),
+        pl.BlockSpec(block, kv_index),
+        pl.BlockSpec(block, kv_index),
     ]
     operands = [q_r, pool["k"], pool["v"]]
     if quantized:
-        in_specs += [pl.BlockSpec((1, block_size, 1), scale_index),
-                     pl.BlockSpec((1, block_size, 1), scale_index)]
+        in_specs += [pl.BlockSpec(block[:3], scale_index),
+                     pl.BlockSpec(block[:3], scale_index)]
         operands += [pool["ks"], pool["vs"]]
 
     kernel = functools.partial(
@@ -415,11 +440,11 @@ def _chunk_attention(q, pool, tables, meta, window: Optional[int],
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rows, head_dim), q_index),
+        out_specs=pl.BlockSpec(q_block, q_index),
         scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, head_dim), jnp.float32),
+            pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+            pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+            pltpu.VMEM((kv_heads, rows, head_dim), jnp.float32),
         ])
     out_r = pl.pallas_call(
         kernel,
@@ -449,7 +474,7 @@ def paged_prefill_attention(q, k_new, v_new, pool, tables, cached_lens,
         positions ``cached_lens[row] + [0, T)``.
       pool: per-layer dict ``{"k", "v"[, "ks", "vs"]}`` of
         ``(n_blocks, block_size, kv_heads, head_dim)`` block pools
-        (int8 layouts quantize in-kernel, absmax per (token, head)).
+        (int8 layouts quantize absmax per (token, head)).
       tables: ``(batch, max_blocks)`` int32 block table; entries
         covering ``[0, cached + T)`` must be allocated.
       cached_lens: ``(batch,)`` int32 — tokens already in the pool for
@@ -471,9 +496,9 @@ def paged_prefill_attention(q, k_new, v_new, pool, tables, cached_lens,
         is much longer than the row can be.
 
     Returns ``(out (batch, T, kv_heads, group, head_dim) in q.dtype,
-    new_pool)``.  Falls back to :func:`paged_prefill_reference` when
-    Pallas TPU is unavailable (and not interpreting) or the shape is
-    unsupported (``head_dim > 128``, ``T`` not block-aligned).
+    new_pool)``.  Off the TPU backend (and not interpreting) this IS
+    :func:`paged_prefill_reference`; see
+    :func:`~.paged_attention.runs_kernel`.
     """
     batch, T, kv_heads, group, head_dim = q.shape
     block_size = pool["k"].shape[1]
@@ -481,18 +506,22 @@ def paged_prefill_attention(q, k_new, v_new, pool, tables, cached_lens,
     if sm_scale is None:
         sm_scale = head_dim ** -0.5
 
-    on_tpu = jax.default_backend() == "tpu"
-    if (not (_PALLAS_TPU and (on_tpu or interpret))
-            or head_dim > 128 or T % block_size != 0):
+    if not runs_kernel(interpret):
         return paged_prefill_reference(q, k_new, v_new, pool, tables,
                                        cached_lens, chunk_lens,
                                        window=window)
+    if T % block_size or not kernel_serves(head_dim, kv_heads,
+                                           pool["k"].dtype, interpret):
+        raise ValueError(
+            f"paged append kernel cannot serve head_dim={head_dim} "
+            f"kv_heads={kv_heads} {pool['k'].dtype} pools, chunk width "
+            f"{T} over {block_size}-token blocks")
 
     tables = tables.astype(jnp.int32)
     meta = jnp.stack([cached_lens.astype(jnp.int32),
                       chunk_lens.astype(jnp.int32)], axis=1)
     if q_tile is None:
-        q_tile = _q_tile_size(T)
+        q_tile = _q_tile_size(T, kv_heads * group, q.dtype.itemsize)
     if T % q_tile:
         raise ValueError(f"q_tile {q_tile} must divide chunk width {T}")
     kv_blocks = max_blocks if kv_limit is None else min(kv_limit,
@@ -511,126 +540,6 @@ def paged_prefill_attention(q, k_new, v_new, pool, tables, cached_lens,
 # starts mid-block at its own decode position)
 
 
-def _append_kv_ragged_kernel(tables_ref, meta_ref,     # scalar prefetch
-                             k_new_ref, v_new_ref, k_in_ref, v_in_ref,
-                             *rest, block_size: int, span: int,
-                             quantized: bool):
-    """Grid: (batch, kv_heads, span_blocks).  One program MERGES the
-    row's verify slab into one pool block: unlike the aligned chunk
-    writer (whole-block overwrite), a verify window starts mid-block,
-    so the program reads the resident block, replaces only the rows in
-    ``[cached, cached + chunk_len)``, and flushes the merge back.
-
-    Row selection is an unrolled ``jnp.where`` sweep over the slab (2D
-    tiles only, no gather): exact value passthrough, so the int8 quant
-    below is bit-identical to the aligned writer's per-row absmax."""
-    if quantized:
-        ks_in, vs_in, k_out, v_out, ks_out, vs_out = rest
-    else:
-        k_out, v_out = rest
-    b = pl.program_id(0)
-    sb = pl.program_id(2)
-    cached = meta_ref[b, 0]
-    chunk_len = meta_ref[b, 1]
-    # Token index held by this block's row 0 (negative in the first
-    # block of an unaligned span: rows before ``cached`` keep their
-    # committed values).
-    entry = cached // block_size + sb
-    base = entry * block_size - cached
-    t = base + jax.lax.broadcasted_iota(jnp.int32, (block_size, 1), 0)
-    row_new = (t >= 0) & (t < chunk_len)
-
-    def select(slab_ref):
-        slab = slab_ref[0, :, 0].astype(jnp.float32)      # (span, hd)
-        acc = jnp.zeros((block_size, slab.shape[-1]), jnp.float32)
-        for tt in range(span):
-            acc = jnp.where(t == tt, slab[tt:tt + 1, :], acc)
-        return acc
-
-    if quantized:
-        for slab_ref, in_ref, s_in, out, s_out in (
-                (k_new_ref, k_in_ref, ks_in, k_out, ks_out),
-                (v_new_ref, v_in_ref, vs_in, v_out, vs_out)):
-            r32 = select(slab_ref)
-            amax = jnp.max(jnp.abs(r32), axis=-1, keepdims=True)
-            scale = jnp.where(amax == 0, 1.0, amax / 127.0)  # (bs, 1)
-            rows_q = jnp.clip(jnp.round(r32 / scale),
-                              -127, 127).astype(out.dtype)
-            out[0, :, 0] = jnp.where(row_new, rows_q, in_ref[0, :, 0])
-            s_out[0] = jnp.where(row_new, scale, s_in[0])
-    else:
-        for slab_ref, in_ref, out in ((k_new_ref, k_in_ref, k_out),
-                                      (v_new_ref, v_in_ref, v_out)):
-            rows = select(slab_ref).astype(out.dtype)
-            out[0, :, 0] = jnp.where(row_new, rows, in_ref[0, :, 0])
-
-
-def _append_kv_ragged(k_new, v_new, pool, tables, meta,
-                      interpret: bool):
-    """Merge (batch, T, kv, hd) verify slabs into pool blocks at
-    arbitrary (unaligned) per-row start positions ``meta[:, 0]``.
-    Blocks outside a row's live span — and every block of a row with
-    ``chunk_len == 0`` — retarget reserved scratch block 0 and write
-    back what they read (identity flush)."""
-    batch, T, kv_heads, head_dim = k_new.shape
-    block_size = pool["k"].shape[1]
-    max_blocks = tables.shape[1]
-    quantized = "ks" in pool
-    # An unaligned span of T rows straddles at most ceil(T/bs)+1 blocks.
-    span_blocks = -(-T // block_size) + 1
-    grid = (batch, kv_heads, span_blocks)
-
-    def new_index(b, h, sb, tables_ref, meta_ref):
-        return (b, 0, h, 0)
-
-    def pool_index(b, h, sb, tables_ref, meta_ref):
-        cached = meta_ref[b, 0]
-        entry = cached // block_size + sb
-        live = (entry * block_size < cached + meta_ref[b, 1]) \
-            & (meta_ref[b, 1] > 0)
-        entry = jnp.minimum(entry, max_blocks - 1)
-        return (jnp.where(live, tables_ref[b, entry], 0), 0, h, 0)
-
-    def scale_index(b, h, sb, tables_ref, meta_ref):
-        return pool_index(b, h, sb, tables_ref, meta_ref)[:3]
-
-    kv_spec = pl.BlockSpec((1, T, 1, head_dim), new_index)
-    pool_spec = pl.BlockSpec((1, block_size, 1, head_dim), pool_index)
-    scale_spec = pl.BlockSpec((1, block_size, 1), scale_index)
-
-    in_specs = [kv_spec, kv_spec, pool_spec, pool_spec]
-    operands = [k_new, v_new, pool["k"], pool["v"]]
-    out_specs = [pool_spec, pool_spec]
-    out_shape = [jax.ShapeDtypeStruct(pool["k"].shape, pool["k"].dtype),
-                 jax.ShapeDtypeStruct(pool["v"].shape, pool["v"].dtype)]
-    aliases = {4: 0, 5: 1}
-    if quantized:
-        in_specs += [scale_spec, scale_spec]
-        operands += [pool["ks"], pool["vs"]]
-        out_specs += [scale_spec, scale_spec]
-        out_shape += [
-            jax.ShapeDtypeStruct(pool["ks"].shape, pool["ks"].dtype),
-            jax.ShapeDtypeStruct(pool["vs"].shape, pool["vs"].dtype)]
-        aliases.update({6: 2, 7: 3})
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=grid,
-        in_specs=in_specs, out_specs=out_specs)
-    outs = pl.pallas_call(
-        functools.partial(_append_kv_ragged_kernel,
-                          block_size=block_size, span=T,
-                          quantized=quantized),
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(tables, meta, *operands)
-    new_pool = {"k": outs[0], "v": outs[1]}
-    if quantized:
-        new_pool["ks"], new_pool["vs"] = outs[2], outs[3]
-    return new_pool
-
-
 def paged_verify_attention(q, k_new, v_new, pool, tables, cached_lens,
                            chunk_lens, window: Optional[int] = None,
                            sm_scale: Optional[float] = None,
@@ -643,11 +552,13 @@ def paged_verify_attention(q, k_new, v_new, pool, tables, cached_lens,
     Two contract differences from the prefill entry:
 
     * ``cached_lens`` need NOT be block-aligned — each slot verifies at
-      its own decode position, so the write kernel merges into the
-      partial first block instead of overwriting whole blocks.
+      its own decode position.  A window that starts mid-block cannot
+      land as whole blocks, so its rows are scattered one
+      ``(kv_heads, head_dim)`` tile per token, the way single-token
+      decode writes its row (:func:`_write_rows`).
     * ``chunk_lens`` may vary per row (ragged k across the batch); rows
-      with ``chunk_lens[row] == 0`` (inactive slots) write nothing at
-      all — their programs identity-flush scratch block 0.
+      at or past ``chunk_lens[row]`` — every row of an inactive slot —
+      land in reserved scratch block 0.
 
     ``T`` (the slab width) is padded internally to a power of two ≥ 16
     so the attention tile satisfies the TPU sublane floor; pad rows are
@@ -657,36 +568,39 @@ def paged_verify_attention(q, k_new, v_new, pool, tables, cached_lens,
     verify pass reads each row's real history once — no pool gather.
 
     Returns ``(out (batch, T, kv_heads, group, head_dim), new_pool)``.
-    Falls back to :func:`paged_prefill_reference` (which supports
-    arbitrary per-row positions natively) off-TPU or for
-    ``head_dim > 128`` / ``T > Q_TILE_CAP``.
+    Off the TPU backend (and not interpreting) this IS
+    :func:`paged_prefill_reference` (which supports arbitrary per-row
+    positions natively); see :func:`~.paged_attention.runs_kernel`.
     """
     batch, T, kv_heads, group, head_dim = q.shape
     max_blocks = tables.shape[1]
     if sm_scale is None:
         sm_scale = head_dim ** -0.5
 
-    on_tpu = jax.default_backend() == "tpu"
-    if (not (_PALLAS_TPU and (on_tpu or interpret))
-            or head_dim > 128 or T > Q_TILE_CAP):
+    if not runs_kernel(interpret):
         return paged_prefill_reference(q, k_new, v_new, pool, tables,
                                        cached_lens, chunk_lens,
                                        window=window)
+    if T > Q_TILE_CAP or not kernel_serves(head_dim, kv_heads,
+                                           pool["k"].dtype, interpret):
+        raise ValueError(
+            f"paged verify kernel cannot serve head_dim={head_dim} "
+            f"kv_heads={kv_heads} {pool['k'].dtype} pools, window {T} "
+            f"(one query tile holds {Q_TILE_CAP})")
+
+    tables = tables.astype(jnp.int32)
+    cached_lens = cached_lens.astype(jnp.int32)
+    chunk_lens = chunk_lens.astype(jnp.int32)
+    positions = cached_lens[:, None] + jnp.arange(T, dtype=jnp.int32)
+    new_pool = _write_rows(pool, k_new, v_new, tables, positions,
+                           chunk_lens)
 
     Tp = max(16, 1 << (T - 1).bit_length())
     if Tp != T:
-        pad = ((0, 0), (0, Tp - T)) + ((0, 0),) * (q.ndim - 2)
-        q = jnp.pad(q, pad)
-        k_new = jnp.pad(k_new, pad[:k_new.ndim])
-        v_new = jnp.pad(v_new, pad[:v_new.ndim])
-
-    tables = tables.astype(jnp.int32)
-    meta = jnp.stack([cached_lens.astype(jnp.int32),
-                      chunk_lens.astype(jnp.int32)], axis=1)
+        q = jnp.pad(q, ((0, 0), (0, Tp - T)) + ((0, 0),) * (q.ndim - 2))
+    meta = jnp.stack([cached_lens, chunk_lens], axis=1)
     kv_blocks = max_blocks if kv_limit is None else min(kv_limit,
                                                         max_blocks)
-    new_pool = _append_kv_ragged(k_new, v_new, pool, tables, meta,
-                                 interpret)
     out = _chunk_attention(q, new_pool, tables, meta, window=window,
                            sm_scale=sm_scale, q_tile=Tp,
                            kv_blocks=kv_blocks, interpret=interpret)

@@ -566,6 +566,21 @@ class PagedContinuousServer(ContinuousBatchingServer):
         # Real pool geometry: the kernel walks the slot's block table.
         return self.block_size, self.tables.shape[1]
 
+    def _kv_geometry(self):
+        head_dim, kv_heads, dtype = super()._kv_geometry()
+        return head_dim, kv_heads // self.tp_degree, dtype
+
+    def _attention_paths(self):
+        """Decode walks the block table; admission appends slices of
+        ``chunk_prefill_tokens`` (whole pow2 buckets when 0)."""
+        from ..ops.paged_attention import decode_attention_path
+        from ..ops.paged_prefill import prefill_attention_path
+        geometry = self._kv_geometry()
+        chunk = self.chunk_prefill_tokens or self._bucket_minimum
+        return (decode_attention_path(*geometry),
+                prefill_attention_path(*geometry, self.block_size,
+                                       chunk))
+
     def _blocks_for(self, rows: int) -> int:
         return math.ceil(rows / self.block_size)
 
@@ -1242,7 +1257,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
             # descending power-of-two pieces, so arbitrary prefix
             # lengths reuse log-many program shapes instead of being
             # rounded down (the old pow2 truncation threw away up to
-            # half the hit — the BENCH_r05 low-hit-rate culprit).
+            # half the hit).
         # PIN the hits before any eviction (eviction must never free a
         # block we are about to reference), with rollback on deferral.
         # Snapshot the LRU order first: a deferred request never ran,
@@ -1957,8 +1972,8 @@ class PagedContinuousServer(ContinuousBatchingServer):
     def _spec_verify(self, st, chunk, lora):
         """Pool-direct verify: the (slots, k+1) window's K/V appends
         straight into each slot's table-resolved blocks (ragged
-        starts, in-kernel int8 quant — no gather, no bucket,
-        jaxpr-guarded in tests/test_spec_paged.py), logits come back
+        starts — no gather, no bucket, jaxpr-guarded in
+        tests/test_spec_paged.py), logits come back
         for the acceptance kernel.  Inactive rows (chunked prefills in
         flight, free slots) write scratch block 0.  Rejected tails
         stay as stale rows behind the absolute-position mask; the

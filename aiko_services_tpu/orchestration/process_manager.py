@@ -5,6 +5,15 @@ process_manager.py:48-110``.  ``create(id, command, arguments)`` resolves
 python-module commands to the interpreter, Popens the child, and a poll
 timer (0.2 s) detects exits and fires the exit handler;
 ``delete(id, kill=…)`` terminates or kills.
+
+One process per chip: an accelerator belongs to one OS process at a
+time.  A parent that has initialised a JAX backend on the chip holds
+it, and a child that needs it then fails or hangs — so a supervisor
+that launches chip-owning replicas stays off JAX itself (the
+autoscaler and the control-plane CLIs do), and children that only need
+a control plane are launched with ``env={"JAX_PLATFORMS": "cpu"}``.
+Children inherit the parent's environment, including the compile
+cache placement exported by ``obs.compiles.entry_point_cache``.
 """
 
 from __future__ import annotations

@@ -28,28 +28,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["allgather_matmul", "matmul_reducescatter",
            "allgather_matmul_sharded", "matmul_reducescatter_sharded"]
 
 
-def _axis_size(axis_name) -> int:
-    """Static mesh-axis size inside a shard_map body.
-    ``jax.lax.axis_size`` only exists on newer jax; ``psum`` of a
-    literal 1 is constant-folded to a python int on every version."""
-    if hasattr(jax.lax, "axis_size"):      # jax >= 0.6
-        return int(jax.lax.axis_size(axis_name))
-    return int(jax.lax.psum(1, axis_name))
-
-
 def _ring_perm(axis_name):
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     return [(i, (i + 1) % size) for i in range(size)]
 
 
@@ -66,7 +53,7 @@ def allgather_matmul(x_shard, w_shard, axis_name: str):
     offset.  After ``axis_size`` steps every device has computed the
     full gathered product against its own weight shard.
     """
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     index = jax.lax.axis_index(axis_name)
     perm = _ring_perm(axis_name)
     m_local = x_shard.shape[0]
@@ -103,7 +90,7 @@ def matmul_reducescatter(x_shard, w_shard, axis_name: str):
     x/w shard against the column slice owned by the device the
     accumulator is travelling toward, adds, and forwards.
     """
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     index = jax.lax.axis_index(axis_name)
     perm = _ring_perm(axis_name)
     m = x_shard.shape[0]

@@ -147,10 +147,7 @@ def initialize_multihost(config: Optional[MultiHostConfig] = None,
     platforms = (jax.config.jax_platforms or
                  os.environ.get("JAX_PLATFORMS", ""))
     if "cpu" in (platforms or ""):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 - older jaxlib: single impl
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     initialize = _initialize or jax.distributed.initialize
     try:

@@ -240,10 +240,6 @@ def filter_specs_for_mesh(specs, mesh: Mesh):
 def mark_varying(x, axis_name):
     """shard_map varying-axis tracking: loop carries that pass through
     ``ppermute`` become axis-varying, so zero-inits must be marked
-    varying too.  Single home for the jax version dispatch."""
+    varying too."""
     import jax
-    if hasattr(jax.lax, "pcast"):          # jax >= 0.8
-        return jax.lax.pcast(x, axis_name, to="varying")
-    if hasattr(jax.lax, "pvary"):          # deprecated predecessor
-        return jax.lax.pvary(x, axis_name)
-    return x
+    return jax.lax.pcast(x, axis_name, to="varying")

@@ -14,6 +14,7 @@ import sys
 
 import click
 
+from ..obs import compiles
 from ..utils.sexpr import parse_tree
 from ..runtime.context import pipeline_args, compose_instance
 from ..runtime.process import default_process
@@ -50,6 +51,7 @@ def main():
 def create(definition_pathname, name, graph_path, stream_id,
            stream_parameters, frame_data, frame_count, frame_rate,
            grace_time, show_response, no_stream):
+    compiles.entry_point_cache()
     definition = load_pipeline_definition(definition_pathname)
     process = default_process()
     pipeline = compose_instance(

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import click
 
+from ..obs import compiles
 from ..runtime.process import default_process
 from .registrar import Registrar
 
@@ -11,6 +12,9 @@ from .registrar import Registrar
 @click.command()
 @click.option("--name", default="registrar")
 def main(name):
+    # The registrar compiles nothing itself; the placement is exported
+    # for whatever it launches.
+    compiles.entry_point_cache()
     process = default_process()
     Registrar(process=process)
     try:

@@ -19,6 +19,7 @@ built-in MQTT broker cross-process).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import statistics
@@ -618,8 +619,7 @@ def service_scale_sweep(services: int, broker: str = "scale-sweep",
     dispatch path.  Raises AssertionError if discovery or any RPC is
     incomplete within its own (separate) timeout budget.
 
-    Shared by ``tests/test_scale.py`` and the distributed-artifact
-    capture (``scripts/capture_cpu_artifacts.py``)."""
+    Used by ``tests/test_scale.py``."""
     import time as time_module
 
     from ..registry import Registrar
@@ -1521,7 +1521,10 @@ def run_compile_cache_ab(cache_dir: Optional[str] = None,
 
     ``cache_dir=None`` (the default) uses a fresh temp directory —
     pass a directory only if you can guarantee it starts empty, or
-    the cold arm is not cold and the gate proves nothing."""
+    the cold arm is not cold and the gate proves nothing.  A CPU test
+    rig: it leaves the cache setting as it found it, and where
+    ``JAX_COMPILATION_CACHE_DIR`` pins the cache to a directory the
+    rig cannot empty it refuses to run."""
     import tempfile
 
     import jax
@@ -1535,12 +1538,18 @@ def run_compile_cache_ab(cache_dir: Optional[str] = None,
     ledger = compiles.install(service="cache-ab")
     rng = np.random.RandomState(seed)
     prompt = rng.randint(1, 256, size=prompt_len).astype(np.int32)
-    tmp = None
-    if cache_dir is None:
-        tmp = tempfile.TemporaryDirectory(prefix="compile-cache-ab-")
-        cache_dir = tmp.name
     reports = []
-    try:
+    with contextlib.ExitStack() as scope:
+        if ledger_owned:
+            scope.callback(compiles.uninstall)
+        if cache_dir is None:
+            cache_dir = scope.enter_context(tempfile.TemporaryDirectory(
+                prefix="compile-cache-ab-"))
+        if scope.enter_context(
+                compiles.persistent_cache(cache_dir)) != cache_dir:
+            raise RuntimeError(
+                f"cache A/B: {compiles.CACHE_DIR_ENV} pins the cache "
+                "elsewhere, so the cold arm cannot start empty")
         for arm in ("cold", "warm"):
             jax.clear_caches()
             base = ledger.snapshot()
@@ -1579,12 +1588,6 @@ def run_compile_cache_ab(cache_dir: Optional[str] = None,
                 done[0].request_id:
                 [int(t) for t in (done[0].tokens or [])]}
             reports.append(report)
-    finally:
-        if ledger_owned:
-            compiles.uninstall()
-        compiles.disable_persistent_cache()
-        if tmp is not None:
-            tmp.cleanup()
     cold, warm = reports
     cold_tokens = next(iter(cold.final_tokens.values()))
     warm_tokens = next(iter(warm.final_tokens.values()))
@@ -1699,12 +1702,14 @@ def run_chaos(seed: int = 0, n_requests: int = 40,
     warmup_began = time.time()
     ledger = None
     ledger_owned = False
-    cache_tmp = None
+    cache_scope = contextlib.ExitStack()
+    cache_dir = None
     if compile_gate:
         ledger_owned = compiles.LEDGER is None
         ledger = compiles.install(service="chaos-gate")
-        cache_tmp = tempfile.TemporaryDirectory(
-            prefix="chaos-compile-cache-")
+        cache_dir = cache_scope.enter_context(compiles.persistent_cache(
+            cache_scope.enter_context(tempfile.TemporaryDirectory(
+                prefix="chaos-compile-cache-"))))
     # The fault plan arms AFTER the warmup wave when gating compiles —
     # warmup pumps must not consume the schedule's nth counters, or
     # the kill would land mid-warmup instead of mid-measured-decode.
@@ -1742,8 +1747,7 @@ def run_chaos(seed: int = 0, n_requests: int = 40,
                 spill_blocks=spill_blocks,
                 draft_config_name="tiny" if spec_k else None,
                 spec_k=spec_k or 4,
-                compilation_cache_dir=(cache_tmp.name if cache_tmp
-                                       else None))
+                compilation_cache_dir=cache_dir)
             if spec_k:
                 # Kill-mid-spec-round coverage: greedy determinism +
                 # idempotent replay must hold through rejected-tail
@@ -1825,9 +1829,7 @@ def run_chaos(seed: int = 0, n_requests: int = 40,
             ledger.lift_fence()
             if ledger_owned:
                 compiles.uninstall()
-        if cache_tmp is not None:
-            compiles.disable_persistent_cache()
-            cache_tmp.cleanup()
+        cache_scope.close()
 
 
 def run_spec_ab(spec_k: int = 4, n_requests: int = 24,

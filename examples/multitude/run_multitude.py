@@ -90,6 +90,9 @@ def run_cross_process(pipelines: int, frames: int):
     namespace = f"mt{broker.port}"
     os.environ["AIKO_MQTT_HOST"] = broker.host
     os.environ["AIKO_MQTT_PORT"] = str(broker.port)
+    # Children are pinned to the CPU: this is a control-plane demo, and
+    # a chip belongs to one process at a time — N children must not
+    # contend for it (nor take it from a parent that holds it).
     env = dict(os.environ, AIKO_NAMESPACE=namespace, JAX_PLATFORMS="cpu")
 
     children = []

@@ -167,10 +167,8 @@ def race(kk, nn, m=64):
 
 
 def race_one(variant, kk, nn, bn, m=64):
-    """Validate + time EXACTLY ONE kernel variant/tile — the unit the
-    capture daemon runs post-capture, riskiest shape last, committing
-    between shapes (a server-side Mosaic failure wedges the relay, so
-    each run must risk only itself; see docs/RELAY.md)."""
+    """Validate + time EXACTLY ONE kernel variant/tile, so a compile
+    failure names the one shape that caused it."""
     rng = np.random.default_rng(0)
     w = jnp.asarray(rng.normal(size=(kk, nn)) * 0.02, jnp.float32)
     x = jnp.asarray(rng.normal(size=(m, kk)), jnp.bfloat16)

@@ -131,8 +131,8 @@ def test_persistent_cache_counters_via_real_cache(tmp_path):
     import jax.numpy as jnp
 
     ledger = compiles.install(service="cache-unit")
-    compiles.enable_persistent_cache(str(tmp_path / "cache"))
-    try:
+    found = jax.config.jax_compilation_cache_dir
+    with compiles.persistent_cache(str(tmp_path / "cache")):
         with compiles.label("unit", "t"):
             jax.jit(lambda x: x * 3 + 1)(jnp.arange(16))
         assert ledger.cache_misses > 0
@@ -144,8 +144,8 @@ def test_persistent_cache_counters_via_real_cache(tmp_path):
         assert ledger.cache_hits > 0
         # retrievals were NOT booked as compiles
         assert ledger.compiles == compiles_cold
-    finally:
-        compiles.disable_persistent_cache()
+    # the rig scope restores the setting it found
+    assert jax.config.jax_compilation_cache_dir == found
 
 
 # ---------------------------------------------------------------- #

@@ -134,7 +134,7 @@ def test_kernel_matches_attention_reference():
 
 
 def _iter_eqns(jaxpr):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def subjaxprs(val):
         if isinstance(val, Jaxpr):

@@ -218,7 +218,7 @@ def test_prefill_append_two_slices_match_one_shot(monkeypatch, mode):
 
 
 def _iter_eqns(jaxpr):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def subjaxprs(val):
         if isinstance(val, Jaxpr):

@@ -117,7 +117,6 @@ def test_tp_base_server_greedy_parity(virtual_mesh_devices):
 # ---------------------------------------------------------------- #
 
 def _iter_eqns(jaxpr):
-    from jax.extend import core as jex_core  # noqa: F401  (version pin)
     for eqn in jaxpr.eqns:
         yield eqn
         for value in eqn.params.values():
@@ -125,11 +124,10 @@ def _iter_eqns(jaxpr):
 
 
 def _sub_eqns(value):
-    core = jax.core
-    closed = getattr(core, "ClosedJaxpr", None)
-    if closed is not None and isinstance(value, closed):
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    if isinstance(value, ClosedJaxpr):
         yield from _iter_eqns(value.jaxpr)
-    elif isinstance(value, core.Jaxpr):
+    elif isinstance(value, Jaxpr):
         yield from _iter_eqns(value)
     elif isinstance(value, (list, tuple)):
         for item in value:
