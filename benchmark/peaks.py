@@ -1,0 +1,18 @@
+"""Published peaks of the chips the benchmark runs on, keyed by JAX's
+``device_kind``.  A device that is not here is an error, never a
+default."""
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e" system architecture page:
+    # 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at 819 GB/s per chip.
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9},
+}
+
+
+def of(device_kind: str) -> dict:
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add it to benchmark/peaks.py "
+                       "with its source")
+    return PEAKS[device_kind]
