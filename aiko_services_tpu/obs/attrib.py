@@ -25,16 +25,15 @@ Gaps tile the recorded window exactly, so the component rows sum to
 the covered window by construction; against an externally measured
 wall time the residual shows up honestly as an ``uninstrumented``
 row rather than silently inflating a phase.  With a device-time
-sample (``probe_device_ms`` — timed ``block_until_ready`` off the
-hot path, or an XLA trace via the ProfilerActor), the ``sync_wait``
-row splits into ``device_compute`` (the part the hardware needed)
-and ``sync_excess`` (scheduling slack — host tax again).
+sample (the ``(profile N)`` bracket of :mod:`.profiler`, or any XLA
+trace), the ``sync_wait`` row splits into ``device_compute`` (the
+part the hardware needed) and ``sync_excess`` (scheduling slack —
+host tax again).
 
 Each component row names its ROADMAP lever, so the bench table reads
 as a worklist, not a post-mortem.
 
-Stdlib-only, host-side; ``jax`` is imported lazily and ONLY inside
-:func:`probe_device_ms` (invariant 7 — importing this module never
+Stdlib-only, host-side (invariant 7 — importing this module never
 touches a backend).
 """
 
@@ -43,8 +42,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Tuple
 
-__all__ = ["TaxRow", "TaxTable", "attribute_steps", "probe_device_ms",
-           "LEVERS", "ADMISSION_COMPONENTS"]
+__all__ = ["TaxRow", "TaxTable", "attribute_steps", "LEVERS",
+           "ADMISSION_COMPONENTS"]
 
 #: Component → the ROADMAP lever that would shrink it.
 LEVERS: Dict[str, str] = {
@@ -221,23 +220,3 @@ def attribute_steps(events: Iterable[Tuple[float, str, Dict]],
             lever=LEVERS.get(component, "")))
     table.rows.sort(key=lambda row: -row.ms)
     return table
-
-
-def probe_device_ms(thunk, reps: int = 5, warmup: int = 1) -> float:
-    """Median wall time of ``thunk()`` fully retired on device —
-    ``jax.block_until_ready`` around an already-compiled step, OFF the
-    serving hot path.  The sample feeds ``device_step_ms`` so the tax
-    table can separate device compute from host slack."""
-    import time
-
-    import jax
-
-    for _ in range(max(0, warmup)):
-        jax.block_until_ready(thunk())
-    samples = []
-    for _ in range(max(1, reps)):
-        t0 = time.perf_counter()
-        jax.block_until_ready(thunk())
-        samples.append((time.perf_counter() - t0) * 1e3)
-    samples.sort()
-    return samples[len(samples) // 2]

@@ -1,8 +1,8 @@
 """On-demand device profiling: bracket N engine steps in an XLA trace.
 
-PR 13's attribution table *estimates* device time with a probe
-(``attrib.probe_device_ms``).  This module replaces the estimate with
-measurement, on demand, fleet-wide, without restarting anything:
+PR 13's attribution table had only the host's view of a sync wait.
+This module gives it measured device time, on demand, fleet-wide,
+without restarting anything:
 
 * An operator sends ``(profile N)`` to any actor (the router fans it
   out like ``(capture)``).  The actor calls :func:`request`, which

@@ -5,6 +5,7 @@ One request → one span tree across processes::
     InferClient "infer" (root)
       └─ ReplicaRouter "route" / "redispatch" / "shed"
            └─ replica "queue" → "prefill" → "decode"
+                │            └─ "slice_wait" → "prefill_run" → "first_chunk"
                 └─ kv transfer source "kv_export"
 
 **Context propagation** rides the EXISTING message layer: the compact
@@ -30,10 +31,10 @@ across processes.
 **Zero-cost discipline**: the module-level :data:`TRACER` is ``None``
 by default; every call site guards with ``trace.TRACER is not None``
 (the ``faults.PLAN`` idiom — one attribute load + identity test when
-disabled).  At span start the active tracer can emit a
-``jax.profiler.TraceAnnotation`` named ``span:<name>#<span_id>`` so a
-device trace captured by the ProfilerActor links back to host spans by
-name; jax is imported lazily and only when annotation is requested.
+disabled).  Spans never touch the profiler: replica spans are
+synthesised after the fact from request stamps, and what a device
+trace needs beside its operations, the engine loop's phases, are the
+``engine:<phase>`` annotations of :mod:`.steplog`.
 
 Env bootstrap (like ``AIKO_FAULTS``): ``AIKO_TRACE=<service-name>``
 installs a tracer at import so child processes opt in without code.
@@ -162,9 +163,8 @@ class Tracer:
     """
 
     def __init__(self, service: str = "", capacity: int = 8192,
-                 annotate: bool = False, seed: Optional[int] = None):
+                 seed: Optional[int] = None):
         self.service = service or f"pid{os.getpid()}"
-        self.annotate = annotate
         self._rng = random.Random(seed)
         self._finished: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
@@ -204,27 +204,12 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, parent=None, attrs: Optional[Dict] = None):
-        """Start + activate + finish.  When ``annotate`` is on, the
-        body also runs under a ``jax.profiler.TraceAnnotation`` named
-        ``span:<name>#<span_id>`` so device traces cross-reference
-        host spans."""
+        """Start + activate + finish."""
         span = self.start_span(name, parent=parent, attrs=attrs)
         token = _ACTIVE.set(span.context)
-        annotation = None
-        if self.annotate:
-            try:
-                import jax
-                annotation = jax.profiler.TraceAnnotation(
-                    f"span:{name}#{span.span_id}")
-                annotation.__enter__()
-            except Exception:  # noqa: BLE001 - backend may lack it
-                annotation = None
         try:
             yield span
         finally:
-            if annotation is not None:
-                with contextlib.suppress(Exception):
-                    annotation.__exit__(None, None, None)
             _ACTIVE.reset(token)
             self.finish(span)
 

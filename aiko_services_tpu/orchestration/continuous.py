@@ -130,6 +130,20 @@ class DecodeRequest:
     #: phases the obs layer histograms: queue-wait (submit→activate),
     #: prefill (activate→first token), decode (first→finish).
     activated_ts: Optional[float] = None
+    #: The request's first prefill dispatch, and the moment its lane
+    #: flipped to decode (``_activate_slot``).  With the stamps above
+    #: they tile time to first token (:func:`ttft_parts`): a slot is
+    #: reserved at once while slots are free, so what a chunked
+    #: admission waits for is mostly OTHER prompts' slices, and
+    #: ``activated_ts`` alone cannot show that.
+    prefill_started_ts: Optional[float] = None
+    decode_ready_ts: Optional[float] = None
+    #: Prompt tokens the prefix cache spared this request, and the
+    #: prefill dispatches it took and tokens they carried (prompts
+    #: are padded to a bucket, so at least its uncached tokens).
+    shared_tokens: int = 0
+    prefill_dispatches: int = 0
+    prefill_tokens: int = 0
     #: Milliseconds spent restoring this request's prefix KV from a
     #: remote replica (0 when no kv_source hint / local hit).
     kv_restore_ms: float = 0.0
@@ -144,6 +158,38 @@ class DecodeRequest:
     #: Loadgen histograms these — the per-request acceptance shape,
     #: not just the fleet-mean rate.
     spec_accepted_rounds: Optional[List[int]] = None
+
+
+#: The parts of time to first token, in order; part ``i`` runs from
+#: stamp ``i`` to stamp ``i + 1`` of :func:`_ttft_stamps`.
+TTFT_PARTS = ("queue", "slice_wait", "prefill_run", "first_chunk")
+#: The server counter that sums each part (milliseconds).
+TTFT_COUNTERS = ("queue_wait_ms", "slice_wait_ms", "prefill_run_ms",
+                 "first_chunk_ms")
+
+
+def _ttft_stamps(request: DecodeRequest):
+    """The five stamps that bound :data:`TTFT_PARTS`, or ``None``
+    until the request has delivered its first token."""
+    stamps = (request.submitted_ts, request.activated_ts,
+              request.prefill_started_ts, request.decode_ready_ts,
+              request.first_token_ts)
+    return None if None in stamps else stamps
+
+
+def ttft_parts(request: DecodeRequest) -> Dict[str, float]:
+    """Seconds per part of the request's time to first token:
+    ``queue`` (waited for a slot or pool blocks), ``slice_wait`` (sat
+    in the slice queue behind older prompts), ``prefill_run`` (its own
+    prefill dispatches, one slice per chunk when chunked) and
+    ``first_chunk`` (the decode chunk, and the ring sync, before its
+    first token was seen).  They sum to ``ttft``; empty until the
+    first token."""
+    stamps = _ttft_stamps(request)
+    if stamps is None:
+        return {}
+    return {part: end - start for part, start, end
+            in zip(TTFT_PARTS, stamps, stamps[1:])}
 
 
 def _bucket(n: int, minimum: int = 16) -> int:
@@ -492,6 +538,17 @@ class ContinuousBatchingServer:
             ring_starved_steps=0, admission_deferred=0,
             decode_blocks_read=0, prefill_tokens=0,
             sp_prefill_dispatches=0,
+            # Time to first token, accounted where it is spent: the
+            # six below are added TOGETHER when a request's first
+            # token is committed (the four parts sum to ttft_ms over
+            # any interval); then the slice queue's service, its
+            # depth summed over dispatches, and the prompt tokens
+            # admitted past the prefix cache (prefill_tokens counts
+            # what was dispatched for them, padding included).
+            first_tokens=0, ttft_ms=0.0, queue_wait_ms=0.0,
+            slice_wait_ms=0.0, prefill_run_ms=0.0, first_chunk_ms=0.0,
+            prefill_slices=0, prefill_slices_mixed=0,
+            prefill_backlog=0, prompt_tokens=0,
             deadline_exceeded=0, shed=0, watchdog_trips=0),
             prefix="server", labels=self._metrics_labels)
         # Per-phase latency histograms — FIXED log-spaced buckets, so
@@ -505,6 +562,7 @@ class ContinuousBatchingServer:
                 help=f"Per-request {phase} latency (ms).",
                 labels=self._metrics_labels)
             for phase in ("ttft", "total", "queue", "prefill",
+                          "slice_wait", "prefill_run", "first_chunk",
                           "decode", "kv_restore")}
         self._serve_started: Optional[float] = None
         # ---- robustness: backpressure + device watchdog -------------- #
@@ -522,8 +580,14 @@ class ContinuousBatchingServer:
         self._watchdog_tripped = False
         # ---- on-demand device profiling (PR 14) ---------------------- #
         #: measured per-step device ms from the last (profile) bracket
-        #: (None until one ran; replaces attrib's probe estimate).
+        #: (None until one ran).
         self._device_step_ms: Optional[float] = None
+        #: the open ``dispatch`` step-log span of a decode chunk or
+        #: spec round, from before its dirty-row upload to
+        #: ``_note_dispatch`` (None unless a recorder is installed).
+        #: Parked here so that the layout's ``_serve_chunk`` can note
+        #: the prefill slice the chunk carries: the dispatch's cause.
+        self._dispatch_span = None
         self._profiles = 0
         self._profile_idle = 0
 
@@ -603,8 +667,9 @@ class ContinuousBatchingServer:
         rows = np.nonzero(structural)[0].astype(np.int32)
         sampling_rows = np.nonzero(sampling)[0].astype(np.int32)
         n_dirty = len(rows) + len(sampling_rows)
+        span = None
         if steplog.RECORDER is not None:
-            steplog.RECORDER.record("state_upload", rows=n_dirty)
+            span = steplog.RECORDER.begin("state_upload", rows=n_dirty)
         if not self.compact_upload:
             # Legacy merge has no per-leaf mask; update_sampling
             # settles the ring before marking on this path, so every
@@ -644,6 +709,8 @@ class ContinuousBatchingServer:
         self._dirty_sampling[:] = False
         self.counters["state_uploads"] += 1
         self.counters["dirty_rows_uploaded"] += n_dirty
+        if span is not None:
+            span.end()
 
     def _pow2_rows(self, rows: np.ndarray) -> np.ndarray:
         """Pad a dirty-row index vector to its pow2 bucket (clamped to
@@ -934,6 +1001,9 @@ class ContinuousBatchingServer:
             or bool(self._ring)
 
     def _admit(self) -> None:
+        span = None
+        if steplog.RECORDER is not None:
+            span = steplog.RECORDER.begin("admission")
         admissions = []
         for slot in range(self.slots):
             if self._requests[slot] is not None or not self._queue:
@@ -950,6 +1020,8 @@ class ContinuousBatchingServer:
                 break      # capacity (paged pool) exhausted; next chunk
             self._queue.pop(0)
             request.activated_ts = time.monotonic()
+            self.counters["prompt_tokens"] += \
+                prompt_len - request.shared_tokens
             prompt_padded = np.zeros((1, padded), np.int32)
             prompt_padded[:, :prompt_len] = prompt
             if self.chunk_prefill_tokens \
@@ -965,11 +1037,14 @@ class ContinuousBatchingServer:
                                             prompt_padded, prompt_len)
                 continue
             admissions.append((slot, request, prompt_padded, prompt_len))
-        if steplog.RECORDER is not None:
+        if span is not None:
+            # The slot scan alone; the wave's prefills and activations
+            # are phases of their own below.
             if admissions or self._prefilling:
-                steplog.RECORDER.record("admission",
-                                        slots=len(admissions),
-                                        chunked=len(self._prefilling))
+                span.end(slots=len(admissions),
+                         chunked=len(self._prefilling))
+            else:
+                span.drop()
         if not admissions:
             return
         self._prefill_and_insert(admissions)
@@ -989,6 +1064,10 @@ class ContinuousBatchingServer:
         re-writes that KV row with identical values and emits the
         first generated token.  The ONE activation path for both
         whole-bucket and chunked admission."""
+        span = None
+        if steplog.RECORDER is not None:
+            span = steplog.RECORDER.begin("sampling_edit", slot=slot)
+        request.decode_ready_ts = time.monotonic()
         self.tokens[slot, 0] = prompt_padded[0, prompt_len - 1]
         self.positions[slot] = prompt_len - 1
         self.active[slot] = True
@@ -1012,11 +1091,9 @@ class ContinuousBatchingServer:
             self._autostates[slot] = (
                 self._automata["table"].start(name)
                 if name is not None else -1)
-        if steplog.RECORDER is not None:
-            steplog.RECORDER.record(
-                "sampling_edit", slot=slot,
-                temperature=float(request.temperature),
-                top_p=float(request.top_p))
+        if span is not None:
+            span.end(temperature=float(request.temperature),
+                     top_p=float(request.top_p))
 
     def _begin_chunked_prefill(self, slot: int, request, prompt_padded,
                                prompt_len: int) -> None:
@@ -1044,11 +1121,11 @@ class ContinuousBatchingServer:
             size = min(self.chunk_prefill_tokens,
                        state["prompt_padded"].shape[1] - start)
             chunk = state["prompt_padded"][:, start:start + size]
+            self._note_prefill(size, (state["request"],), sliced=True)
             _, state["bucket"] = self._llama.prefill_chunk(
                 self.params, jnp.asarray(chunk), state["bucket"],
                 jnp.int32(start), self.config, lora=state["lora"])
             state["start"] = start + size
-            self._note_prefill(size)
             if state["start"] >= state["prompt_len"]:
                 # Rows past prompt_len stay zero-initialized — exactly
                 # as unattendable as the whole-prefill path's
@@ -1088,7 +1165,7 @@ class ContinuousBatchingServer:
         for slot, request, prompt_padded, prompt_len in admissions:
             adapter_id = self._adapter_id(request)
             groups.setdefault(prompt_padded.shape[1], []).append(
-                (slot, prompt_padded, adapter_id))
+                (slot, prompt_padded, adapter_id, request))
         for padded, group in groups.items():
             start = 0
             while start < len(group):
@@ -1096,9 +1173,11 @@ class ContinuousBatchingServer:
                 size = 1 << ((len(group) - start).bit_length() - 1)
                 sub = group[start:start + size]
                 start += size
-                slots = [slot for slot, _, _ in sub]
-                prompts = np.concatenate([p for _, p, _ in sub],
+                slots = [slot for slot, _, _, _ in sub]
+                prompts = np.concatenate([p for _, p, _, _ in sub],
                                          axis=0)
+                self._note_prefill(len(sub) * padded,
+                                   [request for _, _, _, request in sub])
                 if compiles.LEDGER is not None:
                     # Shape-bucket signature: any compile with a
                     # signature OUTSIDE the pow2 grid is a bucket-
@@ -1107,7 +1186,7 @@ class ContinuousBatchingServer:
                                        f"b{padded}x{len(sub)}")
                 # The prompt KV must be built under the SAME adapter
                 # the decode chunks will run (None for all-base).
-                lora = self._make_lora([aid for _, _, aid in sub])
+                lora = self._make_lora([aid for _, _, aid, _ in sub])
                 bucket_cache = self._llama.init_cache(
                     self.config, len(sub), padded,
                     quantize_kv=self.quantize_kv)
@@ -1117,7 +1196,6 @@ class ContinuousBatchingServer:
                 slot_rows = jnp.asarray(np.asarray(slots, np.int32))
                 self.cache = self._insert_slots(
                     self.cache, bucket_cache, slot_rows, padded)
-                self._note_prefill(len(sub) * padded)
                 if self._draft is not None:
                     # The draft needs the SAME committed history: its
                     # prompt KV lands in its own slot cache alongside.
@@ -1724,6 +1802,10 @@ class ContinuousBatchingServer:
         if not live.any():
             return False
         steps = int(min(self.chunk_steps, int(plan[live].max())))
+        if steplog.RECORDER is not None:
+            self._dispatch_span = steplog.RECORDER.begin(
+                "dispatch", chunk=self.counters["dispatches"] + 1,
+                steps=steps, live_rows=int(live.sum()))
         self._sync_dirty()
         rng_key = None
         if self._any_sampled:
@@ -1837,6 +1919,10 @@ class ContinuousBatchingServer:
                 # decode" rung — and tick the re-probe counters.
                 controller.tick_cold_round(live)
                 return self._dispatch_chunk()
+        if steplog.RECORDER is not None:
+            self._dispatch_span = steplog.RECORDER.begin(
+                "dispatch", chunk=self.counters["dispatches"] + 1,
+                steps=1, live_rows=int(live.sum()))
         self._sync_dirty()
         if compiles.LEDGER is not None:
             compiles.set_label("spec_round", f"k{k}")
@@ -2015,23 +2101,52 @@ class ContinuousBatchingServer:
         self.counters["dispatches"] += 1
         self.counters["max_in_flight"] = max(
             self.counters["max_in_flight"], len(self._ring))
-        if steplog.RECORDER is not None:
+        # The slice queue's depth, summed over dispatches: over an
+        # interval, prefill_backlog / dispatches is its mean depth.
+        self.counters["prefill_backlog"] += len(self._prefilling)
+        span, self._dispatch_span = self._dispatch_span, None
+        if span is not None:
             if self._post_admission:
-                steplog.RECORDER.record("dispatch", ring=len(self._ring),
-                                        after_admission=1)
+                span.end(ring=len(self._ring), after_admission=1)
             else:
-                steplog.RECORDER.record("dispatch", ring=len(self._ring))
+                span.end(ring=len(self._ring))
         self._post_admission = False
 
-    def _note_prefill(self, tokens: int) -> None:
-        """Count prompt tokens dispatched to prefill (any path:
-        whole-bucket, standalone chunk, mixed step).  Prefix-cache
-        hits never reach a prefill dispatch, so this measures work
-        actually done — the gap to raw admitted prompt length IS the
-        cache's savings."""
+    def _note_prefill(self, tokens: int, requests=(),
+                      sliced: bool = False, mixed: bool = False) -> None:
+        """Count prompt tokens about to be dispatched to prefill (any
+        path: whole-bucket, standalone chunk, mixed step), for the
+        ``requests`` whose prompt they belong to; the first such
+        dispatch stamps a request's ``prefill_started_ts``.
+        Prefix-cache hits never reach a prefill dispatch, so this
+        measures work actually done — the gap to raw admitted prompt
+        length IS the cache's savings.  ``sliced``: one slice of a
+        chunked admission (the slice queue served once); ``mixed``:
+        it rides a decode chunk rather than running standalone."""
         if self._serve_started is None:
             self._serve_started = time.monotonic()
         self.counters["prefill_tokens"] += int(tokens)
+        for request in requests:
+            if request.prefill_started_ts is None:
+                request.prefill_started_ts = time.monotonic()
+            request.prefill_dispatches += 1
+            request.prefill_tokens += int(tokens) // len(requests)
+        if sliced:
+            self.counters["prefill_slices"] += 1
+            if mixed:
+                self.counters["prefill_slices_mixed"] += 1
+
+    def _note_first_token(self, request: DecodeRequest) -> None:
+        """A request's first token was committed: add its time to
+        first token and the parts that tile it to the counters, all at
+        once, so that the parts sum to the whole over any interval."""
+        counters = self.counters
+        counters["first_tokens"] += 1
+        counters["ttft_ms"] += (request.first_token_ts
+                                - request.submitted_ts) * 1e3
+        for key, seconds in zip(TTFT_COUNTERS,
+                                ttft_parts(request).values()):
+            counters[key] += seconds * 1e3
 
     def _consume_one(self) -> None:
         """Apply the OLDEST in-flight entry's results (see
@@ -2058,6 +2173,9 @@ class ContinuousBatchingServer:
         if count <= 0:
             return
         entries = [self._ring.popleft() for _ in range(count)]
+        sync_span = commit_span = None
+        if steplog.RECORDER is not None:
+            sync_span = steplog.RECORDER.begin("sync", entries=count)
         wait_start = time.monotonic()
         if faults.PLAN is not None:
             stall = faults.PLAN.check("stall_step")
@@ -2102,10 +2220,10 @@ class ContinuousBatchingServer:
         self.counters["sync_wait_ms"] += wait_ms
         self.counters["sync_elements"] += elements
         self.counters["decode_steps"] += batch_steps
+        if sync_span is not None:
+            sync_span.end(wait_ms=round(wait_ms, 3), steps=batch_steps)
         if steplog.RECORDER is not None:
-            steplog.RECORDER.record(
-                "sync", wait_ms=round(wait_ms, 3), steps=batch_steps,
-                entries=count)
+            commit_span = steplog.RECORDER.begin("commit")
         # ONE vectorized live-mask sweep across the whole batch: an
         # entry's lane is live iff its dispatch-time serial still
         # matches, the slot is active and occupied.  Serials only
@@ -2166,6 +2284,7 @@ class ContinuousBatchingServer:
                 if count:
                     if request.first_token_ts is None:
                         request.first_token_ts = now
+                        self._note_first_token(request)
                     request.tokens.extend(token_rows[slot][:count])
                     self._emitted[slot] += count
                     self._remaining[slot] = (request.max_new_tokens
@@ -2222,13 +2341,17 @@ class ContinuousBatchingServer:
                     batch_live[index + 1:, slot] = False
         self.counters["tokens_committed"] += delivered
         if steplog.RECORDER is not None:
+            # The instant that carries the walk's duration in a field
+            # (obs/attrib reads it so); ``commit`` is the walk as a
+            # span.
             steplog.RECORDER.record(
                 "token_dispatch", slots=len(touched_slots),
                 tokens=delivered,
                 ms=round((time.monotonic() - dispatch_start) * 1e3, 3))
+        if commit_span is not None:
             # Device-reported emit counts: stale-serial lanes may be
             # excluded above, so this is an upper bound on committed.
-            steplog.RECORDER.record("commit", tokens=committed_upper)
+            commit_span.end(tokens=committed_upper)
 
     def _trip_watchdog(self) -> None:
         """Mark the replica wedged (idempotent; callable from the
@@ -3157,7 +3280,9 @@ class ContinuousReplica(Actor):
 
     def _phase_latencies(self, request: DecodeRequest) -> Dict[str, float]:
         """Seconds per phase from the request's lifecycle stamps:
-        ``queue`` (submit→slot), ``prefill`` (slot→first token),
+        ``queue`` (submit→slot), ``prefill`` (slot→first token) with
+        the three parts that tile it (``slice_wait``, ``prefill_run``,
+        ``first_chunk``: :func:`ttft_parts`),
         ``decode`` (first→finish), the classic end-to-end ``ttft`` /
         ``total``, and any ``kv_restore`` time (the warm-start fetch
         runs BEFORE submission, so it is invisible to — not double-
@@ -3178,9 +3303,35 @@ class ContinuousReplica(Actor):
                 if request.finished_ts is not None:
                     out["decode"] = (request.finished_ts
                                      - request.first_token_ts)
+        out.update(ttft_parts(request))     # ``queue`` is the same
         if request.kv_restore_ms:
             out["kv_restore"] = request.kv_restore_ms / 1e3
         return out
+
+    def _prefill_children(self, request: DecodeRequest, prefill_span,
+                          offset: float) -> List:
+        """The ``prefill`` span's children, which tile it: where the
+        time from slot to first token went (:func:`ttft_parts`)."""
+        from ..obs import trace
+        stamps = _ttft_stamps(request)
+        if stamps is None:
+            return []
+        parent = trace.inject(prefill_span)
+        children = []
+        for part, start, end in zip(TTFT_PARTS[1:], stamps[1:],
+                                    stamps[2:]):
+            attrs = {"request_id": request.request_id}
+            if part == "prefill_run":
+                attrs.update(
+                    slices=request.prefill_dispatches,
+                    tokens_dispatched=request.prefill_tokens,
+                    prompt_tokens=(len(request.prompt)
+                                   - request.shared_tokens),
+                    shared_tokens=request.shared_tokens)
+            children.append(trace.synth_span(
+                part, parent, self.name, offset + start, offset + end,
+                attrs=attrs))
+        return children
 
     _SLOW_K = 5
 
@@ -3234,9 +3385,12 @@ class ContinuousReplica(Actor):
                     "queue", parent, self.name, submitted, activated))
                 if request.first_token_ts is not None:
                     first = offset + request.first_token_ts
-                    spans.append(trace.synth_span(
+                    prefill_span = trace.synth_span(
                         "prefill", parent, self.name, activated,
-                        first))
+                        first)
+                    spans.append(prefill_span)
+                    spans.extend(self._prefill_children(
+                        request, prefill_span, offset))
                     decode_span = trace.synth_span(
                         "decode", parent, self.name, first, finished)
                     decode_span.mark("first_token", first)

@@ -1287,6 +1287,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
         blocks = shared + private
         self._owned[slot] = blocks
         self._pending_shared[slot] = len(shared)
+        request.shared_tokens = len(shared) * self.block_size
         row = np.zeros(self.tables.shape[1], np.int32)
         row[:needed] = blocks
         self.tables[slot] = row
@@ -1614,6 +1615,11 @@ class PagedContinuousServer(ContinuousBatchingServer):
         lora = self._request_lora(request)
         start = n_shared * block_size
         remaining = kv_limit - n_shared
+        span = None
+        if steplog.RECORDER is not None:
+            span = steplog.RECORDER.begin(
+                "paged_prefill", slot=slot, shared_blocks=n_shared,
+                total_blocks=kv_limit)
         while remaining > 0:
             size = 1 << (remaining.bit_length() - 1)
             width = size * block_size
@@ -1622,6 +1628,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
                 # pow2 piece widths ⇒ log-many prefill signatures per
                 # bucket; any other width in the ledger is a breach.
                 compiles.set_label("paged_prefill", f"w{width}")
+            self._note_prefill(width, (request,))
             if self._tp_engine is not None:
                 _, self.pool = self._tp_engine.prefill_append_paged(
                     self.params, jnp.asarray(chunk), self.pool,
@@ -1632,20 +1639,13 @@ class PagedContinuousServer(ContinuousBatchingServer):
                     self.params, jnp.asarray(chunk), self.pool,
                     tables_row, jnp.int32(start), self.config,
                     lora=lora, kv_limit=kv_limit, compute_logits=False)
-            self._note_prefill(width)
             start += width
             remaining -= size
-        # Recorded AFTER the dispatch loop: gap-based attribution
-        # charges each gap to the event that ends it, so the event
-        # must close the window that held this prefill's enqueue (and,
-        # on a throttled backend, the previous piece's compute block).
-        # Recording up front pushed prefill compute into whatever host
-        # phase ran next — the table blamed ``sampling_edit`` for
-        # device work.
-        if steplog.RECORDER is not None:
-            steplog.RECORDER.record(
-                "paged_prefill", slot=slot, shared_blocks=n_shared,
-                total_blocks=prompt_padded.shape[1] // self.block_size)
+        # The span holds this prefill's enqueues (and, on a throttled
+        # backend, the earlier pieces' compute blocks), so nothing of
+        # it is charged to the host phase that runs next.
+        if span is not None:
+            span.end()
         if self._draft is not None:
             # Draft prompt KV for this slot's contiguous draft cache —
             # ALWAYS the whole padded prompt: the draft has no pool
@@ -1742,6 +1742,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
             chunk = state["prompt_padded"][:, start:start + width]
             tables_row = jnp.asarray(self.tables[slot:slot + 1])
             lora = self._request_lora(state["request"])
+            self._note_prefill(width, (state["request"],), sliced=True)
             if sp_width:
                 if compiles.LEDGER is not None:
                     # ONE window shape per (sp, cap) — the sp ladder
@@ -1766,7 +1767,6 @@ class PagedContinuousServer(ContinuousBatchingServer):
                     lora=lora,
                     kv_limit=state["kv_limit"], compute_logits=False)
             state["start"] = start + width
-            self._note_prefill(width)
             if state["start"] >= state["prompt_len"]:
                 self._finish_prefill(slot, state)
 
@@ -1927,6 +1927,12 @@ class PagedContinuousServer(ContinuousBatchingServer):
         sp_width = self._sp_window_width(prefill)
         width = sp_width or self._next_slice_width(prefill)
         chunk = prefill["prompt_padded"][:, start:start + width]
+        self._note_prefill(width, (prefill["request"],), sliced=True,
+                           mixed=True)
+        if self._dispatch_span is not None:
+            self._dispatch_span.note(
+                slice_slot=slot, slice_width=width,
+                request_id=prefill["request"].request_id)
         if sp_width:
             # Mixed step with the slice run as an sp-sharded window:
             # sp chunks of this prompt prefill in ONE dispatch while
@@ -1961,7 +1967,6 @@ class PagedContinuousServer(ContinuousBatchingServer):
                     rng_key=rng_key, lora_shared=lora_shared,
                     prefill_kv_limit=prefill["kv_limit"])
         prefill["start"] = start + width
-        self._note_prefill(width)
         if prefill["start"] >= prefill["prompt_len"]:
             self._finish_prefill(slot, prefill)
         return tokens_d, counts_d, new_state

@@ -1,7 +1,11 @@
 """Event engine tests: timers, mailboxes (priority), queues, leases —
 all deterministic via the virtual clock."""
 
+import sys
 import threading
+import time
+
+import pytest
 
 from aiko_services_tpu.runtime.event import EventEngine, VirtualClock
 from aiko_services_tpu.runtime.lease import Lease
@@ -83,6 +87,33 @@ def test_real_loop_wakes_on_post():
     engine.terminate()
     thread.join(timeout=2.0)
     assert not thread.is_alive()
+
+
+@pytest.mark.skipif(not sys.platform.startswith("linux"),
+                    reason="thread names at the OS level: Linux")
+def test_threaded_loop_carries_its_name_at_the_os_level():
+    """What a profiler lists the engine loop's thread under (its
+    ``engine:<phase>`` annotations get a line of their own)."""
+    engine = EventEngine()
+    names = []
+
+    def handler(_name, _item):
+        tid = threading.get_native_id()
+        with open(f"/proc/self/task/{tid}/comm") as comm:
+            names.append(comm.read().strip())
+
+    engine.add_mailbox_handler(handler, "m")
+    thread = engine.run_in_thread()
+    engine.mailbox_put("m", "who")
+    for _ in range(200):
+        if names:
+            break
+        time.sleep(0.01)
+    engine.terminate()
+    thread.join(timeout=2.0)
+    assert names == ["aiko-event-loop"]
+    with open("/proc/self/comm") as comm:      # the process keeps its own
+        assert comm.read().strip() != "aiko-event-loop"
 
 
 def test_lease_expiry(engine):

@@ -280,6 +280,98 @@ def test_steplog_chrome_events_durations():
     assert duration["args"]["steps"] == 4
 
 
+def test_steplog_spans_have_a_start_an_end_and_two_views():
+    recorder = steplog.StepRecorder()
+    span = recorder.begin("dispatch", chunk=7, steps=8)
+    span.note(slice_slot=3, request_id="r9")
+    span.end(ring=2)
+    recorder.begin("admission").drop()          # an empty phase
+    recorder.record("token_dispatch", ms=0.5)   # an instant
+    (start, end, name, fields), instant = recorder.spans()
+    assert name == "dispatch" and end >= start
+    assert fields == {"chunk": 7, "steps": 8, "slice_slot": 3,
+                      "request_id": "r9", "ring": 2}
+    assert instant[0] == instant[1] and instant[2] == "token_dispatch"
+    # The older view: one row per span, stamped with its end.
+    assert recorder.events() == [(end, "dispatch", fields),
+                                 (instant[1], "token_dispatch",
+                                  {"ms": 0.5})]
+    assert recorder.counts() == {"dispatch": 1, "token_dispatch": 1}
+    drawn = recorder.chrome_events()[1]
+    assert drawn["ph"] == "X" and drawn["name"] == "dispatch"
+    assert drawn["ts"] + drawn["dur"] == pytest.approx(end * 1e6, abs=2)
+    assert drawn["args"]["request_id"] == "r9"
+
+
+#: Names of ``events()`` for the scripted run below, recorded on the
+#: commit before the step log had spans (d19211b): the older view
+#: keeps its names and their order.
+SCRIPTED_EVENTS = (
+    ["admission", "paged_prefill", "sampling_edit", "state_upload",
+     "dispatch", "dispatch", "sync", "token_dispatch", "commit",
+     "admission", "state_upload", "dispatch", "sync", "token_dispatch",
+     "commit", "admission", "sync", "token_dispatch", "commit",
+     "admission", "sampling_edit", "state_upload", "dispatch", "sync",
+     "token_dispatch", "commit"]
+    + ["admission", "paged_prefill", "sampling_edit", "state_upload",
+       "dispatch", "sync", "token_dispatch", "commit"])
+
+
+def test_steplog_events_of_a_scripted_run_are_what_they_were():
+    """Whole-bucket admission, a chunked admission riding decode
+    chunks and one finishing standalone, on a paged server with the
+    ring pinned at depth 2: ``events()`` names the same phases in the
+    same order as before the recorder kept spans; every span ends
+    after it starts, nests or follows its neighbours, and is drawn as
+    a complete event."""
+    from aiko_services_tpu.orchestration.continuous import DecodeRequest
+    from aiko_services_tpu.orchestration.paged import (
+        PagedContinuousServer,
+    )
+    server = PagedContinuousServer(
+        config_name="tiny", slots=4, max_seq=256, chunk_steps=4, seed=3,
+        block_size=16, total_blocks=64, chunk_prefill_tokens=32,
+        ring_max=2)
+
+    def request(name, length, new):
+        prompt = np.random.default_rng([0, length]).integers(
+            1, server.config.vocab_size, length)
+        return DecodeRequest(request_id=name,
+                             prompt=prompt.astype(np.int32),
+                             max_new_tokens=new)
+
+    recorder = steplog.install()
+    server.submit(request("a", 20, 12))
+    server.step()
+    server.submit(request("b", 70, 4))
+    server.run_until_drained()
+    server.submit(request("c", 20, 4))
+    server.run_until_drained()
+    steplog.uninstall()
+
+    assert [name for _, name, _ in recorder.events()] == SCRIPTED_EVENTS
+    spans = recorder.spans()
+    assert all(end >= start for start, end, _, _ in spans)
+    assert [end for _, end, _, _ in spans] == \
+        sorted(end for _, end, _, _ in spans)
+    timed = [span for span in spans if span[1] > span[0]]
+    assert {name for _, _, name, _ in timed} == {
+        "admission", "paged_prefill", "sampling_edit", "state_upload",
+        "dispatch", "sync", "commit"}
+    drawn = [event for event in recorder.chrome_events()
+             if event["ph"] != "M"]
+    assert {event["ph"] for event in drawn} == {"X"}
+    # The cause of a dispatch: the slice it carried, and for whom.
+    mixed = [fields for _, _, name, fields in spans
+             if name == "dispatch" and "slice_slot" in fields]
+    assert mixed and all(fields["request_id"] == "b"
+                         and fields["slice_width"] == 32
+                         for fields in mixed)
+    serials = [fields["chunk"] for _, _, name, fields in spans
+               if name == "dispatch"]
+    assert serials == list(range(1, len(serials) + 1))
+
+
 def test_steplog_install_switchboard():
     assert steplog.RECORDER is None
     recorder = steplog.install(capacity=16)
