@@ -212,7 +212,10 @@ def _field_layout(server) -> List[tuple]:
     pytree flattening sorts dict keys, so sorted order is the one
     order host and device agree on)."""
     layout = []
-    for layer, buffers in enumerate(server.pool):
+    # The block pools among what the engine donates to its programs
+    # (a model module may keep per-slot state beside them).
+    for layer, buffers in enumerate(
+            server._model.kv_pool_layers(server.pool)):
         for name in sorted(buffers):
             buf = buffers[name]
             shape = tuple(int(s) for s in buf.shape[1:])

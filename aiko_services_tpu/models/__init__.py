@@ -1,8 +1,28 @@
 from . import llama
 from . import moe
+from . import nemotron_h
 from . import classifier
 from . import detector
 from . import asr
 from . import vision
 from . import speculative
 from . import lora
+
+#: The modules a serving engine can bind: each registers its configs
+#: in ``CONFIGS`` and gives the engine's entry points
+#: (``init_paged_cache``, ``prefill_append_paged``,
+#: ``serve_chunk_paged``, ``serve_chunk_mixed``,
+#: ``scatter_state_rows``, ``kv_geometry``, ``kv_pool_layers``).
+SERVING_MODULES = (llama, nemotron_h)
+
+
+def serving_model(config_name: str):
+    """``(module, config)`` of a registered config: the module whose
+    ``CONFIGS`` holds the name is the one that serves it."""
+    for module in SERVING_MODULES:
+        if config_name in module.CONFIGS:
+            return module, module.CONFIGS[config_name]
+    raise KeyError(
+        f"no serving config {config_name!r}; registered: "
+        + ", ".join(sorted(name for module in SERVING_MODULES
+                           for name in module.CONFIGS)))

@@ -47,7 +47,10 @@ __all__ = ["LlamaConfig", "init_params", "forward",
            "serve_chunk_mixed", "prefill_append_paged",
            "verify_chunk_paged",
            "paged_insert_prefix", "paged_scatter_blocks",
-           "paged_gather_blocks", "complete", "CONFIGS"]
+           "paged_gather_blocks", "complete", "CONFIGS",
+           "RECURRENT_STATE", "COUNTERS", "kv_geometry",
+           "kv_pool_layers", "scatter_state_rows",
+           "state_bytes_per_slot", "layer_kinds"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -839,11 +842,44 @@ def prefill(params, tokens, cache, config: LlamaConfig, lora=None):
 # slots point there, and it is never attendable (masking is by absolute
 # position, and live positions always map to allocated blocks).
 
+#: No per-slot recurrent state, and no counters beyond the engine's own
+#: come back with a serve chunk (see :mod:`.nemotron_h` for a module
+#: that has both).
+RECURRENT_STATE = False
+COUNTERS = ()
+
+
+def kv_geometry(config: LlamaConfig, quantize_kv: bool):
+    """``(head_dim, kv heads, KV dtype)`` of the block pools, as the
+    attention dispatch sees them."""
+    return (config.head_dim, config.n_kv_heads,
+            jnp.int8 if quantize_kv else config.dtype)
+
+
+def kv_pool_layers(pool) -> list:
+    """The block pools among what :func:`init_paged_cache` returns:
+    here, all of it."""
+    return pool
+
+
+def state_bytes_per_slot(config: LlamaConfig) -> int:
+    """Bytes a slot holds beside its KV blocks: none."""
+    return 0
+
+
+def layer_kinds(config: LlamaConfig) -> Dict[str, int]:
+    """Every layer is attention plus a feed-forward block, dense or
+    routed."""
+    return {"attention": config.n_layers,
+            "experts" if config.n_experts else "mlp": config.n_layers}
+
+
 def init_paged_cache(config: LlamaConfig, n_blocks: int,
                      block_size: int = 16,
-                     quantize_kv: bool = False) -> list:
+                     quantize_kv: bool = False, slots: int = 1) -> list:
     """Block pool, one dict per layer.  ``n_blocks`` INCLUDES the
-    reserved scratch block 0."""
+    reserved scratch block 0.  (``slots``: the engine tells every model
+    module how many rows it serves; only per-slot state needs it.)"""
     return _kv_layer_buffers(
         config,
         (n_blocks, block_size, config.n_kv_heads, config.head_dim),

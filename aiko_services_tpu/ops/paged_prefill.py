@@ -78,6 +78,13 @@ Q_TILE_CAP = 128
 #: one (10 MiB of state) fits with its block buffers and temporaries.
 TILE_STATE_BYTES = 12 * 2**20
 
+#: Rows (queries x group heads) of one kv head in a tile.  The kernel's
+#: temporaries are per kv head, ``(rows, head_dim)`` and ``(rows,
+#: block)`` in f32, on top of the tile's state: 128 queries of group 4
+#: (512 rows) compile; 128 queries of group 16 (2,048 rows, 2 kv heads)
+#: asked for 18.5 MiB of the 16 (TPU compiler, PR 26).
+TILE_HEAD_ROWS = 512
+
 
 # ---------------------------------------------------------------------------
 # Dispatch policy
@@ -124,16 +131,20 @@ def prefill_attention_path(head_dim: int, kv_heads: int, pool_dtype,
     return "kernel" if use_kernel else "reference"
 
 
-def _q_tile_size(chunk: int, heads: int, itemsize: int) -> int:
+def _q_tile_size(chunk: int, heads: int, itemsize: int,
+                 group: int = 1) -> int:
     """Default query tile: the largest power-of-two divisor of
-    ``chunk``, capped at :data:`Q_TILE_CAP` and at what
-    :data:`TILE_STATE_BYTES` holds.  A tile keeps, per query and query
-    head, one lane-padded 128-wide row of q and of the output (both
-    double-buffered, ``itemsize`` bytes) and of the f32 accumulator,
-    running max and denominator."""
+    ``chunk``, capped at :data:`Q_TILE_CAP`, at what
+    :data:`TILE_STATE_BYTES` holds and at :data:`TILE_HEAD_ROWS` rows
+    a kv head.  A tile keeps, per query and query head, one lane-padded
+    128-wide row of q and of the output (both double-buffered,
+    ``itemsize`` bytes) and of the f32 accumulator, running max and
+    denominator."""
     per_query = heads * 128 * (4 * itemsize + 3 * 4)
     fits = max(TILE_STATE_BYTES // per_query, 1)
-    return min(chunk & -chunk, Q_TILE_CAP, 1 << (fits.bit_length() - 1))
+    head_rows = max(TILE_HEAD_ROWS // group, 1)
+    return min(chunk & -chunk, Q_TILE_CAP, 1 << (fits.bit_length() - 1),
+               1 << (head_rows.bit_length() - 1))
 
 
 # ---------------------------------------------------------------------------
@@ -521,7 +532,8 @@ def paged_prefill_attention(q, k_new, v_new, pool, tables, cached_lens,
     meta = jnp.stack([cached_lens.astype(jnp.int32),
                       chunk_lens.astype(jnp.int32)], axis=1)
     if q_tile is None:
-        q_tile = _q_tile_size(T, kv_heads * group, q.dtype.itemsize)
+        q_tile = _q_tile_size(T, kv_heads * group, q.dtype.itemsize,
+                              group)
     if T % q_tile:
         raise ValueError(f"q_tile {q_tile} must divide chunk width {T}")
     kv_blocks = max_blocks if kv_limit is None else min(kv_limit,

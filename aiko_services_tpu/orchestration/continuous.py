@@ -62,6 +62,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import gc
 import itertools
 import threading
 import time
@@ -221,11 +222,27 @@ class ContinuousBatchingServer:
                  ring_max: Optional[int] = None):
         import jax
         import jax.numpy as jnp
+        from .. import models
         from ..models import llama
 
         self._jax = jax
         self._jnp = jnp
+        #: The module that serves ``config_name``: every model call of
+        #: the engine goes through it.  ``_llama`` stays bound for what
+        #: is only ever a Llama-family model (a speculative draft).
+        self._model, self.config = models.serving_model(config_name)
+        #: Its jitted entry points, and how many signatures they held
+        #: when the heap was last frozen (:meth:`_settle_heap`).
+        self._model_programs = [
+            entry for entry in vars(self._model).values()
+            if hasattr(entry, "_cache_size")]
+        self._programs_settled = 0
         self._llama = llama
+        self._refuse_for_recurrent_state(
+            mesh=mesh is not None, replica_mesh=replica_mesh is not None,
+            adapters=bool(adapters),
+            speculation=draft_config_name is not None
+            or draft_mode not in ("auto", "model") or bool(automata))
         # Persistent compilation cache (PR 14): opt-in per replica,
         # wired BEFORE any jit below so the very first prefill/serve
         # compiles land in (or load from) the cache — a warm restart
@@ -237,7 +254,6 @@ class ContinuousBatchingServer:
         if compilation_cache_dir:
             self.compilation_cache_dir = \
                 compiles.enable_persistent_cache(compilation_cache_dir)
-        self.config = llama.CONFIGS[config_name]
         if params is not None:
             # Caller-built weights (trained, imported, or
             # random_quantized_params) — an 8B-class server on a
@@ -247,10 +263,10 @@ class ContinuousBatchingServer:
             # re-quantization happens.
             self.params = params
         else:
-            self.params = llama.init_params(self.config,
-                                            jax.random.PRNGKey(seed))
+            self.params = self._model.init_params(
+                self.config, jax.random.PRNGKey(seed))
             if quantize:
-                self.params = llama.quantize_params(self.params)
+                self.params = self._model.quantize_params(self.params)
         if mesh is not None:
             # Multi-chip serving: megatron-TP-shard the (possibly
             # quantized) params over the mesh's "tp" axis; the decode
@@ -258,8 +274,9 @@ class ContinuousBatchingServer:
             # inserts the activation collectives.  This is the
             # composition a TP serving deployment runs.
             from jax.sharding import NamedSharding
-            specs = (llama.quantized_param_specs(self.config)
-                     if quantize else llama.param_specs(self.config))
+            specs = (self._model.quantized_param_specs(self.config)
+                     if quantize
+                     else self._model.param_specs(self.config))
             self.params = jax.tree.map(
                 lambda leaf, spec: jax.device_put(
                     leaf, NamedSharding(mesh, spec)),
@@ -549,8 +566,18 @@ class ContinuousBatchingServer:
             slice_wait_ms=0.0, prefill_run_ms=0.0, first_chunk_ms=0.0,
             prefill_slices=0, prefill_slices_mixed=0,
             prefill_backlog=0, prompt_tokens=0,
-            deadline_exceeded=0, shed=0, watchdog_trips=0),
+            deadline_exceeded=0, shed=0, watchdog_trips=0,
+            heap_freezes=0),
             prefix="server", labels=self._metrics_labels)
+        for name in self._model.COUNTERS:
+            # What a serve chunk of this model module returns beside
+            # its tokens; added when the chunk is read (_consume_ready).
+            self.counters[name] = 0
+        if self._model.RECURRENT_STATE:
+            # Prompt tokens that advanced a slot's recurrent state, and
+            # prompts that began from a zero state (paged._state_slice).
+            self.counters["ssm_prefill_tokens"] = 0
+            self.counters["ssm_state_resets"] = 0
         # Per-phase latency histograms — FIXED log-spaced buckets, so
         # the router/loadgen can merge them across replicas exactly
         # (they ride EC shares as ``hist.<phase>`` encoded strings).
@@ -588,6 +615,11 @@ class ContinuousBatchingServer:
         #: Parked here so that the layout's ``_serve_chunk`` can note
         #: the prefill slice the chunk carries: the dispatch's cause.
         self._dispatch_span = None
+        #: what the last ``_serve_chunk`` got back beyond tokens,
+        #: counts, state and pool (``self._model.COUNTERS``: device
+        #: scalars, read with the chunk's tokens), until the chunk's
+        #: ring entry takes it.
+        self._chunk_counters = None
         self._profiles = 0
         self._profile_idle = 0
 
@@ -599,6 +631,47 @@ class ContinuousBatchingServer:
             return jax.tree.map(merge, state, host_state)
 
         self._merge_state = merge_state
+
+    def _refuse_for_recurrent_state(self, **asked) -> None:
+        """A model module with per-slot recurrent state
+        (``RECURRENT_STATE``) is refused, at construction, everything
+        that would need a copy of that state the engine cannot take
+        yet.  ``asked``: feature -> whether the caller asked for it."""
+        if not self._model.RECURRENT_STATE:
+            return
+        missing = {
+            "mesh": "a sharding rule for the per-slot state (this "
+                    "model module has only the single-chip programs)",
+            "replica_mesh": "a shard_map engine for this model module "
+                            "(llama_tp serves Llama-family layers only)",
+            "adapters": "LoRA factors through the Mamba and expert "
+                        "projections",
+            "speculation": "a rollback of the recurrent state to the "
+                           "last accepted token (a rejected window has "
+                           "already advanced it)",
+            "prefix_cache": "a snapshot of the recurrent state at "
+                            "block boundaries (a block hit has keys "
+                            "and values behind it, and no state)",
+            "host_tier": "a snapshot of the recurrent state at block "
+                         "boundaries to demote with the blocks",
+            "spill": "a snapshot of the recurrent state at block "
+                     "boundaries to spill with the blocks",
+            "kv_transfer": "the recurrent state at the segment's end to "
+                           "travel with its blocks (a snapshot at block "
+                           "boundaries)",
+            "migration": "the slot's live recurrent state to travel "
+                         "with its block chain",
+            "contiguous_layout": "contiguous-cache programs in this "
+                                 "model module (serve it with "
+                                 "PagedContinuousServer)",
+        }
+        for feature, wanted in asked.items():
+            if wanted:
+                raise ValueError(
+                    f"{feature} is not available for a model with "
+                    f"per-slot recurrent state "
+                    f"({self._model.__name__.rsplit('.', 1)[-1]}): it "
+                    f"needs {missing[feature]}")
 
     def _init_device_state(self) -> Dict:
         """Device-resident per-slot serving state (layout hook: the
@@ -732,7 +805,7 @@ class ContinuousBatchingServer:
         if self._mesh is not None:
             return self._llama_tp.scatter_state_rows(
                 state, padded, packet, self._mesh)
-        return self._llama.scatter_state_rows(state, padded, packet)
+        return self._model.scatter_state_rows(state, padded, packet)
 
     def _attention_blocks(self):
         """``(block_size, total_blocks_per_row)`` of the decode-
@@ -747,8 +820,7 @@ class ContinuousBatchingServer:
         """``(head_dim, local kv heads, KV dtype)`` as the attention
         dispatch sees them (the paged server divides the heads by its
         tensor-parallel degree)."""
-        dtype = self._jnp.int8 if self.quantize_kv else self.config.dtype
-        return self.config.head_dim, self.config.n_kv_heads, dtype
+        return self._model.kv_geometry(self.config, self.quantize_kv)
 
     def _attention_paths(self):
         """``(decode, prefill)`` path tags of the contiguous layout:
@@ -793,7 +865,8 @@ class ContinuousBatchingServer:
         contiguous layout reserves ``slots x max_seq`` rows."""
         jax = self._jax
 
-        self.cache = self._llama.init_cache(
+        self._refuse_for_recurrent_state(contiguous_layout=True)
+        self.cache = self._model.init_cache(
             self.config, self.slots, self.max_seq,
             quantize_kv=self.quantize_kv)
         if self._mesh is not None:
@@ -1106,7 +1179,7 @@ class ContinuousBatchingServer:
             request=request, prompt_padded=prompt_padded,
             prompt_len=prompt_len, start=0,
             lora=self._request_lora(request),
-            bucket=self._llama.init_cache(
+            bucket=self._model.init_cache(
                 self.config, 1, prompt_padded.shape[1],
                 quantize_kv=self.quantize_kv))
 
@@ -1122,7 +1195,7 @@ class ContinuousBatchingServer:
                        state["prompt_padded"].shape[1] - start)
             chunk = state["prompt_padded"][:, start:start + size]
             self._note_prefill(size, (state["request"],), sliced=True)
-            _, state["bucket"] = self._llama.prefill_chunk(
+            _, state["bucket"] = self._model.prefill_chunk(
                 self.params, jnp.asarray(chunk), state["bucket"],
                 jnp.int32(start), self.config, lora=state["lora"])
             state["start"] = start + size
@@ -1187,10 +1260,10 @@ class ContinuousBatchingServer:
                 # The prompt KV must be built under the SAME adapter
                 # the decode chunks will run (None for all-base).
                 lora = self._make_lora([aid for _, _, aid, _ in sub])
-                bucket_cache = self._llama.init_cache(
+                bucket_cache = self._model.init_cache(
                     self.config, len(sub), padded,
                     quantize_kv=self.quantize_kv)
-                _, bucket_cache = self._llama.prefill(
+                _, bucket_cache = self._model.prefill(
                     self.params, jnp.asarray(prompts), bucket_cache,
                     self.config, lora=lora)
                 slot_rows = jnp.asarray(np.asarray(slots, np.int32))
@@ -1485,11 +1558,11 @@ class ContinuousBatchingServer:
         bucket cache.  Used by the PAGED server's cache-miss path (its
         prefix-cache walk is per-slot); the contiguous layout itself
         admits through the batched ``_prefill_and_insert``."""
-        llama, jnp = self._llama, self._jnp
-        bucket_cache = llama.init_cache(
+        model, jnp = self._model, self._jnp
+        bucket_cache = model.init_cache(
             self.config, 1, prompt_padded.shape[1],
             quantize_kv=self.quantize_kv)
-        _, bucket_cache = llama.prefill(
+        _, bucket_cache = model.prefill(
             self.params, jnp.asarray(prompt_padded), bucket_cache,
             self.config, lora=lora)
         return bucket_cache
@@ -1674,6 +1747,7 @@ class ContinuousBatchingServer:
         dispatched = False
         while len(self._ring) < depth and self._dispatch_round():
             dispatched = True
+        self._settle_heap()
         target = depth - 1 if dispatched else 0
         if len(self._ring) > target:
             self._consume_ready(len(self._ring) - target)
@@ -1688,6 +1762,34 @@ class ContinuousBatchingServer:
             self._fail_all("watchdog_stalled")
         done, self.completed = self.completed, []
         return done
+
+    def _settle_heap(self) -> None:
+        """Keep the collector off the compiled programs.  Tracing a
+        serving program leaves hundreds of thousands of long-lived
+        Python objects (jaxprs, avals, tracebacks: 320,000 for one
+        mixed program of an 11-layer model, 0.12 s a full collection
+        here), and a full collection walks all of them with the
+        interpreter lock held: with thirty programs resident it
+        stalled the engine loop, and so the device, for 1.7 to 3.0 s
+        once or twice a run at 400 streamed partials a second (my chip
+        runs, PR 26).  ``gc.freeze()`` moves everything alive into the
+        permanent generation, which no collection visits.  It is
+        called after a step in which the model module's jitted entry
+        points traced a signature they did not hold (their caches
+        grew: a count the engine reads, no clock), so at most once a
+        program of the process, whichever server dispatched it first,
+        and never once the programs are warm.  What else is alive at
+        that moment is frozen with it: requests hold no cycles, so
+        reference counts still free them, and a frozen cycle that
+        dies stays until the process ends.  Not covered: programs of
+        the shard_map engine (``replica_mesh``), which live in
+        another module."""
+        traced = sum(program._cache_size()
+                     for program in self._model_programs)
+        if traced != self._programs_settled:
+            self._programs_settled = traced
+            gc.freeze()
+            self.counters["heap_freezes"] += 1
 
     def _evict_expired(self) -> None:
         """Deadline enforcement between chunks: drop expired queued
@@ -1833,7 +1935,9 @@ class ContinuousBatchingServer:
         self._ring.append(dict(
             kind="chunk", tokens=tokens_d, counts=counts_d,
             active_after=self._state["active"], steps=steps,
-            sched=sched, serial=serial))
+            sched=sched, serial=serial,
+            model_counters=self._chunk_counters))
+        self._chunk_counters = None
         self._note_dispatch()
         return True
 
@@ -1854,7 +1958,7 @@ class ContinuousBatchingServer:
         admission order, budgets, EOS, retirement — stays in this
         class (and most of THAT now runs in-jit)."""
         tokens_d, counts_d, new_state, self.cache = \
-            self._llama.serve_chunk_ragged(
+            self._model.serve_chunk_ragged(
                 self.params, state, self.cache, steps, self.config,
                 eos_id=eos_id, sampled=sampled, rng_key=rng_key,
                 lora_shared=lora_shared)
@@ -2082,7 +2186,7 @@ class ContinuousBatchingServer:
         the paged server overrides this with the pool-direct
         :func:`~..models.llama.verify_chunk_paged` (and its TPEngine
         twin under a replica mesh)."""
-        logits, self.cache = self._llama.verify_chunk_ragged(
+        logits, self.cache = self._model.verify_chunk_ragged(
             self.params, chunk, self.cache, st["positions"],
             st["active"], self.config, lora=lora)
         return logits
@@ -2206,6 +2310,9 @@ class ContinuousBatchingServer:
                          + entry["active_after"].size)
             if entry["kind"] == "spec":
                 entry["counts_full"] = np.asarray(entry["counts_full"])
+            for name, value in (entry.get("model_counters")
+                                or {}).items():
+                self.counters[name] += int(np.asarray(value))
         if alarm is not None:
             alarm.cancel()
             if time.monotonic() - wait_start > self.watchdog_s:

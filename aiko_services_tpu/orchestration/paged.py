@@ -198,9 +198,14 @@ class PagedContinuousServer(ContinuousBatchingServer):
                          self.slots * max_blocks // 2)
         else:
             usable = self._requested_blocks
-        self.pool = self._llama.init_paged_cache(
+        self._refuse_for_recurrent_state(
+            prefix_cache=self.enable_prefix_cache,
+            host_tier=self.host_tier_blocks > 0,
+            spill=self.spill_dir is not None)
+        self.pool = self._model.init_paged_cache(
             self.config, usable + 1, block_size,
-            quantize_kv=self.quantize_kv)            # +1: scratch
+            quantize_kv=self.quantize_kv,            # +1: scratch
+            slots=self.slots)
         self._tp_engine = None
         if self._mesh is not None:
             # TP replica: the pool becomes a GLOBAL jax.Array sharded
@@ -401,6 +406,13 @@ class PagedContinuousServer(ContinuousBatchingServer):
             kv_prefetch_promotions=self.kv_prefetch_promotions,
             free_blocks=self.free_blocks,
             total_blocks=self.total_blocks,
+            # What a slot holds beside its blocks (a recurrent model's
+            # convolution windows and states), and the stack's layers.
+            state_bytes_per_slot=self._model.state_bytes_per_slot(
+                self.config),
+            layer_kinds=",".join(
+                f"{kind}={count}" for kind, count
+                in self._model.layer_kinds(self.config).items()),
             kv_hbm_blocks=self.total_blocks - len(self._free),
             kv_hbm_bytes=(self.total_blocks - len(self._free))
             * self._block_nbytes(),
@@ -1555,6 +1567,26 @@ class PagedContinuousServer(ContinuousBatchingServer):
             worst = tier if worst is None else max(worst, tier)
         return worst
 
+    def _state_slice(self, slot: int, prompt, start: int,
+                     width: int) -> dict:
+        """What a model module with per-slot recurrent state is told
+        about a prefill slice beyond its tokens: whose state it carries
+        and how many of its tokens advance it.  The prompt's LAST token
+        does not (the first decode step processes it,
+        :meth:`_activate_slot`), nor does the bucket's padding; a slice
+        at ``start`` 0 begins from a zero state inside the program, so
+        a reused slot needs no dispatch of its own.  Nothing, for a
+        module without such state: its programs stay as they are."""
+        if not self._model.RECURRENT_STATE:
+            return {}
+        jnp = self._jnp
+        counted = max(0, min(width, len(prompt) - 1 - start))
+        if start == 0 and len(prompt):
+            self.counters["ssm_state_resets"] += 1
+        self.counters["ssm_prefill_tokens"] += counted
+        return dict(state_row=jnp.int32(slot),
+                    valid_len=jnp.int32(counted))
+
     def _prefill_and_insert(self, admissions) -> None:
         """Append-attention admission: each request's chunk K/V lands
         straight in its own blocks and shared prefix blocks are only
@@ -1606,7 +1638,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
         the kernel's attention sweep, never copied).  The uncached
         tail runs as descending power-of-two pieces so arbitrary
         prefix lengths reuse log-many program shapes per bucket."""
-        llama, jnp = self._llama, self._jnp
+        model, jnp = self._model, self._jnp
         self._pending_shared[slot] = 0
         block_size = self.block_size
         padded = prompt_padded.shape[1]
@@ -1635,10 +1667,12 @@ class PagedContinuousServer(ContinuousBatchingServer):
                     tables_row, jnp.int32(start), lora=lora,
                     kv_limit=kv_limit)
             else:
-                _, self.pool = llama.prefill_append_paged(
+                _, self.pool = model.prefill_append_paged(
                     self.params, jnp.asarray(chunk), self.pool,
                     tables_row, jnp.int32(start), self.config,
-                    lora=lora, kv_limit=kv_limit, compute_logits=False)
+                    lora=lora, kv_limit=kv_limit, compute_logits=False,
+                    **self._state_slice(slot, request.prompt, start,
+                                        width))
             start += width
             remaining -= size
         # The span holds this prefill's enqueues (and, on a throttled
@@ -1733,7 +1767,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
             return
         if self._spec is None and (self._plan_remaining() > 0).any():
             return
-        llama, jnp = self._llama, self._jnp
+        model, jnp = self._model, self._jnp
         for slot in list(self._prefilling):
             state = self._prefilling[slot]
             start = state["start"]
@@ -1761,11 +1795,13 @@ class PagedContinuousServer(ContinuousBatchingServer):
                     tables_row, jnp.int32(start), lora=lora,
                     kv_limit=state["kv_limit"])
             else:
-                _, self.pool = llama.prefill_append_paged(
+                _, self.pool = model.prefill_append_paged(
                     self.params, jnp.asarray(chunk), self.pool,
                     tables_row, jnp.int32(start), self.config,
                     lora=lora,
-                    kv_limit=state["kv_limit"], compute_logits=False)
+                    kv_limit=state["kv_limit"], compute_logits=False,
+                    **self._state_slice(slot, state["request"].prompt,
+                                        start, width))
             state["start"] = start + width
             if state["start"] >= state["prompt_len"]:
                 self._finish_prefill(slot, state)
@@ -1861,11 +1897,12 @@ class PagedContinuousServer(ContinuousBatchingServer):
                                 kv_limit=kv_limit)
                     else:
                         _, self.pool = \
-                            self._llama.prefill_append_paged(
+                            self._model.prefill_append_paged(
                                 self.params, tokens, self.pool,
                                 tables_row, jnp.int32(0), self.config,
                                 lora=lora, kv_limit=kv_limit,
-                                compute_logits=False)
+                                compute_logits=False,
+                                **self._state_slice(0, (), 0, width))
                     dispatched += 1
         return dispatched
 
@@ -1905,7 +1942,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
         chunk run as ONE jitted program
         (:func:`~..models.llama.serve_chunk_mixed`), so admission no
         longer stalls the running batch between chunks."""
-        llama, jnp = self._llama, self._jnp
+        model, jnp = self._model, self._jnp
         slot = next(iter(self._prefilling), None) \
             if self._prefilling else None
         if slot is None:
@@ -1916,11 +1953,12 @@ class PagedContinuousServer(ContinuousBatchingServer):
                         eos_id=eos_id, sampled=sampled,
                         rng_key=rng_key, lora_shared=lora_shared)
             else:
-                tokens_d, counts_d, new_state, self.pool = \
-                    llama.serve_chunk_paged(
+                tokens_d, counts_d, new_state, self.pool, *more = \
+                    model.serve_chunk_paged(
                         self.params, state, self.pool, steps,
                         self.config, eos_id=eos_id, sampled=sampled,
                         rng_key=rng_key, lora_shared=lora_shared)
+                self._chunk_counters = more[0] if more else None
             return tokens_d, counts_d, new_state
         prefill = self._prefilling[slot]
         start = prefill["start"]
@@ -1959,13 +1997,16 @@ class PagedContinuousServer(ContinuousBatchingServer):
                     lora_shared=lora_shared,
                     prefill_kv_limit=prefill["kv_limit"])
         else:
-            tokens_d, counts_d, new_state, self.pool = \
-                llama.serve_chunk_mixed(
+            tokens_d, counts_d, new_state, self.pool, *more = \
+                model.serve_chunk_mixed(
                     self.params, state, self.pool, jnp.asarray(chunk),
                     jnp.int32(slot), jnp.int32(start), steps,
                     self.config, eos_id=eos_id, sampled=sampled,
                     rng_key=rng_key, lora_shared=lora_shared,
-                    prefill_kv_limit=prefill["kv_limit"])
+                    prefill_kv_limit=prefill["kv_limit"],
+                    **self._state_slice(slot, prefill["request"].prompt,
+                                        start, width))
+            self._chunk_counters = more[0] if more else None
         prefill["start"] = start + width
         if prefill["start"] >= prefill["prompt_len"]:
             self._finish_prefill(slot, prefill)
@@ -1988,7 +2029,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
                 self.params, chunk, self.pool, st["tables"],
                 st["positions"], st["active"], lora=lora)
             return logits
-        logits, self.pool = self._llama.verify_chunk_paged(
+        logits, self.pool = self._model.verify_chunk_paged(
             self.params, chunk, self.pool, st["tables"],
             st["positions"], st["active"], self.config, lora=lora)
         return logits
@@ -2140,6 +2181,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
         slot's ref like any admission-registered key, so
         ``_release_slot`` at the request's (post-cutover) retirement
         leaves them cached-evictable — no new lifecycle."""
+        self._refuse_for_recurrent_state(migration=True)
         if not self.enable_prefix_cache:
             return 0
         adapter_id = self._adapter_id(request)
@@ -2218,6 +2260,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
         pool rows host-side.  Returns the wire dict or ``None`` (the
         segment is gone — caller answers with an error and the
         importer recomputes)."""
+        self._refuse_for_recurrent_state(kv_transfer=True)
         started = time.perf_counter()
         payload = _kvxfer.export_payload(self, keys_hex, start_depth)
         if payload is None:
@@ -2237,6 +2280,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
         path) registers the keys behind the ``RESTORING`` sentinel
         and lands the rows a few blocks per step — see
         :func:`~..kvstore.transfer.import_payload`."""
+        self._refuse_for_recurrent_state(kv_transfer=True)
         started = time.perf_counter()
         imported = _kvxfer.import_payload(self, payload,
                                           engine=engine,
