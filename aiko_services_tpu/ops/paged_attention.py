@@ -366,7 +366,8 @@ def _bf16_terms(x):
 
 
 def _contract_pool_rows(lhs, rows, dims):
-    """``lhs`` (f32) against pool rows at f32 contract precision.
+    """``lhs`` (f32 or bf16) against pool rows at f32 contract
+    precision.
 
     f32 rows: the MXU at :data:`MXU_PRECISION`, six bf16 passes over
     the rows.  bf16 rows (a bf16 pool's, or an int8 pool's widened:
@@ -374,11 +375,15 @@ def _contract_pool_rows(lhs, rows, dims):
     a row is exact and the MXU accumulates in f32, so ONE pass over
     the rows with the three terms of ``lhs`` stacked gives the same
     precision — the decode kernel's time is in those passes and in
-    widening the rows, not in bytes (v5e, PERF.md PR 25)."""
+    widening the rows, not in bytes (v5e, PERF.md PR 25).  A bf16
+    ``lhs`` (the append kernel's queries) is its own single term."""
     if rows.dtype != jnp.bfloat16:
         return jax.lax.dot_general(
-            lhs, rows, (dims, ((), ())), precision=MXU_PRECISION,
-            preferred_element_type=jnp.float32)
+            lhs.astype(jnp.float32), rows, (dims, ((), ())),
+            precision=MXU_PRECISION, preferred_element_type=jnp.float32)
+    if lhs.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(lhs, rows, (dims, ((), ())),
+                                   preferred_element_type=jnp.float32)
     n = lhs.shape[0]
     out = jax.lax.dot_general(_bf16_terms(lhs), rows, (dims, ((), ())),
                               preferred_element_type=jnp.float32)

@@ -18,20 +18,28 @@ full gather.  The append kernel here removes both copies:
   write.  Blocks past ``chunk_len`` retarget the allocator's reserved
   scratch block 0 (never attendable, the same contract inactive decode
   lanes rely on).
-* an **attention kernel** (grid ``(row, query-tile, kv-block)``, kv
-  fastest) runs flash-style online softmax for the chunk's queries
-  over the row's cached prefix blocks plus the causally-visible part
-  of the chunk itself, reading whole pool blocks and splitting heads
-  in-kernel (:func:`~.paged_attention.load_head_rows`).  Per-row
-  ``(cached_len, chunk_len)`` metadata rides scalar prefetch; dead
-  steps (blocks past the tile's last query, or wholly below its
-  sliding window) clamp their index map to a resident block and skip
-  compute, so a row's HBM traffic is O(its real history).
+* an **attention kernel** (grid ``(row, query-tile, band step)``,
+  band steps fastest) runs flash-style online softmax for the chunk's
+  queries over the tile's LIVE band of table entries — from the block
+  the tile's first query's sliding window still reaches to the block
+  of its last query — 128 keys a step (``P = 128 / block_size`` pool
+  blocks; each pool array is passed ``P`` times, and copy ``i`` of
+  step ``j`` is one lookup in a scalar-prefetched plan of the band's
+  pool blocks that XLA resolves from the table once a call), laying
+  the step's whole blocks side by side in VMEM and splitting heads
+  in-kernel (:func:`~.paged_attention.load_head_rows`, one strided
+  read of 128 rows a head).  Score, mask, softmax update and
+  accumulator rescale happen once per 128 keys on full-lane tiles;
+  int8 rows stay bf16-exact and their scales multiply the score and
+  weight tiles (:func:`~.paged_attention._contract_pool_rows`).
+  Steps past the band's end repeat the last live step's blocks (no
+  HBM copy) and skip compute, so a row's HBM traffic is O(its real
+  history) and its time is in live steps.
 * all ``group`` query heads of a kv head stack into the tile's row
   axis (``(q_tile·group, head_dim)``), so masking is per-row by
   absolute ids and every matmul is MXU-shaped 2D.
 * unlike single-token decode, a multi-query tile CAN hold rows with no
-  visible key in a live block (a later chunk row's first block, or a
+  visible key in a live step (a later chunk row's first block, or a
   window that has slid past), so masked positions are explicitly
   zeroed in the probability tile — the decode kernel's "every live
   block has a visible key" invariant does not extend here.
@@ -59,12 +67,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import NEG_INF
-from .paged_attention import (MXU_PRECISION, cached_gqa_attention,
-                              kernel_mode, kernel_serves,
-                              load_head_rows, runs_kernel)
+from .paged_attention import (MXU_PRECISION, _contract_pool_rows,
+                              cached_gqa_attention,
+                              decode_blocks_per_iteration, kernel_mode,
+                              kernel_serves, load_head_rows, runs_kernel)
 
 __all__ = ["paged_prefill_attention", "paged_prefill_reference",
            "paged_verify_attention",
+           "paged_prefill_call", "prefill_key_blocks",
            "prefill_kernel_mode", "prefill_dispatch",
            "verify_dispatch", "prefill_attention_path"]
 
@@ -145,6 +155,25 @@ def _q_tile_size(chunk: int, heads: int, itemsize: int,
     head_rows = max(TILE_HEAD_ROWS // group, 1)
     return min(chunk & -chunk, Q_TILE_CAP, 1 << (fits.bit_length() - 1),
                1 << (head_rows.bit_length() - 1))
+
+
+def prefill_key_blocks(start: int, width: int, block_size: int,
+                       window: Optional[int], *, heads: int, group: int,
+                       itemsize: int) -> int:
+    """Key blocks x query tiles the attention of ONE append slice
+    ``[start, start + width)`` has to visit in one layer: for each
+    query tile (:func:`_q_tile_size`), the pool blocks from the first
+    its sliding window still reaches to the one holding its last query
+    — what :func:`_live_bands` gives the kernel, counted on the host
+    (the serving counter ``prefill_key_blocks``).  With the kernel's
+    device time over the same dispatches it gives microseconds per
+    ``128 / block_size`` blocks per tile, a step of the sweep."""
+    q_tile = _q_tile_size(width, heads, itemsize, group)
+    total = 0
+    for q_min in range(start, start + width, q_tile):
+        first = max(q_min - window + 1, 0) // block_size if window else 0
+        total += (q_min + q_tile - 1) // block_size - first + 1
+    return total
 
 
 # ---------------------------------------------------------------------------
@@ -302,32 +331,116 @@ def _append_kv(k_new, v_new, pool, tables, meta, interpret: bool):
 # The attention kernel: chunk queries over cached prefix + chunk
 
 
-def _prefill_attention_kernel(tables_ref, meta_ref,   # scalar prefetch
-                              q_ref, k_ref, v_ref, *rest,
-                              block_size: int, q_tile: int, group: int,
+def _live_bands(cached_lens, n_tiles: int, *, q_tile: int,
+                block_size: int, window: Optional[int], kv_blocks: int):
+    """``(q_min, first, last)``, each ``(batch, n_tiles)``: per query
+    tile of a row holding ``cached`` positions, the tile's first query
+    position and the first and last table entries its queries can see
+    — from the block the FIRST query's sliding window still reaches to
+    the block of the LAST query (never past the ``kv_blocks`` the sweep
+    is bounded to)."""
+    q_min = (cached_lens[:, None]
+             + jnp.arange(n_tiles, dtype=jnp.int32)[None, :] * q_tile)
+    last = jnp.minimum((q_min + q_tile - 1) // block_size, kv_blocks - 1)
+    first = jnp.zeros_like(last)
+    if window is not None:
+        first = jnp.minimum(
+            jnp.maximum(q_min - window + 1, 0) // block_size, last)
+    return q_min, first, last
+
+
+def _band_steps(q_tile: int, block_size: int, window: Optional[int],
+                kv_blocks: int, blocks_per_step: int) -> int:
+    """Grid steps that cover the longest live band a tile can have:
+    the bounded table, or with a sliding window the blocks that
+    ``window + q_tile`` positions can straddle."""
+    blocks = kv_blocks
+    if window is not None:
+        blocks = min(blocks, (q_tile + window - 2) // block_size + 2)
+    return -(-blocks // blocks_per_step)
+
+
+def _sweep_plan(tables, cached_lens, n_tiles: int, steps: int, P: int,
+                **geometry):
+    """What the sweep's scalar prefetch carries, computed by XLA once
+    a call (a gather of a few hundred integers out of the row's table)
+    so that an index map is ONE table lookup: ``bands`` ``(batch ·
+    n_tiles, 3)`` = :func:`_live_bands`, and ``blocks`` ``(batch ·
+    n_tiles, steps · P)``, the pool block behind every (tile, step,
+    copy).  A step past the band's end is clamped onto its last live
+    step, and an entry past the band's last onto that one: an unchanged
+    block index makes Pallas keep the resident VMEM tile instead of
+    issuing a fresh HBM copy, and no entry a tile cannot see is ever
+    dereferenced."""
+    q_min, first, last = _live_bands(cached_lens, n_tiles, **geometry)
+    step = jnp.minimum(jnp.arange(steps, dtype=jnp.int32),
+                       ((last - first) // P)[..., None])
+    entry = jnp.minimum(
+        first[..., None, None] + step[..., None] * P
+        + jnp.arange(P, dtype=jnp.int32), last[..., None, None])
+    batch = tables.shape[0]
+    blocks = jnp.take_along_axis(
+        tables, entry.reshape(batch, n_tiles * steps * P), axis=1)
+    return (jnp.stack([q_min, first, last], axis=-1).reshape(-1, 3),
+            blocks.reshape(batch * n_tiles, steps * P))
+
+
+def _head_scale_rows(planes):
+    """Per-head, lane-dense scales ``(kv_heads, keys)`` from a step's
+    scale planes ``(keys, kv_heads)`` (keys on sublanes, as the pool
+    holds them): a contraction with the identity over the head axis,
+    so a selection — exact at :data:`MXU_PRECISION`."""
+    kv_heads = planes.shape[1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (kv_heads, kv_heads), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (kv_heads, kv_heads), 1)
+           ).astype(jnp.float32)
+    return jax.lax.dot_general(
+        eye, planes, (((1,), (1,)), ((), ())), precision=MXU_PRECISION,
+        preferred_element_type=jnp.float32)
+
+
+def _lanes(column, width: int):
+    """A per-row statistic kept lane-replicated ``(rows, n)`` (every
+    lane of a row the same value) at ``width`` lanes: itself where the
+    widths agree (compiled: score tile and head_dim are both 128)."""
+    if column.shape[1] == width:
+        return column
+    return jnp.broadcast_to(column[:, :1], (column.shape[0], width))
+
+
+def _prefill_attention_kernel(bands_ref, blocks_ref,   # scalar prefetch
+                              q_ref, *rest,
+                              blocks_per_step: int, group: int,
                               sm_scale: float, window: Optional[int],
                               quantized: bool):
-    """Grid: (batch, q_tiles, kv_blocks); kv fastest.
+    """Grid: (batch, q_tiles, band steps); band steps fastest.
 
     One program sweeps one (row, query-tile), every kv head, through
-    the row's pool blocks carrying online-softmax state in VMEM
-    scratch.  The tile's row axis interleaves queries and their group
-    heads (``row = token·group + head``), so per-row masking by
-    absolute ids covers ragged causality AND the sliding window in one
-    2D tile."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-    b = pl.program_id(0)
-    qt = pl.program_id(1)
+    the tile's LIVE band of table entries, ``blocks_per_step`` pool
+    blocks (128 keys) a step, carrying online-softmax state in VMEM
+    scratch.  Step ``j`` holds entries ``first + j·P … + P - 1`` (each
+    pool array arrives ``P`` times, once per entry of the step, at the
+    blocks :func:`_sweep_plan` resolved); a step past the band's end
+    repeats the last live step's blocks (no copy) and skips compute.
+    The tile's row axis interleaves queries and their group heads
+    (``row = token·group + head``), so per-row masking by absolute ids
+    covers ragged causality, the sliding window AND the entries a short
+    last step clamps, in one 2D tile.
+
+    Running max and denominator are kept lane-replicated, as wide as
+    the score tile: every vector op of the update is a full-lane op on
+    ``(rows, 128)`` tiles, once per 128 keys."""
+    P = blocks_per_step
+    pools, (o_ref, m_scr, l_scr, acc_scr, k_buf, v_buf) = (rest[:-6],
+                                                            rest[-6:])
+    k_refs, v_refs = pools[:P], pools[P:2 * P]
     j = pl.program_id(2)
-    num_j = pl.num_programs(2)
-    cached = meta_ref[b, 0]
-    q_min = cached + qt * q_tile          # tile's first query position
-    q_max = q_min + q_tile - 1            # tile's last query position
-    kv_heads = k_ref.shape[2]
-    rows = q_tile * group
+    tile = pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)
+    q_min, first, last = (bands_ref[tile, 0], bands_ref[tile, 1],
+                          bands_ref[tile, 2])
+    block_size, kv_heads, head_dim = k_refs[0].shape[1:]
+    rows = q_ref.shape[2]
+    keys = P * block_size
 
     @pl.when(j == 0)
     def _init():
@@ -335,134 +448,160 @@ def _prefill_attention_kernel(tables_ref, meta_ref,   # scalar prefetch
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # Liveness: a block wholly past the tile's LAST query contributes
-    # nothing; with a sliding window, neither does a block whose last
-    # key is out of even the FIRST query's window.  Dead steps also
-    # clamp their index map (see kv_index) so they trigger no HBM→VMEM
-    # copy.
-    block_live = j * block_size <= q_max
-    if window is not None:
-        block_live &= (j + 1) * block_size - 1 > q_min - window
+    entry = first + j * P               # the step's first table entry
 
-    @pl.when(block_live)
+    @pl.when(entry <= last)
     def _compute():
         q_ids = q_min + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_size), 0) // group
-        key_ids = jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_size), 1) + j * block_size
-        visible = key_ids <= q_ids
+            jnp.int32, (rows, keys), 0) // group
+        key_ids = entry * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 1)
+        # Entries past `last` inside the band's last step were clamped
+        # to it by the index maps: their ids lie past every query of
+        # the tile (or past the bounded table) and are masked here.
+        visible = (key_ids <= q_ids) & (key_ids < (last + 1) * block_size)
         if window is not None:
             visible &= key_ids > q_ids - window
+        # Pool rows that are bf16 values (bf16 and widened int8) meet
+        # the MXU as bf16: see _contract_pool_rows.
+        row_dtype = (jnp.float32 if k_refs[0].dtype == jnp.float32
+                     else jnp.bfloat16)
+        score_scale = sm_scale
         if quantized:
-            k_scales = ks_ref[0]                   # (bs, kv_heads)
-            v_scales = vs_ref[0]
-        for head in range(kv_heads):
-            q = q_ref[0, head].astype(jnp.float32)    # (rows, hd)
-            k = load_head_rows(k_ref.at[0], head)     # (bs, hd) f32
-            v = load_head_rows(v_ref.at[0], head)
-            if quantized:
-                k = k * k_scales[:, head:head + 1]
-                v = v * v_scales[:, head:head + 1]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), precision=MXU_PRECISION,
-                preferred_element_type=jnp.float32) * sm_scale
-            s = jnp.where(visible, s, NEG_INF)
+            # Per-(token, head) scales factor OUT of the q·k
+            # contraction and INTO the softmax weights
+            # (cached_gqa_attention has the algebra): they multiply the
+            # (rows, keys) tiles, and the int8 rows stay bf16-exact.
+            ks_refs, vs_refs = pools[2 * P:3 * P], pools[3 * P:]
+            k_scales = _head_scale_rows(jnp.concatenate(
+                [ref[0] for ref in ks_refs], axis=0)) * sm_scale
+            v_scales = _head_scale_rows(jnp.concatenate(
+                [ref[0] for ref in vs_refs], axis=0))
 
-            m_prev = m_scr[head]                      # (rows, 1)
+        # The step's P blocks side by side in one buffer: a head's 128
+        # rows are then ONE strided read and one widening, not P of
+        # each (the kernel's Mosaic lowering is priced per read and
+        # per conversion, docs/KERNELS.md).
+        for i in range(P):
+            k_buf[i * block_size:(i + 1) * block_size] = k_refs[i][0]
+            v_buf[i * block_size:(i + 1) * block_size] = v_refs[i][0]
+
+        for head in range(kv_heads):
+            if quantized:
+                score_scale = k_scales[head:head + 1, :]
+            s = _contract_pool_rows(
+                q_ref[0, head],
+                load_head_rows(k_buf, head, row_dtype),
+                ((1,), (1,))) * score_scale
+            s = jnp.where(visible, s, NEG_INF)       # (rows, keys)
+
+            m_prev = m_scr[head]                     # (rows, keys)
             m_new = jnp.maximum(m_prev,
                                 jnp.max(s, axis=-1, keepdims=True))
-            # A live block can hold rows with NO visible key (later
+            # A live step can hold rows with NO visible key (later
             # chunk rows, or a window that slid past): their m stays
             # NEG_INF and exp(NEG_INF - NEG_INF) = 1 would be bogus
             # mass — zero masked probabilities explicitly (the
             # single-query decode kernel's
-            # every-live-block-has-a-visible-key invariant does not
+            # every-live-group-has-a-visible-key invariant does not
             # extend to multi-query tiles).
             p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
             correction = jnp.exp(m_prev - m_new)
             l_scr[head] = correction * l_scr[head] + jnp.sum(
                 p, axis=-1, keepdims=True)
+            if quantized:
+                p = p * v_scales[head:head + 1, :]
             acc_scr[head] = (
-                acc_scr[head] * correction + jax.lax.dot_general(
-                    p, v, (((1,), (0,)), ((), ())),
-                    precision=MXU_PRECISION,
-                    preferred_element_type=jnp.float32))
+                acc_scr[head] * _lanes(correction, head_dim)
+                + _contract_pool_rows(
+                    p, load_head_rows(v_buf, head, row_dtype),
+                    ((1,), (0,))))
             m_scr[head] = m_new
 
-    @pl.when(j == num_j - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        denom = jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
-        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+        for head in range(kv_heads):
+            denom = _lanes(l_scr[head], head_dim)
+            o_ref[0, head] = (
+                acc_scr[head] / jnp.where(denom == 0.0, 1.0, denom)
+            ).astype(o_ref.dtype)
 
 
-def _chunk_attention(q, pool, tables, meta, window: Optional[int],
-                     sm_scale: float, q_tile: int,
-                     kv_blocks: int, interpret: bool):
-    """Dispatch the attention kernel over the (already appended) pool.
-    ``q`` (batch, T, kv, group, hd) → out same shape."""
+@functools.partial(jax.jit,
+                   static_argnames=("window", "sm_scale", "q_tile",
+                                    "kv_blocks", "interpret"))
+def paged_prefill_call(q, pool, tables, cached_lens, *,
+                       window: Optional[int],
+                       sm_scale: float, q_tile: int, kv_blocks: int,
+                       interpret: bool):
+    """The attention sweep over the (already appended) pool, behind a
+    jit of its own so that the layers of a program share ONE trace of
+    the kernel and ONE Mosaic lowering, and the device trace prints
+    one stable name for it (XLA names a Pallas custom call after the
+    innermost computation around it).  ``q`` (batch, T, kv, group, hd)
+    → out same shape."""
     batch, T, kv_heads, group, head_dim = q.shape
     block_size = pool["k"].shape[1]
     quantized = "ks" in pool
+    P = decode_blocks_per_iteration(block_size)
     # All group heads of a query stack into the tile row axis: 2D tiles
-    # everywhere in-kernel, one (q_tile*group, hd) x (hd, bs) matmul
-    # per head per block.
+    # everywhere in-kernel, one (q_tile*group, hd) x (hd, keys) matmul
+    # per head per step.
     q_r = q.transpose(0, 2, 1, 3, 4).reshape(batch, kv_heads,
                                              T * group, head_dim)
-    grid = (batch, T // q_tile, kv_blocks)
+    n_tiles = T // q_tile
+    steps = _band_steps(q_tile, block_size, window, kv_blocks, P)
+    bands, blocks = _sweep_plan(
+        tables, cached_lens, n_tiles, steps, P, q_tile=q_tile,
+        block_size=block_size, window=window, kv_blocks=kv_blocks)
+    grid = (batch, n_tiles, steps)
     rows = q_tile * group
 
-    def q_index(b, qt, j, tables_ref, meta_ref):
+    def q_index(b, qt, j, bands_ref, blocks_ref):
         return (b, 0, qt, 0)
 
-    def kv_index(b, qt, j, tables_ref, meta_ref):
-        # Clamp dead steps into the tile's live band: an unchanged
-        # block index makes Pallas reuse the resident VMEM tile
-        # instead of issuing a fresh HBM copy.
-        cached = meta_ref[b, 0]
-        last = (cached + (qt + 1) * q_tile - 1) // block_size
-        j_c = jnp.minimum(j, last)
-        if window is not None:
-            first_live = jnp.maximum(
-                cached + qt * q_tile - window + 1, 0) // block_size
-            j_c = jnp.maximum(j_c, first_live)
-        return (tables_ref[b, j_c], 0, 0, 0)
+    def pool_specs(buf):
+        # k/v tiles are whole blocks, scale planes the same minus the
+        # head_dim axis; copy i of a step is block `blocks[tile, j·P +
+        # i]`, the rest of the index zeros to the buffer's rank.
+        tile = (1,) + buf.shape[1:]
+        rest = (0,) * (len(tile) - 1)
+        return [pl.BlockSpec(
+            tile, lambda b, qt, j, bands_ref, blocks_ref, i=i: (
+                blocks_ref[b * n_tiles + qt, j * P + i],) + rest)
+            for i in range(P)]
 
-    def scale_index(b, qt, j, tables_ref, meta_ref):
-        return kv_index(b, qt, j, tables_ref, meta_ref)[:3]
-
-    block = (1, block_size, kv_heads, head_dim)
     q_block = (1, kv_heads, rows, head_dim)
-    in_specs = [
-        pl.BlockSpec(q_block, q_index),
-        pl.BlockSpec(block, kv_index),
-        pl.BlockSpec(block, kv_index),
-    ]
-    operands = [q_r, pool["k"], pool["v"]]
-    if quantized:
-        in_specs += [pl.BlockSpec(block[:3], scale_index),
-                     pl.BlockSpec(block[:3], scale_index)]
-        operands += [pool["ks"], pool["vs"]]
+    keys = [key for key in ("k", "v", "ks", "vs") if key in pool]
+    in_specs = [pl.BlockSpec(q_block, q_index)]
+    operands = [q_r]
+    for key in keys:
+        in_specs += pool_specs(pool[key])
+        operands += [pool[key]] * P
 
     kernel = functools.partial(
-        _prefill_attention_kernel, block_size=block_size,
-        q_tile=q_tile, group=group, sm_scale=sm_scale, window=window,
-        quantized=quantized)
+        _prefill_attention_kernel, blocks_per_step=P, group=group,
+        sm_scale=sm_scale, window=window, quantized=quantized)
+    stat = pltpu.VMEM((kv_heads, rows, P * block_size), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(q_block, q_index),
         scratch_shapes=[
-            pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
-            pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+            stat, stat,
             pltpu.VMEM((kv_heads, rows, head_dim), jnp.float32),
+            pltpu.VMEM((P * block_size, kv_heads, head_dim),
+                       pool["k"].dtype),
+            pltpu.VMEM((P * block_size, kv_heads, head_dim),
+                       pool["v"].dtype),
         ])
     out_r = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_r.shape, q.dtype),
         interpret=interpret,
-    )(tables, meta, *operands)
+    )(bands, blocks, *operands)
     return out_r.reshape(batch, kv_heads, T, group,
                          head_dim).transpose(0, 2, 1, 3, 4)
 
@@ -540,9 +679,10 @@ def paged_prefill_attention(q, k_new, v_new, pool, tables, cached_lens,
                                                         max_blocks)
 
     new_pool = _append_kv(k_new, v_new, pool, tables, meta, interpret)
-    out = _chunk_attention(q, new_pool, tables, meta, window=window,
-                           sm_scale=sm_scale, q_tile=q_tile,
-                           kv_blocks=kv_blocks, interpret=interpret)
+    out = paged_prefill_call(q, new_pool, tables, meta[:, 0],
+                             window=window, sm_scale=sm_scale,
+                             q_tile=q_tile,
+                             kv_blocks=kv_blocks, interpret=interpret)
     return out, new_pool
 
 
@@ -610,10 +750,9 @@ def paged_verify_attention(q, k_new, v_new, pool, tables, cached_lens,
     Tp = max(16, 1 << (T - 1).bit_length())
     if Tp != T:
         q = jnp.pad(q, ((0, 0), (0, Tp - T)) + ((0, 0),) * (q.ndim - 2))
-    meta = jnp.stack([cached_lens, chunk_lens], axis=1)
     kv_blocks = max_blocks if kv_limit is None else min(kv_limit,
                                                         max_blocks)
-    out = _chunk_attention(q, new_pool, tables, meta, window=window,
-                           sm_scale=sm_scale, q_tile=Tp,
-                           kv_blocks=kv_blocks, interpret=interpret)
+    out = paged_prefill_call(q, new_pool, tables, cached_lens,
+                             window=window, sm_scale=sm_scale, q_tile=Tp,
+                             kv_blocks=kv_blocks, interpret=interpret)
     return out[:, :T], new_pool

@@ -566,6 +566,10 @@ class ContinuousBatchingServer:
             slice_wait_ms=0.0, prefill_run_ms=0.0, first_chunk_ms=0.0,
             prefill_slices=0, prefill_slices_mixed=0,
             prefill_backlog=0, prompt_tokens=0,
+            # What the append attention was asked to do: key blocks x
+            # query tiles of every paged prefill dispatch, one layer's
+            # worth (ops/paged_prefill.prefill_key_blocks).
+            prefill_key_blocks=0,
             deadline_exceeded=0, shed=0, watchdog_trips=0,
             heap_freezes=0),
             prefix="server", labels=self._metrics_labels)
@@ -2217,7 +2221,8 @@ class ContinuousBatchingServer:
         self._post_admission = False
 
     def _note_prefill(self, tokens: int, requests=(),
-                      sliced: bool = False, mixed: bool = False) -> None:
+                      sliced: bool = False, mixed: bool = False,
+                      key_blocks: int = 0) -> None:
         """Count prompt tokens about to be dispatched to prefill (any
         path: whole-bucket, standalone chunk, mixed step), for the
         ``requests`` whose prompt they belong to; the first such
@@ -2226,10 +2231,13 @@ class ContinuousBatchingServer:
         measures work actually done — the gap to raw admitted prompt
         length IS the cache's savings.  ``sliced``: one slice of a
         chunked admission (the slice queue served once); ``mixed``:
-        it rides a decode chunk rather than running standalone."""
+        it rides a decode chunk rather than running standalone;
+        ``key_blocks``: what a paged append's attention sweep has to
+        visit (``prefill_key_blocks`` in the counters)."""
         if self._serve_started is None:
             self._serve_started = time.monotonic()
         self.counters["prefill_tokens"] += int(tokens)
+        self.counters["prefill_key_blocks"] += int(key_blocks)
         for request in requests:
             if request.prefill_started_ts is None:
                 request.prefill_started_ts = time.monotonic()

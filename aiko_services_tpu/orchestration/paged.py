@@ -593,6 +593,18 @@ class PagedContinuousServer(ContinuousBatchingServer):
                 prefill_attention_path(*geometry, self.block_size,
                                        chunk))
 
+    def _slice_key_blocks(self, start: int, width: int) -> int:
+        """Key blocks x query tiles the append attention of one slice
+        ``[start, start + width)`` has to visit, one layer's worth, at
+        this server's (per-shard) head geometry."""
+        from ..ops.paged_prefill import prefill_key_blocks
+        config = self.config
+        return prefill_key_blocks(
+            start, width, self.block_size, config.sliding_window,
+            heads=config.n_heads // self.tp_degree,
+            group=config.n_heads // config.n_kv_heads,
+            itemsize=self._jnp.dtype(config.dtype).itemsize)
+
     def _blocks_for(self, rows: int) -> int:
         return math.ceil(rows / self.block_size)
 
@@ -1660,7 +1672,9 @@ class PagedContinuousServer(ContinuousBatchingServer):
                 # pow2 piece widths ⇒ log-many prefill signatures per
                 # bucket; any other width in the ledger is a breach.
                 compiles.set_label("paged_prefill", f"w{width}")
-            self._note_prefill(width, (request,))
+            self._note_prefill(
+                width, (request,),
+                key_blocks=self._slice_key_blocks(start, width))
             if self._tp_engine is not None:
                 _, self.pool = self._tp_engine.prefill_append_paged(
                     self.params, jnp.asarray(chunk), self.pool,
@@ -1776,7 +1790,9 @@ class PagedContinuousServer(ContinuousBatchingServer):
             chunk = state["prompt_padded"][:, start:start + width]
             tables_row = jnp.asarray(self.tables[slot:slot + 1])
             lora = self._request_lora(state["request"])
-            self._note_prefill(width, (state["request"],), sliced=True)
+            self._note_prefill(
+                width, (state["request"],), sliced=True,
+                key_blocks=self._slice_key_blocks(start, width))
             if sp_width:
                 if compiles.LEDGER is not None:
                     # ONE window shape per (sp, cap) — the sp ladder
@@ -1965,8 +1981,9 @@ class PagedContinuousServer(ContinuousBatchingServer):
         sp_width = self._sp_window_width(prefill)
         width = sp_width or self._next_slice_width(prefill)
         chunk = prefill["prompt_padded"][:, start:start + width]
-        self._note_prefill(width, (prefill["request"],), sliced=True,
-                           mixed=True)
+        self._note_prefill(
+            width, (prefill["request"],), sliced=True, mixed=True,
+            key_blocks=self._slice_key_blocks(start, width))
         if self._dispatch_span is not None:
             self._dispatch_span.note(
                 slice_slot=slot, slice_width=width,

@@ -30,7 +30,7 @@ RNG = np.random.default_rng(11)
 
 def _case(batch=3, kv=2, group=4, hd=32, bs=16, max_blocks=4,
           cached_blocks=(0, 1, 2), T=32, chunk_lens=(32, 17, 5),
-          quant=False):
+          quant=False, pool_dtype=np.float32):
     """Random pool + shuffled block tables + a ragged append chunk.
     ``cached_blocks[b]`` full blocks of prefix are already resident for
     row ``b`` (append starts block-aligned by construction); row ``b``
@@ -56,17 +56,17 @@ def _case(batch=3, kv=2, group=4, hd=32, bs=16, max_blocks=4,
                 np.float32) / 127.0 + 1e-3)
     else:
         pool = dict(
-            k=RNG.standard_normal((n_blocks, bs, kv, hd)).astype(
-                np.float32),
-            v=RNG.standard_normal((n_blocks, bs, kv, hd)).astype(
-                np.float32))
+            k=jnp.asarray(RNG.standard_normal((n_blocks, bs, kv, hd)),
+                          pool_dtype),
+            v=jnp.asarray(RNG.standard_normal((n_blocks, bs, kv, hd)),
+                          pool_dtype))
     cached_lens = np.array([c * bs for c in cached_blocks], np.int32)
     return dict(q=q, k_new=k_new, v_new=v_new, pool=pool,
                 tables=tables, cached_lens=cached_lens,
                 chunk_lens=np.array(chunk_lens, np.int32), bs=bs)
 
 
-def _run(case, path, window=None):
+def _run(case, path, window=None, kv_limit=None):
     """One parity arm on a FRESH pool copy (the kernel aliases the
     pool buffers in and out — reusing a consumed input would fail)."""
     pool = {key: jnp.asarray(val) for key, val in case["pool"].items()}
@@ -79,13 +79,15 @@ def _run(case, path, window=None):
         out, new_pool = pp.paged_prefill_reference(*args, window=window)
     else:
         out, new_pool = pp.paged_prefill_attention(*args, window=window,
-                                                   interpret=True)
+                                                   interpret=True,
+                                                   kv_limit=kv_limit)
     return np.asarray(out, np.float32), {
-        key: np.asarray(val) for key, val in new_pool.items()}
+        key: np.asarray(val, np.float32) for key, val in new_pool.items()}
 
 
-def _parity(case, tol, window=None):
-    out_k, pool_k = _run(case, "kernel", window=window)
+def _parity(case, tol, window=None, kv_limit=None):
+    out_k, pool_k = _run(case, "kernel", window=window,
+                         kv_limit=kv_limit)
     out_r, pool_r = _run(case, "reference", window=window)
     bs = case["bs"]
     for b in range(out_k.shape[0]):
@@ -120,7 +122,8 @@ def test_append_mid_block_boundaries():
                   chunk_lens=(1, 15)), 2e-5)
 
 
-@pytest.mark.parametrize("heads,kv_heads", [(1, 1), (4, 1), (8, 2)])
+@pytest.mark.parametrize("heads,kv_heads",
+                         [(1, 1), (4, 1), (8, 2), (32, 2)])
 def test_append_gqa_group_sizes(heads, kv_heads):
     group = heads // kv_heads
     _parity(_case(kv=kv_heads, group=group), 2e-5)
@@ -135,6 +138,108 @@ def test_append_int8_kv_parity():
     _parity(_case(quant=True), 1e-3)
     _parity(_case(quant=True, cached_blocks=(2, 1, 0),
                   chunk_lens=(9, 32, 23)), 1e-3, window=19)
+
+
+# The sweep takes P = 128 / block_size pool blocks (128 keys) a step
+# over a tile's live band of table entries; each case below puts one
+# edge of that walk under the oracle.
+SWEEP_CASES = {
+    # 11 live blocks: one full step of 8 and a clamped, masked step
+    "band_not_multiple_of_step": dict(
+        case=dict(max_blocks=12, cached_blocks=(9, 3, 0))),
+    "band_of_two_full_steps": dict(
+        case=dict(max_blocks=16, cached_blocks=(14, 6, 0))),
+    # window 20 at 10 cached blocks: the band starts in block 8, the
+    # whole first step's worth of entries is never visited
+    "window_drops_leading_steps": dict(
+        case=dict(max_blocks=12, cached_blocks=(10, 9, 0)), window=20),
+    "window_inside_one_block": dict(
+        case=dict(max_blocks=12, cached_blocks=(10, 9, 1),
+                  quant=True), window=5, tol=1e-3),
+    # the table is longer than the row can be: the sweep is bounded
+    "kv_limit_shorter_than_table": dict(
+        case=dict(max_blocks=24, cached_blocks=(6, 2, 0)), kv_limit=8),
+    "row_without_tokens": dict(
+        case=dict(max_blocks=12, cached_blocks=(9, 4, 0),
+                  chunk_lens=(32, 0, 5))),
+    "int8_row_without_tokens": dict(
+        case=dict(max_blocks=12, cached_blocks=(9, 4, 0),
+                  chunk_lens=(32, 0, 5), quant=True), tol=1e-3),
+    # the contiguous view's geometry: one 128-key block a step
+    "block_128_one_block_a_step": dict(
+        case=dict(bs=128, T=128, max_blocks=3,
+                  cached_blocks=(0, 1, 2), chunk_lens=(128, 70, 5))),
+    "block_128_int8": dict(
+        case=dict(bs=128, T=128, max_blocks=3, cached_blocks=(2, 1, 0),
+                  chunk_lens=(128, 70, 5), quant=True), tol=1e-3),
+    "block_64_two_blocks_a_step": dict(
+        case=dict(bs=64, T=64, max_blocks=5, cached_blocks=(3, 1, 0),
+                  chunk_lens=(64, 33, 5))),
+    "group_16_int8": dict(
+        case=dict(kv=2, group=16, max_blocks=12,
+                  cached_blocks=(9, 3, 0), quant=True), tol=1e-3),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SWEEP_CASES))
+def test_append_sweep_walks_the_live_band(name):
+    spec = SWEEP_CASES[name]
+    _parity(_case(**spec["case"]), spec.get("tol", 2e-5),
+            window=spec.get("window"), kv_limit=spec.get("kv_limit"))
+
+
+@pytest.mark.parametrize("pool_dtype,q_dtype,tol", [
+    ("float32", "float32", 2e-5),       # MXU_PRECISION over f32 rows
+    ("bfloat16", "float32", 2e-5),      # q in three bf16 terms
+    ("bfloat16", "bfloat16", 1e-2),     # q is its own single term
+    ("int8", "bfloat16", 1e-2),         # the serving pair
+])
+def test_append_pool_and_query_dtypes(pool_dtype, q_dtype, tol):
+    """One contraction per pool dtype, at f32 contract precision: the
+    oracle is given the pool's values in f32, so what is compared is
+    the kernel's arithmetic and (bf16 queries) its output rounding."""
+    case = _case(max_blocks=12, cached_blocks=(9, 3, 0),
+                 quant=pool_dtype == "int8",
+                 pool_dtype=jnp.dtype(pool_dtype)
+                 if pool_dtype != "int8" else np.float32)
+    for key in ("q", "k_new", "v_new"):
+        case[key] = jnp.asarray(case[key], q_dtype)
+    out_k, _ = _run(case, "kernel")
+    exact = dict(case, q=jnp.asarray(case["q"], jnp.float32))
+    if pool_dtype == "bfloat16":
+        exact["pool"] = {key: jnp.asarray(val, jnp.float32)
+                         for key, val in case["pool"].items()}
+        exact["k_new"] = jnp.asarray(case["k_new"], jnp.bfloat16)
+        exact["v_new"] = jnp.asarray(case["v_new"], jnp.bfloat16)
+    out_r, _ = _run(exact, "reference")
+    for b in range(out_k.shape[0]):
+        chunk = int(case["chunk_lens"][b])
+        np.testing.assert_allclose(out_k[b, :chunk], out_r[b, :chunk],
+                                   atol=tol, rtol=tol, err_msg=f"row {b}")
+
+
+def test_prefill_key_blocks_counts_the_kernels_band():
+    """The host's count of a slice's work is the kernel's own band:
+    summed over tiles, ``last - first + 1`` of ``_live_bands``."""
+    heads, group = 8, 4
+    for start, width, bs, window in ((0, 256, 16, None),
+                                     (768, 256, 16, None),
+                                     (4096, 256, 16, 4096),
+                                     (6144, 256, 16, 4096),
+                                     (512, 64, 16, 100),
+                                     (256, 128, 128, None)):
+        q_tile = pp._q_tile_size(width, heads, 2, group)
+        _, first, last = pp._live_bands(
+            jnp.asarray([start], jnp.int32), width // q_tile,
+            q_tile=q_tile, block_size=bs, window=window,
+            kv_blocks=10**6)
+        want = int(jnp.sum(last - first + 1))
+        assert pp.prefill_key_blocks(start, width, bs, window,
+                                     heads=heads, group=group,
+                                     itemsize=2) == want
+    # 256 tokens at position 768, tiles of 128: blocks 0..55 and 0..63
+    assert pp.prefill_key_blocks(768, 256, 16, None, heads=32, group=4,
+                                 itemsize=2) == 56 + 64
 
 
 def test_append_zero_cached_equals_fresh_prefill():
@@ -485,6 +590,11 @@ def test_prefill_telemetry_counters():
     stats = server.stats()
     assert stats["prefill_attention_path"] in ("kernel", "reference")
     assert server.counters["prefill_tokens"] >= 33 + 16
+    # Slices of 16 tokens over 16-key blocks, one tile each: a slice at
+    # position s visits blocks 0 .. s/16.  The 33-token prompt takes
+    # three slices (1 + 2 + 3), the 6-token one a single piece.
+    assert server.counters["prefill_key_blocks"] == 6 + 1
+    assert stats["prefill_key_blocks"] == 7
     assert stats["prefill_tokens_per_sec"] > 0
     assert stats["prefill_queue_depth"] == 0
     telemetry = serving_telemetry(stats)

@@ -340,6 +340,70 @@ def test_reference_verify_does_gather(monkeypatch):
     assert gathers, "reference verify path should gather the pool view"
 
 
+# The verify window rides the append kernel's sweep at each row's OWN
+# position: the same walk of the live band, started mid-block.
+VERIFY_CASES = {
+    # (cached_lens, chunk_lens, window, int8 pool)
+    "mid_block_and_inactive_row": ((37, 100, 0, 14), (5, 3, 0, 5),
+                                   None, False),
+    "at_a_block_boundary": ((32, 96, 0, 16), (5, 5, 0, 1), None, False),
+    "int8_mid_block_and_inactive_row": ((37, 150, 0, 14), (5, 3, 0, 5),
+                                        None, True),
+    "int8_window_drops_leading_steps": ((37, 170, 0, 140), (5, 5, 0, 2),
+                                        24, True),
+    "band_past_one_step": ((130, 131, 127, 0), (5, 1, 5, 0), None,
+                           False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(VERIFY_CASES))
+def test_verify_kernel_matches_reference(name):
+    """``paged_verify_attention`` in interpret mode against
+    ``paged_prefill_reference``: outputs of every real query and every
+    pool row an active slot appended; rows past a row's chunk length
+    (all of an inactive row's) land in scratch block 0 only."""
+    from aiko_services_tpu.ops import paged_prefill as pp
+    from .test_paged_prefill import _case
+    cached, chunk, window, quant = VERIFY_CASES[name]
+    case = _case(batch=4, T=5, max_blocks=12, cached_blocks=(0,) * 4,
+                 chunk_lens=chunk, quant=quant)
+    tol = 1e-3 if quant else 2e-5
+
+    def arm(fn, **kwargs):
+        pool = {key: jnp.asarray(val) for key, val in case["pool"].items()}
+        out, new_pool = fn(
+            jnp.asarray(case["q"]), jnp.asarray(case["k_new"]),
+            jnp.asarray(case["v_new"]), pool, jnp.asarray(case["tables"]),
+            jnp.asarray(cached, jnp.int32), jnp.asarray(chunk, jnp.int32),
+            window=window, **kwargs)
+        return np.asarray(out, np.float32), {
+            key: np.asarray(val, np.float32)
+            for key, val in new_pool.items()}
+
+    out_k, pool_k = arm(pp.paged_verify_attention, interpret=True)
+    out_r, pool_r = arm(pp.paged_prefill_reference)
+    for b in range(4):
+        np.testing.assert_allclose(out_k[b, :chunk[b]],
+                                   out_r[b, :chunk[b]], atol=tol,
+                                   rtol=tol, err_msg=f"row {b}")
+        for position in range(cached[b], cached[b] + chunk[b]):
+            block = int(case["tables"][b, position // 16])
+            for key in pool_k:
+                np.testing.assert_allclose(
+                    pool_k[key][block, position % 16],
+                    pool_r[key][block, position % 16], atol=tol,
+                    rtol=tol, err_msg=f"row {b} pos {position} {key}")
+    # Nothing but the appended rows and scratch block 0 changed.
+    for key, before in case["pool"].items():
+        changed = np.flatnonzero(
+            (pool_k[key] != np.asarray(before, np.float32)).reshape(
+                before.shape[0], -1).any(axis=1))
+        owned = {0} | {int(case["tables"][b, p // 16])
+                       for b in range(4)
+                       for p in range(cached[b], cached[b] + chunk[b])}
+        assert set(changed) <= owned, (key, sorted(set(changed) - owned))
+
+
 def test_spec_counters_stay_host_side():
     """Invariant 7: acceptance counters, rollback accounting, and
     per-request histograms are HOST bookkeeping — the traced model and

@@ -1,0 +1,182 @@
+"""Compiles for a DESCRIBED TPU v5e (no chip attached): what the chip's
+compiler refuses — a tile off Mosaic's tiling, a kernel over its VMEM
+limit — and what it makes of the serving programs, checked here at no
+chip time.  Nothing runs, so nothing here is a time or a result.
+
+The topology is described inside a fixture, never at import (one
+process at a time may load the TPU's library; every xdist worker
+imports every test file), and every such test lives in THIS file so
+that one worker loads it.  Code that asks ``jax.default_backend()``
+still sees the CPU, so the tests steer it to its TPU branch themselves
+and drop every jit cache afterwards (a trace made under that steer
+must not be found by a CPU test of the same shapes)."""
+
+import dataclasses
+import functools
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as error:          # noqa: BLE001 — any refusal
+        pytest.skip(f"no v5e:2x2 topology can be described here: {error}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Steer the program's backend questions to their TPU answers for
+    one test, and forget every trace made meanwhile."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yield
+    jax.clear_caches()
+
+
+def _shaped(tree, sharding):
+    return jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                          sharding=sharding), tree)
+
+
+# --------------------------------------------------------------------------- #
+# The append kernels at the widths the benchmark's cells serve
+
+#: name → (batch, T, kv_heads, group, block, pool blocks, table width,
+#: pool dtype, query dtype, window, kv_limit, verify)
+APPEND_GEOMETRIES = {
+    "mistral_int8_window": (1, 256, 8, 4, 16, 4609, 160, jnp.int8,
+                            jnp.bfloat16, 4096, 128, False),
+    "mixtral_int8": (1, 256, 8, 4, 16, 6145, 160, jnp.int8,
+                     jnp.bfloat16, None, 128, False),
+    "longdoc_int8_window_kv512": (1, 256, 8, 4, 16, 4609, 512, jnp.int8,
+                                  jnp.bfloat16, 4096, 512, False),
+    "nemotron_bf16_group16": (1, 256, 2, 16, 16, 9217, 144, jnp.bfloat16,
+                              jnp.bfloat16, None, 64, False),
+    "tp_shard_bf16_two_heads": (1, 256, 2, 4, 16, 4609, 160,
+                                jnp.bfloat16, jnp.bfloat16, 4096, 128,
+                                False),
+    "f32_pool_f32_queries": (1, 128, 8, 4, 16, 1025, 64, jnp.float32,
+                             jnp.float32, None, 64, False),
+    "contiguous_view_block128_int8": (4, 256, 8, 4, 128, 65, 16,
+                                      jnp.int8, jnp.bfloat16, None, None,
+                                      False),
+    "verify_int8_32_slots": (32, 5, 8, 4, 16, 4609, 160, jnp.int8,
+                             jnp.bfloat16, 4096, None, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(APPEND_GEOMETRIES))
+def test_append_kernels_compile_for_v5e(name, one_chip, as_on_tpu):
+    """Write kernel + attention sweep through the TPU compiler: tiles
+    on Mosaic's tiling, scoped VMEM inside its limit, and the sweep's
+    call named ``paged_prefill_call`` with a 4-D result (the decode
+    kernel's roofline metric looks for ``closed_call`` and a 3-D one)."""
+    from aiko_services_tpu.ops import paged_prefill as pp
+    (batch, T, kv, group, bs, n_blocks, max_blocks, pool_dt, q_dt,
+     window, kv_limit, verify) = APPEND_GEOMETRIES[name]
+    hd = 128
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    pool = {"k": S((n_blocks, bs, kv, hd), pool_dt),
+            "v": S((n_blocks, bs, kv, hd), pool_dt)}
+    if pool_dt == jnp.int8:
+        pool["ks"] = S((n_blocks, bs, kv), jnp.float32)
+        pool["vs"] = S((n_blocks, bs, kv), jnp.float32)
+    fn = pp.paged_verify_attention if verify else pp.paged_prefill_attention
+    compiled = jax.jit(
+        functools.partial(fn, window=window, kv_limit=kv_limit),
+        donate_argnums=(3,)).lower(
+        S((batch, T, kv, group, hd), q_dt), S((batch, T, kv, hd), q_dt),
+        S((batch, T, kv, hd), q_dt), pool, S((batch, max_blocks), jnp.int32),
+        S((batch,), jnp.int32), S((batch,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = re.findall(
+        r"^\s*%paged_prefill_call[.\d]* = \w+\[([\d,]+)\]\S* custom-call\(",
+        text, re.M)
+    assert calls and all(shape.count(",") == 3 for shape in calls), calls
+    assert " while(" not in text
+
+
+# --------------------------------------------------------------------------- #
+# The serving programs that hold the sweep
+
+
+def _kernel_width(config):
+    """A tiny config at the one head width the compiled kernels serve
+    (head_dim 128), with kv heads that fill an int8 scale row."""
+    return dataclasses.replace(config, d_model=1024, n_heads=8,
+                               n_kv_heads=8)
+
+
+def _pool_shaped_ops(text, n_blocks, rank):
+    """copy / transpose instructions whose result is an array of
+    ``rank`` dimensions led by the pool's block count.  (Not
+    ``copy-start``: XLA's own asynchronous moves of a buffer between
+    memory spaces keep its layout.)"""
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(r"= \(?\w+\[%d(,\d+){%d}\]" % (n_blocks, rank - 1),
+                         line)
+            and re.search(r" (copy|transpose)\(", line)]
+
+
+@pytest.mark.parametrize("config_name", ["mistral_tiny", "moe_tiny"])
+def test_serving_programs_hold_one_scan_and_no_pool_copy(
+        config_name, one_chip, as_on_tpu):
+    """``serve_chunk_mixed`` holds exactly ONE ``while`` (the decode
+    scan: ``decode_step_ms`` reads "the one %while" of that program)
+    and ``prefill_append_paged`` none; neither copies nor transposes a
+    K/V pool, and the int8 scale planes are re-laid-out no more often
+    than the write kernel and the decode scan already cost them (the
+    attention sweep reads the planes where the write kernel left
+    them)."""
+    from aiko_services_tpu.models import llama
+    config = _kernel_width(llama.CONFIGS[config_name])
+    layers, slots, bs, n_blocks, table = config.n_layers, 4, 16, 97, 24
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = _shaped(jax.eval_shape(
+        lambda: llama.init_params(config, jax.random.PRNGKey(0))),
+        one_chip)
+    pool = _shaped(jax.eval_shape(
+        lambda: llama.init_paged_cache(config, n_blocks, bs,
+                                       quantize_kv=True)), one_chip)
+    state = {"token": S((slots, 1), jnp.int32),
+             "positions": S((slots,), jnp.int32),
+             "active": S((slots,), jnp.bool_),
+             "remaining": S((slots,), jnp.int32),
+             "temps": S((slots,), jnp.float32),
+             "tops": S((slots,), jnp.float32),
+             "adapter_ids": S((slots,), jnp.int32),
+             "tables": S((slots, table), jnp.int32)}
+    tokens = S((1, 128), jnp.int32)
+    scalar = S((), jnp.int32)
+    mixed = llama.serve_chunk_mixed.lower(
+        params, state, pool, tokens, scalar, scalar, 4, config,
+        prefill_kv_limit=16).compile().as_text()
+    standalone = llama.prefill_append_paged.lower(
+        params, tokens, pool, S((1, table), jnp.int32), scalar, config,
+        kv_limit=16, compute_logits=False).compile().as_text()
+
+    assert mixed.count(" while(") == 1
+    assert standalone.count(" while(") == 0
+    for text in (mixed, standalone):
+        assert "%paged_prefill_call" in text       # the kernel path ran
+        assert not _pool_shaped_ops(text, n_blocks, 4)
+    # Scale planes (n, bs, kv): in and out of the write kernel's layout,
+    # k and v, a layer — what the parent of PR 27 already paid.
+    assert len(_pool_shaped_ops(standalone, n_blocks, 3)) <= 4 * layers
+    # ... and the decode scan's rows (n, bs*kv) in and out beside them.
+    assert (len(_pool_shaped_ops(mixed, n_blocks, 3))
+            + len(_pool_shaped_ops(mixed, n_blocks, 2))) <= 8 * layers
