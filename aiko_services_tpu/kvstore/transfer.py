@@ -32,8 +32,8 @@ sync per victim batch), :func:`scatter_block_rows` /
 :func:`scatter_block_row_dicts` the restore upload (host→device, one
 upload per landing batch) — one codec, three movers (wire, demote,
 restore), so bit-exactness is proved once.  The pre-fusion per-layer
-implementations survive as ``*_legacy`` for the bench A/B and the
-byte-identity tests.
+implementations survive as ``*_legacy``: the reference of the
+byte-identity tests (tests/test_kv_transfer_fast.py).
 
 Shape discipline: the big fused gather/scatter programs compile once
 per power-of-two id bucket (``_bucket_ids``).  Padding never crosses
@@ -357,8 +357,8 @@ def gather_block_rows(server, blocks: List[int]) -> Dict[str,
 def gather_block_rows_legacy(server, blocks: List[int]) -> Dict[
         str, np.ndarray]:
     """Pre-fusion gather: one blocking ``np.asarray`` pull per
-    layer×buffer.  Kept for the bench legacy-vs-fused A/B and the
-    byte-identity tests — never on the serving path."""
+    layer×buffer.  Kept as the byte-identity tests' reference —
+    never on the serving path."""
     count = len(blocks)
     ids = server._jnp.asarray(_bucket_ids(blocks))
     rows = {}
@@ -479,8 +479,8 @@ def scatter_block_row_dicts(server, blocks: List[int],
 def scatter_block_rows_legacy(server, blocks: List[int],
                               rows: Dict[str, np.ndarray]) -> None:
     """Pre-fusion scatter: one ``.at[ids].set`` plus one H2D upload
-    per layer buffer.  Kept for the bench legacy-vs-fused A/B —
-    never on the serving path.  (The unconditional ``.astype`` the
+    per layer buffer.  Kept as the tests' reference — never on
+    the serving path.  (The unconditional ``.astype`` the
     original paid is fixed here too: the cast is skipped when the
     host rows already match the pool dtype, which they always do on
     the demote→restore path.)"""
@@ -522,7 +522,8 @@ def export_payload(server, keys_hex: List[str], start_depth: int,
 
     ``fused`` (default) serves the wire fields as zero-copy views of
     the one-sync staging buffer; ``fused=False`` is the legacy
-    per-layer gather + per-position splice, kept for the A/B."""
+    per-layer gather + per-position splice, kept as the tests'
+    reference."""
     start_depth = int(start_depth)
     host_tier = getattr(server, "_host", {})
     resolved: List[bytes] = []
@@ -639,7 +640,7 @@ def import_payload(server, payload: Dict, engine=None,
     :class:`~..runtime.lease.Lease` (released — made evictable — at
     expiry if no admission adopted them; ``engine=None`` skips the
     pin and registers them immediately evictable, the synchronous
-    test/bench mode).
+    test mode).
 
     ``async_import=True`` (the serving path, requires ``engine`` and
     a tiered-queue server) registers the keys immediately behind the
@@ -648,7 +649,7 @@ def import_payload(server, payload: Dict, engine=None,
     step loop keeps producing while the segment lands, no reader
     ever resolves a half-landed chain, and the lease arms when the
     last block lands.  ``fused=False`` keeps the legacy per-layer
-    scatter for the bench A/B (synchronous only)."""
+    scatter as the tests' reference (synchronous only)."""
     if str(payload.get("kv_sig")) != pool_signature(server) or \
             int(payload.get("kv_block_size", -1)) != server.block_size:
         return 0
@@ -786,7 +787,7 @@ def import_payload(server, payload: Dict, engine=None,
 
 
 def seed_chain(server, tokens, adapter_id: int = 0) -> int:
-    """Bench/test helper: allocate and REGISTER the shareable chain
+    """Test helper: allocate and REGISTER the shareable chain
     for ``tokens`` without prefilling (block content stays zeros) —
     lets transfer bandwidth be measured without paying an 8k-token
     prefill first.  Never used on the serving path."""

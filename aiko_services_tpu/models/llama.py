@@ -98,7 +98,7 @@ class LlamaConfig:
                          dtype=self.dtype)
 
 
-#: Named configs: tiny/small for tests+bench on one chip, the real ones
+#: Named configs: tiny/small for tests on one chip, the real ones
 #: for parity with BASELINE.json targets.
 CONFIGS: Dict[str, LlamaConfig] = {
     "tiny": LlamaConfig(vocab_size=1024, d_model=128, n_layers=2,
@@ -132,7 +132,7 @@ CONFIGS: Dict[str, LlamaConfig] = {
                              n_heads=4, n_kv_heads=2, d_ff=352,
                              max_seq_len=512, n_experts=8,
                              moe_capacity_factor=4.0),
-    # Single-chip MoE bench config (~0.6 B params, int8 ≈ 0.6 GB);
+    # Single-chip MoE config (~0.6 B params, int8 ≈ 0.6 GB);
     # cf=4.0 = E/k keeps decode drop-free (see moe_capacity_factor).
     "moe_small": LlamaConfig(vocab_size=32_000, d_model=1024,
                              n_layers=8, n_heads=16, n_kv_heads=8,

@@ -66,7 +66,7 @@ shard_map mirrors of the paged serving entry points in
   decode is therefore token-identical to single-chip LoRA serving
   (tests/test_multi_lora.py TP gates).
 
-``overlap=True`` (opt-in, bench-only) routes the dense-MLP
+``overlap=True`` (opt-in) routes the dense-MLP
 down-projection through :func:`..parallel.collective_matmul.
 matmul_reducescatter` — the row-parallel lossy-LAYOUT path whose
 ring partial sums reorder float addition vs single-chip, trading
@@ -119,7 +119,7 @@ def tp_param_specs(params, axis: str = "tp", ep_axis=None,
       needs the full router resident.
     * ``overlap=True`` re-lays the dense MLP ``w_down`` row-parallel
       (``P(axis, None)`` on its contraction dim) for the
-      reduce-scatter overlap path — lossy layout, bench-only.
+      reduce-scatter overlap path — lossy layout, opt-in.
     """
     specs = jax.tree.map(
         lambda leaf: P(None, axis) if getattr(leaf, "ndim", 0) == 2
@@ -278,7 +278,7 @@ def _tp_mlp_block(layer, config: LlamaConfig, axis: str, x,
         # this shard already holds), so the down-projection skips the
         # act gather entirely and reduce-scatters ring partial sums
         # behind the matmuls.  Partial-sum float order differs from
-        # the single-chip program — bench-only, never the default.
+        # the single-chip program — opt-in, never the default.
         from ..parallel.collective_matmul import matmul_reducescatter
         act = (gate * up).astype(x.dtype)
         b, s, fl = act.shape

@@ -30,8 +30,8 @@ trace), the ``sync_wait`` row splits into ``device_compute`` (the
 part the hardware needed) and ``sync_excess`` (scheduling slack —
 host tax again).
 
-Each component row names its ROADMAP lever, so the bench table reads
-as a worklist, not a post-mortem.
+Each component row names its ROADMAP lever, so the table ``doctor``
+renders reads as a worklist, not a post-mortem.
 
 Stdlib-only, host-side (invariant 7 — importing this module never
 touches a backend).
@@ -65,8 +65,8 @@ LEVERS: Dict[str, str] = {
 }
 
 #: Components that belong to ADMISSION (prompt intake + prefill), not
-#: the steady-state decode loop — the split behind the decode-loop
-#: engine-vs-raw ratio (``bench.py --section step_attribution``).
+#: the steady-state decode loop: a reader that wants the decode
+#: loop's own tax leaves these rows out.
 ADMISSION_COMPONENTS = ("admission", "paged_prefill", "sampling_edit",
                         "post_admission_dispatch")
 
@@ -119,7 +119,7 @@ class TaxTable:
                 "rows": [row.to_dict() for row in self.rows]}
 
     def render(self) -> str:
-        """Aligned text table (the doctor / bench output)."""
+        """Aligned text table (the doctor output)."""
         lines = [f"step-time tax budget — wall {self.wall_ms:.1f} ms, "
                  f"attributed {self.total_ms:.1f} ms "
                  f"({self.steps} steps)"]

@@ -348,7 +348,7 @@ def default_cache_dir() -> str:
 
 def entry_point_cache() -> str:
     """Place the persistent cache for a process that STARTS the program
-    (``chip_smoke.py``, ``bench.py`` section children, the pipeline and
+    (``chip_smoke.py``, ``benchmark/run.py``, the pipeline and
     registrar CLIs); returns the directory.  Call it first thing in
     ``main``: it exports the placement — the directory the environment
     already names, else :func:`default_cache_dir` — together with the

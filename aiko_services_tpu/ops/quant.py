@@ -18,7 +18,6 @@ Two execution paths with identical numerics:
 from __future__ import annotations
 
 import functools
-import os
 from typing import Dict
 
 import jax
@@ -28,19 +27,6 @@ from jax.experimental import pallas as pl
 __all__ = ["quantize_int8", "dequantize", "int8_matmul",
            "quantize_int4", "dequantize_int4", "int4_matmul",
            "quantize_tree", "is_quantized", "is_quantized_int4"]
-
-#: AIKO_INT4_XLA=1 (read at import): route int4_matmul through the XLA
-#: grouped-einsum path even on TPU, bypassing the Pallas kernel.  XLA
-#: fuses the nibble unpack + scale into the contraction itself; this
-#: switch exists so benchmarks can compare the two int4 lowerings
-#: head-to-head on hardware.
-_INT4_FORCE_XLA = os.environ.get("AIKO_INT4_XLA", "") not in ("", "0")
-
-#: AIKO_INT8_XLA=1 (read at import): route int8_matmul through XLA's
-#: fused convert+dot even at kernel-eligible decode shapes (m <= 64).
-#: Same rationale as the int4 switch: lets the bench capture both int8
-#: lowerings head-to-head.
-_INT8_FORCE_XLA = os.environ.get("AIKO_INT8_XLA", "") not in ("", "0")
 
 #: int8 symmetric range (−127…127; −128 unused to keep scales symmetric).
 _QMAX = 127.0
@@ -165,7 +151,7 @@ def int8_matmul(x, q, s, interpret: bool = False):
     # (prefill/training) shapes are compute-bound and XLA's own int8
     # convert+dot fusion handles them without VMEM pressure.
     if not (on_tpu or interpret) or block_n == 0 \
-            or k % 32 or m > 64 or (_INT8_FORCE_XLA and not interpret):
+            or k % 32 or m > 64:
         out = jnp.dot(x2, q.astype(x.dtype),
                       preferred_element_type=jnp.float32) * s
         return out.astype(x.dtype).reshape(*lead, n)
@@ -192,8 +178,8 @@ def _int4_kernel_repeat(xe_ref, xo_ref, p_ref, s_ref, o_ref,
     Mosaic fuses the unpack/scale chain into the dot's operand stream,
     so neither the dequantized weights nor the f32 intermediates
     materialize in HBM — measured 2.6x faster than the grouped-unroll
-    kernel at K=4096 decode shapes on v5e (scripts/int4_kernel_lab.py)
-    and equal at K=14336."""
+    kernel at K=4096 decode shapes on v5e and equal at K=14336 (a
+    pre-ledger lab run; no cell of the benchmark runs int4)."""
     low, high = _unpack_int4(p_ref[:])
     se = jnp.repeat(s_ref[:], gs_half, axis=0)
     # bf16 weights feed the MXU at full rate on TPU; interpret mode
@@ -209,7 +195,7 @@ def _int4_kernel_repeat(xe_ref, xo_ref, p_ref, s_ref, o_ref,
 
 
 #: khalf -> output-column blocks (preferred first), drawn from the tile
-#: classes compiled and run on the v5e (scripts/int4_kernel_lab.py):
+#: classes compiled and run on the v5e (a pre-ledger lab run):
 #: K=4096 (khalf 2048) ran at bn 128/256/512 — 256 measured fastest,
 #: 512 validated but never preferred (any n divisible by 512 picks 256
 #: first anyway) — and K=14336 (khalf 7168) at bn=128.  A bn=512 tile
@@ -293,8 +279,7 @@ def int4_matmul(x, q4, s, interpret: bool = False):
     x2 = x.reshape(-1, k)
     m = x2.shape[0]
     on_tpu = jax.default_backend() == "tpu"
-    pallas_ok = ((on_tpu or interpret) and m <= 64
-                 and not _INT4_FORCE_XLA)
+    pallas_ok = (on_tpu or interpret) and m <= 64
     repeat_block = _pick_block_repeat(khalf, n, interpret) \
         if pallas_ok else 0
     unroll_block = _pick_block_int4(m, khalf, n, groups) \

@@ -1081,7 +1081,7 @@ class FleetAutoscaler(Actor):
         population to it and retire the source.  With
         ``policy.migrate_drains`` off this degrades to the drain-based
         replacement (retire and wait out the in-flight tail) — the
-        A/B control the bench compares against."""
+        A/B control of ``loadgen.run_rolling_upgrade(drain_based=True)``."""
         topic = self._topics.get(source)
         if topic is None:
             self._bump("upgrades_completed")
@@ -1168,7 +1168,7 @@ class FleetAutoscaler(Actor):
         return sorted(self.state.quarantined)
 
     def stats(self) -> Dict:
-        """Counters + fleet state for bench/loadgen reporting."""
+        """Counters + fleet state for loadgen reporting."""
         return dict(self.counters,
                     replicas_live=self.share["replicas_live"],
                     replicas_draining=self.share["replicas_draining"],

@@ -2564,7 +2564,7 @@ class ContinuousBatchingServer:
 
     def stats(self) -> Dict:
         """Serving perf counters + derived rates (dashboard payloads,
-        bench sections, smoke assertions)."""
+        the benchmark's counters, smoke assertions)."""
         steps = self.counters["decode_steps"]
         elapsed = (time.monotonic() - self._serve_started
                    if self._serve_started is not None else 0.0)

@@ -83,7 +83,7 @@ class MigrationController:
         #: migration id -> request id (destination replies carry the
         #: migration id; this maps them back to the client request).
         self._by_mid: Dict[str, str] = {}
-        #: completed cutover latencies (ms) — bench/loadgen rigs read
+        #: completed cutover latencies (ms) — loadgen rigs read
         #: this for p50/p95 without scraping shares.
         self.cutover_ms: List[float] = []
 

@@ -60,7 +60,7 @@ ROUTER_PROTOCOL = "replica_router:0"
 RETRIABLE_ERRORS = ("watchdog_stalled",)
 
 #: Server-stats keys worth broadcasting to operators.  Shared by
-#: ContinuousReplica EC shares, dashboard rendering, and bench
+#: ContinuousReplica EC shares, dashboard rendering, and loadgen
 #: reporting so all three show the SAME derived counters.
 TELEMETRY_KEYS = (
     "slots_active", "queue_depth", "in_flight",

@@ -225,7 +225,7 @@ class SpecController:
         """Compact ``spec_k_effective`` encoding: ``"0:12|4:80"``
         (ladder k -> slot-rounds), zero rungs omitted; ``"-"`` before
         any dispatch.  A string survives the serving_telemetry
-        projection (EC shares / dashboard / bench) unmangled."""
+        projection (EC shares / dashboard) unmangled."""
         parts = [f"{k}:{count}" for k, count in sorted(
             self.k_hist.items()) if count]
         return "|".join(parts) if parts else "-"

@@ -109,7 +109,7 @@ class ReplicaMesh:
     ``overlap=True`` opts the MLP down-projection into the
     :mod:`..parallel.collective_matmul` reduce-scatter layout — a
     LOSSY-layout bandwidth trade (partial-sum float order differs from
-    single-chip), bench-only, off by default.
+    single-chip), off by default; no cell or server turns it on.
     """
 
     tp: int = 1

@@ -855,8 +855,9 @@ def run_shared_prefix(n_requests: int = 24, rate_hz: float = 50.0,
     """In-process 2-replica PAGED serving rig (prefix caches on)
     driven by :func:`shared_prefix_payloads` through a ReplicaRouter.
     ``prefix_routing=False`` degrades the router to pure
-    least-loaded P2C (``prefix_alpha=0``) — the A/B baseline bench.py
-    compares TTFT against.  The report carries ``prefix_hit_rate``,
+    least-loaded P2C (``prefix_alpha=0``), the CLI's
+    ``--no-prefix-routing`` baseline.  The report carries
+    ``prefix_hit_rate``,
     ``kv_transfer_bytes`` and histogram-merged ``fleet_latency_ms``
     aggregated across the fleet.  ``trace_out`` enables distributed
     tracing for the run and dumps the ``trace_top`` slowest requests'
@@ -1003,8 +1004,8 @@ def run_longtail(n_requests: int = 36, rate_hz: float = 25.0,
     router.  ``host_tier_blocks=0`` is the HBM-only baseline — same
     pool, same workload, eviction deletes.  The tier-on run must beat
     it on BOTH ``prefix_hit_rate`` and mean TTFT (the capacity gate in
-    tests/test_kv_tier.py; numbers in bench.py's ``kv_tier``
-    section).  The report's ``prefix_hit_rate_host`` says how many of
+    tests/test_kv_tier.py).  The report's ``prefix_hit_rate_host`` says
+    how many of
     the hits only existed because demotion preserved them.
 
     Default sizing makes restore beat recompute in STEPS, which is
@@ -1470,8 +1471,8 @@ def run_restart_ab(n_requests: int = 18, rate_hz: float = 25.0,
     warmup phases are identical.  Asserts
     the greedy outputs are BIT-EXACT request for request (a restored
     block may never change a token), then returns ``(cold, warm)``;
-    the caller (bench.py's ``kv_tier`` section, tests/test_kv_spill)
-    checks warm strictly beats cold on measured-phase hit rate and
+    the caller (tests/test_kv_spill.py) checks warm strictly beats
+    cold on measured-phase hit rate and
     mean TTFT."""
     import tempfile
 
@@ -2181,7 +2182,7 @@ def run_elastic(duration_s: float = 10.0, seed: int = 0,
     ``static_replicas=N`` instead pins a fixed N-replica fleet with no
     autoscaler — the A/B baseline: the autoscaled fleet must beat the
     static PEAK-sized fleet on ``goodput_per_replica`` over a diurnal
-    day (bench.py's ``serving_autoscale`` section and the slow gate).
+    day (the slow gate in tests/test_autoscaler.py).
 
     ``scale_script`` is a sequence of ``(delay_s, target)`` operator
     ``(scale_target …)`` commands fired mid-run (the chaos gate's
@@ -2617,8 +2618,8 @@ def run_rolling_upgrade(duration_s: float = 10.0, seed: int = 0,
     time with its in-flight population LIVE-MIGRATED onto the
     successor.  ``drain_based=True`` is the A/B control: the same
     replacement loop but each predecessor drains its tail instead of
-    migrating it (``policy.migrate_drains`` off).  The bench section
-    compares goodput and total upgrade wall-time between the two."""
+    migrating it (``policy.migrate_drains`` off); the CLI's
+    ``--rolling-upgrade`` prints both arms' goodput."""
     from ..orchestration.autoscaler import AutoscalerPolicy
 
     policy = AutoscalerPolicy(

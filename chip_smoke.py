@@ -408,7 +408,7 @@ def build_server(geo: Geometry, tp: int):
     """The paged server as a deployment builds it: int8 weights from a
     seed (a bf16 8B init does not fit one chip), int8 KV, prefix cache
     on, the default chunked admission, and a pool that holds every
-    slot's worst case (``bench_serving_8b(paged=True)``'s sizing)."""
+    slot's worst case."""
     import jax
     from aiko_services_tpu.models import llama
     from aiko_services_tpu.orchestration.paged import (

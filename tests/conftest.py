@@ -21,8 +21,9 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 # Force backend init NOW (while the flag is set), then restore the
-# caller's XLA_FLAGS so subprocesses spawned by tests (bench probes,
-# CLI smoke runs) don't inherit the 8-device virtual platform.
+# caller's XLA_FLAGS so subprocesses spawned by tests (CLI smoke
+# runs, the benchmark's rehearsals) don't inherit the 8-device
+# virtual platform.
 jax.devices()
 if _ORIG_XLA_FLAGS is None:
     os.environ.pop("XLA_FLAGS", None)

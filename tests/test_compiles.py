@@ -17,12 +17,9 @@ Pins the contracts OBSERVABILITY.md's compile sections promise:
   program);
 * the ``(profile)`` bracket measures real per-step device ms on the
   live paged engine and its manifest lands in flight bundles /
-  ``doctor --json`` (schema pinned here);
-* ``scripts/bench_diff.py`` diffs bench captures and its regression
-  gate exits non-zero.
+  ``doctor --json`` (schema pinned here).
 """
 
-import importlib.util
 import json
 import math
 import pathlib
@@ -45,14 +42,6 @@ def _no_leaked_ledger():
     profiler.LAST = None
     steplog.uninstall()
     flight.uninstall()
-
-
-def _load_script(name):
-    spec = importlib.util.spec_from_file_location(
-        name, REPO / "scripts" / f"{name}.py")
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
 
 
 # ---------------------------------------------------------------- #
@@ -316,57 +305,6 @@ def test_actor_profile_command_reports_unsupported():
     Actor.profile(_FakeActor(), steps=2, response_topic="resp/t")
     assert published and published[0][0] == "resp/t"
     assert "unsupported" in published[0][1]
-
-
-# ---------------------------------------------------------------- #
-# bench_diff: capture diffing + the regression gate
-# ---------------------------------------------------------------- #
-
-def _write_capture(path, rows):
-    path.write_text("\n".join(json.dumps(row) for row in rows) + "\n")
-
-
-def test_bench_diff_directions_and_gate(tmp_path):
-    bench_diff = _load_script("bench_diff")
-    old = tmp_path / "old.jsonl"
-    new = tmp_path / "new.jsonl"
-    _write_capture(old, [
-        {"section": "s", "ok": True,
-         "result": {"decode_tokens_per_sec": 100.0, "ttft_p50_ms": 10.0,
-                    "bytes": 512}},
-        # duplicate section: the LAST entry must win
-        {"section": "s", "ok": True,
-         "result": {"decode_tokens_per_sec": 200.0, "ttft_p50_ms": 8.0,
-                    "bytes": 512}},
-    ])
-    _write_capture(new, [
-        {"section": "s", "ok": True,
-         "result": {"decode_tokens_per_sec": 150.0,
-                    "ttft_p50_ms": 8.04, "bytes": 4096}},
-    ])
-    deltas, problems = bench_diff.diff_captures(
-        bench_diff.load_sections(old), bench_diff.load_sections(new))
-    assert not problems
-    by_name = {delta.metric: delta for delta in deltas}
-    assert by_name["decode_tokens_per_sec"].old == 200.0  # last wins
-    assert by_name["decode_tokens_per_sec"].verdict == "REGRESSED"
-    assert by_name["ttft_p50_ms"].verdict == "~"     # 0.5% < noise
-    assert by_name["bytes"].verdict == "info"        # directionless
-    # the CLI gate: 25% throughput regression trips --fail-on-regress
-    assert bench_diff.main([str(old), str(new),
-                            "--fail-on-regress", "10"]) == 1
-    assert bench_diff.main([str(old), str(new),
-                            "--fail-on-regress", "30"]) == 0
-    # a section failing in the new capture is always a gate failure
-    _write_capture(new, [{"section": "s", "ok": False,
-                          "error": "boom"}])
-    assert bench_diff.main([str(old), str(new),
-                            "--fail-on-regress", "99"]) == 1
-
-
-def test_bench_diff_check_schema_on_checked_in_captures():
-    bench_diff = _load_script("bench_diff")
-    assert bench_diff.check_schema([]) == 0
 
 
 # ---------------------------------------------------------------- #

@@ -16,8 +16,7 @@ The three gates of ARCHITECTURE invariant 10:
   modules).
 * **Capacity** — a long-tail workload whose prefix working set
   overflows the HBM pool gets strictly higher prefix hit rate AND
-  lower mean TTFT with the tier on than off (slow test; numbers in
-  bench.py's ``kv_tier`` section).
+  lower mean TTFT with the tier on than off (slow test).
 """
 
 import ast

@@ -248,7 +248,7 @@ def test_warm_prefill_ladder_counts_and_idle_guard(
 def test_overlap_mode_dense_only_and_off_the_exact_path(
         virtual_mesh_devices):
     """``overlap=True`` (collective-matmul reduce-scatter down-proj)
-    is a LOSSY-layout bench mode: it requires dense MLP weights and
+    is a LOSSY-layout opt-in mode: it requires dense MLP weights and
     the exactness suite never enables it.  Quantized weights reject
     at engine construction; a dense server serves."""
     with pytest.raises(ValueError, match="dense"):

@@ -1210,3 +1210,39 @@ def test_int4_matmul_large_k_fallback_correct():
         np.float32)
     rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)
     assert rel < 0.02, rel
+
+
+def test_jitted_modules_read_only_the_attention_mode_variables():
+    """``ops/`` and ``models/`` choose a lowering from what they can
+    observe (backend, shapes, ``interpret``).  The two attention mode
+    variables, read by ``ops.paged_attention.kernel_mode``, are the
+    only environment they consult: a switch that only a measuring rig
+    sets has no place in the program."""
+    import ast
+    import pathlib
+
+    import aiko_services_tpu
+
+    package = pathlib.Path(aiko_services_tpu.__file__).parent
+    allowed = {"AIKO_DECODE_ATTENTION", "AIKO_PREFILL_ATTENTION"}
+    reads, modes = [], set()
+    for path in sorted((package / "ops").rglob("*.py")) + \
+            sorted((package / "models").rglob("*.py")):
+        where = path.relative_to(package).as_posix()
+        for top in ast.parse(path.read_text(), filename=str(path)).body:
+            for node in ast.walk(top):
+                names = {getattr(node, "attr", None),
+                         getattr(node, "id", None)}
+                names.update(getattr(alias, "name", None)   # imports
+                             for alias in getattr(node, "names", ()))
+                if names & {"environ", "environb", "getenv", "putenv"}:
+                    reads.append((where, getattr(top, "name", None),
+                                  node.lineno))
+                if isinstance(node, ast.Call) and \
+                        getattr(node.func, "id", None) == "kernel_mode":
+                    modes.update(
+                        arg.value if isinstance(arg, ast.Constant)
+                        else ast.dump(arg) for arg in node.args)
+    assert [(where, function) for where, function, _ in reads] == \
+        [("ops/paged_attention.py", "kernel_mode")], reads
+    assert modes == allowed

@@ -349,3 +349,25 @@ def test_model_replica_and_profiler_plugins():
                             "last_trace_dir": "/tmp/t",
                             "last_trace_seconds": 1.5})
     assert any("1.5s" in line for line in lines)
+
+
+def test_ci_checks_static_runs_and_names_only_files_that_exist():
+    """Nothing else runs ``scripts/ci_checks.sh``, so a file it names
+    that a PR deleted would go unnoticed until someone's pre-commit
+    hook failed.  ``--static`` is the no-jax subset (seconds)."""
+    import pathlib
+    import re
+    import subprocess
+
+    repo = pathlib.Path(__file__).resolve().parent.parent
+    script = repo / "scripts" / "ci_checks.sh"
+    named = set(re.findall(
+        r"\b(?:scripts|tests|aiko_services_tpu|benchmark|examples)"
+        r"/[\w/.-]*\w", script.read_text()))
+    assert "scripts/obs_lint.py" in named and "tests/test_obs.py" in named
+    assert [path for path in sorted(named)
+            if not (repo / path).exists()] == []
+    done = subprocess.run(["bash", str(script), "--static"], cwd=repo,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stdout + done.stderr
+    assert "static checks OK" in done.stdout
