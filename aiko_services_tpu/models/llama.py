@@ -27,7 +27,10 @@ from jax.sharding import PartitionSpec as P
 from ..ops.attention import attention_reference, flash_attention
 from ..ops.paged_attention import (cached_gqa_attention,
                                    contiguous_block_size,
+                                   decode_append_dispatch,
                                    decode_dispatch,
+                                   decode_scale_row,
+                                   paged_decode_append,
                                    paged_decode_attention)
 from ..ops.paged_prefill import (paged_prefill_attention,
                                  paged_verify_attention,
@@ -889,21 +892,23 @@ def init_paged_cache(config: LlamaConfig, n_blocks: int,
 def _scan_scale_rows(pool, config: LlamaConfig):
     """Entry of a paged decode scan: where the decode kernel will read
     an int8 pool, its scale planes ``(n, bs, kv)`` ride the scan as
-    rows ``(n, bs·kv)`` — the same values in the same order, so the
-    lane-row view the kernel copies blocks from
-    (:func:`~..ops.paged_attention.decode_scale_row`) is what the scan
-    carries, or a bitcast of it.  Carried as planes, XLA re-lays each one
+    lane rows ``(n · bs·kv / W, W)`` — the same values in the same
+    order, so the view the kernel copies blocks from
+    (:func:`~..ops.paged_attention.decode_scale_row`) and the append
+    kernel patches (:func:`~..ops.paged_attention.paged_decode_append`)
+    is what the scan carries.  Carried as planes, XLA re-lays each one
     out before every kernel call: two copies of the whole plane per
     layer per step, as long as the kernel itself (TPU compiler,
     PR 25).  :func:`_rest_scale_planes` undoes it at the scan's exit;
     the pool at rest never changes shape."""
-    if "ks" not in pool[0] or not decode_dispatch(
-            config.head_dim, pool[0]["k"].shape[2],
-            pool[0]["k"].dtype)[0]:
+    block_size, kv_heads = pool[0]["k"].shape[1:3]
+    if "ks" not in pool[0] or not decode_append_dispatch(
+            config.head_dim, kv_heads, pool[0]["k"].dtype,
+            block_size)[0]:
         return pool
-    return [dict(layer,
-                 ks=layer["ks"].reshape(layer["ks"].shape[0], -1),
-                 vs=layer["vs"].reshape(layer["vs"].shape[0], -1))
+    width = decode_scale_row(block_size, kv_heads)
+    return [dict(layer, ks=layer["ks"].reshape(-1, width),
+                 vs=layer["vs"].reshape(-1, width))
             for layer in pool]
 
 
@@ -923,30 +928,25 @@ def _rest_scale_planes(pool):
 
 
 def _paged_write_rows(pool_layer, k, v, tables, positions):
-    """Scatter one (batch, 1, kv, hd) row per slot into the pool at
-    (tables[s, pos // bs], pos % bs) — a single batched scatter.
-    Scales carried as rows (:func:`_scan_scale_rows`) take theirs at
-    lanes ``offset·kv .. offset·kv + kv - 1`` of the block's row."""
-    block_size, kv_heads = pool_layer["k"].shape[1:3]
+    """Write one (batch, 1, kv, hd) row per slot into the pool at
+    (tables[s, pos // bs], pos % bs).  Where the scan carries an int8
+    pool's scales as lane rows (:func:`_scan_scale_rows`) one kernel
+    call appends the token, rows and scales, in place; anything else
+    (the CPU, the reference, float pools) takes a batched scatter per
+    buffer."""
+    block_size, kv_heads, head_dim = pool_layer["k"].shape[1:]
     block_ids = jnp.take_along_axis(
         tables, (positions // block_size)[:, None], axis=1)[:, 0]
     offsets = positions % block_size
-    lanes = offsets[:, None] * kv_heads + jnp.arange(kv_heads)
-
-    def scatter(pool, rows):
-        if pool.ndim == 2:
-            # Lane by lane.  XLA rewrites the plane for it (30 us a
-            # plane a step on a v5e), and both cheaper-looking forms
-            # cost more: one window of kv lanes per slot becomes a
-            # serial loop per slot, whole rows gathered, patched and
-            # scattered back take the TPU compiler 12x as long over a
-            # 32-layer decode program (PERF.md, PR 25).
-            return pool.at[block_ids[:, None], lanes].set(rows)
-        return pool.at[block_ids, offsets].set(rows.astype(pool.dtype))
-
-    return {key: scatter(pool_layer[key], src)
-            for key, src in _quantize_pairs(pool_layer, k[:, 0],
-                                            v[:, 0]).items()}
+    rows = _quantize_pairs(pool_layer, k[:, 0], v[:, 0])
+    if "ks" in pool_layer and pool_layer["ks"].ndim == 2:
+        _, interpret = decode_append_dispatch(
+            head_dim, kv_heads, pool_layer["k"].dtype, block_size)
+        return paged_decode_append(pool_layer, rows, block_ids, offsets,
+                                   interpret=interpret)
+    return {key: pool_layer[key].at[block_ids, offsets].set(
+                src.astype(pool_layer[key].dtype))
+            for key, src in rows.items()}
 
 
 def _paged_gather(pool_layer, tables):

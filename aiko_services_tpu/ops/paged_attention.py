@@ -75,7 +75,9 @@ __all__ = ["paged_decode_attention", "paged_decode_reference",
            "decode_kernel_mode",
            "decode_dispatch", "decode_attention_path",
            "contiguous_block_size", "kernel_serves", "load_head_rows",
-           "decode_blocks_per_iteration", "decode_scale_row"]
+           "decode_blocks_per_iteration", "decode_scale_row",
+           "decode_append_dispatch", "decode_scale_append_path",
+           "paged_decode_append"]
 
 #: Maximum pool block size the degenerate contiguous view uses — small
 #: enough that short rows skip most of the cache, large enough for the
@@ -767,3 +769,188 @@ def closed_call(q, k_pool, v_pool, tables, positions, ks, vs, *,
         interpret=interpret,
     )(tables, positions, *operands)
     return out.reshape(q.shape)
+
+
+# ---------------------------------------------------------------------------
+# The append kernel: a decode step's new scales into the carried lane rows
+
+
+def decode_append_dispatch(head_dim: int, kv_heads: int, pool_dtype,
+                           block_size: int) -> Tuple[bool, bool]:
+    """``(use_kernel, interpret)`` for a decode step's scale append:
+    where the decode kernel reads an int8 pool (:func:`decode_dispatch`)
+    whose blocks fill whole lane rows of scales
+    (:func:`decode_scale_row`), the paged decode scans carry the scale
+    planes as those rows and :func:`paged_decode_append` writes the new
+    token's scales into them.  Anything else — float pools, the
+    reference, the CPU, an interpreted geometry without whole rows —
+    keeps planes and the XLA scatter."""
+    use_kernel, interpret = decode_dispatch(head_dim, kv_heads, pool_dtype)
+    serves = (jnp.dtype(pool_dtype) == jnp.int8
+              and bool(decode_scale_row(block_size, kv_heads)))
+    return (use_kernel and serves), interpret
+
+
+def decode_scale_append_path(head_dim: int, kv_heads: int, pool_dtype,
+                             block_size: int) -> str:
+    """``"kernel"``, ``"scatter"`` or ``"none"`` (a float pool has no
+    scales) — the serving path tag of :func:`decode_append_dispatch`."""
+    if jnp.dtype(pool_dtype) != jnp.int8:
+        return "none"
+    use_kernel, _ = decode_append_dispatch(head_dim, kv_heads, pool_dtype,
+                                           block_size)
+    return "kernel" if use_kernel else "scatter"
+
+
+#: What XLA's scheduler is told a :func:`paged_decode_append` call
+#: costs, in passes over each of its two planes.  Not what it moves (a
+#: row a slot): XLA charges a Mosaic call nothing unless told, and the
+#: two scatters this call replaces were its measure of how much
+#: prefetching a decode step's attention can hide.  With nothing in
+#: their place, memory-space assignment searched ten times as long for
+#: windows for the weights' prefetches (the TPU compiler over the
+#: 32-layer Mistral decode program: 367 s, against 36 s before and 40 s
+#: with this) and came back with other prefetches; with this it makes
+#: the choices it made (PERF.md, PR 29).
+SCHEDULER_PLANE_PASSES = 8
+
+
+def _decode_append_kernel(targets_ref, blocks_ref, offsets_ref,  # prefetch
+                          own_ref, ks_new_ref, vs_new_ref, k_new_ref,
+                          v_new_ref, ks_hbm, vs_hbm, k_hbm, v_hbm,
+                          ks_out, vs_out, k_out, v_out,
+                          ks_buf, vs_buf, sems, *, kv_heads: int):
+    """Grid: (1,).  Per live batch row ``r`` (``blocks_ref[r]`` not the
+    scratch block): its new K and V rows ``(kv_heads, head_dim)`` are
+    copied to ``pool[blocks_ref[r], offsets_ref[r]]``, and in each scale
+    plane the lane row ``targets_ref[r]`` that holds the new key is
+    read, patched and written back — ``own_ref[r]`` is the key's place
+    among the ``LANES // kv_heads`` keys of that row, and
+    ``ks_new_ref`` / ``vs_new_ref`` are the new scales repeated along
+    the lanes, so lane ``l`` already holds head ``l % kv_heads``.  The
+    pools are the outputs' own buffers (aliased) and stay where they
+    are.
+
+    What a row costs is a DMA's latency, not its bytes, so every row's
+    copies are in flight before the first wait: the K/V rows and the
+    scale reads first, the scale writes once the rows are patched."""
+    batch = ks_buf.shape[0]
+
+    def for_live_rows(act):
+        def body(r, carry):
+            @pl.when(blocks_ref[r] != 0)
+            def _live():
+                act(r)
+            return carry
+        jax.lax.fori_loop(0, batch, body, 0)
+
+    def row_writes(r):
+        slot = (blocks_ref[r], offsets_ref[r])
+        return [pltpu.make_async_copy(new_ref.at[r], pool_out.at[slot],
+                                      sems.at[0])
+                for new_ref, pool_out in ((k_new_ref, k_out),
+                                          (v_new_ref, v_out))]
+
+    def scale_reads(r):
+        return [pltpu.make_async_copy(plane.at[pl.ds(targets_ref[r], 1)],
+                                      buf.at[pl.ds(r, 1)], sems.at[1])
+                for plane, buf in ((ks_hbm, ks_buf), (vs_hbm, vs_buf))]
+
+    def scale_writes(r):
+        return [pltpu.make_async_copy(buf.at[pl.ds(r, 1)],
+                                      plane.at[pl.ds(targets_ref[r], 1)],
+                                      sems.at[2])
+                for plane, buf in ((ks_out, ks_buf), (vs_out, vs_buf))]
+
+    def each(copies, act):
+        for_live_rows(lambda r: [act(copy) for copy in copies(r)])
+
+    each(scale_reads, lambda copy: copy.start())
+    each(row_writes, lambda copy: copy.start())
+    each(scale_reads, lambda copy: copy.wait())
+    lane = jax.lax.broadcasted_iota(jnp.int32, ks_buf.shape, 1)
+    own = lane // kv_heads == own_ref[...]
+    # A selection: the other keys' scales pass through bit for bit.
+    ks_buf[...] = jnp.where(own, ks_new_ref[...], ks_buf[...])
+    vs_buf[...] = jnp.where(own, vs_new_ref[...], vs_buf[...])
+    each(scale_writes, lambda copy: copy.start())
+    each(row_writes, lambda copy: copy.wait())
+    each(scale_writes, lambda copy: copy.wait())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_decode_append(pool, rows, block_ids, offsets, *,
+                        interpret: bool = False):
+    """Append one decode step's token to an int8 pool layer whose scale
+    planes a paged decode scan carries as lane rows.
+
+    Args:
+      pool: ``k`` / ``v`` ``(n_blocks, block_size, kv_heads, head_dim)``
+        int8, and ``ks`` / ``vs`` ``(n_blocks · plane_rows, W)`` f32 —
+        the planes ``(n_blocks, block_size, kv_heads)`` seen as rows of
+        ``W = decode_scale_row(block_size, kv_heads)`` lanes (row-major
+        ``[block, key, head]``, ``plane_rows`` rows a block).
+      rows: the new token's ``k`` / ``v`` ``(batch, kv_heads, head_dim)``
+        int8 and ``ks`` / ``vs`` ``(batch, kv_heads)`` f32.
+      block_ids / offsets: ``(batch,)`` int32 — the pool block and the
+        key within it each batch row writes.  Rows that write reserved
+        scratch block 0 (idle slots) are skipped: block 0 is never
+        attendable.  Two live rows never write one block.
+
+    Returns the pool layer, updated in place: the pools are aliased to
+    the outputs and the kernel moves only the rows it writes — where
+    an XLA scatter into lanes rewrites the whole plane (29 us a plane a
+    step on a v5e, PERF.md PR 25 and PR 29).  The pool has to be the
+    caller's to overwrite — a scan's carry, or a donated argument, as
+    in every serving program: on buffers the caller keeps XLA must
+    copy the pools for the alias, and the TPU compiler aborts on that
+    copy into the K/V outputs, which are pinned to HBM (below).
+    Behind a jit of its own, like :func:`closed_call`: a program
+    traces and lowers it once, and the custom call takes this
+    function's name in a trace."""
+    keys = ("ks", "vs", "k", "v")
+    batch, kv_heads = rows["ks"].shape
+    block_size = pool["k"].shape[1]
+    width = pool["ks"].shape[1]
+    plane_rows = block_size * kv_heads // width
+    keys_per_row = width // kv_heads
+    block_ids = block_ids.astype(jnp.int32)
+    offsets = offsets.astype(jnp.int32)
+    targets = block_ids * plane_rows + offsets * kv_heads // width
+    own = (offsets % keys_per_row)[:, None]
+
+    def whole(array):
+        return pl.BlockSpec(array.shape,
+                            lambda i, *prefetch: (0,) * array.ndim)
+
+    operands = [own] + [
+        jnp.tile(rows[key].astype(pool[key].dtype), (1, keys_per_row))
+        for key in ("ks", "vs")] + [
+        rows[key].astype(pool[key].dtype) for key in ("k", "v")]
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
+    row_buffer = pltpu.VMEM((batch, width), pool["ks"].dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(1,),
+        in_specs=[whole(op) for op in operands] + [in_place] * len(keys),
+        out_specs=[in_place] * len(keys),
+        scratch_shapes=[row_buffer, row_buffer,
+                        pltpu.SemaphoreType.DMA((3,))])
+    first_pool = 3 + len(operands)      # after the prefetched scalars
+    outs = pl.pallas_call(
+        functools.partial(_decode_append_kernel, kv_heads=kv_heads),
+        grid_spec=grid_spec,
+        # The K/V pools are pinned to HBM: left free, XLA moved one of
+        # Mistral's 75 MB pools on chip and back inside every decode
+        # step (two round the parent's scatters) for a call that
+        # writes 32 KB of it (TPU compiler, PR 29).  On the planes the
+        # pin changes nothing in the compiled program.
+        out_shape=[(pltpu.HBM if pool[key].ndim == 4 else
+                    jax.ShapeDtypeStruct)(pool[key].shape, pool[key].dtype)
+                   for key in keys],
+        input_output_aliases={first_pool + i: i for i in range(len(keys))},
+        cost_estimate=pl.CostEstimate(
+            flops=0, transcendentals=0,
+            bytes_accessed=SCHEDULER_PLANE_PASSES * 2 * pool["ks"].nbytes),
+        interpret=interpret,
+    )(targets, block_ids, offsets, *operands, *(pool[key] for key in keys))
+    return dict(zip(keys, outs))

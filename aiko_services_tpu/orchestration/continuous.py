@@ -430,6 +430,7 @@ class ContinuousBatchingServer:
             self._attention_blocks()
         self.decode_attention_path, self.prefill_attention_path = \
             self._attention_paths()
+        self.decode_scale_append_path = self._scale_append_path()
         # Bookkeeping state lives HOST-side (numpy): admissions and
         # retirements mutate it for free, and it rides into the chunk
         # dispatch as three tiny h2d transfers.  The device-returned
@@ -842,6 +843,13 @@ class ContinuousBatchingServer:
                                  self._bucket_minimum))
         return decode, "kernel" if flash else "reference"
 
+    def _scale_append_path(self) -> str:
+        """How a decode step appends its int8 KV scales: ``"kernel"``
+        (the paged layout's lane rows, patched in place),
+        ``"scatter"`` (XLA, into planes: this layout always) or
+        ``"none"`` (a float cache has no scales)."""
+        return "scatter" if self.quantize_kv else "none"
+
     def _note_decode_blocks(self, live, sched) -> None:
         """Estimate the KV blocks each dispatched decode step reads,
         from the host position mirrors (positions as of dispatch;
@@ -1076,6 +1084,34 @@ class ContinuousBatchingServer:
         # final chunk has been dispatched.
         return bool(self._queue) or self.slots_active > 0 \
             or bool(self._ring)
+
+    #: ``arrival_hold_s``: the share of the host's last waits on a
+    #: chunk that its loop may spend listening, and the most it may.
+    ARRIVAL_HOLD_SHARE = 0.125
+    ARRIVAL_HOLD_MAX_S = 0.010
+
+    def arrival_hold_s(self) -> float:
+        """How long the caller's loop may go on taking messages before
+        its next :meth:`step`, at no cost to the device.
+
+        ``step()`` admits what has arrived, commits the next chunk and
+        then blocks on the running one, so a request that reaches the
+        loop just after ``_admit`` waits a whole chunk more than one
+        just before it.  Straight after a step that left a chunk in
+        flight that chunk has only begun, and the next dispatch is not
+        due until it ends: an eighth of what the host lately waited on
+        a chunk (at most 10 ms) is slack, and listening through it
+        takes the cut-off away from the chunk's own end, which is
+        where a caller that sends on a completion, or on a schedule
+        the chunks happen to beat against, arrives (my chip runs,
+        PR 29: a request due 0.2 to 3.1 ms after a chunk's end made or
+        missed the 1 ms the loop then listened, run by run, and
+        ``ttft_p50_ms`` read 207 or 233).  0.0 with nothing in flight:
+        an idle device must not wait."""
+        if not self._ring or self._ema_wait_ms is None:
+            return 0.0
+        return min(self.ARRIVAL_HOLD_MAX_S,
+                   self.ARRIVAL_HOLD_SHARE * self._ema_wait_ms / 1e3)
 
     def _admit(self) -> None:
         span = None
@@ -2581,6 +2617,7 @@ class ContinuousBatchingServer:
             ep_degree=self.ep_degree,
             mesh_shape=self.mesh_shape,
             decode_attention_path=self.decode_attention_path,
+            decode_scale_append_path=self.decode_scale_append_path,
             prefill_attention_path=self.prefill_attention_path,
             blocks_read_per_step=(
                 round(self.counters["decode_blocks_read"] / steps, 2)
@@ -2949,8 +2986,11 @@ class ContinuousReplica(Actor):
 
     def _schedule_pump(self):
         from ..runtime.actor import ActorMessage, Mailbox
+        # At least the millisecond that lets other mailbox traffic
+        # in between steps; more while a chunk that has just begun
+        # keeps the device busy (``arrival_hold_s``).
         self._post_message(Mailbox.IN, ActorMessage("pump", []),
-                           delay=0.001)
+                           delay=max(0.001, self.server.arrival_hold_s()))
 
     def _pump(self):
         if faults.PLAN is not None:

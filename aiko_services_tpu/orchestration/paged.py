@@ -593,6 +593,13 @@ class PagedContinuousServer(ContinuousBatchingServer):
                 prefill_attention_path(*geometry, self.block_size,
                                        chunk))
 
+    def _scale_append_path(self) -> str:
+        from ..ops.paged_attention import decode_scale_append_path
+        if self._mesh is not None and self.quantize_kv:
+            return "scatter"    # the shard_map engine's scans carry planes
+        return decode_scale_append_path(*self._kv_geometry(),
+                                        self.block_size)
+
     def _slice_key_blocks(self, start: int, width: int) -> int:
         """Key blocks x query tiles the append attention of one slice
         ``[start, start + width)`` has to visit, one layer's worth, at

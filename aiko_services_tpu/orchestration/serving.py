@@ -78,7 +78,8 @@ TELEMETRY_KEYS = (
     "kv_spills", "kv_disk_blocks", "kv_disk_bytes",
     "kv_disk_restores", "kv_checksum_failures", "kv_adopted_chains",
     "kv_prefetch_promotions",
-    "decode_attention_path", "blocks_read_per_step",
+    "decode_attention_path", "decode_scale_append_path",
+    "blocks_read_per_step",
     "prefill_tokens_per_sec", "prefill_queue_depth",
     "prefill_attention_path",
     "deadline_exceeded", "shed", "watchdog_trips", "free_slots",

@@ -214,6 +214,51 @@ def check_decode(geo: Geometry, quantized: bool, interpret: bool):
             "(bf16 q), with and without a window")
 
 
+def check_decode_append(geo: Geometry, interpret: bool):
+    """``paged_decode_append`` vs the XLA scatter of the same token
+    into the pool: live rows at a block's last key and a fresh block's
+    first beside idle rows (scratch block 0, which the kernel leaves as
+    it was).  Bit for bit: the kernel copies and selects, it computes
+    nothing."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from aiko_services_tpu.ops import paged_attention as pa
+    rng = np.random.default_rng(5)
+    pool, _, tables = _pool_case(rng, geo, geo.slots, 2, True)
+    kv, hd = geo.kv_heads, geo.head_dim
+    block_ids = tables[:, 0].at[1::3].set(0)
+    offsets = jnp.asarray([BLOCK_SIZE - 1, 3, 0, 7, 9, 2, 15, 1
+                           ][:geo.slots], jnp.int32)
+    rows = {
+        "k": jnp.asarray(rng.integers(-127, 128, (geo.slots, kv, hd)),
+                         jnp.int8),
+        "v": jnp.asarray(rng.integers(-127, 128, (geo.slots, kv, hd)),
+                         jnp.int8),
+        "ks": jnp.asarray(rng.random((geo.slots, kv)), jnp.float32),
+        "vs": jnp.asarray(rng.random((geo.slots, kv)), jnp.float32)}
+    width = pa.decode_scale_row(BLOCK_SIZE, kv)
+    want = {}
+    for key, buf in pool.items():
+        want[key] = np.array(buf.at[block_ids, offsets].set(rows[key]))
+        want[key][0] = np.asarray(buf[0])
+    # Donated, as every serving program donates its pool: the call
+    # overwrites it.  Called bare on buffers the caller keeps, XLA has
+    # to copy the pools for the alias, and the TPU compiler aborts on a
+    # copy into an output pinned to HBM (v5e, PR 29).
+    got = jax.jit(
+        lambda scan_pool: pa.paged_decode_append(
+            scan_pool, rows, block_ids, offsets, interpret=interpret),
+        donate_argnums=0)(
+        dict(pool, ks=pool["ks"].reshape(-1, width),
+             vs=pool["vs"].reshape(-1, width)))
+    for key, buf in got.items():
+        np.testing.assert_array_equal(
+            np.asarray(buf).reshape(want[key].shape), want[key])
+    live = int((block_ids != 0).sum())
+    return f"bit-equal, {live} live rows of {geo.slots}"
+
+
 def _append_case(rng, geo: Geometry, quantized: bool, width: int,
                  cached_lens, chunk_lens):
     import jax.numpy as jnp
@@ -393,6 +438,9 @@ def kernel_phase(report: Report, geo: Geometry, interpret: bool):
                 continue
             report.check(f"{name} attention, {label}", fn, geo,
                          quantized, interpret)
+    if decode_scale_row(BLOCK_SIZE, geo.kv_heads):
+        report.check("decode append, int8 KV", check_decode_append,
+                     geo, interpret)
     for shape in geo.matmuls:
         report.check(f"int8_matmul m={geo.slots} K,N={shape}",
                      check_int8_matmul, geo, shape, interpret)
@@ -579,7 +627,8 @@ def serve_phase(report: Report, geo: Geometry, tp: int):
             served = (stats["decode_attention_path"],
                       stats["prefill_attention_path"])
             print(f"   attention paths: decode={served[0]} "
-                  f"prefill={served[1]}; prefix_hits="
+                  f"prefill={served[1]}; scale append="
+                  f"{stats['decode_scale_append_path']}; prefix_hits="
                   f"{stats['prefix_hits']}; requests "
                   f"{len(tokens)}; tokens "
                   f"{sum(map(len, tokens.values()))}", flush=True)

@@ -190,6 +190,30 @@ def test_streaming_ordering_under_async_dispatch(engine):
     assert joined == want               # in-order, gapless, complete
 
 
+def test_pump_listens_longer_while_a_chunk_is_in_flight(engine):
+    """Between steps the pump yields a millisecond to other mailbox
+    traffic, and the server's ``arrival_hold_s`` while a chunk that
+    has just begun keeps the device busy."""
+    process = Process(namespace="test", hostname="h", pid="89",
+                      engine=engine, broker="async_hold")
+    server = ContinuousBatchingServer(config_name="tiny", slots=2,
+                                      max_seq=64, chunk_steps=2, seed=6)
+    replica = compose_instance(
+        ContinuousReplica, actor_args("cbh"), process=process,
+        server=server)
+    delays = []
+    replica._post_message = (
+        lambda mailbox, message, delay=0.0: delays.append(delay))
+    replica._schedule_pump()
+    server._ring.append(object())
+    server._ema_wait_ms = 80.0
+    replica._schedule_pump()
+    server._ema_wait_ms = 2.0
+    replica._schedule_pump()
+    server._ring.clear()
+    assert delays == [0.001, server.ARRIVAL_HOLD_MAX_S, 0.001]
+
+
 def test_serving_smoke_counters_monotone():
     """Fast CPU smoke for the async loop (tier-1): run BOTH servers a
     few steps and check every cumulative counter is monotone

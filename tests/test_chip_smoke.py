@@ -124,6 +124,14 @@ def test_path_tags_follow_the_dispatch_predicate(monkeypatch, mode):
                                    max_seq=64).stats()
     assert narrow["decode_attention_path"] == "kernel"
     assert narrow["prefill_attention_path"] == "kernel"
+    # ... and how a step's int8 scales are appended: by the kernel
+    # where blocks fill lane rows of scales (8 kv heads), by the
+    # scatter where they do not (2), not at all in a float pool.
+    assert narrow["decode_scale_append_path"] == "none"
+    for name, path in (("tiny_tp", "kernel"), ("tiny", "scatter")):
+        assert PagedContinuousServer(
+            config_name=name, slots=2, max_seq=64,
+            quantize_kv=True).stats()["decode_scale_append_path"] == path
     wide = PagedContinuousServer(
         config_name=_wide_head_config(monkeypatch), slots=2,
         max_seq=64).stats()

@@ -227,6 +227,37 @@ def test_ring_depth_stays_clamped_and_telemetered():
 
 
 # ---------------------------------------------------------------- #
+# Listening for arrivals while a chunk that has just begun runs
+# ---------------------------------------------------------------- #
+
+@pytest.mark.parametrize("ring, wait_ms, want", [
+    ((), 90.0, 0.0),           # nothing in flight: an idle device
+    (("chunk",), None, 0.0),   # cold start: no wait measured yet
+    (("chunk",), 4.0, 0.0005),  # an eighth of the host's wait
+    (("chunk",), 90.0, 0.010),  # capped
+])
+def test_arrival_hold_follows_the_ring_and_the_wait(ring, wait_ms, want):
+    server = ContinuousBatchingServer.__new__(ContinuousBatchingServer)
+    server._ring, server._ema_wait_ms = list(ring), wait_ms
+    assert server.arrival_hold_s() == pytest.approx(want)
+
+
+def test_arrival_hold_is_zero_once_the_ring_has_drained():
+    server = _paged(True)
+    for request in _requests(server.config, [(7, 10)]):
+        server.submit(request)
+    held = []
+    while server.busy:
+        server.step()
+        held.append((bool(server._ring), server.arrival_hold_s()))
+    assert any(ring for ring, _ in held)
+    assert all(0.0 <= hold <= server.ARRIVAL_HOLD_MAX_S
+               for _, hold in held)
+    assert all(hold == 0.0 for ring, hold in held if not ring)
+    assert server.arrival_hold_s() == 0.0
+
+
+# ---------------------------------------------------------------- #
 # Device-resident sampling-param edits
 # ---------------------------------------------------------------- #
 

@@ -457,24 +457,29 @@ def test_llama_decode_kernel_vs_reference(monkeypatch, quantize_kv):
                                   greedy("interpret"))
 
 
+@pytest.mark.parametrize("config_name,carried", [("tiny", 3),
+                                                 ("tiny_tp", 2)])
 @pytest.mark.parametrize("quantize_kv", [False, True])
-def test_paged_decode_scan_kernel_vs_reference(monkeypatch,
-                                               quantize_kv):
-    """A paged decode scan through the kernel == through the oracle:
-    tokens, and the pool it hands back.  With int8 KV the scan carries
-    the scale planes as rows for the kernel's copies
-    (llama._scan_scale_rows); at its exit they are planes again, with
+def test_paged_decode_scan_kernel_vs_reference(monkeypatch, quantize_kv,
+                                               config_name, carried):
+    """An 8-step paged decode scan through the kernels == through the
+    oracle: tokens, and the pool it hands back.  With int8 KV whose
+    blocks fill lane rows of scales (``tiny_tp``: 8 kv heads) the scan
+    carries the scale planes as those rows, for the decode kernel's
+    copies and the append kernel's patches (llama._scan_scale_rows);
+    a geometry without whole rows (``tiny``: 2 kv heads) keeps planes
+    and the scatter.  At the scan's exit they are planes again, with
     the scan's writes in them."""
     import functools
     from aiko_services_tpu.models import llama
-    config = llama.CONFIGS["tiny"]
-    slots, bs, max_blocks, steps = 3, 16, 4, 5
+    config = llama.CONFIGS[config_name]
+    slots, bs, max_blocks, steps = 3, 16, 4, 8
     n_blocks = slots * max_blocks + 1
     params = llama.init_params(config, jax.random.PRNGKey(1))
     tables = (jnp.arange(slots, dtype=jnp.int32)[:, None] * max_blocks
               + jnp.arange(max_blocks, dtype=jnp.int32)[None, :] + 1)
     tokens = jnp.asarray([[3], [7], [11]], jnp.int32)
-    positions = jnp.asarray([0, 14, 30], jnp.int32)  # crosses a block
+    positions = jnp.asarray([0, 12, 30], jnp.int32)  # crosses a block
     active = jnp.asarray([True, True, False])
 
     def run(mode):
@@ -505,7 +510,7 @@ def test_paged_decode_scan_kernel_vs_reference(monkeypatch,
     np.testing.assert_array_equal(np.asarray(got_pos),
                                   np.asarray(want_pos))
     if quantize_kv:
-        assert (ndim["ks"], ndim["vs"]) == (2, 2)
+        assert (ndim["ks"], ndim["vs"]) == (carried, carried)
         assert (ref_ndim["ks"], ref_ndim["vs"]) == (3, 3)
     for layer, want_layer in zip(got_pool, want_pool):
         assert sorted(layer) == sorted(want_layer)
@@ -513,10 +518,154 @@ def test_paged_decode_scan_kernel_vs_reference(monkeypatch,
             assert buf.shape == want_layer[key].shape
     # Layer 0's K/V rows depend on the tokens alone, so both paths
     # must have written the same bytes (deeper layers see each path's
-    # own rounding of the attention before them).
+    # own rounding of the attention before them) — but for the idle
+    # slot's write into scratch block 0, which the append kernel skips.
+    first = 1 if quantize_kv and carried == 2 else 0
     for key, buf in got_pool[0].items():
-        np.testing.assert_array_equal(np.asarray(buf),
-                                      np.asarray(want_pool[0][key]))
+        np.testing.assert_array_equal(
+            np.asarray(buf)[first:],
+            np.asarray(want_pool[0][key])[first:])
+
+
+# --------------------------------------------------------------------------- #
+# The append kernel: a step's token into a layer whose scales ride as rows
+
+
+def _append_config(kv_heads):
+    import dataclasses
+    from aiko_services_tpu.models import llama
+    return dataclasses.replace(llama.CONFIGS["tiny_tp"],
+                               n_heads=2 * kv_heads, n_kv_heads=kv_heads)
+
+
+#: name -> (block size, kv heads, pool blocks, (block, offset) a row;
+#: block 0 = an idle slot's scratch write), planes carried as
+APPEND_CASES = {
+    "block16_kv8": (16, 8, 9, [(3, 5), (0, 1), (8, 9), (5, 0)], 2),
+    "contiguous_view_block128_kv8": (
+        128, 8, 5, [(2, 127), (0, 3), (4, 0), (1, 77)], 2),
+    "refused_geometry_kv2_falls_to_the_scatter": (
+        16, 2, 9, [(3, 5), (0, 1), (8, 9), (5, 0)], 3),
+    "all_rows_idle": (16, 8, 9, [(0, 0), (0, 1), (0, 2), (0, 3)], 2),
+    "last_offset_of_a_block_beside_offset_0_of_a_fresh_one": (
+        16, 8, 9, [(4, 15), (7, 0), (0, 2), (6, 15)], 2),
+    "kv16_eight_keys_a_row": (16, 16, 9, [(3, 5), (4, 4), (0, 1)], 2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(APPEND_CASES))
+def test_append_kernel_matches_the_scatter(monkeypatch, name):
+    """``llama._paged_write_rows`` on what a decode scan carries, the
+    append kernel interpreted, against the same write on planes by the
+    XLA scatter (the oracle, and the path of the CPU and the
+    reference): K/V rows and scale planes bit-equal outside reserved
+    scratch block 0, which the kernel leaves as it was."""
+    from aiko_services_tpu.models import llama
+    bs, kv, n_blocks, writes, carried = APPEND_CASES[name]
+    config = _append_config(kv)
+    hd, batch = config.head_dim, len(writes)
+    rng = np.random.default_rng(11)
+    layer = {
+        "k": jnp.asarray(rng.integers(-127, 128, (n_blocks, bs, kv, hd)),
+                         jnp.int8),
+        "v": jnp.asarray(rng.integers(-127, 128, (n_blocks, bs, kv, hd)),
+                         jnp.int8),
+        "ks": jnp.asarray(rng.random((n_blocks, bs, kv)), jnp.float32),
+        "vs": jnp.asarray(rng.random((n_blocks, bs, kv)), jnp.float32)}
+    k = jnp.asarray(rng.standard_normal((batch, 1, kv, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((batch, 1, kv, hd)), jnp.float32)
+    # One table entry a row: position // bs == 0 picks the block.
+    tables = jnp.asarray([[block] for block, _ in writes], jnp.int32)
+    positions = jnp.asarray([offset for _, offset in writes], jnp.int32)
+
+    monkeypatch.setenv("AIKO_DECODE_ATTENTION", "reference")
+    assert llama._scan_scale_rows([layer], config)[0]["ks"].ndim == 3
+    want = llama._paged_write_rows(layer, k, v, tables, positions)
+
+    monkeypatch.setenv("AIKO_DECODE_ATTENTION", "interpret")
+    scan_layer = llama._scan_scale_rows([layer], config)[0]
+    assert scan_layer["ks"].ndim == scan_layer["vs"].ndim == carried
+    if carried == 2:
+        assert scan_layer["ks"].shape == (n_blocks * bs * kv // 128, 128)
+    got = llama._rest_scale_planes(
+        [llama._paged_write_rows(scan_layer, k, v, tables, positions)])[0]
+
+    assert sorted(got) == sorted(want)
+    for key, buf in got.items():
+        assert buf.shape == layer[key].shape
+        np.testing.assert_array_equal(np.asarray(buf)[1:],
+                                      np.asarray(want[key])[1:])
+        # Scratch block 0: the scatter writes idle slots' rows there,
+        # the kernel leaves it as it was.
+        np.testing.assert_array_equal(
+            np.asarray(buf)[0],
+            np.asarray((layer if carried == 2 else want)[key])[0])
+    live = [block for block, _ in writes if block]
+    assert (np.asarray(got["ks"]) != np.asarray(layer["ks"])).any() \
+        == bool(live)
+
+
+def test_append_call_keeps_its_name_and_writes_in_place():
+    """What a device trace and the TPU compiler see: ONE Pallas call
+    behind a jit named ``paged_decode_append`` (XLA names the custom
+    call after it: not the decode kernel's ``closed_call``, whose
+    roofline metric must not count this call), all four pools aliased
+    to its outputs, rows walked by the kernel's own loop and no XLA-level
+    loop (``decode_step_ms`` reads the decode scan as the program's one
+    ``%while``)."""
+    n_blocks, batch, kv, hd = 9, 4, 8, 32
+    pool = {"k": jnp.zeros((n_blocks, 16, kv, hd), jnp.int8),
+            "v": jnp.zeros((n_blocks, 16, kv, hd), jnp.int8),
+            "ks": jnp.zeros((n_blocks, 128), jnp.float32),
+            "vs": jnp.zeros((n_blocks, 128), jnp.float32)}
+    rows = {"k": jnp.ones((batch, kv, hd), jnp.int8),
+            "v": jnp.ones((batch, kv, hd), jnp.int8),
+            "ks": jnp.ones((batch, kv), jnp.float32),
+            "vs": jnp.ones((batch, kv), jnp.float32)}
+    ids = jnp.asarray([3, 0, 8, 5], jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda *args: pa.paged_decode_append(*args, interpret=True))(
+        pool, rows, ids, ids)
+    calls = [eqn for eqn in _iter_eqns(jaxpr.jaxpr)
+             if eqn.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    call = calls[0]
+    around = [eqn for eqn in _iter_eqns(jaxpr.jaxpr)
+              if eqn.primitive.name in ("pjit", "jit")
+              and any(sub is call for sub in _iter_eqns_of(eqn))]
+    assert [eqn.params["name"] for eqn in around][-1] == \
+        "paged_decode_append"
+    assert call.params["grid_mapping"].grid == (1,)
+    # (3 prefetched scalars, 5 row operands, then ks, vs, k, v)
+    assert tuple(call.params["input_output_aliases"]) == (
+        (8, 0), (9, 1), (10, 2), (11, 3))
+    assert [(out.aval.shape, out.aval.dtype) for out in call.outvars] \
+        == [(pool[key].shape, pool[key].dtype)
+            for key in ("ks", "vs", "k", "v")]
+    kernel_body = {id(eqn) for eqn in _iter_eqns_of(call)}
+    loops = [eqn for eqn in _iter_eqns(jaxpr.jaxpr)
+             if eqn.primitive.name in ("while", "scan")]
+    assert loops and all(id(eqn) in kernel_body for eqn in loops)
+
+
+@pytest.mark.parametrize("dtype,bs,kv,mode,path", [
+    (jnp.int8, 16, 8, (True, False), "kernel"),
+    (jnp.int8, 128, 8, (True, False), "kernel"),
+    (jnp.int8, 16, 8, (True, True), "kernel"),
+    (jnp.int8, 16, 2, (True, True), "scatter"),
+    (jnp.int8, 16, 4, (True, False), "scatter"),
+    (jnp.int8, 16, 8, (False, False), "scatter"),
+    (jnp.bfloat16, 16, 8, (True, False), "none"),
+])
+def test_append_path_follows_the_decode_dispatch(monkeypatch, dtype, bs,
+                                                 kv, mode, path):
+    """The kernel appends exactly where the scans carry lane rows: the
+    decode kernel dispatched (``mode``: what AIKO_DECODE_ATTENTION and
+    the backend say) on an int8 pool whose blocks fill whole rows."""
+    monkeypatch.setattr(pa, "decode_kernel_mode", lambda: mode)
+    assert pa.decode_scale_append_path(128, kv, dtype, bs) == path
+    assert pa.decode_append_dispatch(128, kv, dtype, bs) == (
+        path == "kernel", mode[1])
 
 
 # --------------------------------------------------------------------------- #
@@ -564,6 +713,7 @@ def test_serving_stats_decode_attention_counters():
     server.run_until_drained()
     stats = server.stats()
     assert stats["decode_attention_path"] in ("kernel", "reference")
+    assert stats["decode_scale_append_path"] == "none"      # float cache
     assert stats["decode_blocks_read"] > 0
     assert stats["blocks_read_per_step"] > 0
     # 2 slots x (64 / 64 =) 1 block a row; never above what is there.
@@ -574,5 +724,6 @@ def test_serving_stats_decode_attention_counters():
     telemetry = serving_telemetry(stats)
     assert telemetry["decode_attention_path"] == \
         stats["decode_attention_path"]
+    assert telemetry["decode_scale_append_path"] == "none"
     assert telemetry["blocks_read_per_step"] == pytest.approx(
         stats["blocks_read_per_step"], abs=0.01)

@@ -13,6 +13,8 @@ must not be found by a CPU test of the same shapes)."""
 
 import dataclasses
 import functools
+import json
+import pathlib
 import re
 
 import pytest
@@ -111,7 +113,76 @@ def test_append_kernels_compile_for_v5e(name, one_chip, as_on_tpu):
 
 
 # --------------------------------------------------------------------------- #
-# The serving programs that hold the sweep
+# The decode step's scale append at the cells' widths
+
+
+def _decode_attn_pattern():
+    spec = (pathlib.Path(__file__).resolve().parent.parent / "benchmark"
+            / "layer_metrics" / "decode_attn_roofline.json")
+    return re.compile(json.loads(spec.read_text())["op_pattern"])
+
+
+def _custom_call_lines(text, name):
+    return [line.strip() for line in text.splitlines()
+            if re.match(r"\s*%%%s[.\d]* = .* custom-call\(" % name, line)]
+
+
+#: name → (block, pool blocks): an int8 pool layer of 8 kv heads, 32 rows.
+APPEND_GEOMETRIES_DECODE = {
+    "mistral7b_chat_pool": (16, 4609),
+    "mixtral8x7b_chat_pool": (16, 6145),
+    "contiguous_view_block128": (128, 65),
+}
+
+
+@pytest.mark.parametrize("name", sorted(APPEND_GEOMETRIES_DECODE))
+def test_decode_append_compiles_for_v5e(name, one_chip):
+    """The decode step's append kernel through the TPU compiler inside
+    a scan, as the decode programs hold it: single-row copies at
+    dynamic rows and ``(kv, head_dim)`` int8 row copies into
+    ``pool[block, offset]`` on Mosaic's tiling, all four pools written
+    in place (no copy of a pool or a plane to satisfy the alias: no
+    temporaries), the scan still the one ``while``, and a custom call
+    named ``paged_decode_append`` that the decode kernel's roofline
+    metric does not take for its own."""
+    from aiko_services_tpu.ops import paged_attention as pa
+    bs, n_blocks = APPEND_GEOMETRIES_DECODE[name]
+    kv, hd, batch = 8, 128, 32
+    n_rows = n_blocks * bs * kv // pa.decode_scale_row(bs, kv)
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    pool = {"k": S((n_blocks, bs, kv, hd), jnp.int8),
+            "v": S((n_blocks, bs, kv, hd), jnp.int8),
+            "ks": S((n_rows, 128), jnp.float32),
+            "vs": S((n_rows, 128), jnp.float32)}
+    rows = {"k": S((batch, kv, hd), jnp.int8),
+            "v": S((batch, kv, hd), jnp.int8),
+            "ks": S((batch, kv), jnp.float32),
+            "vs": S((batch, kv), jnp.float32)}
+
+    def steps(pool, rows, blocks, offsets):
+        def body(pool, _):
+            return pa.paged_decode_append(pool, rows, blocks,
+                                          offsets), None
+        return jax.lax.scan(body, pool, None, length=8)[0]
+
+    compiled = jax.jit(steps, donate_argnums=(0,)).lower(
+        pool, rows, S((batch,), jnp.int32), S((batch,), jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    calls = _custom_call_lines(text, "paged_decode_append")
+    assert len(calls) == 1
+    assert not any(_decode_attn_pattern().search(line) for line in calls)
+    assert ("output_to_operand_aliasing={{0}: (8, {}), {1}: (9, {}), "
+            "{2}: (10, {}), {3}: (11, {})}") in calls[0]
+    assert text.count(" while(") == 1
+    assert not [line for line in text.splitlines()
+                if re.search(r"= \(?(f32\[%d,128\]|s8\[%d,)\S* copy\("
+                             % (n_rows, n_blocks), line)]
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+# --------------------------------------------------------------------------- #
+# The serving programs that hold the sweep and the decode scan
 
 
 def _kernel_width(config):
@@ -132,16 +203,72 @@ def _pool_shaped_ops(text, n_blocks, rank):
             and re.search(r" (copy|transpose)\(", line)]
 
 
+def _scan_body(text):
+    """The lines of the computation the program's one ``while`` runs
+    as its body."""
+    body = re.search(r" while\(.*body=(%[\w.-]+)", text).group(1)
+    start = re.search(r"^%s \(.*\{$" % re.escape(body), text, re.M)
+    return text[start.end():text.index("\n}", start.end())].splitlines()
+
+
+def _scale_row_makers(lines, n_rows):
+    """What in ``lines`` makes an array shaped like a scale plane's lane
+    rows ``f32[n_rows,128]`` (tuple results included), as
+    ``(opcode, instruction name)``.  Not the pure plumbing of a loop's
+    state (``get-tuple-element``, ``bitcast``, ``parameter``)."""
+    made = []
+    for line in lines:
+        found = re.match(
+            r"\s*(?:ROOT )?%%([\w.-]+) = \(?f32\[%d,128\]\S*"
+            r"(?:, [^=]*?)?\)? ([\w-]+)\(" % n_rows, line)
+        if found and found.group(2) not in ("get-tuple-element",
+                                            "bitcast", "parameter"):
+            made.append((found.group(2), found.group(1)))
+    return made
+
+
+def test_one_step_chunk_compiles_with_the_append_in_line(one_chip,
+                                                        as_on_tpu):
+    """A chunk's one-step tail: XLA unrolls the scan, so the append
+    kernel sits in the entry computation on the program's own donated
+    pool.  It has to compile there too — on a pool the caller KEEPS the
+    TPU compiler aborts (a copy for the alias into the K/V outputs
+    pinned to HBM), which is why every serving program donates."""
+    from aiko_services_tpu.models import llama
+    config = _kernel_width(llama.CONFIGS["mistral_tiny"])
+    slots, bs, n_blocks, table = 4, 16, 97, 24
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = _shaped(jax.eval_shape(
+        lambda: llama.init_params(config, jax.random.PRNGKey(0))),
+        one_chip)
+    pool = _shaped(jax.eval_shape(
+        lambda: llama.init_paged_cache(config, n_blocks, bs,
+                                       quantize_kv=True)), one_chip)
+    text = llama.decode_chunk_paged.lower(
+        params, S((slots, 1), jnp.int32), pool,
+        S((slots, table), jnp.int32), S((slots,), jnp.int32),
+        S((slots,), jnp.bool_), 1, config).compile().as_text()
+    assert text.count(" while(") == 0
+    assert len(_custom_call_lines(text, "paged_decode_append")) \
+        == config.n_layers
+    assert not _pool_shaped_ops(text, n_blocks, 4)
+
+
 @pytest.mark.parametrize("config_name", ["mistral_tiny", "moe_tiny"])
 def test_serving_programs_hold_one_scan_and_no_pool_copy(
         config_name, one_chip, as_on_tpu):
-    """``serve_chunk_mixed`` holds exactly ONE ``while`` (the decode
-    scan: ``decode_step_ms`` reads "the one %while" of that program)
-    and ``prefill_append_paged`` none; neither copies nor transposes a
-    K/V pool, and the int8 scale planes are re-laid-out no more often
-    than the write kernel and the decode scan already cost them (the
-    attention sweep reads the planes where the write kernel left
-    them)."""
+    """``serve_chunk_mixed`` and ``decode_chunk_paged`` hold exactly ONE
+    ``while`` (the decode scan: ``decode_step_ms`` reads "the one
+    %while" of that program) and ``prefill_append_paged`` none; none
+    copies or transposes a K/V pool, and the int8 scale planes are
+    re-laid-out no more often than the write kernel and the decode
+    scan's entry and exit already cost them (the attention sweep reads
+    the planes where the write kernel left them).  Inside the scan's
+    body nothing makes a scale plane but the append kernel, a call a
+    layer that writes both planes in place — no scatter fusion over a
+    plane, and no copy of one to satisfy the alias — and whatever XLA
+    moves between memory spaces on its own (``copy-start``/``-done``,
+    sliced or whole: asynchronous, layout kept)."""
     from aiko_services_tpu.models import llama
     config = _kernel_width(llama.CONFIGS[config_name])
     layers, slots, bs, n_blocks, table = config.n_layers, 4, 16, 97, 24
@@ -165,18 +292,42 @@ def test_serving_programs_hold_one_scan_and_no_pool_copy(
     mixed = llama.serve_chunk_mixed.lower(
         params, state, pool, tokens, scalar, scalar, 4, config,
         prefill_kv_limit=16).compile().as_text()
+    decode = llama.decode_chunk_paged.lower(
+        params, state["token"], pool, state["tables"],
+        state["positions"], state["active"], 4, config).compile().as_text()
     standalone = llama.prefill_append_paged.lower(
         params, tokens, pool, S((1, table), jnp.int32), scalar, config,
         kv_limit=16, compute_logits=False).compile().as_text()
 
     assert mixed.count(" while(") == 1
+    assert decode.count(" while(") == 1
     assert standalone.count(" while(") == 0
     for text in (mixed, standalone):
         assert "%paged_prefill_call" in text       # the kernel path ran
+    for text in (mixed, decode, standalone):
         assert not _pool_shaped_ops(text, n_blocks, 4)
     # Scale planes (n, bs, kv): in and out of the write kernel's layout,
     # k and v, a layer — what the parent of PR 27 already paid.
     assert len(_pool_shaped_ops(standalone, n_blocks, 3)) <= 4 * layers
-    # ... and the decode scan's rows (n, bs*kv) in and out beside them.
-    assert (len(_pool_shaped_ops(mixed, n_blocks, 3))
-            + len(_pool_shaped_ops(mixed, n_blocks, 2))) <= 8 * layers
+    # The decode scan: planes to lane rows at its entry, back at its
+    # exit, k and v, a layer (both budgets are met with equality) — and
+    # nothing pool-shaped in between.
+    for text, budget in ((decode, 4 * layers), (mixed, 8 * layers)):
+        assert (len(_pool_shaped_ops(text, n_blocks, 3))
+                + len(_pool_shaped_ops(text, n_blocks, 2))) <= budget
+        body = _scan_body(text)
+        appends = _custom_call_lines("\n".join(body), "paged_decode_append")
+        assert len(appends) == layers
+        assert not any(_decode_attn_pattern().search(line)
+                       for line in appends)
+        assert len(_custom_call_lines("\n".join(body),
+                                      "closed_call")) == layers
+        # XLA's own asynchronous moves of a plane between memory
+        # spaces, whole or sliced (a ``ConcatBitcast`` custom call
+        # glues the slices), are not the program's work.
+        strangers = [
+            (op, name) for op, name in _scale_row_makers(body, n_blocks)
+            if not re.match(r"((copy|slice)-(start|done)|tuple)$", op)
+            and not name.startswith(("paged_decode_append",
+                                     "custom-call"))]
+        assert not strangers
