@@ -1,6 +1,7 @@
 from . import llama
 from . import moe
 from . import nemotron_h
+from . import mistral4
 from . import classifier
 from . import detector
 from . import asr
@@ -12,8 +13,12 @@ from . import lora
 #: in ``CONFIGS`` and gives the engine's entry points
 #: (``init_paged_cache``, ``prefill_append_paged``,
 #: ``serve_chunk_paged``, ``serve_chunk_mixed``,
-#: ``scatter_state_rows``, ``kv_geometry``, ``kv_pool_layers``).
-SERVING_MODULES = (llama, nemotron_h)
+#: ``scatter_state_rows``, ``kv_geometry``, ``kv_pool_layers``,
+#: ``state_bytes_per_slot``, ``layer_kinds``; ``RECURRENT_STATE``,
+#: ``COUNTERS``, ``UNSUPPORTED``; and, where the K/V kernels' own
+#: dispatch does not describe its pool, ``attention_paths`` and
+#: ``slice_key_blocks``).
+SERVING_MODULES = (llama, nemotron_h, mistral4)
 
 
 def serving_model(config_name: str):

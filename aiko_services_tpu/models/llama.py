@@ -51,7 +51,7 @@ __all__ = ["LlamaConfig", "init_params", "forward",
            "verify_chunk_paged",
            "paged_insert_prefix", "paged_scatter_blocks",
            "paged_gather_blocks", "complete", "CONFIGS",
-           "RECURRENT_STATE", "COUNTERS", "kv_geometry",
+           "RECURRENT_STATE", "COUNTERS", "UNSUPPORTED", "kv_geometry",
            "kv_pool_layers", "scatter_state_rows",
            "state_bytes_per_slot", "layer_kinds"]
 
@@ -850,6 +850,8 @@ def prefill(params, tokens, cache, config: LlamaConfig, lora=None):
 #: that has both).
 RECURRENT_STATE = False
 COUNTERS = ()
+#: Nothing the engine offers is refused for this module.
+UNSUPPORTED = None
 
 
 def kv_geometry(config: LlamaConfig, quantize_kv: bool):

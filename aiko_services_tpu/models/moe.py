@@ -25,7 +25,8 @@ says which one it is:
 * **latent projections** (``d_latent``): routed experts work in a
   narrower width, reached by ``latent_in`` and left by ``latent_out``;
 * **shared expert** (``d_shared``): a dense block of the same
-  activation on the full width, added to the routed result;
+  activation on the full width (two matrices under ``relu2``, three
+  with a ``shared_gate`` under ``swiglu``), added to the routed result;
 * **experts held here** (``held = (first, count)``): the chip's share
   of an expert-parallel deployment.  The router keeps its full width
   and ``top_k``, and the layer computes the part of the result its own
@@ -123,6 +124,9 @@ def init_moe_params(config: MoEConfig, key) -> Dict:
     if config.d_shared:
         params["shared_up"] = init(more[3], (d, config.d_shared))
         params["shared_down"] = init(more[4], (config.d_shared, d))
+        if config.activation == "swiglu":
+            params["shared_gate"] = init(jax.random.fold_in(key, 2),
+                                         (d, config.d_shared))
     return params
 
 
@@ -327,8 +331,11 @@ def moe_layer(params, x, config: MoEConfig, rows=None):
     if config.d_latent:
         out = _dense(out.astype(x.dtype), params["latent_out"])
     if config.d_shared:
+        gate = params.get("shared_gate")
+        if gate is not None:
+            gate = _dense(xt, gate).astype(jnp.float32)
         hidden = _activate(
-            _dense(xt, params["shared_up"]).astype(jnp.float32))
+            _dense(xt, params["shared_up"]).astype(jnp.float32), gate)
         out = out + _dense(hidden.astype(x.dtype),
                            params["shared_down"])
     return out.reshape(batch, seq, d), counts

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from ..ops.attention import attention_reference
 from ..ops.paged_attention import decode_dispatch, paged_decode_attention
 from ..ops.paged_prefill import paged_prefill_attention, prefill_dispatch
-from ..ops.quant import quantize_int8
+from ..ops.quant import quantize_named_int8
 from . import llama as _llama
 from .llama import (_cached_gqa_attention, _embed_lookup, _matmul,
                     _paged_gather, _paged_write_rows, _paged_write_slab,
@@ -48,6 +48,7 @@ from .llama import (_cached_gqa_attention, _embed_lookup, _matmul,
 from .moe import MoEConfig, init_moe_params, moe_layer
 
 __all__ = ["NemotronHConfig", "CONFIGS", "COUNTERS", "RECURRENT_STATE",
+           "UNSUPPORTED",
            "init_params", "quantize_params", "forward",
            "init_paged_cache", "kv_pool_layers", "kv_geometry",
            "state_bytes_per_slot", "layer_kinds",
@@ -57,6 +58,34 @@ __all__ = ["NemotronHConfig", "CONFIGS", "COUNTERS", "RECURRENT_STATE",
 #: The engine refuses, for a model module that says so, what needs a
 #: snapshot or a rollback of per-slot state it cannot take yet.
 RECURRENT_STATE = True
+#: What that is, and per feature the piece it lacks: a copy of the
+#: state the engine cannot take yet.
+UNSUPPORTED = ("per-slot recurrent state", {
+    "mesh": "a sharding rule for the per-slot state (this "
+            "model module has only the single-chip programs)",
+    "replica_mesh": "a shard_map engine for this model module "
+                    "(llama_tp serves Llama-family layers only)",
+    "adapters": "LoRA factors through the Mamba and expert "
+                "projections",
+    "speculation": "a rollback of the recurrent state to the "
+                   "last accepted token (a rejected window has "
+                   "already advanced it)",
+    "prefix_cache": "a snapshot of the recurrent state at "
+                    "block boundaries (a block hit has keys "
+                    "and values behind it, and no state)",
+    "host_tier": "a snapshot of the recurrent state at block "
+                 "boundaries to demote with the blocks",
+    "spill": "a snapshot of the recurrent state at block "
+             "boundaries to spill with the blocks",
+    "kv_transfer": "the recurrent state at the segment's end to "
+                   "travel with its blocks (a snapshot at block "
+                   "boundaries)",
+    "migration": "the slot's live recurrent state to travel "
+                 "with its block chain",
+    "contiguous_layout": "contiguous-cache programs in this "
+                         "model module (serve it with "
+                         "PagedContinuousServer)",
+})
 #: Counters a serve chunk returns beside its tokens (no extra sync);
 #: the engine adds them to ``server.counters`` when it reads the chunk.
 COUNTERS = ("moe_pairs", "moe_pairs_here", "moe_experts_hit")
@@ -201,15 +230,7 @@ def quantize_params(params, bits: int = 8) -> Dict:
         raise NotImplementedError("int8 weight-only is the one "
                                   "quantized layout of this model")
 
-    def visit(tree):
-        return {name: (visit(leaf) if isinstance(leaf, dict)
-                       else [visit(item) for item in leaf]
-                       if isinstance(leaf, list)
-                       else quantize_int8(leaf) if name in _INT8_LEAVES
-                       else leaf)
-                for name, leaf in tree.items()}
-
-    return visit(params)
+    return quantize_named_int8(params, _INT8_LEAVES)
 
 
 # --------------------------------------------------------------------------- #

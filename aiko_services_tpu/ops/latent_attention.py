@@ -1,0 +1,489 @@
+"""Paged attention over a LATENT block pool (multi-head latent
+attention, DeepSeek-V2's MLA): Pallas TPU kernels and the jnp forms
+they must match.
+
+A position's cache row is ``[c_kv | k_r]``: the compressed key/value
+vector after its norm (``rank`` values) and the one rotary key every
+head shares (``rope`` values) — ``width = rank + rope`` values, no head
+axis.  The pool is ``(n_blocks, block_size, width)``, walked through the
+same block tables as the K/V pools of :mod:`.paged_attention`.
+
+Both kernels compute the ABSORBED form.  A head's query arrives already
+carried into the latent width (``q~_h = q_nope_h W_uk_h^T``, beside its
+rotated ``q_rope_h``), so for every head at once
+
+    s = [q~ | q_rope] . [c_kv | k_r]^T * scale,   o = softmax(s) c_kv
+
+and the caller carries ``o`` back out (``o_h W_uv_h``).  All heads of a
+token read ONE row a key, so a step is two plain matmuls with the heads
+on the row axis — ``(rows, width) x (width, keys)`` and ``(rows, keys)
+x (keys, rank)`` — and nothing of the context is ever expanded to
+per-head keys or values.
+
+* :func:`latent_decode_attention`: one query token a batch row against
+  the row's live blocks (grid ``(batch,)``, a loop over the live table
+  entries ``keys / block_size`` blocks an iteration, double-buffered
+  DMAs: the structure of :func:`.paged_attention.closed_call`).
+* :func:`latent_prefill_attention`: a slice of ``T`` query tokens of ONE
+  row against the ``start`` positions its table already holds (shared
+  prefix blocks included) and against the slice's own rows, causally
+  (grid ``(T / q_tile,)``; the own rows ride VMEM).
+* :func:`latent_append`: rows into the pool in place.  A decode step's
+  one row a slot is read-modify-write of its 16-key block (a bf16 row
+  is half of each 32-bit word of its sublane pair, so a lone row is not
+  a DMA); a prefill slice writes whole blocks.
+
+Off the TPU (and not interpreting) each is its jnp form.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .attention import NEG_INF
+from .paged_attention import (_contract_pool_rows, decode_kernel_mode,
+                              runs_kernel)
+from .paged_prefill import prefill_kernel_mode
+
+__all__ = ["latent_decode_attention", "latent_decode_reference",
+           "latent_prefill_attention", "latent_prefill_reference",
+           "latent_append", "latent_append_reference",
+           "latent_attention_paths", "latent_slice_key_blocks",
+           "closed_call", "KEYS_PER_ITERATION", "PREFILL_Q_TILE"]
+
+#: Keys one loop iteration of either kernel holds in VMEM.
+KEYS_PER_ITERATION = 128
+
+#: Query tokens of one prefill program.  Its state is per (token, head)
+#: row: 64 tokens of 32 heads are 2,048 rows, whose f32 accumulator
+#: ``(rows, rank 256)`` is 2 MiB and score tile ``(rows, 128)`` 1 MiB.
+PREFILL_Q_TILE = 64
+
+#: Scoped VMEM either attention program may take.  Mosaic's default is
+#: 16 MiB of a v5e's 128; a prefill tile of 2,048 rows asked for 16.8 to
+#: 19.8 MiB (TPU compiler, PR 31: its state above, the queries and the
+#: temporaries of one step).
+VMEM_LIMIT_BYTES = 48 * 2**20
+
+
+def latent_attention_paths() -> Tuple[str, str]:
+    """``(decode, prefill)`` serving path tags: ``"kernel"`` where the
+    mode variables of :mod:`.paged_attention` / :mod:`.paged_prefill`
+    run the Pallas kernels (the TPU, or interpreting), else
+    ``"reference"``.  The kernels serve any latent pool whose block
+    size divides :data:`KEYS_PER_ITERATION`."""
+    return tuple("kernel" if mode()[0] else "reference"
+                 for mode in (decode_kernel_mode, prefill_kernel_mode))
+
+
+def latent_slice_key_blocks(start: int, width: int, block_size: int,
+                            q_tile: int = PREFILL_Q_TILE) -> int:
+    """Pool blocks x query tiles the attention of ONE prefill slice
+    ``[start, start + width)`` sweeps in one layer: every tile reads
+    the ``start / block_size`` cached blocks, and of the slice's own
+    rows the blocks up to its last query (the serving counter
+    ``prefill_key_blocks``, counted on the host)."""
+    q_tile = min(q_tile, width)
+    total = 0
+    for first in range(0, width, q_tile):
+        total += start // block_size + -(-(first + q_tile) // block_size)
+    return total
+
+
+# --------------------------------------------------------------------------- #
+# jnp forms
+
+
+def _gathered(pool, tables):
+    """``(batch, table width * block_size, width)`` rows of each row's
+    table, in position order."""
+    rows = pool[tables]
+    return rows.reshape(tables.shape[0], -1, pool.shape[-1])
+
+
+def latent_decode_reference(q, pool, tables, positions, *, rank: int,
+                            sm_scale: float):
+    """``q (batch, heads, width)`` against keys ``0..positions[row]`` of
+    each row's table -> ``(batch, heads, rank)`` in ``q.dtype``."""
+    rows = _gathered(pool, tables).astype(jnp.float32)
+    s = jnp.einsum("bhw,bsw->bhs", q.astype(jnp.float32), rows,
+                   precision=jax.lax.Precision.HIGHEST) * sm_scale
+    visible = jnp.arange(rows.shape[1])[None] <= positions[:, None]
+    s = jnp.where(visible[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhs,bsr->bhr", p, rows[..., :rank],
+                      precision=jax.lax.Precision.HIGHEST).astype(q.dtype)
+
+
+def latent_prefill_reference(q, own, pool, table, start, *, rank: int,
+                             sm_scale: float):
+    """``q (T, heads, width)`` of positions ``start .. start + T - 1``
+    against the ``start`` cached positions of ``table (table width,)``
+    and the slice's ``own (T, width)`` rows -> ``(T, heads, rank)``."""
+    tokens = q.shape[0]
+    cached = _gathered(pool, table[None])[0]
+    rows = jnp.concatenate([cached, own.astype(pool.dtype)], axis=0
+                           ).astype(jnp.float32)
+    s = jnp.einsum("thw,sw->ths", q.astype(jnp.float32), rows,
+                   precision=jax.lax.Precision.HIGHEST) * sm_scale
+    key = jnp.arange(rows.shape[0])
+    n_cached = cached.shape[0]
+    visible = jnp.where(key[None] < n_cached, key[None] < start,
+                        key[None] - n_cached <= jnp.arange(tokens)[:, None])
+    s = jnp.where(visible[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("ths,sr->thr", p, rows[:, :rank],
+                      precision=jax.lax.Precision.HIGHEST).astype(q.dtype)
+
+
+def latent_append_reference(pool, rows, block_ids, offsets=None):
+    """``rows (batch, width)`` at ``pool[block_ids, offsets]``, or
+    whole blocks ``rows (n, block_size, width)`` at ``pool[block_ids]``
+    (``offsets`` None)."""
+    rows = rows.astype(pool.dtype)
+    if offsets is None:
+        return pool.at[block_ids].set(rows)
+    return pool.at[block_ids, offsets].set(rows)
+
+
+# --------------------------------------------------------------------------- #
+# The attention kernel: one body, two grids
+
+
+def _latent_attention_kernel(tables_ref, lengths_ref,   # scalar prefetch
+                             q_ref, pool_hbm, *rest, block_size: int,
+                             blocks_per_iter: int, heads: int, rank: int,
+                             sm_scale: float, prefill: bool):
+    """One program = one tile of query rows (``token * heads + head``)
+    and a loop over the cached blocks its table row holds,
+    ``blocks_per_iter`` an iteration, copied into one of two VMEM key
+    buffers while the other is attended over.
+
+    Decode (grid ``(batch,)``): table row ``program_id``, keys
+    ``0 .. lengths_ref[row] - 1`` (the step's own row is already in the
+    pool).  Prefill (grid ``(T / q_tile,)``): table row 0, the
+    ``lengths_ref[0]`` cached keys all visible, then the slice's own
+    rows (``own_ref``, VMEM) up to the tile's last query, causally."""
+    if prefill:
+        own_ref, o_ref, buf, sems, m_scr, l_scr, acc_scr = rest
+        tile = pl.program_id(0)
+        row, length = 0, lengths_ref[0]
+    else:
+        o_ref, buf, sems, m_scr, l_scr, acc_scr = rest
+        row = pl.program_id(0)
+        length = lengths_ref[row]
+    rows = q_ref.shape[1]
+    keys = blocks_per_iter * block_size
+    n_blocks = (length + block_size - 1) // block_size
+    last_live = jnp.minimum(n_blocks, tables_ref.shape[1]) - 1
+    iterations = (n_blocks + blocks_per_iter - 1) // blocks_per_iter
+
+    def copies(c, slot, resolve: bool):
+        """The DMAs of iteration ``c``.  Entries past the last live one
+        are clamped to it (their keys are masked by absolute id), so no
+        entry the row does not own is dereferenced; a descriptor built
+        only to be waited on names block 0."""
+        out = []
+        for i in range(blocks_per_iter):
+            block = 0
+            if resolve:
+                entry = jnp.minimum(c * blocks_per_iter + i, last_live)
+                block = tables_ref[row, entry]
+            out.append(pltpu.make_async_copy(
+                pool_hbm.at[block],
+                buf.at[slot, pl.ds(i * block_size, block_size)],
+                sems.at[slot]))
+        return out
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    row_dtype = (jnp.float32 if buf.dtype == jnp.float32
+                 else jnp.bfloat16)
+    q = q_ref[0]
+
+    def attend(k, visible):
+        """Online-softmax update of every row from ``k (n, width)``."""
+        k = k.astype(row_dtype)
+        s = _contract_pool_rows(q, k, ((1,), (1,))) * sm_scale
+        s = jnp.where(visible, s, NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # A row with nothing visible yet keeps a zero weight on all.
+        p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+        correction = jnp.exp(m_prev - m_new)
+        l_scr[:] = correction * l_scr[:] + jnp.sum(p, axis=-1,
+                                                    keepdims=True)
+        if q.dtype == jnp.bfloat16:
+            p = p.astype(jnp.bfloat16)
+        acc_scr[:] = acc_scr[:] * correction + _contract_pool_rows(
+            p, k[:, :rank], ((1,), (0,)))
+        m_scr[:] = m_new
+
+    @pl.when(iterations > 0)
+    def _first():
+        for copy in copies(0, 0, True):
+            copy.start()
+
+    def body(c, carry):
+        slot = c % 2
+
+        @pl.when(c + 1 < iterations)
+        def _prefetch():
+            for copy in copies(c + 1, 1 - slot, True):
+                copy.start()
+
+        for copy in copies(c, slot, False):
+            copy.wait()
+        key_ids = c * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 1)
+        attend(buf[slot], key_ids < length)
+        return carry
+
+    jax.lax.fori_loop(0, iterations, body, 0)
+    if prefill:
+        tokens = own_ref.shape[0]
+        q_tile = rows // heads
+        first_query = tile * q_tile
+        query = first_query + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, min(keys, tokens)), 0) // heads
+        for chunk in range(0, tokens, keys):
+            size = min(keys, tokens - chunk)
+
+            @pl.when(chunk < first_query + q_tile)
+            def _own(chunk=chunk, size=size):
+                key = chunk + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, size), 1)
+                attend(own_ref[chunk:chunk + size], key <= query[:, :size])
+    denom = jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
+    o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+
+
+def _attention_call(q_tiles, pool, tables, lengths, own, *, heads: int,
+                    rank: int, sm_scale: float, interpret: bool,
+                    out_dtype):
+    """``q_tiles (programs, rows, width)`` through the kernel;
+    ``own`` None for decode."""
+    programs, rows, width = q_tiles.shape
+    block_size = pool.shape[1]
+    blocks_per_iter = max(1, KEYS_PER_ITERATION // block_size)
+    keys = blocks_per_iter * block_size
+    prefill = own is not None
+
+    def tile_index(i, tables_ref, lengths_ref):
+        return (i, 0, 0)
+
+    in_specs = [pl.BlockSpec((1, rows, width), tile_index),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [q_tiles, pool]
+    if prefill:
+        in_specs.append(pl.BlockSpec(
+            own.shape, lambda i, tables_ref, lengths_ref: (0, 0)))
+        operands.append(own.astype(pool.dtype))
+    kernel = functools.partial(
+        _latent_attention_kernel, block_size=block_size,
+        blocks_per_iter=blocks_per_iter, heads=heads, rank=rank,
+        sm_scale=sm_scale, prefill=prefill)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(programs,), in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, rows, rank), tile_index),
+        scratch_shapes=[
+            pltpu.VMEM((2, keys, width), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, rank), jnp.float32)])
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((programs, rows, rank), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
+
+
+def _kernel_runs(use_kernel, interpret: bool) -> bool:
+    """A caller that asked the mode variables passes their answer
+    (``use_kernel``); called bare (None), the entry rule of the paged
+    kernels: interpreting or on the TPU the kernel runs, anywhere else
+    the jnp form is the path."""
+    return runs_kernel(interpret) if use_kernel is None else use_kernel
+
+
+def latent_decode_attention(q, pool, tables, positions, *, rank: int,
+                            sm_scale: float, interpret: bool = False,
+                            use_kernel=None):
+    """Ragged paged latent decode attention: ``q (batch, heads,
+    width)``, ONE query token a row whose own cache row is already in
+    the pool; ``tables (batch, table width)``; keys
+    ``0..positions[row]`` visible.  Returns ``(batch, heads, rank)`` in
+    ``q.dtype``: the softmax-weighted ``c_kv`` rows, still latent."""
+    if not _kernel_runs(use_kernel, interpret):
+        return latent_decode_reference(q, pool, tables, positions,
+                                       rank=rank, sm_scale=sm_scale)
+    return closed_call(q, pool, tables, positions, rank=rank,
+                       sm_scale=sm_scale, interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("rank", "sm_scale", "interpret"))
+def closed_call(q, pool, tables, positions, *, rank: int, sm_scale: float,
+                interpret: bool):
+    """The decode kernel's call behind a jit of its own (one trace and
+    one Mosaic lowering a program).  The name is the one
+    :func:`.paged_attention.closed_call` carries, for the same reason:
+    ``benchmark/layer_metrics/decode_attn_roofline.json`` finds a
+    paged decode attention kernel in a device trace as
+    ``%closed_call.N`` with a 3-D result and the block tables first,
+    and this is the decode attention of the models it serves.
+
+    The queries ride as f32 rows (three bf16 terms in the kernel:
+    f32's precision at one pass over the bf16 pool rows, as there)."""
+    return _attention_call(q.astype(jnp.float32), pool, tables,
+                           positions + 1, None, heads=q.shape[1],
+                           rank=rank, sm_scale=sm_scale,
+                           interpret=interpret, out_dtype=q.dtype)
+
+
+def latent_prefill_attention(q, own, pool, table, start, *, rank: int,
+                             sm_scale: float, interpret: bool = False,
+                             use_kernel=None):
+    """A prefill slice's attention: ``q (T, heads, width)`` of
+    positions ``start .. start + T - 1`` of ONE row, its ``own (T,
+    width)`` cache rows (not yet in the pool), and ``table (table
+    width,)`` whose first ``start / block_size`` blocks hold the row's
+    earlier positions — written by its earlier slices or shared from
+    the prefix cache, read in place either way.  ``start`` is a
+    multiple of the block size.  Returns ``(T, heads, rank)``."""
+    if not _kernel_runs(use_kernel, interpret):
+        return latent_prefill_reference(q, own, pool, table, start,
+                                        rank=rank, sm_scale=sm_scale)
+    return latent_prefill_call(q, own, pool, table, start, rank=rank,
+                               sm_scale=sm_scale, interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("rank", "sm_scale", "interpret"))
+def latent_prefill_call(q, own, pool, table, start, *, rank: int,
+                        sm_scale: float, interpret: bool):
+    """The prefill kernel's call, a jit of its own name."""
+    tokens, heads, width = q.shape
+    q_tile = min(PREFILL_Q_TILE, tokens)
+    if tokens % q_tile:
+        raise ValueError(f"a slice of {tokens} tokens is not whole "
+                         f"query tiles of {q_tile}")
+    out = _attention_call(
+        q.reshape(tokens // q_tile, q_tile * heads, width), pool,
+        table[None], jnp.reshape(start, (1,)), own, heads=heads,
+        rank=rank, sm_scale=sm_scale, interpret=interpret,
+        out_dtype=q.dtype)
+    return out.reshape(tokens, heads, rank)
+
+
+# --------------------------------------------------------------------------- #
+# The append kernel
+
+
+def _latent_append_kernel(blocks_ref,                     # scalar prefetch
+                          offsets_ref, rows_ref, pool_hbm, pool_out, buf,
+                          sems, *,
+                          whole_blocks: bool):
+    """Grid ``(1,)``.  ``whole_blocks``: ``rows_ref (n, block_size,
+    width)`` copied to ``pool[blocks_ref[i]]``.  Else, per batch row
+    ``r`` whose block is not the scratch block 0: its block is read,
+    row ``offsets_ref[r]`` of it replaced by ``rows_ref[r]`` (a
+    selection: the other keys pass through bit for bit) and the block
+    written back.  Every row's copy is in flight before the first
+    wait: a row costs a DMA's latency, not its bytes."""
+    count = rows_ref.shape[0]
+
+    def for_rows(act):
+        def body(r, carry):
+            @pl.when(jnp.logical_or(whole_blocks, blocks_ref[r] != 0))
+            def _live():
+                act(r)
+            return carry
+        jax.lax.fori_loop(0, count, body, 0)
+
+    if whole_blocks:
+        def write(r):
+            return pltpu.make_async_copy(
+                rows_ref.at[r], pool_out.at[blocks_ref[r]], sems.at[0])
+        for_rows(lambda r: write(r).start())
+        for_rows(lambda r: write(r).wait())
+        return
+
+    def read(r):
+        return pltpu.make_async_copy(pool_hbm.at[blocks_ref[r]], buf.at[r],
+                                     sems.at[0])
+
+    def write(r):
+        return pltpu.make_async_copy(buf.at[r], pool_out.at[blocks_ref[r]],
+                                     sems.at[1])
+
+    for_rows(lambda r: read(r).start())
+    for_rows(lambda r: read(r).wait())
+    key = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 1)
+    buf[...] = jnp.where(key == offsets_ref[...][:, :, None],
+                         rows_ref[...][:, None, :], buf[...])
+    for_rows(lambda r: write(r).start())
+    for_rows(lambda r: write(r).wait())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "use_kernel"))
+def latent_append(pool, rows, block_ids, offsets=None, *,
+                  interpret: bool = False, use_kernel=None):
+    """Cache rows into a latent pool, in place (the pool is aliased to
+    the result and has to be the caller's to overwrite: a scan's carry
+    or a donated argument, as in every serving program).
+
+    ``offsets (batch,)``: ``rows (batch, width)`` land at
+    ``pool[block_ids, offsets]`` — a decode step's one row a slot; rows
+    whose block is the reserved scratch block 0 (idle slots) are
+    skipped, and two live rows never write one block.  ``offsets``
+    None: ``rows (n, block_size, width)`` replace the blocks
+    ``block_ids`` — a prefill slice.  No pass over the pool either
+    way."""
+    if not _kernel_runs(use_kernel, interpret):
+        return latent_append_reference(pool, rows, block_ids, offsets)
+    whole_blocks = offsets is None
+    count = rows.shape[0]
+    block_size, width = pool.shape[1:]
+    rows = rows.astype(pool.dtype)
+    block_ids = block_ids.astype(jnp.int32)
+    if whole_blocks:
+        offsets_2d = jnp.zeros((1, 1), jnp.int32)
+    else:
+        offsets_2d = offsets.astype(jnp.int32)[:, None]
+
+    def whole(array):
+        return pl.BlockSpec(array.shape,
+                            lambda i, *prefetch: (0,) * array.ndim)
+
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(1,),
+        in_specs=[whole(offsets_2d), whole(rows), in_place],
+        out_specs=in_place,
+        scratch_shapes=[
+            pltpu.VMEM((1 if whole_blocks else count, block_size, width),
+                       pool.dtype),
+            pltpu.SemaphoreType.DMA((2,))])
+    return pl.pallas_call(
+        functools.partial(_latent_append_kernel,
+                          whole_blocks=whole_blocks),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={3: 0},
+        cost_estimate=pl.CostEstimate(
+            flops=0, transcendentals=0,
+            bytes_accessed=2 * count * block_size * width
+            * pool.dtype.itemsize),
+        interpret=interpret,
+    )(block_ids, offsets_2d, rows, pool)

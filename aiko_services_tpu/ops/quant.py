@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["quantize_int8", "dequantize", "int8_matmul",
+__all__ = ["quantize_int8", "quantize_named_int8", "dequantize",
+           "int8_matmul",
            "quantize_int4", "dequantize_int4", "int4_matmul",
            "quantize_tree", "is_quantized", "is_quantized_int4"]
 
@@ -337,3 +338,19 @@ def quantize_tree(tree, bits: int = 8, group_size: int = 128):
         return leaf
     return jax.tree_util.tree_map(
         visit, tree, is_leaf=lambda x: isinstance(x, jnp.ndarray))
+
+
+def quantize_named_int8(tree, names) -> Dict:
+    """int8 weight-only for the leaves of a parameter tree of dicts and
+    lists whose KEY is in ``names``; every other leaf stays as it is
+    (a model module says which of its 2-D matrices are served int8:
+    its router, small vectors and 3-D expert leaves are not)."""
+    def visit(node):
+        return {name: (visit(leaf) if isinstance(leaf, dict)
+                       else [visit(item) for item in leaf]
+                       if isinstance(leaf, list)
+                       else quantize_int8(leaf) if name in names
+                       else leaf)
+                for name, leaf in node.items()}
+
+    return visit(tree)

@@ -238,7 +238,7 @@ class ContinuousBatchingServer:
             if hasattr(entry, "_cache_size")]
         self._programs_settled = 0
         self._llama = llama
-        self._refuse_for_recurrent_state(
+        self._refuse_unsupported(
             mesh=mesh is not None, replica_mesh=replica_mesh is not None,
             adapters=bool(adapters),
             speculation=draft_config_name is not None
@@ -637,44 +637,20 @@ class ContinuousBatchingServer:
 
         self._merge_state = merge_state
 
-    def _refuse_for_recurrent_state(self, **asked) -> None:
-        """A model module with per-slot recurrent state
-        (``RECURRENT_STATE``) is refused, at construction, everything
-        that would need a copy of that state the engine cannot take
-        yet.  ``asked``: feature -> whether the caller asked for it."""
-        if not self._model.RECURRENT_STATE:
+    def _refuse_unsupported(self, **asked) -> None:
+        """What a model module cannot be served with yet is refused at
+        construction, by name.  The module says what that is
+        (``UNSUPPORTED``: what the model has that the feature cannot
+        carry, and per feature the missing piece); ``asked``: feature
+        -> whether the caller asked for it."""
+        if self._model.UNSUPPORTED is None:
             return
-        missing = {
-            "mesh": "a sharding rule for the per-slot state (this "
-                    "model module has only the single-chip programs)",
-            "replica_mesh": "a shard_map engine for this model module "
-                            "(llama_tp serves Llama-family layers only)",
-            "adapters": "LoRA factors through the Mamba and expert "
-                        "projections",
-            "speculation": "a rollback of the recurrent state to the "
-                           "last accepted token (a rejected window has "
-                           "already advanced it)",
-            "prefix_cache": "a snapshot of the recurrent state at "
-                            "block boundaries (a block hit has keys "
-                            "and values behind it, and no state)",
-            "host_tier": "a snapshot of the recurrent state at block "
-                         "boundaries to demote with the blocks",
-            "spill": "a snapshot of the recurrent state at block "
-                     "boundaries to spill with the blocks",
-            "kv_transfer": "the recurrent state at the segment's end to "
-                           "travel with its blocks (a snapshot at block "
-                           "boundaries)",
-            "migration": "the slot's live recurrent state to travel "
-                         "with its block chain",
-            "contiguous_layout": "contiguous-cache programs in this "
-                                 "model module (serve it with "
-                                 "PagedContinuousServer)",
-        }
+        has, missing = self._model.UNSUPPORTED
         for feature, wanted in asked.items():
-            if wanted:
+            if wanted and feature in missing:
                 raise ValueError(
                     f"{feature} is not available for a model with "
-                    f"per-slot recurrent state "
+                    f"{has} "
                     f"({self._model.__name__.rsplit('.', 1)[-1]}): it "
                     f"needs {missing[feature]}")
 
@@ -877,7 +853,7 @@ class ContinuousBatchingServer:
         contiguous layout reserves ``slots x max_seq`` rows."""
         jax = self._jax
 
-        self._refuse_for_recurrent_state(contiguous_layout=True)
+        self._refuse_unsupported(contiguous_layout=True)
         self.cache = self._model.init_cache(
             self.config, self.slots, self.max_seq,
             quantize_kv=self.quantize_kv)
@@ -2775,6 +2751,10 @@ class ContinuousReplica(Actor):
     #: idle replica's cached prefixes drop out of routing.
     KV_ADVERTISE_S = 5.0
 
+    #: While requests are being served the digest is refreshed at most
+    #: this often (``_share_telemetry``).
+    KV_DIGEST_S = 0.25
+
     def __init__(self, context, process=None, server=None,
                  prefill_only: bool = False,
                  kv_fetch_timeout_s: float = 2.0):
@@ -2823,6 +2803,8 @@ class ContinuousReplica(Actor):
         self._kv_started: Dict[str, float] = {}
         self._kv_counter = 0
         self._kv_topic = f"{self.topic_path}/kv"
+        self._kv_digest_ts = float("-inf")
+        self._kv_digest_migrating = False
         if self._kv_capable():
             self.process.add_message_handler(self._on_kv_message,
                                              self._kv_topic)
@@ -3051,9 +3033,23 @@ class ContinuousReplica(Actor):
         from .serving import serving_telemetry
         updates = serving_telemetry(self.server.stats())
         if self._kv_capable():
-            updates["kv_prefixes"] = self.server.prefix_digest(
-                role=self.kv_role,
-                migrating=bool(self._migrating_ids))
+            # The digest walks the whole prefix index (33 ms a call
+            # with 48,000 blocks cached before its walk was pared
+            # down, my chip runs, PR 31) to tell routers what they
+            # cannot act on sixteen times a second, and every change
+            # of it is a publication.  So: at most every KV_DIGEST_S
+            # while busy, at once when the ``migrating`` flag turns,
+            # and always on the pump that leaves the server idle, so
+            # what an idle replica advertises is exact.
+            migrating = bool(self._migrating_ids)
+            now = time.monotonic()
+            if not self.server.busy \
+                    or migrating != self._kv_digest_migrating \
+                    or now - self._kv_digest_ts >= self.KV_DIGEST_S:
+                self._kv_digest_ts = now
+                self._kv_digest_migrating = migrating
+                updates["kv_prefixes"] = self.server.prefix_digest(
+                    role=self.kv_role, migrating=migrating)
         hists = self.server.latency_hists
         if hists["ttft"].count:
             updates["ttft_p50_ms"] = round(hists["ttft"].quantile(0.5), 1)

@@ -56,6 +56,8 @@ Greedy outputs exactly match the contiguous server and per-request
 
 from __future__ import annotations
 
+import heapq
+import itertools
 import math
 import time
 from collections import OrderedDict
@@ -198,7 +200,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
                          self.slots * max_blocks // 2)
         else:
             usable = self._requested_blocks
-        self._refuse_for_recurrent_state(
+        self._refuse_unsupported(
             prefix_cache=self.enable_prefix_cache,
             host_tier=self.host_tier_blocks > 0,
             spill=self.spill_dir is not None)
@@ -271,10 +273,11 @@ class PagedContinuousServer(ContinuousBatchingServer):
         self._children: dict = {}
         self._pending_shared: List[int] = [0] * self.slots
         #: block -> slot whose chunked prefill has not yet written the
-        #: block's content.  The prefix-cache hit walk treats these as
-        #: misses: their keys are registered (so no duplicate block is
-        #: indexed) but the KV only lands slice by slice over the next
-        #: steps.  Cleared at _finish_prefill; purged on cancel.
+        #: block's content.  An admission whose hit walk reaches one
+        #: is deferred: their keys are registered (so no duplicate
+        #: block is indexed) but the KV only lands slice by slice over
+        #: the next steps.  Cleared at _finish_prefill; purged on
+        #: cancel.
         self._producing: dict = {}
         # Distributed KV-cache state (kvstore subsystem):
         #   _hex_key: directory-width hex16 -> full chain key (block
@@ -416,6 +419,12 @@ class PagedContinuousServer(ContinuousBatchingServer):
             kv_hbm_blocks=self.total_blocks - len(self._free),
             kv_hbm_bytes=(self.total_blocks - len(self._free))
             * self._block_nbytes(),
+            # What the pool costs: a position's bytes over every layer
+            # field as the pool lays them out, and the whole pool's
+            # (the scratch block included).
+            kv_bytes_per_position=self._block_nbytes()
+            // self.block_size,
+            kv_pool_bytes=(self.total_blocks + 1) * self._block_nbytes(),
         )
         pages = self._adapter_page_counts()
         out.update(
@@ -587,8 +596,12 @@ class PagedContinuousServer(ContinuousBatchingServer):
         ``chunk_prefill_tokens`` (whole pow2 buckets when 0)."""
         from ..ops.paged_attention import decode_attention_path
         from ..ops.paged_prefill import prefill_attention_path
-        geometry = self._kv_geometry()
         chunk = self.chunk_prefill_tokens or self._bucket_minimum
+        own = getattr(self._model, "attention_paths", None)
+        if own is not None:
+            # A pool the K/V kernels' dispatch does not describe.
+            return own(self.config, self.block_size, chunk)
+        geometry = self._kv_geometry()
         return (decode_attention_path(*geometry),
                 prefill_attention_path(*geometry, self.block_size,
                                        chunk))
@@ -606,6 +619,9 @@ class PagedContinuousServer(ContinuousBatchingServer):
         this server's (per-shard) head geometry."""
         from ..ops.paged_prefill import prefill_key_blocks
         config = self.config
+        own = getattr(self._model, "slice_key_blocks", None)
+        if own is not None:
+            return own(config, start, width, self.block_size)
         return prefill_key_blocks(
             start, width, self.block_size, config.sliding_window,
             heads=config.n_heads // self.tp_degree,
@@ -1180,26 +1196,40 @@ class PagedContinuousServer(ContinuousBatchingServer):
         entry whose indexed children are all already selected —
         selecting a leaf makes its parent selectable, so the order
         is exactly what ``want`` sequential :meth:`_evict_one` calls
-        would produce."""
+        would produce.
+
+        One pass: the LRU order is walked once, as far as the victims
+        reach, and an entry that a selection has just turned into a
+        leaf waits in a heap by its place in that order.  (Restarting
+        the walk for every victim cost a fresh 12k-token document's
+        admission a quarter of a second of host time in a pool of
+        48,000 cached blocks, PR 31: chains are released root first,
+        so every restart skipped a whole chain to reach its leaf.)"""
         victims: List = []
-        taken = set()
         pending: Dict = {}
+        rank: Dict = {}             # walked entries: key -> LRU place
+        walked: List = []           # LRU place -> (key, block)
+        ready: List[int] = []       # places of selectable entries
+        walk = iter(self._evictable.items())
         while len(victims) < want:
-            picked = None
-            for key, block in self._evictable.items():   # LRU order
-                if key in taken:
-                    continue
-                if self._children.get(key, 0) \
-                        - pending.get(key, 0) == 0:
-                    picked = (key, block)
-                    break
-            if picked is None:
-                break
-            victims.append(picked)
-            taken.add(picked[0])
-            parent = self._parent.get(picked[0])
+            while not ready:
+                entry = next(walk, None)
+                if entry is None:
+                    return victims
+                key = entry[0]
+                rank[key] = len(walked)
+                walked.append(entry)
+                if self._children.get(key, 0) == pending.get(key, 0):
+                    ready.append(rank[key])     # the largest so far
+            # Nothing not yet walked can come before a walked entry.
+            key, block = walked[heapq.heappop(ready)]
+            victims.append((key, block))
+            parent = self._parent.get(key)
             if parent is not None:
                 pending[parent] = pending.get(parent, 0) + 1
+                if parent in rank and self._children.get(parent, 0) \
+                        == pending[parent]:
+                    heapq.heappush(ready, rank[parent])
         return victims
 
     def _evict_until(self, needed: int) -> None:
@@ -1266,17 +1296,21 @@ class PagedContinuousServer(ContinuousBatchingServer):
                     # In-flight chunked prefills register their keys
                     # at reservation but write content slice by slice
                     # — sharing before the content lands would read
-                    # zeros.  Treated as a miss; shareable again once
-                    # the producer finishes.  A RESTORING block is
-                    # this chain's own promotion still landing: WAIT
-                    # for it (it lands within queue/rate steps) —
-                    # admitting now would recompute the very blocks in
-                    # flight.
-                    restore_wait = self._producing[block] == RESTORING
+                    # zeros.  WAIT for the producer, as for a
+                    # RESTORING block (this chain's own promotion
+                    # still landing): admitting now would recompute
+                    # the very blocks in flight, and the slice queue
+                    # serves the oldest prefill first, so the
+                    # recomputation could not even start before the
+                    # producer has finished.  (Until PR 31 a live
+                    # prefill's blocks were taken for a miss: 64
+                    # callers asking about one new 12k-token document
+                    # prefilled it once each.)
+                    restore_wait = True
                     break
                 shared.append(block)
             if restore_wait:
-                return False       # defer: restore lands next steps
+                return False       # defer: the blocks land next steps
             if restore_host and self._begin_restore(keys, shared):
                 # Defer WITHOUT pinning anything: the queue head
                 # retries each step and adopts the chain once landed.
@@ -1289,28 +1323,23 @@ class PagedContinuousServer(ContinuousBatchingServer):
             # lengths reuse log-many program shapes instead of being
             # rounded down (the old pow2 truncation threw away up to
             # half the hit).
+        # Can the private blocks be had at all?  The hits that sit in
+        # _evictable will be pinned, not evicted.  If not, defer
+        # WITHOUT destroying cached prefixes for zero benefit, and
+        # without touching the LRU order: a deferred request never
+        # ran.
+        private_needed = needed - len(shared)
+        pinned_evictable = sum(
+            1 for block in shared
+            if self._block_key[block] in self._evictable)
+        if private_needed > len(self._free) + len(self._evictable) \
+                - pinned_evictable:
+            return False
         # PIN the hits before any eviction (eviction must never free a
-        # block we are about to reference), with rollback on deferral.
-        # Snapshot the LRU order first: a deferred request never ran,
-        # so rollback must restore each block's ORIGINAL _evictable
-        # position (re-appending would promote untouched blocks to MRU
-        # and distort eviction order).  Nothing else mutates
-        # _evictable between here and the rollback below.
-        evictable_snapshot = list(self._evictable.items())
+        # block we are about to reference).
         for block in shared:
             self._refs[block] += 1
             self._evictable.pop(self._block_key[block], None)
-        private_needed = needed - len(shared)
-        if private_needed > len(self._free) + len(self._evictable):
-            # Cannot admit even after a full cache flush — defer
-            # WITHOUT destroying cached prefixes for zero benefit.
-            for block in shared:
-                self._refs[block] -= 1
-            self._evictable.clear()
-            self._evictable.update(
-                (key, block) for key, block in evictable_snapshot
-                if self._refs[block] == 0)
-            return False
         self._evict_until(private_needed)
         private = [self._free.pop() for _ in range(private_needed)]
         if private:
@@ -1351,14 +1380,24 @@ class PagedContinuousServer(ContinuousBatchingServer):
         # hold garbage — safe ONLY because _prefill_and_insert runs
         # producers before their dependents (same-wave shared-prefix
         # overlaps keep admission order; disjoint chains carry no
-        # ordering).  Keys already indexed are SKIPPED (defensive: an
-        # overwrite would strand the old block in _evictable under a
-        # reused key — a permanent leak).
+        # ordering).  A key already indexed is never overwritten (that
+        # would strand the old block in _evictable under a reused key —
+        # a permanent leak), and ends the registration.
         if self.enable_prefix_cache:
             for position in range(len(shared), len(keys)):
                 key = keys[position]
                 if key in self._index:
-                    continue
+                    # Another request's block holds this key and this
+                    # one pins nothing of that chain.  Its later keys
+                    # would be indexed as children of a block it does
+                    # not hold — a cached block whose child outlives
+                    # its last reference can never be reached
+                    # leaf-first, and ``_evictable`` would count
+                    # blocks no eviction frees (``pop from empty
+                    # list`` below, first chip run of PR 31, when a
+                    # live prefill's blocks still read as a miss).  So
+                    # the rest of this prompt stays private.
+                    break
                 # Recomputing a chain the host tier still holds (the
                 # restore could not fit): the fresh registration
                 # supersedes the demoted copy — identical bytes, but
@@ -1717,7 +1756,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
         chain — no bucket ever exists, and a prefix-cache hit skips
         its shared blocks entirely (the first slice starts past
         them).  Blocks this slot will produce are marked in-flight so
-        later admissions' hit walks treat them as misses until the
+        later admissions whose hit walk reaches them wait until the
         content lands."""
         n_shared = self._pending_shared[slot]
         self._pending_shared[slot] = 0
@@ -2160,37 +2199,42 @@ class PagedContinuousServer(ContinuousBatchingServer):
         (plus the adopted flag for warm-restart survivors) so the
         router prices each rung: HBM hit > host restore > disk
         restore > recompute."""
+        hits, depths, seeds = self._key_hits, self._depth, self._key_seed
+
+        def ranked(keys, tier):
+            """``(-hotness, -depth, key, tier)`` of the keys that may
+            be advertised: what the digest is ordered by and nothing
+            else, because this runs for EVERY cached key on the loop
+            that drives the device (33 ms a digest at 48,000 cached
+            blocks when each key also got its hex string, its refs and
+            a tuple of eight, my chip run, PR 31).  Positive seeds
+            (per-request adapter KV) never leave the replica.
+            ADAPTER_SEED pages advertise their chain ROOT only,
+            flagged in the 8th wire field — holding page 1 implies the
+            whole chain (lora_paged header walk), and one digest slot
+            per warm adapter keeps the EC share small."""
+            for key in keys:
+                seed = seeds.get(key, 0)
+                if seed > 0 or (seed == _kvadp.ADAPTER_SEED
+                                and depths.get(key, 0) != 1):
+                    continue
+                yield -hits.get(key, 0), -depths.get(key, 0), key, tier
+
+        cached = self._index
+        if self._producing:
+            cached = (key for key, block in self._index.items()
+                      if block not in self._producing)
         entries = []
-
-        def _entry(key, refs, tier, adopted=0):
-            # Positive seeds (per-request adapter KV) never leave the
-            # replica.  ADAPTER_SEED pages advertise their chain ROOT
-            # only, flagged in the 8th wire field — holding page 1
-            # implies the whole chain (lora_paged header walk), and
-            # one digest slot per warm adapter keeps the EC share
-            # small.
-            seed = self._key_seed.get(key, 0)
-            if seed > 0:
-                return
-            adapter = seed == _kvadp.ADAPTER_SEED
-            depth = self._depth.get(key, 0)
-            if adapter and depth != 1:
-                return
-            entries.append((key.hex()[:_kvdir.HEX_KEY_CHARS],
-                            depth, refs, self._key_hits.get(key, 0),
-                            tier, adopted, 0, int(adapter)))
-
-        for key, block in self._index.items():
-            if block in self._producing:
-                continue
-            _entry(key, self._refs.get(block, 0), 0)
-        for key in self._host:
-            _entry(key, 0, 1)
-        for key in self._spill:
-            _entry(key, 0, 2, 1 if key in self._adopted_keys else 0)
-        entries.sort(key=lambda e: (-e[3], -e[1], e[0]))
-        return _kvdir.digest_encode(self.block_size, role,
-                                    entries[:max_entries],
+        for hot, depth, key, tier in heapq.nsmallest(
+                max_entries, itertools.chain(ranked(cached, 0),
+                                             ranked(self._host, 1),
+                                             ranked(self._spill, 2))):
+            refs = self._refs.get(self._index[key], 0) if tier == 0 else 0
+            entries.append((
+                key.hex()[:_kvdir.HEX_KEY_CHARS], -depth, refs, -hot,
+                tier, int(tier == 2 and key in self._adopted_keys), 0,
+                int(seeds.get(key, 0) == _kvadp.ADAPTER_SEED)))
+        return _kvdir.digest_encode(self.block_size, role, entries,
                                     migrating=int(migrating))
 
     def publish_live_chain(self, request) -> int:
@@ -2205,7 +2249,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
         slot's ref like any admission-registered key, so
         ``_release_slot`` at the request's (post-cutover) retirement
         leaves them cached-evictable — no new lifecycle."""
-        self._refuse_for_recurrent_state(migration=True)
+        self._refuse_unsupported(migration=True)
         if not self.enable_prefix_cache:
             return 0
         adapter_id = self._adapter_id(request)
@@ -2284,7 +2328,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
         pool rows host-side.  Returns the wire dict or ``None`` (the
         segment is gone — caller answers with an error and the
         importer recomputes)."""
-        self._refuse_for_recurrent_state(kv_transfer=True)
+        self._refuse_unsupported(kv_transfer=True)
         started = time.perf_counter()
         payload = _kvxfer.export_payload(self, keys_hex, start_depth)
         if payload is None:
@@ -2304,7 +2348,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
         path) registers the keys behind the ``RESTORING`` sentinel
         and lands the rows a few blocks per step — see
         :func:`~..kvstore.transfer.import_payload`."""
-        self._refuse_for_recurrent_state(kv_transfer=True)
+        self._refuse_unsupported(kv_transfer=True)
         started = time.perf_counter()
         imported = _kvxfer.import_payload(self, payload,
                                           engine=engine,

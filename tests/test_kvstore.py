@@ -617,6 +617,37 @@ def test_wire_warm_start_via_kv_source(engine):
     assert int(replica_b.share["prefix_remote_hits"]) == 1
 
 
+def test_digest_refreshes_at_a_bounded_rate_while_busy(engine):
+    """The digest walks the whole index, so a busy replica rebuilds it
+    at most every ``KV_DIGEST_S``; the pump that leaves the server idle
+    always does, and so does a turn of the ``migrating`` flag."""
+    process, server, replica = _paged_replica(engine, 2, "rate", "rr")
+    calls = []
+    digest = server.prefix_digest
+    server.prefix_digest = lambda **kw: calls.append(kw) or digest(**kw)
+    responses = []
+    process.add_message_handler(
+        lambda _t, payload: responses.append(parse(payload)[0]),
+        "test/rate/resp")
+    process.message.publish(
+        replica.topic_in,
+        generate("infer", ["r1", "test/rate/resp",
+                           encode_swag({"tokens": np.arange(1, 50,
+                                                            dtype=np.int32),
+                                        "max_new_tokens": 24})]))
+    replica.KV_DIGEST_S = 3600.0
+    _drive(engine, lambda: "infer_response" in responses)
+    assert server.counters["dispatches"] >= 6      # several busy pumps
+    # One at the first busy pump, one at the pump that ended idle.
+    assert len(calls) == 2
+    assert digest_decode(replica.share["kv_prefixes"])[2]  # the chain
+    replica._migrating_ids.add("r2")
+    server._queue.append(None)                     # busy, nothing due
+    replica._share_telemetry()
+    server._queue.clear()
+    assert len(calls) == 3 and calls[-1]["migrating"] is True
+
+
 def test_wire_kv_fetch_timeout_falls_back_to_local(engine):
     """A kv_source pointing at a dead owner must NOT lose the request:
     the fetch times out and the replica prefills locally."""

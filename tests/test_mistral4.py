@@ -1,0 +1,477 @@
+"""The latent-attention model module (MLA + routed SwiGLU experts
+beside a shared one) through the paged engine at a tiny size, float32:
+absorbed against expanded attention, prefill in slices, a prefix hit on
+shared latent blocks, decode through the cache, reused slots and
+evicted documents; the kernels in interpret mode against their jnp
+forms; what the engine refuses for a latent pool.
+
+The tiny config's original context is 64 positions, so every prompt
+here reaches past it: both YaRN's blended frequencies and the query
+scale ``1 + beta ln(1 + floor(p / 64))`` act."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from aiko_services_tpu import models
+from aiko_services_tpu.models import mistral4, moe
+from aiko_services_tpu.ops import latent_attention as la
+from aiko_services_tpu.orchestration.continuous import (
+    ContinuousBatchingServer, DecodeRequest)
+from aiko_services_tpu.orchestration.paged import PagedContinuousServer
+
+F32 = dataclasses.replace(mistral4.CONFIGS["mistral4_tiny"],
+                          dtype=jnp.float32)
+mistral4.CONFIGS["mistral4_tiny_f32"] = F32
+
+
+def make_server(**kwargs):
+    options = dict(config_name="mistral4_tiny_f32", slots=4, max_seq=256,
+                   chunk_steps=4, block_size=16, total_blocks=64,
+                   chunk_prefill_tokens=32, seed=3,
+                   enable_prefix_cache=True)
+    options.update(kwargs)
+    return PagedContinuousServer(**options)
+
+
+def forward_logits(params, tokens):
+    return np.asarray(mistral4.forward(
+        params, jnp.asarray([tokens], jnp.int32), F32))[0]
+
+
+def greedy(server, prompt, served):
+    """What the full-sequence forward (expanded attention, no cache)
+    picks behind ``prompt`` followed by each prefix of ``served``: the
+    served tokens themselves, by induction, when every entry agrees."""
+    logits = forward_logits(server.params, list(prompt) + list(served))
+    return logits[len(prompt) - 1:-1].argmax(-1).tolist()
+
+
+def request(name, prompt, count):
+    return DecodeRequest(request_id=name,
+                         prompt=np.asarray(prompt, np.int32),
+                         max_new_tokens=count)
+
+
+@pytest.fixture(params=["reference", "interpret"])
+def kernels(request, monkeypatch):
+    """Both attention paths: the jnp forms, and the Pallas kernels
+    interpreted."""
+    monkeypatch.setenv("AIKO_DECODE_ATTENTION", request.param)
+    monkeypatch.setenv("AIKO_PREFILL_ATTENTION", request.param)
+    jax.clear_caches()
+    yield request.param
+    jax.clear_caches()
+
+
+def test_the_engine_binds_the_module_and_sizes_a_latent_pool():
+    module, config = models.serving_model("mistral4_tiny")
+    assert module is mistral4 and config.n_experts == 16
+    server = make_server()
+    stats = server.stats()
+    assert stats["layer_kinds"] == "latent_attention=2,experts=2"
+    assert stats["state_bytes_per_slot"] == 0
+    # A row needs 32 + 16 values; the pool pads it to one lane row:
+    # 2 layers x 128 values x 4 bytes a position, 16 positions a block.
+    assert mistral4.cache_row_values(F32) == 48
+    assert stats["kv_bytes_per_position"] == 2 * 128 * 4
+    assert server._block_nbytes() == 16 * 2 * 128 * 4
+    assert stats["kv_pool_bytes"] == 65 * server._block_nbytes()
+    assert server.pool_census()["block_bytes"] == server._block_nbytes()
+    assert [sorted(layer) for layer in server.pool] == [["c"], ["c"]]
+    assert server.pool[0]["c"].shape == (65, 16, 128)
+
+
+def test_the_scalings_act_past_the_original_context():
+    assert F32.rope_original_max == 64 and F32.rope_factor == 8.0
+    plain = dataclasses.replace(F32, rope_factor=1.0)
+    assert F32.sm_scale == pytest.approx(
+        32 ** -0.5 * (0.1 * np.log(8.0) + 1.0) ** 2)
+    assert plain.sm_scale == pytest.approx(32 ** -0.5)
+    blended, unscaled = mistral4._inv_freq(F32), mistral4._inv_freq(plain)
+    # The fastest pair keeps its frequency, the slowest is stretched.
+    assert float(blended[0]) == pytest.approx(float(unscaled[0]))
+    assert float(blended[-1]) == pytest.approx(float(unscaled[-1]) / 8.0)
+    scale = np.asarray(mistral4._query_scale(
+        jnp.asarray([0, 63, 64, 200]), F32))
+    np.testing.assert_allclose(
+        scale, [1.0, 1.0, 1 + 0.1 * np.log(2.0), 1 + 0.1 * np.log(4.0)],
+        rtol=1e-6)
+    # And the forward's logits depend on both.
+    params = mistral4.init_params(F32, jax.random.PRNGKey(0))
+    tokens = np.random.default_rng(0).integers(1, 1024, 100)
+    base = forward_logits(params, tokens)
+    for changed in (plain, dataclasses.replace(F32,
+                                               llama4_scaling_beta=0.0)):
+        other = np.asarray(mistral4.forward(
+            params, jnp.asarray(tokens[None]), changed))[0]
+        assert np.abs(other[70:] - base[70:]).max() > 1e-3
+
+
+def test_absorbed_attention_is_expanded_attention(kernels):
+    """One layer over 150 positions: the expanded form of the forward,
+    against the absorbed form over a latent pool (a slice of 64 at
+    position 0, one of 64 behind it, then decode steps)."""
+    params = mistral4.init_params(F32, jax.random.PRNGKey(4))
+    layer = params["layers"][0]
+    normed = jax.random.normal(jax.random.PRNGKey(5), (1, 150, 128))
+    wanted = np.asarray(mistral4._attention_expanded(layer, F32, normed))[0]
+    pool = mistral4.init_paged_cache(F32, 12, 16)[0]
+    table = jnp.asarray([4, 2, 9, 1, 7, 3, 8, 5, 10, 6], jnp.int32)
+    got = []
+    for start in (0, 64):
+        out, pool = mistral4._attention_append(
+            layer, F32, normed[:, start:start + 64], pool, table,
+            jnp.int32(start))
+        got.append(np.asarray(out)[0])
+    for position in range(128, 150):
+        out, pool = mistral4._attention_decode(
+            layer, F32, normed[:, position:position + 1], pool,
+            table[None], jnp.asarray([position], jnp.int32))
+        got.append(np.asarray(out)[0])
+    np.testing.assert_allclose(np.concatenate(got), wanted, atol=1e-5,
+                               rtol=0)
+    # The cache holds c_kv and k_r and zeros: nothing per head.
+    rows = np.asarray(pool["c"])[np.asarray(table)].reshape(-1, 128)
+    assert np.abs(rows[:150, :48]).min() > 0
+    assert not rows[:, 48:].any()
+
+
+def test_slices_a_prefix_hit_and_decode_give_the_forwards_logits(kernels):
+    """A 200-token document prefilled in slices of 32 and a question
+    behind it; the same document asked again hits its 12 latent
+    blocks and prefills the new question alone; the logits of both
+    prompts' slices and of the decode steps behind them are the
+    full-sequence forward's."""
+    params = mistral4.init_params(F32, jax.random.PRNGKey(1))
+    rng = np.random.default_rng(2)
+    document = rng.integers(1, 1024, 192).astype(np.int32)
+    asks = [np.concatenate([document, rng.integers(1, 1024, n)]).astype(
+        np.int32) for n in (40, 23)]
+    pool = mistral4.init_paged_cache(F32, 40, 16)
+    shared = list(range(1, 13))                 # the document's blocks
+    tables = [shared + [20, 21, 22, 23], shared + [30, 31, 32, 33]]
+    for prompt, table, first in zip(asks, tables, (0, 192)):
+        wanted = forward_logits(params, prompt)
+        padded = np.zeros((1, 256), np.int32)
+        padded[0, :len(prompt)] = prompt
+        table = jnp.asarray([table], jnp.int32)
+        for start in range(first, 256, 32):
+            logits, pool = mistral4.prefill_append_paged(
+                params, jnp.asarray(padded[:, start:start + 32]), pool,
+                table, jnp.int32(start), F32)
+            stop = min(start + 32, len(prompt) - 1)
+            if stop > start:
+                np.testing.assert_allclose(
+                    np.asarray(logits)[0, :stop - start],
+                    wanted[start:stop], atol=1e-5, rtol=0)
+        # The prompt's last token is the first decode step's.
+        last = len(prompt) - 1
+        state = dict(token=jnp.asarray([[0], [prompt[last]]], jnp.int32),
+                     positions=jnp.asarray([0, last], jnp.int32),
+                     active=jnp.asarray([False, True]),
+                     remaining=jnp.asarray([0, 3], jnp.int32),
+                     temps=jnp.zeros((2,)), tops=jnp.ones((2,)),
+                     adapter_ids=jnp.zeros((2,), jnp.int32),
+                     tables=jnp.concatenate(
+                         [jnp.zeros((1, 16), jnp.int32), table]))
+        tokens, counts, _, pool, chunk_counters = \
+            mistral4.serve_chunk_paged(params, state, pool, 3, F32)
+        served = np.asarray(tokens)[1].tolist()
+        sequence = list(prompt)
+        for token in served:
+            logits = forward_logits(params, sequence)[-1]
+            assert logits.max() - logits[token] <= 1e-5
+            sequence.append(token)
+        assert np.asarray(counts).tolist() == [0, 3]
+        assert int(chunk_counters["moe_pairs"]) == 3 * 2 * 4
+        assert int(chunk_counters["moe_pairs_here"]) == 3 * 2 * 4
+
+
+@pytest.mark.parametrize("lengths", [(100, 70, 133, 65), (81, 97, 64, 190)])
+def test_slices_hits_and_reused_slots_serve_the_forward(lengths, kernels):
+    """Requests on both sides of the slice width, two of them asking
+    about a document a third has cached, on slots that are reused:
+    every request's tokens are the forward's own."""
+    server = make_server()
+    rng = np.random.default_rng(sum(lengths))
+    document = rng.integers(1, 1024, 96)
+    prompts = [rng.integers(1, 1024, n) for n in lengths] + [
+        np.concatenate([document, rng.integers(1, 1024, n)])
+        for n in (20, 37, 9)]
+    requests = [request(f"r{i}", prompt, 8 if i % 2 else 12)
+                for i, prompt in enumerate(prompts)]
+    for item in requests[:5]:
+        server.submit(item)
+    server.run_until_drained()
+    for item in requests[5:]:
+        server.submit(item)
+    server.run_until_drained()
+    for item in requests:
+        assert item.error is None
+        assert len(item.tokens) == item.max_new_tokens
+        assert item.tokens == greedy(server, item.prompt,
+                                     item.tokens), item.request_id
+    # The document's six blocks were reused by the two later askings.
+    assert server.prefix_blocks_reused >= 2 * 6
+    counters = server.counters
+    committed = sum(item.max_new_tokens for item in requests)
+    assert counters["moe_pairs"] == committed * 2 * 4
+    assert counters["moe_pairs_here"] == counters["moe_pairs"]
+    assert counters["prefill_key_blocks"] > 0
+    assert counters["decode_blocks_read"] > 0
+    assert (server.decode_attention_path, server.prefill_attention_path) \
+        == (("kernel", "kernel") if kernels == "interpret"
+            else ("reference", "reference"))
+
+
+def test_an_evicted_document_is_prefilled_again_and_serves_the_same():
+    """A pool too small for two documents (a prompt of 150 is admitted
+    in a bucket of 16 blocks and leaves 9 cached): the second evicts
+    blocks of the first, the first is asked again, and its tokens are
+    those of its first asking and of the forward."""
+    server = make_server(slots=1, total_blocks=20)
+    rng = np.random.default_rng(11)
+    first, second = (rng.integers(1, 1024, 150) for _ in range(2))
+    served = []
+    for name, prompt in (("a", first), ("b", second), ("c", first)):
+        server.submit(request(name, prompt, 8))
+        done = server.run_until_drained()[0]
+        assert done.error is None
+        served.append(done.tokens)
+    assert server.prefix_evictions > 0
+    assert served[0] == served[2] == greedy(server, first, served[0])
+    assert served[1] == greedy(server, second, served[1])
+    assert server.free_blocks + len(server._evictable) == 20
+
+
+def test_an_asking_waits_for_its_document_in_flight_and_shares_it():
+    """Two askings of one document admitted together: the second finds
+    the first's blocks still being produced.  It waits at the head of
+    the queue until they land (the slice queue serves the oldest
+    prefill first, so a second prefill of the document could not start
+    sooner anyway) and then shares them; before PR 31 it took them for
+    a miss, prefilled the document again, and indexed its question's
+    blocks under a chain it did not hold, which no leaf-first eviction
+    could then reach (``pop from empty list`` in ``_reserve_slot``,
+    first chip run of PR 31).  Afterwards every cached block can be
+    evicted: a prompt that needs the pool gets it."""
+    server = make_server(slots=2, total_blocks=54, max_seq=512)
+    rng = np.random.default_rng(13)
+    document = rng.integers(1, 1024, 96)
+    short, long = (request(name, np.concatenate(
+        [document, rng.integers(1, 1024, 40)]), count)
+        for name, count in (("a", 4), ("b", 40)))
+    server.submit(short)
+    server.submit(long)
+    server.step()
+    assert server._prefilling and len(server._queue) == 1
+    assert server.counters["admission_deferred"] >= 1
+    finished = []
+    while not finished:
+        finished = server.step()
+    assert [item.request_id for item in finished] == ["a"]
+    assert (short.shared_tokens, long.shared_tokens) == (0, 96)
+    assert server.prefix_blocks_reused == 6
+    third = request("c", rng.integers(1, 1024, 300), 4)
+    server.submit(third)
+    server.run_until_drained()
+    for item in (short, long, third):
+        assert item.error is None
+        assert item.tokens == greedy(server, item.prompt, item.tokens)
+    assert server.free_blocks + len(server._evictable) == 54
+    while server._evict_one():
+        pass
+    assert server.free_blocks == 54 and not server._index
+
+
+def test_a_reused_slot_gives_what_a_fresh_server_gives():
+    rng = np.random.default_rng(5)
+    first, second = rng.integers(1, 1024, 90), rng.integers(1, 1024, 71)
+    used = make_server(slots=1, enable_prefix_cache=False)
+    used.submit(request("a", first, 12))
+    used.run_until_drained()
+    used.submit(request("b", second, 12))
+    reused = used.run_until_drained()[0].tokens
+    fresh = make_server(slots=1, enable_prefix_cache=False)
+    fresh.submit(request("b", second, 12))
+    assert reused == fresh.run_until_drained()[0].tokens
+
+
+# --- the kernels against their jnp forms -------------------------------- #
+
+
+def _pool(rng, blocks=40, width=128):
+    return jnp.asarray(rng.normal(size=(blocks, 16, width)), jnp.float32)
+
+
+def test_the_decode_kernel_interpreted_is_its_jnp_form():
+    rng = np.random.default_rng(0)
+    pool = _pool(rng)
+    tables = jnp.asarray(rng.permutation(np.arange(1, 40))[:36].reshape(
+        3, 12), jnp.int32)
+    positions = jnp.asarray([5, 130, 191], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(3, 4, 128)), jnp.float32)
+    wanted = la.latent_decode_reference(q, pool, tables, positions,
+                                        rank=32, sm_scale=0.3)
+    got = la.latent_decode_attention(q, pool, tables, positions, rank=32,
+                                     sm_scale=0.3, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(wanted),
+                               atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("tokens, start", [(16, 0), (32, 16), (64, 128),
+                                           (128, 32), (256, 144)])
+def test_the_prefill_kernel_interpreted_is_its_jnp_form(tokens, start):
+    rng = np.random.default_rng(tokens + start)
+    pool = _pool(rng)
+    table = jnp.asarray(rng.permutation(np.arange(1, 40))[:30], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(tokens, 4, 128)), jnp.float32)
+    own = jnp.asarray(rng.normal(size=(tokens, 128)), jnp.float32)
+    wanted = la.latent_prefill_reference(q, own, pool, table,
+                                         jnp.int32(start), rank=32,
+                                         sm_scale=0.3)
+    got = la.latent_prefill_attention(q, own, pool, table,
+                                      jnp.int32(start), rank=32,
+                                      sm_scale=0.3, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(wanted),
+                               atol=1e-5, rtol=0)
+
+
+def test_the_append_kernel_writes_one_row_or_whole_blocks_in_place():
+    rng = np.random.default_rng(3)
+    pool = _pool(rng)
+    rows = jnp.asarray(rng.normal(size=(3, 128)), jnp.float32)
+    blocks = jnp.asarray([3, 0, 7], jnp.int32)     # the idle row: block 0
+    offsets = jnp.asarray([2, 5, 15], jnp.int32)
+    got = np.asarray(jax.jit(
+        lambda p: la.latent_append(p, rows, blocks, offsets,
+                                   interpret=True),
+        donate_argnums=0)(pool + 0))
+    wanted = np.asarray(pool).copy()
+    wanted[3, 2], wanted[7, 15] = np.asarray(rows[0]), np.asarray(rows[2])
+    np.testing.assert_array_equal(got, wanted)
+    whole = jnp.asarray(rng.normal(size=(2, 16, 128)), jnp.float32)
+    got = np.asarray(jax.jit(
+        lambda p: la.latent_append(p, whole, jnp.asarray([4, 9]),
+                                   interpret=True),
+        donate_argnums=0)(pool + 0))
+    wanted = np.asarray(pool).copy()
+    wanted[4], wanted[9] = np.asarray(whole)
+    np.testing.assert_array_equal(got, wanted)
+
+
+def test_a_slice_sweeps_its_cached_blocks_once_a_query_tile():
+    # 256 tokens at position 1024: four tiles of 64, each over the 64
+    # cached blocks and its own rows up to its last query.
+    assert la.latent_slice_key_blocks(1024, 256, 16) == \
+        4 * 64 + 4 + 8 + 12 + 16
+    assert la.latent_slice_key_blocks(0, 32, 16) == 2
+    assert mistral4.slice_key_blocks(F32, 1024, 256, 16) == 296
+
+
+# --- what a latent pool is refused -------------------------------------- #
+
+REFUSED_AT_CONSTRUCTION = {
+    "host tier": (dict(host_tier_blocks=8), "host_tier .*latent blocks"),
+    "spill": (dict(spill_dir="/tmp/never-created-by-this-test"),
+              "spill .*pool signature"),
+    "draft model": (dict(draft_config_name="tiny"),
+                    "speculation .*verify program"),
+    "n-gram self-draft": (dict(draft_mode="ngram"), "speculation"),
+    "lora": (dict(adapters={"a": {}}, lora_config=object()),
+             "adapters .*LoRA"),
+    "replica mesh": (dict(replica_mesh=object()),
+                     "replica_mesh .*head axis"),
+    "int8 cache": (dict(quantize_kv=True), "no int8 layout"),
+}
+
+
+@pytest.mark.parametrize("what", sorted(REFUSED_AT_CONSTRUCTION))
+def test_the_engine_refuses_what_cannot_carry_a_latent_block(what):
+    options, message = REFUSED_AT_CONSTRUCTION[what]
+    with pytest.raises(ValueError, match=message):
+        make_server(**options)
+
+
+def test_transfer_migration_mesh_and_the_contiguous_layout_are_refused():
+    server = make_server()
+    with pytest.raises(ValueError, match="kv_transfer .*wire format"):
+        server.kv_export_payload(["00"], 0)
+    with pytest.raises(ValueError, match="kv_transfer"):
+        server.kv_import_payload({})
+    with pytest.raises(ValueError, match="migration .*latent block chain"):
+        server.publish_live_chain("r0")
+    with pytest.raises(ValueError, match="contiguous_layout"):
+        ContinuousBatchingServer(config_name="mistral4_tiny_f32", slots=2)
+    with pytest.raises(ValueError, match="mesh .*sharding rule"):
+        ContinuousBatchingServer(config_name="mistral4_tiny_f32", slots=2,
+                                 mesh=object())
+    with pytest.raises(ValueError, match="latent block pool"):
+        make_server(host_tier_blocks=4)
+
+
+# --- the feed-forward --------------------------------------------------- #
+
+SWIGLU = moe.MoEConfig(d_model=32, d_ff=48, n_experts=16, top_k=4,
+                       capacity_factor=None, dtype=jnp.float32,
+                       activation="swiglu", d_shared=40)
+
+
+def _swiglu_layer(params, x, held=None):
+    """The layer written out, one expert after another."""
+    gates = np.asarray(jax.nn.softmax(x @ params["router"], axis=-1))
+    out = np.zeros_like(x)
+    for t, row in enumerate(gates):
+        ids = np.argsort(-row)[:4]
+        chosen = row[ids] / row[ids].sum()
+        for expert, gate in zip(ids, chosen):
+            if held is not None and not held[0] <= expert < sum(held):
+                continue
+            e = expert - (held[0] if held else 0)
+            hidden = jax.nn.silu(x[t] @ params["w_gate"][e]) \
+                * (x[t] @ params["w_up"][e])
+            out[t] += gate * np.asarray(hidden @ params["w_down"][e])
+    shared = (jax.nn.silu(x @ params["shared_gate"])
+              * (x @ params["shared_up"])) @ params["shared_down"]
+    return out, np.asarray(shared)
+
+
+def test_the_shared_expert_is_a_swiglu_of_three_matrices():
+    params = moe.init_moe_params(SWIGLU, jax.random.PRNGKey(7))
+    assert params["shared_gate"].shape == (32, 40)
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(8), (24, 32)))
+    with jax.default_matmul_precision("highest"):
+        routed, shared = _swiglu_layer(params, x)
+        got, counts = moe.moe_layer(params, jnp.asarray(x)[None], SWIGLU)
+    np.testing.assert_allclose(np.asarray(got)[0], routed + shared,
+                               atol=1e-5, rtol=0)
+    assert np.asarray(counts).tolist()[0::2] == [24 * 4, 24]
+    # relu2 keeps its two matrices.
+    two = moe.init_moe_params(dataclasses.replace(
+        SWIGLU, activation="relu2"), jax.random.PRNGKey(7))
+    assert "shared_gate" not in two
+
+
+def test_the_four_shares_of_a_layer_add_up_to_the_layer():
+    """Held 0-3 ... 12-15 of 16, the shared expert counted once."""
+    whole = moe.init_moe_params(SWIGLU, jax.random.PRNGKey(9))
+    x = jax.random.normal(jax.random.PRNGKey(10), (1, 40, 32))
+    with jax.default_matmul_precision("highest"):
+        wanted, _ = moe.moe_layer(whole, x, SWIGLU)
+        _, shared = _swiglu_layer(whole, np.asarray(x)[0])
+        total, pairs = shared.copy(), 0
+        for first in range(0, 16, 4):
+            config = dataclasses.replace(SWIGLU, held=(first, 4))
+            share = dict(whole, **{name: whole[name][first:first + 4]
+                                   for name in ("w_gate", "w_up",
+                                                "w_down")})
+            out, counts = moe.moe_layer(share, x, config)
+            total += np.asarray(out)[0] - shared
+            pairs += int(counts[0])
+    np.testing.assert_allclose(total, np.asarray(wanted)[0], atol=1e-5,
+                               rtol=0)
+    assert pairs == 40 * 4

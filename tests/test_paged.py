@@ -372,3 +372,47 @@ def test_paged_pool_smaller_than_contiguous():
     contiguous_rows = 4 * 128
     pool_rows = server.pool[0]["k"].shape[0] * server.block_size
     assert pool_rows <= contiguous_rows // 2 + server.block_size
+
+def test_victim_selection_is_sequential_leaf_first_eviction():
+    """``_select_victims`` walks the LRU order once; what it returns is
+    what that many ``_evict_one`` calls would evict, in their order —
+    over chains released root first, chains that share a prefix, and
+    cached blocks whose child is still pinned (never reachable)."""
+    from collections import OrderedDict
+    from aiko_services_tpu.orchestration.paged import (
+        PagedContinuousServer)
+    server = PagedContinuousServer(config_name="tiny", slots=2,
+                                   total_blocks=8,
+                                   enable_prefix_cache=True)
+    rng = np.random.default_rng(41)
+    for trial in range(40):
+        count = int(rng.integers(1, 60))
+        keys = [bytes([trial, i]) for i in range(count)]
+        parent = {}
+        for i in range(1, count):
+            if rng.random() < 0.85:         # chains, some branching
+                parent[keys[i]] = keys[int(rng.integers(max(0, i - 3), i))]
+        children = {}
+        for child, above in parent.items():
+            children[above] = children.get(above, 0) + 1
+        pinned = {key for key in keys if rng.random() < 0.15}
+        order = [keys[i] for i in rng.permutation(count)
+                 if keys[i] not in pinned]
+        server._evictable = OrderedDict(
+            (key, 100 + keys.index(key)) for key in order)
+        server._parent, server._children = dict(parent), dict(children)
+        want = int(rng.integers(1, count + 2))
+        got = server._select_victims(want)
+        # The same by the definition: restart the walk for every pick.
+        wanted, taken, pending = [], set(), {}
+        while len(wanted) < want:
+            pick = next((key for key in order if key not in taken
+                         and children.get(key, 0) == pending.get(key, 0)),
+                        None)
+            if pick is None:
+                break
+            wanted.append((pick, 100 + keys.index(pick)))
+            taken.add(pick)
+            if pick in parent:
+                pending[parent[pick]] = pending.get(parent[pick], 0) + 1
+        assert got == wanted

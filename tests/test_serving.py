@@ -28,6 +28,31 @@ def collect_responses(process, topic, into):
     process.add_message_handler(handler, topic)
 
 
+def test_short_integer_vectors_cross_the_wire_as_text():
+    """A streamed partial's token ids take the ``v`` tag: same dtype,
+    same values, one axis, through the S-expression and back; what is
+    not a short integer vector keeps ``np.save``."""
+    from aiko_services_tpu.pipeline import codec
+    for array in (np.asarray([5, 131071, 0], np.int32),
+                  np.asarray([], np.int32),
+                  np.asarray([-7, 2**40], np.int64),
+                  np.asarray([2**63 + 1], np.uint64),
+                  np.arange(codec.VECTOR_VALUES, dtype=np.int32)):
+        encoded = encode_swag({"tokens_out": array})
+        assert encoded["tokens_out"].startswith(f"v:{array.dtype.name}:")
+        _, params = parse(generate("infer_partial", ["r", encoded]))
+        back = decode_swag(params[1])["tokens_out"]
+        assert back.dtype == array.dtype and back.shape == array.shape
+        assert back.tolist() == array.tolist()
+    for array in (np.arange(codec.VECTOR_VALUES + 1, dtype=np.int32),
+                  np.asarray([1.5], np.float32),
+                  np.zeros((2, 2), np.int32)):
+        text = codec.encode_value(array)
+        assert text.startswith("n:")
+        back = codec.decode_value(text)
+        assert back.dtype == array.dtype and (back == array).all()
+
+
 def test_round_robin_and_failover(engine):
     p0 = make_process(engine, 1)
     Registrar(process=p0)

@@ -124,7 +124,8 @@ def _decode_attn_pattern():
 
 def _custom_call_lines(text, name):
     return [line.strip() for line in text.splitlines()
-            if re.match(r"\s*%%%s[.\d]* = .* custom-call\(" % name, line)]
+            if re.match(r"\s*(ROOT )?%%%s[.\d]* = .* custom-call\(" % name,
+                        line)]
 
 
 #: name → (block, pool blocks): an int8 pool layer of 8 kv heads, 32 rows.
@@ -331,3 +332,115 @@ def test_serving_programs_hold_one_scan_and_no_pool_copy(
             and not name.startswith(("paged_decode_append",
                                      "custom-call"))]
         assert not strangers
+
+
+# --------------------------------------------------------------------------- #
+# The latent-attention kernels and programs at mistralsmall4.docs' sizes
+
+#: The cell: 64 slots, tables of 16,896 / 16 entries, a pool of 49,152
+#: blocks (+ scratch) of 16 rows of 384 values, 32 heads, rank 256.
+LATENT_CELL = dict(slots=64, table=1056, n_blocks=49153, bs=16, width=384,
+                   heads=32, rank=256)
+
+
+def test_latent_kernels_compile_for_v5e(one_chip, as_on_tpu):
+    """The decode kernel with the cell's 64 x 1,056 block tables as
+    prefetched scalars (270 KB: twelve times the largest a paged
+    kernel here had taken), the prefill kernel at every slice width
+    under its raised VMEM limit, and the append in both its forms, in
+    place: through the TPU compiler, no temporaries."""
+    from aiko_services_tpu.ops import latent_attention as la
+    g = LATENT_CELL
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    pool = S((g["n_blocks"], g["bs"], g["width"]), jnp.bfloat16)
+    attend = dict(rank=g["rank"], sm_scale=0.1)
+    decode = jax.jit(functools.partial(la.latent_decode_attention,
+                                       **attend)).lower(
+        S((g["slots"], g["heads"], g["width"]), jnp.bfloat16), pool,
+        S((g["slots"], g["table"]), jnp.int32),
+        S((g["slots"],), jnp.int32)).compile()
+    calls = _custom_call_lines(decode.as_text(), "closed_call")
+    assert len(calls) == 1
+    # What decode_attn_roofline's pattern asks of the trace's text: a
+    # 3-D bf16 result and the block tables first.
+    assert re.search(r"%closed_call[.\d]* = bf16\[64,32,256\]", calls[0])
+    assert "operand_layout_constraints={s32[64,1056]" in calls[0]
+    for tokens in (256, 128, 64, 32, 16):
+        prefill = jax.jit(functools.partial(la.latent_prefill_attention,
+                                            **attend)).lower(
+            S((tokens, g["heads"], g["width"]), jnp.bfloat16),
+            S((tokens, g["width"]), jnp.bfloat16), pool,
+            S((g["table"],), jnp.int32), S((), jnp.int32)).compile()
+        assert len(_custom_call_lines(prefill.as_text(),
+                                      "latent_prefill_call")) == 1
+
+    def steps(pool, rows, blocks, offsets, whole, whole_ids):
+        def body(pool, _):
+            return la.latent_append(pool, rows, blocks, offsets), None
+        pool = la.latent_append(pool, whole, whole_ids)
+        return jax.lax.scan(body, pool, None, length=2)[0]
+
+    compiled = jax.jit(steps, donate_argnums=(0,)).lower(
+        pool, S((g["slots"], g["width"]), jnp.bfloat16),
+        S((g["slots"],), jnp.int32), S((g["slots"],), jnp.int32),
+        S((16, g["bs"], g["width"]), jnp.bfloat16),
+        S((16,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(_custom_call_lines(text, "latent_append")) == 2
+    assert not _pool_shaped_ops(text, g["n_blocks"], 3)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_latent_decode_reads_rows_and_expands_nothing(one_chip, as_on_tpu):
+    """The decode and the mixed program of the latent module at the
+    published widths (two layers, four held experts): one scan each;
+    in its body a ``latent_append`` and a ``closed_call`` a layer and
+    no copy of the pool; and nowhere an array with the context on an
+    axis — no expanded key or value of a slot's table (16,896
+    positions) exists, per head or otherwise."""
+    from aiko_services_tpu.models import mistral4
+    g = LATENT_CELL
+    config = mistral4.Mistral4Config(
+        vocab_size=4096, d_model=4096, n_layers=2, n_heads=32,
+        q_lora_rank=1024, kv_lora_rank=256, qk_nope_head_dim=64,
+        qk_rope_head_dim=64, v_head_dim=128, n_experts=128, moe_top_k=4,
+        d_ff=2048, d_shared=2048, experts_held=(0, 4), rope_factor=128.0,
+        rope_original_max=8192, max_seq_len=1048576)
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = _shaped(jax.eval_shape(lambda: mistral4.quantize_params(
+        mistral4.init_params(config, jax.random.PRNGKey(0)))), one_chip)
+    pool = _shaped(jax.eval_shape(lambda: mistral4.init_paged_cache(
+        config, 4097, g["bs"])), one_chip)
+    slots, table = g["slots"], g["table"]
+    state = {"token": S((slots, 1), jnp.int32),
+             "positions": S((slots,), jnp.int32),
+             "active": S((slots,), jnp.bool_),
+             "remaining": S((slots,), jnp.int32),
+             "temps": S((slots,), jnp.float32),
+             "tops": S((slots,), jnp.float32),
+             "adapter_ids": S((slots,), jnp.int32),
+             "tables": S((slots, table), jnp.int32)}
+    scalar = S((), jnp.int32)
+    decode = mistral4.serve_chunk_paged.lower(
+        params, state, pool, 2, config).compile()
+    mixed = mistral4._mixed_program.lower(
+        params, state, pool, S((1, 256), jnp.int32), scalar, scalar, 2,
+        config, -1, False, None).compile()
+    for compiled in (decode, mixed):
+        text = compiled.as_text()
+        assert text.count(" while(") == 1
+        body = "\n".join(_scan_body(text))
+        assert len(_custom_call_lines(body, "closed_call")) == 2
+        assert len(_custom_call_lines(body, "latent_append")) == 2
+        assert not _pool_shaped_ops(text, 4097, 3)
+        context = table * g["bs"]
+        assert not re.search(r"\[(\d+,)*(%d|%d,%d)(,\d+)*\]"
+                             % (context, table, g["bs"]), text)
+        # What a step holds beside weights and pool: megabytes.
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    # A slice's logits are not computed (the prompt's last token is
+    # the first decode step's), so the LAST layer's attention feeds
+    # nothing and XLA drops it: its rows are appended, and the prefill
+    # kernel runs in the layers before it.
+    assert len(_custom_call_lines(mixed.as_text(),
+                                  "latent_prefill_call")) == 1
