@@ -22,12 +22,18 @@ per-head keys or values.
 
 * :func:`latent_decode_attention`: one query token a batch row against
   the row's live blocks (grid ``(batch,)``, a loop over the live table
-  entries ``keys / block_size`` blocks an iteration, double-buffered
-  DMAs: the structure of :func:`.paged_attention.closed_call`).
+  entries, double-buffered DMAs, one online-softmax update an
+  iteration).
 * :func:`latent_prefill_attention`: a slice of ``T`` query tokens of ONE
   row against the ``start`` positions its table already holds (shared
   prefix blocks included) and against the slice's own rows, causally
   (grid ``(T / q_tile,)``; the own rows ride VMEM).
+
+  Both are ONE kernel body on two grids, and the keys an iteration
+  covers are a function of a program's query rows
+  (:func:`keys_per_iteration`): a decode program's 32 rows leave an
+  iteration's time to its serial chain, so it covers 1,024 keys; a
+  prefill tile's 2,048 rows fill the MXU at 128.
 * :func:`latent_append`: rows into the pool in place.  A decode step's
   one row a slot is read-modify-write of its 16-key block (a bf16 row
   is half of each 32-bit word of its sublane pair, so a lone row is not
@@ -47,18 +53,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import NEG_INF
-from .paged_attention import (_contract_pool_rows, decode_kernel_mode,
-                              runs_kernel)
+from .paged_attention import (MXU_PRECISION, _bf16_terms,
+                              decode_kernel_mode, runs_kernel)
 from .paged_prefill import prefill_kernel_mode
 
 __all__ = ["latent_decode_attention", "latent_decode_reference",
            "latent_prefill_attention", "latent_prefill_reference",
            "latent_append", "latent_append_reference",
            "latent_attention_paths", "latent_slice_key_blocks",
-           "closed_call", "KEYS_PER_ITERATION", "PREFILL_Q_TILE"]
+           "closed_call", "keys_per_iteration", "KEYS_PER_GROUP",
+           "PREFILL_Q_TILE"]
 
-#: Keys one loop iteration of either kernel holds in VMEM.
-KEYS_PER_ITERATION = 128
+#: Keys of one group of block copies: the least an iteration covers,
+#: the step its width grows in, and (less one block) the most it copies
+#: past a row's last live block.
+KEYS_PER_GROUP = 128
+
+#: The most keys one iteration covers (:func:`keys_per_iteration`).
+MAX_KEYS_PER_ITERATION = 1024
+
+#: What an iteration's f32 score tile ``(query rows, keys)`` may hold.
+SCORE_TILE_BYTES = 128 * 2**10
 
 #: Query tokens of one prefill program.  Its state is per (token, head)
 #: row: 64 tokens of 32 heads are 2,048 rows, whose f32 accumulator
@@ -77,7 +92,7 @@ def latent_attention_paths() -> Tuple[str, str]:
     mode variables of :mod:`.paged_attention` / :mod:`.paged_prefill`
     run the Pallas kernels (the TPU, or interpreting), else
     ``"reference"``.  The kernels serve any latent pool whose block
-    size divides :data:`KEYS_PER_ITERATION`."""
+    size divides :data:`KEYS_PER_GROUP`."""
     return tuple("kernel" if mode()[0] else "reference"
                  for mode in (decode_kernel_mode, prefill_kernel_mode))
 
@@ -156,14 +171,59 @@ def latent_append_reference(pool, rows, block_ids, offsets=None):
 # The attention kernel: one body, two grids
 
 
+def _mxu_terms(x, row_dtype):
+    """``x`` as the MXU takes it against pool rows of ``row_dtype`` at
+    f32 contract precision: against bf16 rows an f32 ``x (n, m)`` is
+    its three bf16 terms stacked ``(3 n, m)`` (every product of a term
+    with a row is exact and the MXU accumulates in f32, so ONE pass
+    over the rows carries f32's 24 bits:
+    :func:`.paged_attention._contract_pool_rows`); a bf16 ``x`` is its
+    own single term; against f32 rows ``x`` goes as f32."""
+    if row_dtype != jnp.bfloat16:
+        return x.astype(jnp.float32)
+    return x if x.dtype == jnp.bfloat16 else _bf16_terms(x)
+
+
+def _contract_terms(terms, n: int, rows, dims):
+    """``terms`` (:func:`_mxu_terms` of an ``(n, m)`` operand) against
+    pool ``rows`` -> f32 ``(n, ...)``."""
+    if rows.dtype != jnp.bfloat16:
+        return jax.lax.dot_general(
+            terms, rows, (dims, ((), ())), precision=MXU_PRECISION,
+            preferred_element_type=jnp.float32)
+    out = jax.lax.dot_general(terms, rows, (dims, ((), ())),
+                              preferred_element_type=jnp.float32)
+    if terms.shape[0] == n:
+        return out
+    return out[:n] + out[n:2 * n] + out[2 * n:]
+
+
 def _latent_attention_kernel(tables_ref, lengths_ref,   # scalar prefetch
                              q_ref, pool_hbm, *rest, block_size: int,
-                             blocks_per_iter: int, heads: int, rank: int,
-                             sm_scale: float, prefill: bool):
+                             blocks_per_iter: int, group_blocks: int,
+                             heads: int, rank: int, sm_scale: float,
+                             prefill: bool):
     """One program = one tile of query rows (``token * heads + head``)
     and a loop over the cached blocks its table row holds,
     ``blocks_per_iter`` an iteration, copied into one of two VMEM key
-    buffers while the other is attended over.
+    buffers while the other is attended over: ONE online-softmax update
+    (one max, one exp, one rescale of the accumulator) of the ``(rows,
+    blocks_per_iter * block_size)`` score tile an iteration.
+
+    The loop is unrolled by two, so each half names its buffer
+    statically, and the next iteration's copies are issued AFTER this
+    one's wait, unrolled, in the block that attends: the scalar core's
+    descriptors (a table entry read, an address and an enqueue a block)
+    then share bundles with the vector work instead of preceding it.
+    Such an iteration copies all its ``blocks_per_iter`` entries,
+    those past the row's last live block clamped to it (no entry the
+    row does not own is dereferenced; their keys are masked by absolute
+    id): up to one iteration's blocks less one, once a row.  A row of
+    ONE iteration (an idle slot on the scratch block, a short context)
+    copies its live groups of ``group_blocks`` blocks only, from a
+    loop.  Keys no copy wrote are masked and weigh zero; what they
+    multiply has to be finite, so the buffers start as zeros and hold
+    pool rows ever after.
 
     Decode (grid ``(batch,)``): table row ``program_id``, keys
     ``0 .. lengths_ref[row] - 1`` (the step's own row is already in the
@@ -184,34 +244,36 @@ def _latent_attention_kernel(tables_ref, lengths_ref,   # scalar prefetch
     last_live = jnp.minimum(n_blocks, tables_ref.shape[1]) - 1
     iterations = (n_blocks + blocks_per_iter - 1) // blocks_per_iter
 
-    def copies(c, slot, resolve: bool):
-        """The DMAs of iteration ``c``.  Entries past the last live one
-        are clamped to it (their keys are masked by absolute id), so no
-        entry the row does not own is dereferenced; a descriptor built
-        only to be waited on names block 0."""
-        out = []
-        for i in range(blocks_per_iter):
-            block = 0
-            if resolve:
-                entry = jnp.minimum(c * blocks_per_iter + i, last_live)
-                block = tables_ref[row, entry]
-            out.append(pltpu.make_async_copy(
-                pool_hbm.at[block],
-                buf.at[slot, pl.ds(i * block_size, block_size)],
-                sems.at[slot]))
-        return out
+    def copy(c, slot, at, resolve: bool):
+        """The DMA of entry ``at`` of iteration ``c``; a descriptor
+        built only to be waited on names block 0."""
+        block = 0
+        if resolve:
+            entry = jnp.minimum(c * blocks_per_iter + at, last_live)
+            block = tables_ref[row, entry]
+        return pltpu.make_async_copy(
+            pool_hbm.at[block],
+            buf.at[slot, pl.ds(pl.multiple_of(at * block_size, block_size),
+                               block_size)],
+            sems.at[slot])
+
+    @pl.when(pl.program_id(0) == 0)
+    def _finite_buffers():
+        buf[...] = jnp.zeros_like(buf)
 
     m_scr[:] = jnp.full_like(m_scr, NEG_INF)
     l_scr[:] = jnp.zeros_like(l_scr)
     acc_scr[:] = jnp.zeros_like(acc_scr)
     row_dtype = (jnp.float32 if buf.dtype == jnp.float32
                  else jnp.bfloat16)
-    q = q_ref[0]
+    q_is_bf16 = q_ref.dtype == jnp.bfloat16
+    # The queries' MXU terms are the program's, not an iteration's.
+    q_terms = _mxu_terms(q_ref[0], row_dtype)
 
     def attend(k, visible):
         """Online-softmax update of every row from ``k (n, width)``."""
         k = k.astype(row_dtype)
-        s = _contract_pool_rows(q, k, ((1,), (1,))) * sm_scale
+        s = _contract_terms(q_terms, rows, k, ((1,), (1,))) * sm_scale
         s = jnp.where(visible, s, NEG_INF)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -220,33 +282,71 @@ def _latent_attention_kernel(tables_ref, lengths_ref,   # scalar prefetch
         correction = jnp.exp(m_prev - m_new)
         l_scr[:] = correction * l_scr[:] + jnp.sum(p, axis=-1,
                                                     keepdims=True)
-        if q.dtype == jnp.bfloat16:
+        if q_is_bf16:
             p = p.astype(jnp.bfloat16)
-        acc_scr[:] = acc_scr[:] * correction + _contract_pool_rows(
-            p, k[:, :rank], ((1,), (0,)))
+        acc_scr[:] = acc_scr[:] * correction + _contract_terms(
+            _mxu_terms(p, row_dtype), rows, k[:, :rank], ((1,), (0,)))
         m_scr[:] = m_new
 
-    @pl.when(iterations > 0)
-    def _first():
-        for copy in copies(0, 0, True):
-            copy.start()
-
-    def body(c, carry):
-        slot = c % 2
-
-        @pl.when(c + 1 < iterations)
-        def _prefetch():
-            for copy in copies(c + 1, 1 - slot, True):
-                copy.start()
-
-        for copy in copies(c, slot, False):
-            copy.wait()
+    def attend_cached(c, slot):
         key_ids = c * keys + jax.lax.broadcasted_iota(
             jnp.int32, (rows, keys), 1)
         attend(buf[slot], key_ids < length)
-        return carry
 
-    jax.lax.fori_loop(0, iterations, body, 0)
+    def for_groups(count, act):
+        """``act(entry)`` on every entry of an iteration's first
+        ``count`` groups, from a loop."""
+        def group(g, carry):
+            for i in range(group_blocks):
+                act(g * group_blocks + i)
+            return carry
+        jax.lax.fori_loop(0, count, group, 0)
+
+    def start_all(c, slot: int):
+        def start(at, carry):
+            copy(c, slot, at, True).start()
+            return carry
+        jax.lax.fori_loop(0, blocks_per_iter, start, 0, unroll=True)
+
+    def step(c, slot: int, successor: bool):
+        """Iteration ``c``, whose copies fill ``buf[slot]``."""
+        for_groups(blocks_per_iter // group_blocks,
+                   lambda at: copy(c, slot, at, False).wait())
+        if successor:
+            start_all(c + 1, 1 - slot)
+        attend_cached(c, slot)
+
+    @pl.when(iterations == 1)
+    def _one_iteration():
+        live_groups = (n_blocks + group_blocks - 1) // group_blocks
+        for_groups(live_groups, lambda at: copy(0, 0, at, True).start())
+        for_groups(live_groups, lambda at: copy(0, 0, at, False).wait())
+        attend_cached(0, 0)
+
+    @pl.when(iterations > 1)
+    def _iterations():
+        start_all(0, 0)
+        pairs = (iterations - 1) // 2
+
+        def pair(j, carry):
+            step(2 * j, 0, True)
+            step(2 * j + 1, 1, True)
+            return carry
+
+        jax.lax.fori_loop(0, pairs, pair, 0)
+        # What the pairs left: the last iteration, or the last two.
+        last = iterations - 1
+        two_left = last == 2 * pairs + 1
+
+        @pl.when(two_left)
+        def _last_two():
+            step(last - 1, 0, True)
+            step(last, 1, False)
+
+        @pl.when(jnp.logical_not(two_left))
+        def _last_one():
+            step(last, 0, False)
+
     if prefill:
         tokens = own_ref.shape[0]
         q_tile = rows // heads
@@ -265,6 +365,26 @@ def _latent_attention_kernel(tables_ref, lengths_ref,   # scalar prefetch
     o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
 
 
+def keys_per_iteration(query_rows: int, block_size: int) -> int:
+    """Keys one pass through a program's loop covers, from what the
+    call can see: the program's query rows and the pool's block size.
+
+    An iteration is a serial chain (wait for the copies, QK, lane max,
+    exp, the split of p, PV, rescale) whose latency hardly depends on
+    its keys while the rows are few: a decode program of 32 rows took
+    0.58 us per 128 keys computing at 128 keys an iteration and 0.18
+    at 1,024 (TPU v5e, PERF.md section 6, PR 32).  So an iteration
+    takes as many keys as its f32 score tile ``(query_rows, keys)``
+    holds in :data:`SCORE_TILE_BYTES`, in whole :data:`KEYS_PER_GROUP`
+    groups between one group and :data:`MAX_KEYS_PER_ITERATION`: the
+    most for a decode program; one group for a prefill tile of 512 to
+    2,048 rows, whose tile is 0.25 to 1 MiB at that and whose time is
+    the MXU's."""
+    group = max(KEYS_PER_GROUP, block_size)
+    keys = SCORE_TILE_BYTES // (4 * query_rows) // group * group
+    return max(group, min(keys, MAX_KEYS_PER_ITERATION // group * group))
+
+
 def _attention_call(q_tiles, pool, tables, lengths, own, *, heads: int,
                     rank: int, sm_scale: float, interpret: bool,
                     out_dtype):
@@ -272,8 +392,8 @@ def _attention_call(q_tiles, pool, tables, lengths, own, *, heads: int,
     ``own`` None for decode."""
     programs, rows, width = q_tiles.shape
     block_size = pool.shape[1]
-    blocks_per_iter = max(1, KEYS_PER_ITERATION // block_size)
-    keys = blocks_per_iter * block_size
+    group_blocks = max(1, KEYS_PER_GROUP // block_size)
+    keys = keys_per_iteration(rows, block_size)
     prefill = own is not None
 
     def tile_index(i, tables_ref, lengths_ref):
@@ -288,8 +408,8 @@ def _attention_call(q_tiles, pool, tables, lengths, own, *, heads: int,
         operands.append(own.astype(pool.dtype))
     kernel = functools.partial(
         _latent_attention_kernel, block_size=block_size,
-        blocks_per_iter=blocks_per_iter, heads=heads, rank=rank,
-        sm_scale=sm_scale, prefill=prefill)
+        blocks_per_iter=keys // block_size, group_blocks=group_blocks,
+        heads=heads, rank=rank, sm_scale=sm_scale, prefill=prefill)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(programs,), in_specs=in_specs,
         out_specs=pl.BlockSpec((1, rows, rank), tile_index),

@@ -308,13 +308,43 @@ def _pool(rng, blocks=40, width=128):
     return jnp.asarray(rng.normal(size=(blocks, 16, width)), jnp.float32)
 
 
-def test_the_decode_kernel_interpreted_is_its_jnp_form():
-    rng = np.random.default_rng(0)
-    pool = _pool(rng)
-    tables = jnp.asarray(rng.permutation(np.arange(1, 40))[:36].reshape(
-        3, 12), jnp.int32)
-    positions = jnp.asarray([5, 130, 191], jnp.int32)
-    q = jnp.asarray(rng.normal(size=(3, 4, 128)), jnp.float32)
+#: Keys one iteration of the decode loop covers for the tests' 4 heads.
+DECODE_KEYS = la.keys_per_iteration(4, 16)
+
+#: name -> (context lengths a row, table width): what a loop that covers
+#: many blocks an iteration can get wrong.  A length 1 row on a table of
+#: zeros is an idle slot on the scratch block.
+DECODE_CONTEXTS = {
+    "shorter_than_a_block": ([6], 12),
+    "one_iteration_exactly": ([DECODE_KEYS], DECODE_KEYS // 16 + 8),
+    "one_iteration_less_a_key": ([DECODE_KEYS - 1], DECODE_KEYS // 16 + 8),
+    "one_iteration_and_a_key": ([DECODE_KEYS + 1], DECODE_KEYS // 16 + 8),
+    "iterations_and_a_ragged_tail": (
+        [2 * DECODE_KEYS + 300, DECODE_KEYS + 16 * 9 + 5, 131],
+        3 * DECODE_KEYS // 16),
+    "table_narrower_than_an_iteration": ([6, 131, 192], 12),
+    "idle_row_beside_live_rows": ([1, DECODE_KEYS + 77, 1, 40],
+                                  DECODE_KEYS // 16 + 8),
+}
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32_pool", "bf16_pool"])
+@pytest.mark.parametrize("case", sorted(DECODE_CONTEXTS))
+def test_the_decode_kernel_interpreted_is_its_jnp_form(case, pool_dtype):
+    lengths, width = DECODE_CONTEXTS[case]
+    assert DECODE_KEYS > 12 * 16         # the narrow table is narrower
+    rng = np.random.default_rng(len(case))
+    n_blocks = 1 + len(lengths) * width
+    pool = _pool(rng, blocks=n_blocks).astype(pool_dtype)
+    tables = rng.permutation(np.arange(1, n_blocks)).reshape(
+        len(lengths), width)
+    for row, length in enumerate(lengths):
+        if length == 1:
+            tables[row] = 0
+    tables = jnp.asarray(tables, jnp.int32)
+    positions = jnp.asarray(lengths, jnp.int32) - 1
+    q = jnp.asarray(rng.normal(size=(len(lengths), 4, 128)), jnp.float32)
     wanted = la.latent_decode_reference(q, pool, tables, positions,
                                         rank=32, sm_scale=0.3)
     got = la.latent_decode_attention(q, pool, tables, positions, rank=32,

@@ -365,6 +365,26 @@ def test_latent_kernels_compile_for_v5e(one_chip, as_on_tpu):
     # 3-D bf16 result and the block tables first.
     assert re.search(r"%closed_call[.\d]* = bf16\[64,32,256\]", calls[0])
     assert "operand_layout_constraints={s32[64,1056]" in calls[0]
+    # The decode program's scoped VMEM at the width it chose for 32
+    # query rows, as the TPU compiler states it when refused: the two
+    # key buffers of one iteration and little else (what a wide
+    # iteration's temporaries spill is on the compiler's own stack).
+    keys = la.keys_per_iteration(g["heads"], g["bs"])
+    assert keys == la.MAX_KEYS_PER_ITERATION
+    buffers = 2 * keys * g["width"] * 2
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(la, "VMEM_LIMIT_BYTES", 64 * 2**10)
+        jax.clear_caches()
+        with pytest.raises(Exception, match="scoped vmem limit") as refused:
+            jax.jit(functools.partial(la.latent_decode_attention,
+                                      **attend)).lower(
+                S((g["slots"], g["heads"], g["width"]), jnp.bfloat16),
+                pool, S((g["slots"], g["table"]), jnp.int32),
+                S((g["slots"],), jnp.int32)).compile()
+    size, unit = re.search(r"Scoped allocation with size ([\d.]+)([KMG])",
+                           str(refused.value)).groups()
+    need = float(size) * 2 ** {"K": 10, "M": 20, "G": 30}[unit]
+    assert buffers <= need * 1.01 and need <= buffers + 256 * 2**10
     for tokens in (256, 128, 64, 32, 16):
         prefill = jax.jit(functools.partial(la.latent_prefill_attention,
                                             **attend)).lower(
