@@ -31,9 +31,12 @@ per-head keys or values.
 
   Both are ONE kernel body on two grids, and the keys an iteration
   covers are a function of a program's query rows
-  (:func:`keys_per_iteration`): a decode program's 32 rows leave an
-  iteration's time to its serial chain, so it covers 1,024 keys; a
-  prefill tile's 2,048 rows fill the MXU at 128.
+  (:func:`keys_per_iteration`): what an iteration pays whatever its
+  width (its serial chain; the lane reductions, the correction and the
+  accumulator's rescale of its softmax state) is paid per query row,
+  so it covers as many keys as its temporaries leave room for: 1,024
+  for a decode program's 32 rows, 512 for a prefill tile's 2,048.
+  Only a row's last iteration and the slice's own rows are masked.
 * :func:`latent_append`: rows into the pool in place.  A decode step's
   one row a slot is read-modify-write of its 16-key block (a bf16 row
   is half of each 32-bit word of its sublane pair, so a lone row is not
@@ -53,7 +56,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import NEG_INF
-from .paged_attention import (MXU_PRECISION, _bf16_terms,
+from .paged_attention import (LANES, MXU_PRECISION, _bf16_terms,
                               decode_kernel_mode, runs_kernel)
 from .paged_prefill import prefill_kernel_mode
 
@@ -72,19 +75,23 @@ KEYS_PER_GROUP = 128
 #: The most keys one iteration covers (:func:`keys_per_iteration`).
 MAX_KEYS_PER_ITERATION = 1024
 
-#: What an iteration's f32 score tile ``(query rows, keys)`` may hold.
-SCORE_TILE_BYTES = 128 * 2**10
-
 #: Query tokens of one prefill program.  Its state is per (token, head)
 #: row: 64 tokens of 32 heads are 2,048 rows, whose f32 accumulator
-#: ``(rows, rank 256)`` is 2 MiB and score tile ``(rows, 128)`` 1 MiB.
+#: ``(rows, rank 256)`` is 2 MiB.
 PREFILL_Q_TILE = 64
 
 #: Scoped VMEM either attention program may take.  Mosaic's default is
-#: 16 MiB of a v5e's 128; a prefill tile of 2,048 rows asked for 16.8 to
-#: 19.8 MiB (TPU compiler, PR 31: its state above, the queries and the
-#: temporaries of one step).
+#: 16 MiB of a v5e's 128; a prefill tile of 2,048 rows at 512 keys an
+#: iteration holds 10 MiB of state, queries, result and key buffers
+#: and asks for 9 to 24 MiB more of temporaries, as much as it is left
+#: (TPU compiler, ``tests/test_tpu_compile.py``).
 VMEM_LIMIT_BYTES = 48 * 2**20
+
+#: What the temporaries of ONE iteration may take, a quarter of
+#: :data:`VMEM_LIMIT_BYTES`, and their bytes a (query row, key): the
+#: f32 scores, the f32 weights and the weights as the MXU takes them.
+ITERATION_VMEM_BYTES = VMEM_LIMIT_BYTES // 4
+ITERATION_BYTES_PER_SCORE = 4 + 4 + 2
 
 
 def latent_attention_paths() -> Tuple[str, str]:
@@ -210,26 +217,39 @@ def _latent_attention_kernel(tables_ref, lengths_ref,   # scalar prefetch
     (one max, one exp, one rescale of the accumulator) of the ``(rows,
     blocks_per_iter * block_size)`` score tile an iteration.
 
-    The loop is unrolled by two, so each half names its buffer
-    statically, and the next iteration's copies are issued AFTER this
-    one's wait, unrolled, in the block that attends: the scalar core's
-    descriptors (a table entry read, an address and an enqueue a block)
-    then share bundles with the vector work instead of preceding it.
-    Such an iteration copies all its ``blocks_per_iter`` entries,
-    those past the row's last live block clamped to it (no entry the
-    row does not own is dereferenced; their keys are masked by absolute
-    id): up to one iteration's blocks less one, once a row.  A row of
-    ONE iteration (an idle slot on the scratch block, a short context)
-    copies its live groups of ``group_blocks`` blocks only, from a
-    loop.  Keys no copy wrote are masked and weigh zero; what they
-    multiply has to be finite, so the buffers start as zeros and hold
-    pool rows ever after.
+    Only a row's LAST iteration can hold a key it may not see (its
+    ragged end, and entries past its last live block), so only that one
+    is masked, by absolute key id; every other iteration attends with
+    no iota, compare or select.
 
     Decode (grid ``(batch,)``): table row ``program_id``, keys
     ``0 .. lengths_ref[row] - 1`` (the step's own row is already in the
-    pool).  Prefill (grid ``(T / q_tile,)``): table row 0, the
+    pool).  Its 32 rows leave an iteration to the scalar core's copy
+    descriptors, so the loop is unrolled by two, each half naming its
+    buffer statically, and the next iteration's copies are issued
+    AFTER this one's wait, unrolled, in the block that attends: the
+    descriptors (a table entry read, an address and an enqueue a block)
+    then share bundles with the vector work.  A row of ONE iteration
+    (an idle slot on the scratch block, a short context) copies its
+    live groups of ``group_blocks`` blocks only, from a loop.
+
+    Prefill (grid ``(T / q_tile,)``): table row 0, the
     ``lengths_ref[0]`` cached keys all visible, then the slice's own
-    rows (``own_ref``, VMEM) up to the tile's last query, causally."""
+    rows (``own_ref``, VMEM) up to the tile's last query, causally.
+    An iteration of its hundreds of rows is microseconds of vector and
+    MXU work, so its copies come from a loop and its buffer is indexed
+    by the iteration: one attending block in the loop and one after
+    it, where decode's structure has six.  Its running max and sum
+    are kept replicated over the lanes (``(rows, 128)``, what a
+    ``(rows, 1)`` tile takes of VMEM anyway), so no use of them is a
+    lane broadcast of 256 registers.
+
+    An iteration copies all its ``blocks_per_iter`` entries, those past
+    the row's last live block clamped to it (no entry the row does not
+    own is dereferenced): up to one iteration's blocks less one, once a
+    row.  Keys no copy wrote are masked and weigh zero; what they
+    multiply has to be finite, so the buffers start as zeros and hold
+    pool rows ever after."""
     if prefill:
         own_ref, o_ref, buf, sems, m_scr, l_scr, acc_scr = rest
         tile = pl.program_id(0)
@@ -270,28 +290,39 @@ def _latent_attention_kernel(tables_ref, lengths_ref,   # scalar prefetch
     # The queries' MXU terms are the program's, not an iteration's.
     q_terms = _mxu_terms(q_ref[0], row_dtype)
 
+    def across(stat, n: int):
+        """A row statistic against a tile of ``n`` lanes: ``(rows, 1)``
+        broadcasts, ``(rows, LANES)`` is repeated and cut to ``n``."""
+        if stat.shape[1] == 1:
+            return stat
+        if n <= LANES:
+            return stat[:, :n]
+        wide = jnp.tile(stat, (1, -(-n // LANES)))
+        return wide if wide.shape[1] == n else wide[:, :n]
+
     def attend(k, visible):
-        """Online-softmax update of every row from ``k (n, width)``."""
+        """Online-softmax update of every row from ``k (n, width)``;
+        ``visible`` None: every row sees every key.  A masked score is
+        NEG_INF against a running max that is finite from a row's first
+        update on (the first keys a row meets, key 0 of its cache or of
+        the slice, are visible to it), so a masked key's weight is
+        ``exp`` of -1e30: zero, with no second select."""
+        n = k.shape[0]
         k = k.astype(row_dtype)
         s = _contract_terms(q_terms, rows, k, ((1,), (1,))) * sm_scale
-        s = jnp.where(visible, s, NEG_INF)
+        if visible is not None:
+            s = jnp.where(visible, s, NEG_INF)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # A row with nothing visible yet keeps a zero weight on all.
-        p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - across(m_new, n))
         correction = jnp.exp(m_prev - m_new)
         l_scr[:] = correction * l_scr[:] + jnp.sum(p, axis=-1,
                                                     keepdims=True)
         if q_is_bf16:
             p = p.astype(jnp.bfloat16)
-        acc_scr[:] = acc_scr[:] * correction + _contract_terms(
+        acc_scr[:] = acc_scr[:] * across(correction, rank) + _contract_terms(
             _mxu_terms(p, row_dtype), rows, k[:, :rank], ((1,), (0,)))
         m_scr[:] = m_new
-
-    def attend_cached(c, slot):
-        key_ids = c * keys + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, keys), 1)
-        attend(buf[slot], key_ids < length)
 
     def for_groups(count, act):
         """``act(entry)`` on every entry of an iteration's first
@@ -302,50 +333,72 @@ def _latent_attention_kernel(tables_ref, lengths_ref,   # scalar prefetch
             return carry
         jax.lax.fori_loop(0, count, group, 0)
 
-    def start_all(c, slot: int):
+    all_groups = blocks_per_iter // group_blocks
+
+    def start_all(c, slot):
+        if prefill:
+            for_groups(all_groups, lambda at: copy(c, slot, at, True).start())
+            return
+
         def start(at, carry):
             copy(c, slot, at, True).start()
             return carry
         jax.lax.fori_loop(0, blocks_per_iter, start, 0, unroll=True)
 
-    def step(c, slot: int, successor: bool):
-        """Iteration ``c``, whose copies fill ``buf[slot]``."""
-        for_groups(blocks_per_iter // group_blocks,
-                   lambda at: copy(c, slot, at, False).wait())
+    def step(c, slot, successor: bool, ragged: bool,
+             live_groups=all_groups):
+        """Iteration ``c``, whose copies fill ``buf[slot]``; ``ragged``:
+        it is the row's last, the one that may hold a hidden key."""
+        for_groups(live_groups, lambda at: copy(c, slot, at, False).wait())
         if successor:
             start_all(c + 1, 1 - slot)
-        attend_cached(c, slot)
+        visible = None
+        if ragged:
+            visible = c * keys + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, keys), 1) < length
+        attend(buf[slot], visible)
 
-    @pl.when(iterations == 1)
-    def _one_iteration():
-        live_groups = (n_blocks + group_blocks - 1) // group_blocks
-        for_groups(live_groups, lambda at: copy(0, 0, at, True).start())
-        for_groups(live_groups, lambda at: copy(0, 0, at, False).wait())
-        attend_cached(0, 0)
+    last = iterations - 1
+    if prefill:
+        @pl.when(iterations > 0)
+        def _cached():
+            start_all(0, 0)
 
-    @pl.when(iterations > 1)
-    def _iterations():
-        start_all(0, 0)
-        pairs = (iterations - 1) // 2
+            def whole(c, carry):
+                step(c, jax.lax.rem(c, 2), True, False)
+                return carry
 
-        def pair(j, carry):
-            step(2 * j, 0, True)
-            step(2 * j + 1, 1, True)
-            return carry
+            jax.lax.fori_loop(0, last, whole, 0)
+            step(last, jax.lax.rem(last, 2), False, True)
+    else:
+        @pl.when(iterations == 1)
+        def _one_iteration():
+            live_groups = (n_blocks + group_blocks - 1) // group_blocks
+            for_groups(live_groups, lambda at: copy(0, 0, at, True).start())
+            step(0, 0, False, True, live_groups)
 
-        jax.lax.fori_loop(0, pairs, pair, 0)
-        # What the pairs left: the last iteration, or the last two.
-        last = iterations - 1
-        two_left = last == 2 * pairs + 1
+        @pl.when(iterations > 1)
+        def _iterations():
+            start_all(0, 0)
+            pairs = last // 2
 
-        @pl.when(two_left)
-        def _last_two():
-            step(last - 1, 0, True)
-            step(last, 1, False)
+            def pair(j, carry):
+                step(2 * j, 0, True, False)
+                step(2 * j + 1, 1, True, False)
+                return carry
 
-        @pl.when(jnp.logical_not(two_left))
-        def _last_one():
-            step(last, 0, False)
+            jax.lax.fori_loop(0, pairs, pair, 0)
+            # What the pairs left: the last iteration, or the last two.
+            two_left = last == 2 * pairs + 1
+
+            @pl.when(two_left)
+            def _last_two():
+                step(last - 1, 0, True, False)
+                step(last, 1, False, True)
+
+            @pl.when(jnp.logical_not(two_left))
+            def _last_one():
+                step(last, 0, False, True)
 
     if prefill:
         tokens = own_ref.shape[0]
@@ -362,26 +415,32 @@ def _latent_attention_kernel(tables_ref, lengths_ref,   # scalar prefetch
                     jnp.int32, (rows, size), 1)
                 attend(own_ref[chunk:chunk + size], key <= query[:, :size])
     denom = jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
-    o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+    o_ref[0] = (acc_scr[:] / across(denom, rank)).astype(o_ref.dtype)
 
 
 def keys_per_iteration(query_rows: int, block_size: int) -> int:
     """Keys one pass through a program's loop covers, from what the
     call can see: the program's query rows and the pool's block size.
 
-    An iteration is a serial chain (wait for the copies, QK, lane max,
-    exp, the split of p, PV, rescale) whose latency hardly depends on
-    its keys while the rows are few: a decode program of 32 rows took
-    0.58 us per 128 keys computing at 128 keys an iteration and 0.18
-    at 1,024 (TPU v5e, PERF.md section 6, PR 32).  So an iteration
-    takes as many keys as its f32 score tile ``(query_rows, keys)``
-    holds in :data:`SCORE_TILE_BYTES`, in whole :data:`KEYS_PER_GROUP`
-    groups between one group and :data:`MAX_KEYS_PER_ITERATION`: the
-    most for a decode program; one group for a prefill tile of 512 to
-    2,048 rows, whose tile is 0.25 to 1 MiB at that and whose time is
-    the MXU's."""
+    An iteration pays, whatever its width, for a serial chain (wait
+    for the copies, QK, lane max, exp, PV, rescale) and for its softmax
+    state: two lane reductions, the correction's ``exp`` and the f32
+    accumulator's read, rescale and write, all of them per query ROW.
+    A decode program of 32 rows took 0.58 us per 128 keys computing at
+    128 keys an iteration and 0.18 at 1,024 (TPU v5e, PERF.md section
+    6, PR 32); a prefill tile of 2,048 rows 6.2 us per 128 keys at 128
+    keys an iteration, 3.4 at 256, 2.3 at 512 and 2.1 at 1,024, whose
+    8 MiB score tile then costs a slice over nothing 0.17 ms (PERF.md
+    section 6, PR 35).  So an iteration takes as many keys as its
+    temporaries (:data:`ITERATION_BYTES_PER_SCORE` a row a key) fit in
+    :data:`ITERATION_VMEM_BYTES`, a quarter of the programs' VMEM
+    limit, in whole :data:`KEYS_PER_GROUP` groups between one group
+    and :data:`MAX_KEYS_PER_ITERATION`: 512 keys for a prefill tile of
+    2,048 rows (a 4 MiB score tile), the most for 1,024 rows or fewer,
+    a decode program's 32 among them."""
     group = max(KEYS_PER_GROUP, block_size)
-    keys = SCORE_TILE_BYTES // (4 * query_rows) // group * group
+    keys = (ITERATION_VMEM_BYTES // (ITERATION_BYTES_PER_SCORE * query_rows)
+            // group * group)
     return max(group, min(keys, MAX_KEYS_PER_ITERATION // group * group))
 
 
@@ -395,6 +454,7 @@ def _attention_call(q_tiles, pool, tables, lengths, own, *, heads: int,
     group_blocks = max(1, KEYS_PER_GROUP // block_size)
     keys = keys_per_iteration(rows, block_size)
     prefill = own is not None
+    stat_lanes = LANES if prefill else 1
 
     def tile_index(i, tables_ref, lengths_ref):
         return (i, 0, 0)
@@ -416,8 +476,8 @@ def _attention_call(q_tiles, pool, tables, lengths, own, *, heads: int,
         scratch_shapes=[
             pltpu.VMEM((2, keys, width), pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, stat_lanes), jnp.float32),
+            pltpu.VMEM((rows, stat_lanes), jnp.float32),
             pltpu.VMEM((rows, rank), jnp.float32)])
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,

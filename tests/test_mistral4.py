@@ -353,22 +353,70 @@ def test_the_decode_kernel_interpreted_is_its_jnp_form(case, pool_dtype):
                                atol=1e-5, rtol=0)
 
 
-@pytest.mark.parametrize("tokens, start", [(16, 0), (32, 16), (64, 128),
-                                           (128, 32), (256, 144)])
-def test_the_prefill_kernel_interpreted_is_its_jnp_form(tokens, start):
+#: Keys one pass of the prefill loop covers for a whole tile of the
+#: tests' 4 heads.
+PREFILL_KEYS = la.keys_per_iteration(la.PREFILL_Q_TILE * 4, 16)
+
+#: name -> (slice tokens, cached positions, table width[, pool dtype,
+#: query dtype: f32 unless given]): what a pass of many blocks can get
+#: wrong at prefill.  The first five are PR 31's.
+PREFILL_CONTEXTS = {
+    "16_tokens_nothing_cached": (16, 0, 30),
+    "32_tokens_one_block": (32, 16, 30),
+    "64_tokens_8_blocks": (64, 128, 30),
+    "128_tokens_2_blocks": (128, 32, 30),
+    "256_tokens_9_blocks": (256, 144, 30),
+    "four_tiles_nothing_cached": (256, 0, 30),
+    "three_tiles_192_tokens": (192, 32, 30),
+    "prefix_shorter_than_a_pass": (64, 48, PREFILL_KEYS // 16 + 8),
+    "one_pass_exactly": (64, PREFILL_KEYS, PREFILL_KEYS // 16 + 8),
+    "one_pass_and_a_block": (64, PREFILL_KEYS + 16,
+                             PREFILL_KEYS // 16 + 8),
+    "passes_and_a_ragged_last": (256, 2 * PREFILL_KEYS + 16 * 9,
+                                 3 * PREFILL_KEYS // 16),
+    "three_passes_16_tokens": (16, 3 * PREFILL_KEYS,
+                               3 * PREFILL_KEYS // 16 + 1),
+    "table_narrower_than_a_pass": (64, 80, 12),
+    "bf16_pool_one_pass_and_a_block": (64, PREFILL_KEYS + 16,
+                                       PREFILL_KEYS // 16 + 8,
+                                       jnp.bfloat16),
+    "bf16_pool_ragged_last_four_tiles": (256, PREFILL_KEYS + 16 * 5,
+                                         2 * PREFILL_KEYS // 16,
+                                         jnp.bfloat16),
+    "bf16_pool_nothing_cached": (16, 0, 12, jnp.bfloat16),
+    # The served path: bf16 queries are their own single MXU term and
+    # the weights go to bf16 for the second product.
+    "bf16_pool_bf16_queries": (64, 2 * PREFILL_KEYS + 16 * 3,
+                               3 * PREFILL_KEYS // 16, jnp.bfloat16,
+                               jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(PREFILL_CONTEXTS))
+def test_the_prefill_kernel_interpreted_is_its_jnp_form(case):
+    tokens, start, width, *dtypes = PREFILL_CONTEXTS[case]
+    pool_dtype, q_dtype = (*dtypes, jnp.float32, jnp.float32)[:2]
+    assert PREFILL_KEYS > 12 * 16        # the narrow table is narrower
+    assert start % 16 == 0 and start + tokens <= width * 16
     rng = np.random.default_rng(tokens + start)
-    pool = _pool(rng)
-    table = jnp.asarray(rng.permutation(np.arange(1, 40))[:30], jnp.int32)
-    q = jnp.asarray(rng.normal(size=(tokens, 4, 128)), jnp.float32)
-    own = jnp.asarray(rng.normal(size=(tokens, 128)), jnp.float32)
+    n_blocks = max(40, width + 10)
+    pool = _pool(rng, blocks=n_blocks).astype(pool_dtype)
+    table = jnp.asarray(rng.permutation(np.arange(1, n_blocks))[:width],
+                        jnp.int32)
+    q = jnp.asarray(rng.normal(size=(tokens, 4, 128)), q_dtype)
+    own = jnp.asarray(rng.normal(size=(tokens, 128)), q_dtype)
     wanted = la.latent_prefill_reference(q, own, pool, table,
                                          jnp.int32(start), rank=32,
                                          sm_scale=0.3)
     got = la.latent_prefill_attention(q, own, pool, table,
                                       jnp.int32(start), rank=32,
                                       sm_scale=0.3, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(wanted),
-                               atol=1e-5, rtol=0)
+    # bf16 queries: the result itself is bf16 (8 bits) and so are the
+    # weights of the second product.
+    atol = 1e-5 if q_dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(wanted, np.float32),
+                               atol=atol, rtol=0)
 
 
 def test_the_append_kernel_writes_one_row_or_whole_blocks_in_place():

@@ -343,6 +343,14 @@ LATENT_CELL = dict(slots=64, table=1056, n_blocks=49153, bs=16, width=384,
                    heads=32, rank=256)
 
 
+def _scoped_vmem_asked(refused) -> float:
+    """Bytes the TPU compiler names when it refuses a kernel for its
+    scoped VMEM limit."""
+    size, unit = re.search(r"Scoped allocation with size ([\d.]+)([KMG])",
+                           str(refused.value)).groups()
+    return float(size) * 2 ** {"K": 10, "M": 20, "G": 30}[unit]
+
+
 def test_latent_kernels_compile_for_v5e(one_chip, as_on_tpu):
     """The decode kernel with the cell's 64 x 1,056 block tables as
     prefetched scalars (270 KB: twelve times the largest a paged
@@ -381,18 +389,47 @@ def test_latent_kernels_compile_for_v5e(one_chip, as_on_tpu):
                 S((g["slots"], g["heads"], g["width"]), jnp.bfloat16),
                 pool, S((g["slots"], g["table"]), jnp.int32),
                 S((g["slots"],), jnp.int32)).compile()
-    size, unit = re.search(r"Scoped allocation with size ([\d.]+)([KMG])",
-                           str(refused.value)).groups()
-    need = float(size) * 2 ** {"K": 10, "M": 20, "G": 30}[unit]
+    need = _scoped_vmem_asked(refused)
     assert buffers <= need * 1.01 and need <= buffers + 256 * 2**10
+    # A prefill tile at the width keys_per_iteration gives its rows:
+    # inside VMEM_LIMIT_BYTES, and refused under Mosaic's default 16 MiB
+    # with the compiler naming what it asks for: its state (the f32
+    # accumulator and the lane-replicated max and sum), the two key
+    # buffers, the slice's rows and the query and result tiles twice
+    # (the grid's pipeline), then the temporaries of one iteration
+    # (scores, weights and their bf16 form).
+    widths = {}
     for tokens in (256, 128, 64, 32, 16):
-        prefill = jax.jit(functools.partial(la.latent_prefill_attention,
-                                            **attend)).lower(
-            S((tokens, g["heads"], g["width"]), jnp.bfloat16),
-            S((tokens, g["width"]), jnp.bfloat16), pool,
-            S((g["table"],), jnp.int32), S((), jnp.int32)).compile()
-        assert len(_custom_call_lines(prefill.as_text(),
+        rows = min(tokens, la.PREFILL_Q_TILE) * g["heads"]
+        keys = widths[tokens] = la.keys_per_iteration(rows, g["bs"])
+
+        def compiled():
+            return jax.jit(functools.partial(
+                la.latent_prefill_attention, **attend)).lower(
+                S((tokens, g["heads"], g["width"]), jnp.bfloat16),
+                S((tokens, g["width"]), jnp.bfloat16), pool,
+                S((g["table"],), jnp.int32), S((), jnp.int32)).compile()
+
+        assert len(_custom_call_lines(compiled().as_text(),
                                       "latent_prefill_call")) == 1
+        held = (rows * g["rank"] * 4 + 2 * rows * la.LANES * 4
+                + 2 * keys * g["width"] * 2 + tokens * g["width"] * 2
+                + 2 * rows * (g["width"] + g["rank"]) * 2)
+        temporaries = rows * keys * la.ITERATION_BYTES_PER_SCORE
+        assert temporaries <= la.ITERATION_VMEM_BYTES
+        assert held + temporaries <= la.VMEM_LIMIT_BYTES
+        if held + temporaries <= 16 * 2**20:
+            continue
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(la, "VMEM_LIMIT_BYTES", 16 * 2**20)
+            jax.clear_caches()
+            with pytest.raises(Exception,
+                               match="scoped vmem limit") as refused:
+                compiled()
+        jax.clear_caches()
+        need = _scoped_vmem_asked(refused)
+        assert held <= need * 1.01 and need <= la.VMEM_LIMIT_BYTES
+    assert widths == {256: 512, 128: 512, 64: 512, 32: 1024, 16: 1024}
 
     def steps(pool, rows, blocks, offsets, whole, whole_ids):
         def body(pool, _):
