@@ -42,8 +42,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Tuple
 
-__all__ = ["TaxRow", "TaxTable", "attribute_steps", "LEVERS",
-           "ADMISSION_COMPONENTS"]
+__all__ = ["TaxRow", "TaxTable", "attribute_steps", "LEVERS"]
 
 #: Component → the ROADMAP lever that would shrink it.
 LEVERS: Dict[str, str] = {
@@ -63,12 +62,6 @@ LEVERS: Dict[str, str] = {
         "on a throttled backend — not decode-loop tax)",
     "uninstrumented": "(outside the step log's window)",
 }
-
-#: Components that belong to ADMISSION (prompt intake + prefill), not
-#: the steady-state decode loop: a reader that wants the decode
-#: loop's own tax leaves these rows out.
-ADMISSION_COMPONENTS = ("admission", "paged_prefill", "sampling_edit",
-                        "post_admission_dispatch")
 
 #: event name → (field carrying an embedded duration, component name).
 _EMBEDDED: Dict[str, Tuple[str, str]] = {

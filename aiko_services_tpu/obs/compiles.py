@@ -8,23 +8,36 @@ serving loop stalls every slot for seconds
 at runtime instead of only in jaxpr tests:
 
 * :class:`CompileLedger` subscribes to ``jax.monitoring`` compilation
-  events and records every XLA compile — program label, shape-bucket
-  signature, wall ms, cumulative count — into REGISTRY
+  events and records every program that reaches the backend — program
+  label, shape-bucket signature, the jitted function's name, and each
+  host phase it paid for: Python tracing (``trace_ms``), lowering to
+  MLIR (``lower_ms``) and the backend's compile or, on a
+  persistent-cache hit, the load (``wall_ms``) — into REGISTRY
   counters/histograms, engine ``stats()`` (and from there EC shares,
-  the dashboard pane, and ``LoadReport``).
+  the dashboard pane, and ``LoadReport``).  Set-up is the sum of
+  those phases over a process's programs; the totals a
+  :meth:`CompileLedger.snapshot` carries are what the benchmark's
+  ``setup_*`` per-layer metrics read.
 * A **steady-state compile detector**: once the harness drops the
   warmup fence (:meth:`CompileLedger.fence`), ANY further real compile
   is a bucket-discipline regression — the ledger bumps
   ``aiko_compiles_steady_state_total`` and fires a flight capture
   (trigger ``"compile"``) with the ledger attached, so the pathology
   is caught in production, not just in tests.
+* The **collector's pauses**: a full (generation 2) garbage collection
+  walks every long-lived object thirty traced programs leave, with
+  the interpreter lock held, and so stops the engine loop.  While the
+  ledger is installed one ``gc.callbacks`` entry times them: two
+  totals, and a line with a time and the label then set for each
+  pause over :data:`GC_PAUSE_LINE_MS`.
 * :func:`enable_persistent_cache` turns on JAX's persistent
   compilation cache — in the directory ``JAX_COMPILATION_CACHE_DIR``
   names when the environment sets it, else a caller's directory or
   the fixed in-checkout :func:`default_cache_dir` — so a warm restart
-  skips recompilation entirely; the ledger's hit/miss/saved-ms
-  counters quantify it (``tools/loadgen.run_compile_cache_ab`` gates
-  on it).
+  skips recompilation entirely; the ledger's hit/miss counters and
+  the measured ``cache_load_ms_total`` against
+  ``compile_wall_ms_total`` quantify it
+  (``tools/loadgen.run_compile_cache_ab`` gates on it).
 
 Event semantics (jax 0.9.0; re-checked on CPU and on a TPU v5e by
 ``chip_smoke.py``'s two-run cache check): the duration events carry
@@ -33,20 +46,44 @@ events carry nothing, so attribution — which also needs the shape
 bucket — uses a **per-thread label** set by the engine at each
 dispatch site (:func:`label` / :func:`set_label`).  On a
 persistent-cache HIT the
-``…/backend_compile_duration`` event STILL fires (it times the ~ms
-cache retrieval, not a real compile) — the ledger pairs a same-thread
+``…/backend_compile_duration`` event STILL fires (it times the cache
+retrieval, not a real compile) — the ledger pairs a same-thread
 preceding ``cache_hits`` event with the next duration event and books
-it as a retrieval, never as a compile.  ``compile_time_saved_sec`` can
-be NEGATIVE for tiny programs (estimated compile time minus retrieval
-time); the ledger accumulates the raw signed sum.
+it as a load, never as a compile.
+
+**Each second is booked once.**  A duration event arrives when its
+phase ENDS, on the thread that ran it, and trace events nest: every
+inner ``jax.jit`` (each Pallas kernel here sits behind one) and every
+``jnp`` wrapper fires its own inside the outer program's, and a
+lowering rule that calls ``jnp`` fires trace events inside the
+lowering's.  So a program's ``trace_ms`` is the UNION of its trace
+intervals ``[end − duration, end]``, not their sum, and ``lower_ms``
+is the lowering less the tracing inside it.  Children end before
+their parent: a per-thread stack of the intervals no later event has
+covered yet does it in O(1) an event, with no lock and no record
+until the backend event closes the program.  A trace that no
+lowering follows (``eval_shape``, a program whose executable is
+already held) waits on the stack — it cannot be told from an earlier
+child of a trace still open — and goes into the totals, under no
+record, when the thread next closes a program, or when the stack
+passes :data:`_STACK_CAP` entries.  The handler reads the clock when
+it is called, a few µs after the phase ended, so an interval's edges
+are late by that much (by a collection's pause, if one falls
+between: then a child that ended in the parent's first instants is
+booked beside it, not inside).
+
+Records, pauses, ``obs/steplog`` events and engine spans share one
+clock (:func:`_now`: the epoch at import plus ``perf_counter``), read
+at the END of what they time, so they overlay without alignment.
 
 Switchboard discipline (swept by ``scripts/obs_lint.py``): module
 default ``LEDGER = None``; every call site outside this module guards
 with ``compiles.LEDGER is not None``.  Listeners are registered ONCE
 per process and forward to whatever ``LEDGER`` currently is — JAX has
 no public listener-unregister API, so :func:`uninstall` simply nulls
-the switchboard and the resident listeners become no-ops.  Invariant
-15 (ARCHITECTURE.md): nothing here touches traced values — jaxprs are
+the switchboard and the resident listeners become no-ops (the
+collector's callback, which can be removed, is).  Invariant 15
+(ARCHITECTURE.md): nothing here touches traced values — jaxprs are
 byte-identical with the ledger installed or absent.
 
 Stdlib-only at import time; ``jax`` strictly lazily (``obs`` package
@@ -56,14 +93,15 @@ discipline).
 from __future__ import annotations
 
 import contextlib
+import gc
 import os
 import sys
 import threading
-import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
 from .metrics import REGISTRY
+from .steplog import _now
 
 __all__ = ["CompileLedger", "LEDGER", "install", "uninstall",
            "enable_persistent_cache", "persistent_cache",
@@ -79,6 +117,27 @@ LEDGER: Optional["CompileLedger"] = None
 _LISTENERS_REGISTERED = False
 
 _TLS = threading.local()
+
+#: The three phases of one program, as ``jax/_src/dispatch.py`` names
+#: their duration events (jax 0.9.0).
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+
+#: A full collection longer than this gets a line of its own in the
+#: snapshot's ``pauses`` (a stall an operator would see), not only its
+#: share of the totals.
+GC_PAUSE_LINE_MS = 100.0
+
+# A thread's open intervals: one flat list, _ENTRY slots an interval —
+# end, duration, the trace seconds and the lowering seconds inside it
+# that no record has yet, its kind, its function's name.
+_ENTRY = 6
+_TRACE, _LOWER, _BOOKED = 0, 1, 2
+#: Intervals one thread may hold before they are booked under no
+#: program: the direct children of one open trace (thousands, for a
+#: model unrolled in Python), or traces nothing ever lowered.
+_STACK_CAP = 1 << 15
 
 
 # --------------------------------------------------------------------------- #
@@ -112,10 +171,11 @@ def label(program: str, signature: str = ""):
 
 
 class CompileLedger:
-    """Record of every XLA compile seen by this process.
+    """Record of every program this process took to the backend, and
+    of the collector's full pauses meanwhile.
 
     Thread-safe; listener callbacks arrive on whichever thread ran the
-    jit.  ``max_records`` bounds the per-compile detail ring (counters
+    jit.  ``max_records`` bounds the per-program detail ring (counters
     are unbounded monotonic).
     """
 
@@ -128,8 +188,18 @@ class CompileLedger:
         self.steady_compiles = 0          # real compiles AFTER the fence
         self.cache_hits = 0
         self.cache_misses = 0
-        self.cache_saved_ms = 0.0         # signed (see module docstring)
-        self.total_ms = 0.0
+        self.total_ms = 0.0               # backend wall of real compiles
+        self.cache_load_ms = 0.0          # backend wall of cache hits
+        self.trace_ms = 0.0               # Python tracing, each second once
+        self.lower_ms = 0.0               # lowering, less tracing inside it
+        self.programs_traced = 0          # records with a trace of their own
+        # The collector's callback takes no lock (it can fire inside
+        # this ledger's own locked regions): plain stores, one
+        # collection at a time.
+        self.gc_full_pauses = 0
+        self.gc_full_pause_ms = 0.0
+        self._gc_began = None
+        self.pauses: deque = deque(maxlen=32)
         self.fenced = False
         self.records: deque = deque(maxlen=max(1, int(max_records)))
         self._counter_compiles = self.registry.counter(
@@ -143,9 +213,6 @@ class CompileLedger:
         self._counter_misses = self.registry.counter(
             "aiko_compile_cache_misses_total",
             "persistent compilation cache misses")
-        self._gauge_saved = self.registry.gauge(
-            "aiko_compile_cache_saved_ms",
-            "signed cumulative compile ms saved by the persistent cache")
         self._hist_wall = self.registry.histogram(
             "aiko_compile_wall_ms", "per-compile wall time (ms)")
 
@@ -178,14 +245,13 @@ class CompileLedger:
             self._counter_misses.inc()
         _TLS.pending_hit = False
 
-    def on_saved(self, saved_ms: float):
-        with self._lock:
-            self.cache_saved_ms += float(saved_ms)
-            self._gauge_saved.inc(float(saved_ms))
-
     def record_compile(self, wall_ms: float, program: str = "",
-                       signature: str = "", cache_hit: bool = False):
-        """Book one backend-compile duration.  Public so engines without
+                       signature: str = "", cache_hit: bool = False,
+                       trace_ms: float = 0.0, lower_ms: float = 0.0,
+                       fun_name: str = ""):
+        """Book one program at its backend event: the compile's wall,
+        or the load's when ``cache_hit``, with the tracing and
+        lowering that led to it.  Public so engines without
         ``jax.monitoring`` can wrap their jit entry points and call this
         directly (the documented fallback path)."""
         if not program:
@@ -194,10 +260,19 @@ class CompileLedger:
         steady = False
         with self._lock:
             entry = {"program": program, "signature": signature,
+                     "fun_name": fun_name,
+                     "trace_ms": round(float(trace_ms), 3),
+                     "lower_ms": round(float(lower_ms), 3),
                      "wall_ms": round(float(wall_ms), 3),
                      "cache_hit": bool(cache_hit),
-                     "steady": False, "ts": time.time()}
-            if not cache_hit:
+                     "steady": False, "ts": _now()}
+            self.trace_ms += float(trace_ms)
+            self.lower_ms += float(lower_ms)
+            if trace_ms > 0:
+                self.programs_traced += 1
+            if cache_hit:
+                self.cache_load_ms += float(wall_ms)
+            else:
                 self.compiles += 1
                 self.total_ms += float(wall_ms)
                 self._counter_compiles.inc()
@@ -210,6 +285,31 @@ class CompileLedger:
             self.records.append(entry)
         if steady:
             self._fire_steady_capture(entry)
+
+    def book_unlowered(self, trace_ms: float, lower_ms: float):
+        """Tracing (and lowering) that led to no backend event: into
+        the totals, under no record."""
+        with self._lock:
+            self.trace_ms += float(trace_ms)
+            self.lower_ms += float(lower_ms)
+
+    def on_full_collection(self, phase: str):
+        """One edge of a generation 2 collection (``gc.callbacks``)."""
+        if phase == "start":
+            self._gc_began = _now()
+            return
+        began, self._gc_began = self._gc_began, None
+        if began is None:       # installed while the collection ran
+            return
+        ended = _now()
+        pause_ms = (ended - began) * 1e3
+        self.gc_full_pauses += 1
+        self.gc_full_pause_ms += pause_ms
+        if pause_ms > GC_PAUSE_LINE_MS:
+            program, signature = current_label()
+            self.pauses.append({"ts": ended, "ms": round(pause_ms, 3),
+                                "program": program,
+                                "signature": signature})
 
     def _fire_steady_capture(self, entry: Dict):
         # Lazy import: flight imports THIS module at top level for its
@@ -247,7 +347,8 @@ class CompileLedger:
     # -- export --------------------------------------------------------------- #
 
     def snapshot(self) -> Dict:
-        """Flight-bundle / doctor section: counters + recent records."""
+        """Flight-bundle / doctor section: monotonic totals, recent
+        records, and the collector's long pauses."""
         with self._lock:
             return {
                 "service": self.service,
@@ -255,10 +356,16 @@ class CompileLedger:
                 "compiles_steady_state": self.steady_compiles,
                 "cache_hits": self.cache_hits,
                 "cache_misses": self.cache_misses,
-                "cache_saved_ms": round(self.cache_saved_ms, 3),
                 "compile_wall_ms_total": round(self.total_ms, 3),
+                "cache_load_ms_total": round(self.cache_load_ms, 3),
+                "trace_ms_total": round(self.trace_ms, 3),
+                "lower_ms_total": round(self.lower_ms, 3),
+                "programs_traced": self.programs_traced,
+                "gc_full_pauses": self.gc_full_pauses,
+                "gc_full_pause_ms": round(self.gc_full_pause_ms, 3),
                 "fenced": self.fenced,
                 "records": [dict(entry) for entry in self.records],
+                "pauses": [dict(entry) for entry in self.pauses],
             }
 
 
@@ -276,19 +383,109 @@ def _on_event(event: str, **kwargs):  # noqa: ARG001 - kwargs are empty
         ledger.on_cache_miss()
 
 
-def _on_duration(event: str, duration_secs: float, **kwargs):  # noqa: ARG001
+def _covered(stack: list, start: float):
+    """Drops from ``stack`` the intervals that ended after ``start`` —
+    the children of an interval that began then — and returns their
+    summed (duration, unbooked trace s, unbooked lowering s)."""
+    covered = trace_s = lower_s = 0.0
+    size = len(stack)
+    while size and stack[size - _ENTRY] > start:
+        covered += stack[size - _ENTRY + 1]
+        trace_s += stack[size - _ENTRY + 2]
+        lower_s += stack[size - _ENTRY + 3]
+        size -= _ENTRY
+    del stack[size:]
+    return covered, trace_s, lower_s
+
+
+def _book(ledger: "CompileLedger", stack: list):
+    """Books under no program the trace and lowering seconds that the
+    intervals on top of ``stack`` still hold, down to the first one
+    booked before.  They stay where they are, as booked time: a trace
+    still open round them subtracts each second that has a place."""
+    trace_s = lower_s = 0.0
+    at = len(stack) - _ENTRY
+    while at >= 0 and stack[at + 4] != _BOOKED:
+        trace_s += stack[at + 2]
+        lower_s += stack[at + 3]
+        stack[at + 2] = stack[at + 3] = 0.0
+        stack[at + 4] = _BOOKED
+        at -= _ENTRY
+    if trace_s or lower_s:
+        ledger.book_unlowered(trace_s * 1e3, lower_s * 1e3)
+
+
+def _on_duration(event: str, duration_secs: float, **kwargs):
+    """Every ``jax.monitoring`` duration event, on the thread that ran
+    the phase and as it ends.  Trace events arrive 10⁴–10⁵ a program:
+    that path takes no lock and makes no record."""
     ledger = LEDGER
     if ledger is None:
         return
-    if "backend_compile" in event:
-        # A persistent-cache hit still fires this event for the ~ms
-        # retrieval; the same-thread pending-hit flag (set by the hit
-        # event that immediately precedes it) reclassifies it.
-        pending = getattr(_TLS, "pending_hit", False)
-        _TLS.pending_hit = False
-        ledger.record_compile(duration_secs * 1e3, cache_hit=pending)
-    elif "compile_time_saved" in event:
-        ledger.on_saved(duration_secs * 1e3)
+    if event == TRACE_EVENT:
+        kind = _TRACE
+    elif event == LOWER_EVENT:
+        kind = _LOWER
+    elif event == BACKEND_EVENT:
+        kind = None
+    else:
+        return
+    end = _now()
+    try:
+        stack = _TLS.stack
+    except AttributeError:
+        stack = _TLS.stack = []
+    covered, trace_s, lower_s = _covered(stack, end - duration_secs)
+    if kind is None:
+        _close_program(ledger, stack, end, duration_secs, trace_s,
+                       lower_s, kwargs.get("fun_name", ""))
+        return
+    own = duration_secs - covered
+    if own > 0.0:
+        if kind == _TRACE:
+            trace_s += own
+        else:
+            lower_s += own
+    stack += (end, duration_secs, trace_s, lower_s, kind,
+              kwargs.get("fun_name", ""))
+    if len(stack) > _STACK_CAP * _ENTRY:
+        _book(ledger, stack)
+        stack[:] = (end, sum(stack[1::_ENTRY]), 0.0, 0.0, _BOOKED, "")
+
+
+def _close_program(ledger: "CompileLedger", stack: list, end: float,
+                   duration_secs: float, trace_s: float, lower_s: float,
+                   fun_name: str):
+    """The backend event closes a program's record.  Its own lowering
+    is the interval on top of ``stack``, its own trace the one below
+    that; what else is still unbooked there led to no program."""
+    began = end - duration_secs
+    for kind in (_LOWER, _TRACE):
+        if stack and stack[-2] == kind:
+            began = stack[-_ENTRY] - stack[-_ENTRY + 1]
+            trace_s += stack[-_ENTRY + 2]
+            lower_s += stack[-_ENTRY + 3]
+            if kind == _TRACE:
+                fun_name = stack[-1] or fun_name
+            del stack[-_ENTRY:]
+    _book(ledger, stack)
+    stack += (end, end - began, 0.0, 0.0, _BOOKED, "")
+    # A persistent-cache hit still fires the backend event, for the
+    # retrieval; the same-thread pending-hit flag (set by the hit
+    # event that immediately precedes it) reclassifies it.
+    pending = getattr(_TLS, "pending_hit", False)
+    _TLS.pending_hit = False
+    ledger.record_compile(duration_secs * 1e3, cache_hit=pending,
+                          trace_ms=trace_s * 1e3, lower_ms=lower_s * 1e3,
+                          fun_name=fun_name)
+
+
+def _on_gc(phase: str, info: Dict):
+    """The ``gc.callbacks`` entry: generations 0 and 1 return at once."""
+    if info["generation"] == 2:
+        ledger = LEDGER
+        if ledger is not None:
+            ledger.on_full_collection(phase)
 
 
 def _register_listeners() -> bool:
@@ -318,13 +515,17 @@ def install(service: str = "", max_records: int = 256,
         LEDGER = ledger or CompileLedger(service=service,
                                          max_records=max_records)
         _register_listeners()
+        gc.callbacks.append(_on_gc)
     return LEDGER
 
 
 def uninstall():
-    """Null the switchboard; resident listeners become no-ops."""
+    """Null the switchboard; resident listeners become no-ops, and
+    the collector's callback goes."""
     global LEDGER
-    LEDGER = None
+    if LEDGER is not None:
+        LEDGER = None
+        gc.callbacks.remove(_on_gc)
 
 
 # --------------------------------------------------------------------------- #

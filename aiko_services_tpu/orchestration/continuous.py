@@ -2645,7 +2645,12 @@ class ContinuousBatchingServer:
                 compiles_steady_state=compiles.LEDGER.steady_compiles,
                 compile_cache_hits=compiles.LEDGER.cache_hits,
                 compile_cache_misses=compiles.LEDGER.cache_misses,
-                compile_wall_ms=round(compiles.LEDGER.total_ms, 1))
+                compile_wall_ms=round(compiles.LEDGER.total_ms, 1),
+                compile_cache_load_ms=round(
+                    compiles.LEDGER.cache_load_ms, 1),
+                compile_trace_ms=round(compiles.LEDGER.trace_ms, 1),
+                compile_lower_ms=round(compiles.LEDGER.lower_ms, 1),
+                programs_traced=compiles.LEDGER.programs_traced)
         if self._device_step_ms is not None:
             out.update(device_step_ms=round(self._device_step_ms, 3),
                        profiles=self._profiles)

@@ -100,6 +100,10 @@ TELEMETRY_KEYS = (
     # CompileLedger is installed / a profile bracket ran)
     "compiles", "compiles_steady_state", "compile_cache_hits",
     "compile_cache_misses", "compile_wall_ms",
+    # Set-up's host phases (PR 36): the loads of cache hits, Python
+    # tracing and lowering, each second once, and the programs traced
+    "compile_cache_load_ms", "compile_trace_ms", "compile_lower_ms",
+    "programs_traced",
     "device_step_ms", "profiles",
     # Memory accountant + pool auditor (PR 15; kv_hbm_* always on a
     # paged server, audit counters only when an AUDITOR is installed)

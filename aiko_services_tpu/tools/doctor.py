@@ -264,16 +264,38 @@ def render_report(bundle: Dict) -> str:
             f"compile ledger: {compiles.get('compiles', 0)} compiles "
             f"({compiles.get('compiles_steady_state', 0)} steady-state)"
             f", cache {compiles.get('cache_hits', 0)} hit / "
-            f"{compiles.get('cache_misses', 0)} miss, "
-            f"saved {compiles.get('cache_saved_ms', 0):g} ms"
+            f"{compiles.get('cache_misses', 0)} miss"
             + (", FENCED" if compiles.get("fenced") else ""))
+        lines.append(
+            f"  host phases: trace "
+            f"{compiles.get('trace_ms_total', 0):g} ms + lower "
+            f"{compiles.get('lower_ms_total', 0):g} ms over "
+            f"{compiles.get('programs_traced', 0)} programs traced; "
+            f"backend compile "
+            f"{compiles.get('compile_wall_ms_total', 0):g} ms, cache "
+            f"load {compiles.get('cache_load_ms_total', 0):g} ms")
+        lines.append(
+            f"  collector: {compiles.get('gc_full_pauses', 0)} full "
+            f"collections, {compiles.get('gc_full_pause_ms', 0):g} ms"
+            + "".join(
+                f"; {pause.get('ms', 0):g} ms at "
+                f"{pause.get('ts', 0):.3f} under "
+                f"{pause.get('program', '?')}"
+                f"[{pause.get('signature', '')}]"
+                for pause in compiles.get("pauses") or []))
+        lines.append(
+            f"  {'program':<16} {'signature':<12} {'function':<24} "
+            f"{'trace':>9} {'lower':>9} {'backend':>9} ms")
         for record in (compiles.get("records") or [])[-12:]:
             flag = "  << STEADY-STATE" if record.get("steady") else (
-                "  (cache hit)" if record.get("cache_hit") else "")
+                "  (cache load)" if record.get("cache_hit") else "")
             lines.append(
                 f"  {record.get('program', '?'):<16} "
                 f"{record.get('signature', '') or '-':<12} "
-                f"{record.get('wall_ms', 0):>9.2f} ms{flag}")
+                f"{record.get('fun_name', '') or '-':<24} "
+                f"{record.get('trace_ms', 0):>9.2f} "
+                f"{record.get('lower_ms', 0):>9.2f} "
+                f"{record.get('wall_ms', 0):>9.2f}{flag}")
 
     if profile:
         lines.append("")
@@ -357,15 +379,15 @@ def bundle_summary(bundle: Dict) -> Dict:
     }
     if compiles:
         summary["compiles"] = {
-            "compiles": compiles.get("compiles", 0),
-            "compiles_steady_state":
-                compiles.get("compiles_steady_state", 0),
-            "cache_hits": compiles.get("cache_hits", 0),
-            "cache_misses": compiles.get("cache_misses", 0),
-            "cache_saved_ms": compiles.get("cache_saved_ms", 0.0),
-            "fenced": bool(compiles.get("fenced")),
-            "records": len(compiles.get("records") or []),
-        }
+            key: compiles.get(key, 0) for key in (
+                "compiles", "compiles_steady_state", "cache_hits",
+                "cache_misses", "compile_wall_ms_total",
+                "cache_load_ms_total", "trace_ms_total",
+                "lower_ms_total", "programs_traced", "gc_full_pauses",
+                "gc_full_pause_ms")}
+        summary["compiles"].update(
+            fenced=bool(compiles.get("fenced")),
+            records=len(compiles.get("records") or []))
     if profile:
         summary["profile"] = {
             "ok": bool(profile.get("ok")),

@@ -17,7 +17,10 @@ Pins the contracts OBSERVABILITY.md's compile sections promise:
   program);
 * the ``(profile)`` bracket measures real per-step device ms on the
   live paged engine and its manifest lands in flight bundles /
-  ``doctor --json`` (schema pinned here).
+  ``doctor --json`` (schema pinned here);
+* a record holds all of a program's host phases (ISSUE 36): nested
+  trace events are booked once, a cache hit's backend wall is a load,
+  threads do not mix, and the collector's full pauses are timed.
 """
 
 import json
@@ -89,10 +92,251 @@ def test_cache_hit_books_retrieval_not_compile():
     assert ledger.cache_misses == 1
     assert ledger.compiles == 1
     assert ledger.steady_compiles == 1
-    # signed saved-time accumulates raw (can be negative)
-    compiles._on_duration("/jax/compilation_cache/compile_time_saved",
-                          -0.001)
-    assert ledger.cache_saved_ms == pytest.approx(-1.0)
+    # the hit's backend wall is the measured load, in a total of its
+    # own; the compile's is the only one in compile_wall_ms_total
+    snapshot = ledger.snapshot()
+    assert snapshot["cache_load_ms_total"] == pytest.approx(2.0)
+    assert snapshot["compile_wall_ms_total"] == pytest.approx(50.0)
+    # neither program was traced here: an executable loaded (or
+    # compiled) for a jaxpr the process already held
+    assert snapshot["programs_traced"] == 0
+    assert [r["trace_ms"] for r in snapshot["records"]] == [0.0, 0.0]
+    # jax's own estimate of what a hit saved is no longer kept
+    compiles._on_duration(
+        "/jax/compilation_cache/compile_time_saved_sec", -0.001)
+    assert ledger.snapshot() == snapshot
+
+
+# ---------------------------------------------------------------- #
+# A record holds all of a program's host phases (synthetic events)
+# ---------------------------------------------------------------- #
+
+class _Clock:
+    """Stands in for the ledger's clock: an event is handed to the
+    listener at the moment its phase ends."""
+
+    def __init__(self, monkeypatch):
+        self.now = 1000.0
+        monkeypatch.setattr(compiles, "_now", lambda: self.now)
+
+    def fire(self, event, end, duration, **kwargs):
+        self.now = 1000.0 + end
+        compiles._on_duration(event, duration, **kwargs)
+
+
+@pytest.fixture()
+def clock(monkeypatch):
+    # A stack of this thread's, left by an earlier test's real jit,
+    # holds intervals on the real clock.
+    compiles._TLS.stack = []
+    yield _Clock(monkeypatch)
+    compiles._TLS.stack = []
+
+
+def _program(clock, start, fun_name="serve_chunk", hit=False):
+    """One program as jax fires it: a jit that calls a jit three
+    times — ten trace events, nested — then lowering with a ``jnp``
+    wrapper traced inside it, then the backend.  Returns the time
+    the call took: 1.0 s of tracing, 0.5 of lowering (0.1 of it the
+    nested trace), 2.0 of backend, and gaps between them."""
+    at = start + 0.01                   # the outer trace began here
+    for call in range(3):
+        inner = at + 0.05 + call * 0.3  # an inner jit: 0.25 s
+        for wrapper in range(2):        # two jnp wrappers inside it
+            clock.fire(compiles.TRACE_EVENT,
+                       inner + 0.05 + wrapper * 0.1 + 0.04, 0.04,
+                       fun_name="_where")
+        clock.fire(compiles.TRACE_EVENT, inner + 0.25, 0.25,
+                   fun_name="paged_decode_append")
+    clock.fire(compiles.TRACE_EVENT, at + 1.0, 1.0, fun_name=fun_name)
+    lowering = at + 1.1                 # 0.1 s of Python in between
+    clock.fire(compiles.TRACE_EVENT, lowering + 0.3, 0.1,
+               fun_name="_take")
+    clock.fire(compiles.LOWER_EVENT, lowering + 0.5, 0.5,
+               fun_name="jit_" + fun_name)
+    if hit:
+        compiles._on_event("/jax/compilation_cache/cache_hits")
+    clock.fire(compiles.BACKEND_EVENT, lowering + 2.6, 2.0,
+               fun_name="jit_" + fun_name)
+    return lowering + 2.6 - start
+
+
+def test_nested_trace_events_are_booked_once(clock):
+    ledger = compiles.install(service="unit")
+    with compiles.label("serve_chunk", "s8"):
+        wall_s = _program(clock, 0.0)
+    (record,) = ledger.snapshot()["records"]
+    # ten trace events sum to 1.99 s; their union is the outer 1.0 s,
+    # plus the 0.1 s traced inside the lowering
+    assert record["trace_ms"] == pytest.approx(1100.0)
+    assert record["lower_ms"] == pytest.approx(400.0)
+    assert record["wall_ms"] == pytest.approx(2000.0)
+    assert record["trace_ms"] + record["lower_ms"] + record["wall_ms"] \
+        <= wall_s * 1e3
+    assert (record["program"], record["signature"],
+            record["fun_name"]) == ("serve_chunk", "s8", "serve_chunk")
+    assert record["ts"] == pytest.approx(1000.0 + wall_s)
+    snapshot = ledger.snapshot()
+    assert snapshot["trace_ms_total"] == pytest.approx(1100.0)
+    assert snapshot["lower_ms_total"] == pytest.approx(400.0)
+    assert snapshot["compile_wall_ms_total"] == pytest.approx(2000.0)
+    assert snapshot["cache_load_ms_total"] == 0.0
+    assert snapshot["programs_traced"] == 1
+
+
+def test_a_hits_backend_wall_is_a_load_with_its_trace_and_lowering(
+        clock):
+    ledger = compiles.install(service="unit")
+    _program(clock, 0.0, hit=True)
+    snapshot = ledger.snapshot()
+    assert snapshot["compiles"] == 0
+    assert snapshot["compile_wall_ms_total"] == 0.0
+    assert snapshot["cache_load_ms_total"] == pytest.approx(2000.0)
+    # a warm start still pays Python for every program it loads
+    assert snapshot["trace_ms_total"] == pytest.approx(1100.0)
+    assert snapshot["programs_traced"] == 1
+    assert ledger.signatures() == []
+
+
+def test_a_trace_nothing_lowers_goes_to_the_totals_not_to_a_record(
+        clock):
+    """``eval_shape``, or a program whose executable is held: the
+    trace is booked under no record when the thread next closes a
+    program, and the next program's record holds only its own."""
+    ledger = compiles.install(service="unit")
+    clock.fire(compiles.TRACE_EVENT, 0.30, 0.05, fun_name="_where")
+    clock.fire(compiles.TRACE_EVENT, 0.50, 0.40, fun_name="shapes")
+    assert ledger.snapshot()["trace_ms_total"] == 0.0   # still open
+    _program(clock, 1.0)
+    snapshot = ledger.snapshot()
+    (record,) = snapshot["records"]
+    assert record["trace_ms"] == pytest.approx(1100.0)
+    assert snapshot["trace_ms_total"] == pytest.approx(1500.0)
+    assert snapshot["programs_traced"] == 1
+    # the stack holds booked time only, an entry a program or trace
+    assert len(compiles._TLS.stack) == 2 * compiles._ENTRY
+    # a program run eagerly inside an open trace is not counted twice
+    # by that trace: 10 s open, 3.7 of them the program's own
+    began = 10.0
+    _program(clock, began + 1.0)
+    clock.fire(compiles.TRACE_EVENT, began + 10.0, 10.0,
+               fun_name="outer")
+    clock.fire(compiles.BACKEND_EVENT, began + 11.0, 0.5,
+               fun_name="jit_outer")
+    outer = ledger.snapshot()["records"][-1]
+    assert outer["fun_name"] == "outer"
+    assert outer["trace_ms"] == pytest.approx(10000.0 - 3700.0)
+
+
+def test_two_threads_stacks_do_not_mix(clock):
+    """Another thread's events arrive between this thread's children
+    and their parent, inside its interval."""
+    import threading
+    ledger = compiles.install(service="unit")
+    clock.fire(compiles.TRACE_EVENT, 0.5, 0.3, fun_name="inner")
+
+    def other():
+        compiles.set_label("other", "t")
+        clock.fire(compiles.TRACE_EVENT, 0.7, 0.6, fun_name="theirs")
+        clock.fire(compiles.BACKEND_EVENT, 0.9, 0.1,
+                   fun_name="jit_theirs")
+
+    thread = threading.Thread(target=other)
+    thread.start()
+    thread.join(timeout=10)
+    assert not thread.is_alive()
+    clock.fire(compiles.TRACE_EVENT, 1.0, 1.0, fun_name="mine")
+    clock.fire(compiles.BACKEND_EVENT, 1.5, 0.4, fun_name="jit_mine")
+    theirs, mine = ledger.snapshot()["records"]
+    assert (theirs["program"], theirs["fun_name"]) == ("other", "theirs")
+    assert theirs["trace_ms"] == pytest.approx(600.0)
+    assert (mine["program"], mine["fun_name"]) == ("unlabeled", "mine")
+    assert mine["trace_ms"] == pytest.approx(1000.0)
+
+
+def test_a_hundred_thousand_nested_trace_events_in_under_a_second(
+        clock):
+    """A serving program fires 10⁴–10⁵ trace events: the handler has
+    to vanish beside the 12–15 s of tracing they time."""
+    import time
+    ledger = compiles.install(service="unit")
+    began = time.perf_counter()
+    for outer in range(100):                # 100 programs' worth
+        base = outer * 10.0
+        for call in range(333):             # children, each with two
+            at = base + call * 0.03
+            clock.fire(compiles.TRACE_EVENT, at + 0.010, 0.005)
+            clock.fire(compiles.TRACE_EVENT, at + 0.020, 0.005)
+            clock.fire(compiles.TRACE_EVENT, at + 0.025, 0.024)
+        clock.fire(compiles.TRACE_EVENT, base + 9.995, 9.995)
+    elapsed = time.perf_counter() - began
+    clock.fire(compiles.BACKEND_EVENT, 1000.0, 0.001)
+    assert elapsed < 1.0, f"100,000 events took {elapsed:.2f} s"
+    snapshot = ledger.snapshot()
+    assert snapshot["trace_ms_total"] == pytest.approx(100 * 9995.0)
+    # 99,900 children went as their parents arrived: what is left is
+    # booked time, an entry for each of the 99 traces nothing lowered
+    # and one for the program the last of them led to
+    assert len(compiles._TLS.stack) == 100 * compiles._ENTRY
+    assert snapshot["programs_traced"] == 1
+
+
+def test_the_stack_is_bounded_where_nothing_is_ever_lowered(clock):
+    ledger = compiles.install(service="unit")
+    for index in range(compiles._STACK_CAP + 10):
+        clock.fire(compiles.TRACE_EVENT, index + 0.5, 0.25)
+    assert len(compiles._TLS.stack) <= 10 * compiles._ENTRY
+    assert ledger.snapshot()["trace_ms_total"] == pytest.approx(
+        (compiles._STACK_CAP + 1) * 250.0)
+    assert ledger.snapshot()["programs_traced"] == 0
+
+
+# ---------------------------------------------------------------- #
+# The collector's pauses
+# ---------------------------------------------------------------- #
+
+def test_full_collections_are_timed_and_young_ones_ignored():
+    import gc
+    found = list(gc.callbacks)
+    ledger = compiles.install(service="unit")
+    assert gc.callbacks == found + [compiles._on_gc]
+    compiles.install(service="again")        # idempotent: one entry
+    assert gc.callbacks == found + [compiles._on_gc]
+    before = ledger.gc_full_pauses    # a collection of the process's
+    gc.collect(0)                     # own may fall anywhere
+    gc.collect(1)
+    assert ledger.gc_full_pauses == before
+    gc.collect()
+    assert ledger.gc_full_pauses == before + 1
+    snapshot = ledger.snapshot()
+    assert snapshot["gc_full_pauses"] == before + 1
+    assert snapshot["gc_full_pause_ms"] > 0
+    compiles.uninstall()
+    assert gc.callbacks == found
+    compiles.uninstall()                     # and again: nothing to do
+    assert gc.callbacks == found
+    gc.collect()
+    assert ledger.gc_full_pauses == before + 1
+
+
+def test_a_long_pause_gets_a_line_with_the_label_then_set(
+        monkeypatch):
+    ledger = compiles.install(service="unit")
+    ticks = iter((50.0, 50.04, 60.0, 60.25))
+    monkeypatch.setattr(compiles, "_now", lambda: next(ticks))
+    with compiles.label("serve_chunk", "s8"):
+        for _ in range(2):
+            compiles._on_gc("start", {"generation": 2})
+            compiles._on_gc("stop", {"generation": 2})
+    snapshot = ledger.snapshot()
+    assert snapshot["gc_full_pauses"] == 2
+    assert snapshot["gc_full_pause_ms"] == pytest.approx(290.0)
+    # 40 ms is in the totals only; 250 ms is a stall with a time on it
+    assert snapshot["pauses"] == [
+        {"ts": 60.25, "ms": pytest.approx(250.0),
+         "program": "serve_chunk", "signature": "s8"}]
+    # records stay what their readers expect: programs
+    assert snapshot["records"] == []
 
 
 def test_steady_compile_fires_flight_capture(tmp_path):
@@ -109,6 +353,17 @@ def test_steady_compile_fires_flight_capture(tmp_path):
     section = bundle["compiles"]
     assert section["compiles_steady_state"] == 1
     assert section["records"][-1]["program"] == "paged_prefill"
+    # the operator's view of the same section: the totals, the
+    # collector's line and a row a program with its phases
+    from aiko_services_tpu.tools import doctor
+    report = doctor.render_report(bundle)
+    assert "host phases: trace 0 ms + lower 0 ms over 0 programs" in report
+    assert "backend compile 40 ms, cache load 0 ms" in report
+    assert "collector: " in report and "full collections" in report
+    row = next(line for line in report.splitlines()
+               if line.lstrip().startswith("paged_prefill"))
+    assert row.split()[:2] == ["paged_prefill", "w64"]
+    assert row.rstrip().endswith("<< STEADY-STATE")
 
 
 # ---------------------------------------------------------------- #
@@ -122,6 +377,12 @@ def test_persistent_cache_counters_via_real_cache(tmp_path):
     ledger = compiles.install(service="cache-unit")
     found = jax.config.jax_compilation_cache_dir
     with compiles.persistent_cache(str(tmp_path / "cache")):
+        # Both halves start as a restart does.  An earlier test of
+        # this worker may have left ``arange(16)``'s program in the
+        # in-memory caches: the cold half then compiled one program,
+        # the directory held one, and the "restart" compiled the
+        # other afresh (the one red test of PR 35's run).
+        jax.clear_caches()
         with compiles.label("unit", "t"):
             jax.jit(lambda x: x * 3 + 1)(jnp.arange(16))
         assert ledger.cache_misses > 0
@@ -133,8 +394,63 @@ def test_persistent_cache_counters_via_real_cache(tmp_path):
         assert ledger.cache_hits > 0
         # retrievals were NOT booked as compiles
         assert ledger.compiles == compiles_cold
+        assert ledger.cache_load_ms > 0
     # the rig scope restores the setting it found
     assert jax.config.jax_compilation_cache_dir == found
+
+
+def test_a_jit_calling_a_jit_is_one_record_within_the_calls_wall():
+    """Real jax on the CPU: the inner jit and every ``jnp`` wrapper
+    fire trace events of their own inside the outer program's, and
+    the record's phases still fit inside the call that paid them."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner(x):
+        return jnp.where(x > 0, jnp.sin(x), jnp.cos(x)) * 2
+
+    @jax.jit
+    def outer(x):
+        for _ in range(3):
+            x = inner(x) + jnp.tanh(x)
+        return x
+
+    from jax._src import monitoring
+
+    argument = jnp.arange(8.0)          # its own small program: before
+    seen = []
+
+    def witness(event, duration, **kwargs):
+        seen.append((event, duration))
+
+    ledger = compiles.install(service="unit")
+    monitoring.register_event_duration_secs_listener(witness)
+    try:
+        began = time.perf_counter()
+        with compiles.label("outer", "s8"):
+            jax.block_until_ready(outer(argument))
+        wall_ms = (time.perf_counter() - began) * 1e3
+    finally:
+        monitoring.unregister_event_duration_listener(witness)
+    (record,) = ledger.snapshot()["records"]
+    assert (record["program"], record["fun_name"]) == ("outer", "outer")
+    assert record["trace_ms"] > 0 and record["lower_ms"] > 0
+    assert record["trace_ms"] + record["lower_ms"] + record["wall_ms"] \
+        <= wall_ms
+    traces = [duration for event, duration in seen
+              if event == compiles.TRACE_EVENT]
+    outermost = max(traces)
+    assert len(traces) >= 10             # nested: a sum counts twice
+    assert sum(traces) > outermost
+    assert record["trace_ms"] == pytest.approx(
+        outermost * 1e3, abs=1.0)
+    assert ledger.snapshot()["programs_traced"] == 1
+    # the same program again: nothing traced, nothing booked
+    jax.block_until_ready(outer(argument))
+    assert len(ledger.snapshot()["records"]) == 1
 
 
 # ---------------------------------------------------------------- #
@@ -323,6 +639,10 @@ def test_compile_cache_ab_warm_beats_cold():
     assert cold.compile_cache["compiles"] > 0
     assert warm.compile_cache["compiles"] < \
         cold.compile_cache["compiles"]
+    # what the cache saved is measured: the warm arm's loads against
+    # the cold arm's compiles
+    assert 0 < warm.compile_cache["cache_load_ms_total"] < \
+        cold.compile_cache["compile_wall_ms_total"]
 
 
 @pytest.mark.slow

@@ -315,7 +315,6 @@ def test_attrib_classifies_post_admission_dispatch():
     by_name = {row.component: row for row in table.rows}
     assert by_name["post_admission_dispatch"].ms == pytest.approx(10.0)
     assert by_name["dispatch"].ms == pytest.approx(10.0)
-    assert "post_admission_dispatch" in attrib.ADMISSION_COMPONENTS
     assert table.within(0.10)
 
 
