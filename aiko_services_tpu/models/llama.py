@@ -1431,7 +1431,7 @@ def decode_chunk_ragged(params, tokens, cache, positions, active,
 
 
 def _serve_scan(step_core, state, cache_state, num_steps, eos_id,
-                sampled, rng_key):
+                sampled, rng_key, first_core=None):
     """Device-resident serving scan: like :func:`_chunk_scan` but the
     per-slot state (token/positions/active/remaining) lives in a device
     ``state`` dict and EOS/budget retirement happens IN-JIT, so the
@@ -1445,7 +1445,13 @@ def _serve_scan(step_core, state, cache_state, num_steps, eos_id,
     counts (slots,), new_state, cache_state)`` where ``counts[s]`` is
     the number of leading entries of ``tokens_out[s]`` actually emitted
     (active only transitions True→False inside a chunk, so emissions
-    are a prefix)."""
+    are a prefix).
+
+    ``first_core`` (same signature) stands in for ``step_core`` at the
+    chunk's first step, which then runs ahead of a scan of the other
+    ``num_steps - 1``: a step that carries work of its own (a prefill
+    slice through the layers beside the slots' rows).  The step around
+    it is the scan's own ``body``: same key split, pick, retirement."""
     if rng_key is None:
         rng_key = jax.random.PRNGKey(0)
     temps, tops = state["temps"], state["tops"]
@@ -1457,11 +1463,10 @@ def _serve_scan(step_core, state, cache_state, num_steps, eos_id,
         drawn = _sample_logits_per_row(logits, key, temps, tops)
         return jnp.where(temps > 0, drawn, greedy)
 
-    def body(carry, _):
+    def body(carry, _, core=step_core):
         token, positions, active, remaining, cache_state, key = carry
         key, step_key = jax.random.split(key)
-        logits, cache_state = step_core(token, cache_state, positions,
-                                        active)
+        logits, cache_state = core(token, cache_state, positions, active)
         next_token = pick(logits[:, -1], step_key)[:, None]
         next_token = jnp.where(active[:, None], next_token, token)
         emitted = active
@@ -1477,9 +1482,18 @@ def _serve_scan(step_core, state, cache_state, num_steps, eos_id,
 
     carry = (state["token"], state["positions"], state["active"],
              state["remaining"], cache_state, rng_key)
-    (token, positions, active, remaining, cache_state, _), \
-        (tokens_out, emits) = jax.lax.scan(body, carry, None,
-                                           length=num_steps)
+    if first_core is None:
+        carry, (tokens_out, emits) = jax.lax.scan(body, carry, None,
+                                                  length=num_steps)
+    else:
+        carry, first = body(carry, None, core=first_core)
+        tokens_out, emits = (out[None] for out in first)
+        if num_steps > 1:
+            carry, rest = jax.lax.scan(body, carry, None,
+                                       length=num_steps - 1)
+            tokens_out, emits = (jnp.concatenate(pair) for pair in zip(
+                (tokens_out, emits), rest))
+    token, positions, active, remaining, cache_state, _ = carry
     counts = emits.astype(jnp.int32).sum(axis=0)
     new_state = dict(state, token=token, positions=positions,
                      active=active, remaining=remaining)

@@ -63,7 +63,11 @@ __all__ = ["Mistral4Config", "CONFIGS", "COUNTERS", "RECURRENT_STATE",
 
 RECURRENT_STATE = False
 #: Counters a serve chunk returns beside its tokens (no extra sync).
-COUNTERS = ("moe_pairs", "moe_pairs_here", "moe_experts_hit")
+#: ``moe_slice_rows_merged``: prefill-slice rows x layers whose
+#: feed-forward rode a decode step's pass over the experts (a mixed
+#: chunk's first step; 0 for a chunk with no slice).
+COUNTERS = ("moe_pairs", "moe_pairs_here", "moe_experts_hit",
+            "moe_slice_rows_merged")
 
 #: What the engine refuses at construction for this module, each with
 #: the piece it lacks: ``(what the model has, {feature: missing})``.
@@ -394,25 +398,29 @@ def _attention_decode(layer, config: Mistral4Config, normed, pool_layer,
 
 
 def _attention_append(layer, config: Mistral4Config, normed, pool_layer,
-                      table, start_index):
+                      table, start_index, attend: bool = True):
     """A prefill slice ``normed (1, T, d)`` of the row whose table is
     ``table (table width,)``: its rows written to the row's blocks
     (whole blocks: slices are block-aligned), and its queries attended
     over the ``start_index`` cached positions, shared blocks included,
-    and over its own rows."""
+    and over its own rows.  ``attend=False`` leaves the rows in the
+    pool and returns no output: all that a slice nobody asks logits of
+    needs from its last layer."""
     c = config
     tokens = normed.shape[1]
     block_size = pool_layer["c"].shape[1]
     positions = start_index + jnp.arange(tokens, dtype=jnp.int32)
-    q_nope, q_rope = _query_heads(layer, c, normed[0], positions)
     rows = _pool_rows(c, *_latent_rows(layer, c, normed[0], positions))
-    q = _absorbed_queries(layer, c, q_nope, q_rope)
     block_ids = jax.lax.dynamic_slice_in_dim(
         table, start_index // block_size, tokens // block_size)
     blocks = rows.reshape(tokens // block_size, block_size, -1)
     use_kernel, interpret = prefill_kernel_mode()
     pool = latent_append(pool_layer["c"], blocks, block_ids,
                          interpret=interpret, use_kernel=use_kernel)
+    if not attend:
+        return None, {"c": pool}
+    q_nope, q_rope = _query_heads(layer, c, normed[0], positions)
+    q = _absorbed_queries(layer, c, q_nope, q_rope)
     out = latent_prefill_attention(
         q, rows, pool, table, start_index, rank=c.kv_lora_rank,
         sm_scale=c.sm_scale, interpret=interpret, use_kernel=use_kernel)
@@ -473,12 +481,17 @@ def _prefill_core(params, tokens, pool, table, start_index,
     start_index = jnp.asarray(start_index, jnp.int32)
     x = _embed_lookup(params, tokens, c.dtype)
     pool = list(pool)
+    last = len(params["layers"]) - 1
     for index, layer in enumerate(params["layers"]):
         normed = rms_norm(x, layer["attn_norm"], c.norm_eps)
+        # Without logits the last layer has only its rows to leave.
+        attend = compute_logits or index < last
         out, pool[index] = _attention_append(layer, c, normed, pool[index],
-                                             table, start_index)
-        x = x + out.astype(x.dtype)
-        x, _ = _feed_forward(layer, c, x)
+                                             table, start_index,
+                                             attend=attend)
+        if attend:
+            x = x + out.astype(x.dtype)
+            x, _ = _feed_forward(layer, c, x)
     return (_head(params, c, x) if compute_logits else None), pool
 
 
@@ -517,48 +530,89 @@ def prefill_append_paged(params, tokens, pool, tables, start_index,
 
 
 def _decode_core(params, token, pool, tables, positions, active,
-                 config: Mistral4Config):
+                 config: Mistral4Config, prefill=None):
     """One token per slot through every layer.  Idle rows write the
     scratch block (``tables`` / ``positions`` already point there).
     Returns ``(logits, pool, int32 (3,) expert counts summed over the
-    layers)``."""
+    layers)``.
+
+    ``prefill = (tokens (1, T), table, start_index)`` carries a prefill
+    slice through the layers beside the slots' rows.  The two meet in
+    no attention (each layer appends and attends the slice's rows, then
+    the slots', by the calls they have alone; the slice's slot is idle
+    among the decode rows) and share the feed-forward: ONE pass over
+    the router, the held experts and the shared expert for the ``T + S``
+    rows, so an expert's matrices are read once for both.  Nobody asks
+    logits of such a slice, so its last layer only leaves its rows in
+    the pool.  The counts stay those of the slots' rows."""
     c = config
     x = _embed_lookup(params, token, c.dtype)
     pool = list(pool)
     counts = jnp.zeros((3,), jnp.int32)
+    if prefill is not None:
+        slice_tokens, table, start_index = prefill
+        if slice_tokens.shape[0] != 1:
+            raise ValueError("one row per prefill slice")
+        width = slice_tokens.shape[1]
+        x_slice = _embed_lookup(params, slice_tokens, c.dtype)
+        rows = jnp.concatenate([jnp.zeros((width,), bool), active])
+    last = len(params["layers"]) - 1
     for index, layer in enumerate(params["layers"]):
+        merge = prefill is not None and index < last
+        if prefill is not None:
+            normed = rms_norm(x_slice, layer["attn_norm"], c.norm_eps)
+            out, pool[index] = _attention_append(
+                layer, c, normed, pool[index], table, start_index,
+                attend=merge)
+            if merge:
+                x_slice = x_slice + out.astype(x_slice.dtype)
         normed = rms_norm(x, layer["attn_norm"], c.norm_eps)
         out, pool[index] = _attention_decode(layer, c, normed, pool[index],
                                              tables, positions)
         x = x + out.astype(x.dtype)
-        x, layer_counts = _feed_forward(layer, c, x, rows=active)
+        if merge:
+            both, layer_counts = _feed_forward(
+                layer, c, jnp.concatenate([x_slice[0, :, None], x]),
+                rows=rows)
+            x_slice, x = both[None, :width, 0], both[width:]
+        else:
+            x, layer_counts = _feed_forward(layer, c, x, rows=active)
         counts = counts + layer_counts
     return _head(params, c, x), pool, counts
 
 
 def _serve(params, state, pool, num_steps, config: Mistral4Config,
-           eos_id, sampled, rng_key):
+           eos_id, sampled, rng_key, prefill=None):
+    """The decode chunk; with ``prefill`` (see :func:`_decode_core`) its
+    first step carries that slice."""
     block_size = pool[0]["c"].shape[1]
     tables = state["tables"]
     slots = tables.shape[0]
     scratch_tables = jnp.zeros_like(tables)
     scratch_positions = jnp.arange(slots, dtype=jnp.int32) % block_size
 
-    def step_core(token, carried, positions, active):
+    def step_core(token, carried, positions, active, prefill=None):
         pool, counts = carried
         write_tables = jnp.where(active[:, None], tables, scratch_tables)
         write_pos = jnp.where(active, positions, scratch_positions)
         logits, pool, step_counts = _decode_core(
-            params, token, pool, write_tables, write_pos, active, config)
+            params, token, pool, write_tables, write_pos, active, config,
+            prefill=prefill)
         return logits, (pool, counts + step_counts)
 
+    merged = 0
+    first_core = None
+    if prefill is not None:
+        first_core = functools.partial(step_core, prefill=prefill)
+        merged = prefill[0].shape[1] * (config.n_layers - 1)
     tokens, emitted, new_state, (pool, counts) = _serve_scan(
         step_core, state, (pool, jnp.zeros((3,), jnp.int32)), num_steps,
-        eos_id, sampled, rng_key)
+        eos_id, sampled, rng_key, first_core=first_core)
     chunk_counters = {
         "moe_pairs": counts[2] * config.moe_top_k,
         "moe_pairs_here": counts[0],
-        "moe_experts_hit": counts[1]}
+        "moe_experts_hit": counts[1],
+        "moe_slice_rows_merged": jnp.int32(merged)}
     return tokens, emitted, new_state, pool, chunk_counters
 
 
@@ -588,10 +642,9 @@ def _mixed_program(params, state, pool, prefill_tokens, prefill_row,
     prefill_row = jnp.asarray(prefill_row, jnp.int32)
     table = jax.lax.dynamic_index_in_dim(state["tables"], prefill_row,
                                          keepdims=False)
-    _, pool = _prefill_core(params, prefill_tokens, pool, table,
-                            prefill_start, config, False)
     return _serve(params, state, pool, num_steps, config, eos_id, sampled,
-                  rng_key)
+                  rng_key, prefill=(prefill_tokens, table,
+                                    jnp.asarray(prefill_start, jnp.int32)))
 
 
 def serve_chunk_mixed(params, state, pool, prefill_tokens, prefill_row,
@@ -599,9 +652,10 @@ def serve_chunk_mixed(params, state, pool, prefill_tokens, prefill_row,
                       eos_id: int = -1, sampled: bool = False,
                       rng_key=None, lora_shared=None,
                       prefill_kv_limit=None):
-    """One prefill slice of the slot ``prefill_row``, then the decode
-    chunk, as one program (``prefill_kv_limit``: see
-    :func:`prefill_append_paged`)."""
+    """One prefill slice of the slot ``prefill_row`` and the decode
+    chunk as one program, the slice riding the chunk's first step
+    through the experts (:func:`_decode_core`; ``prefill_kv_limit``:
+    see :func:`prefill_append_paged`)."""
     del prefill_kv_limit
     if lora_shared is not None:
         raise NotImplementedError("no LoRA path in this model module")

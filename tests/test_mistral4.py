@@ -10,6 +10,7 @@ here reaches past it: both YaRN's blended frequencies and the query
 scale ``1 + beta ln(1 + floor(p / 64))`` act."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -37,9 +38,9 @@ def make_server(**kwargs):
     return PagedContinuousServer(**options)
 
 
-def forward_logits(params, tokens):
+def forward_logits(params, tokens, config=F32):
     return np.asarray(mistral4.forward(
-        params, jnp.asarray([tokens], jnp.int32), F32))[0]
+        params, jnp.asarray([tokens], jnp.int32), config))[0]
 
 
 def greedy(server, prompt, served):
@@ -191,6 +192,117 @@ def test_slices_a_prefix_hit_and_decode_give_the_forwards_logits(kernels):
         assert int(chunk_counters["moe_pairs_here"]) == 3 * 2 * 4
 
 
+# --- a slice riding the chunk's first step ------------------------------- #
+
+#: Three layers, so that "every layer but the last" is more than one.
+F32_L3 = dataclasses.replace(F32, n_layers=3)
+
+
+@functools.partial(jax.jit, static_argnames=("num_steps", "eos_id",
+                                             "sampled"))
+def _slice_then_chunk(params, state, pool, tokens, row, start, key,
+                      num_steps, eos_id, sampled):
+    """The mixed program as it was before the slice rode the first step:
+    the slice to its end, then the chunk."""
+    _, pool = mistral4._prefill_core(params, tokens, pool,
+                                     state["tables"][row], start, F32_L3,
+                                     False)
+    return mistral4._serve(params, state, pool, num_steps, F32_L3, eos_id,
+                           sampled, key)
+
+
+@functools.lru_cache(maxsize=None)
+def _mixed_scene(width):
+    """Five slots over a pool of seeded rows: idle, live, PREFILLING
+    (its first slice cached, its second the mixed one), live (greedy:
+    the row the test retires by EOS), live with one token of budget
+    left.  Returns ``(params, state, pool, the slice's tokens, what
+    slot 3 emits first)``."""
+    params = mistral4.init_params(F32_L3, jax.random.PRNGKey(21))
+    rng = np.random.default_rng(width)
+    rows = rng.normal(size=(3, 60, 16, 128)).astype(np.float32)
+    rows[..., 48:] = 0
+    tables = np.zeros((5, 16), np.int32)
+    tables[1:, :14] = 1 + rng.permutation(59)[:56].reshape(4, 14)
+    # Slot 3's context is its prompt's own rows, so that the forward
+    # names the token its first step emits.
+    asked = rng.integers(1, 1024, 48).astype(np.int32)
+    pool = [{"c": jnp.asarray(layer)} for layer in rows]
+    _, pool = mistral4.prefill_append_paged(
+        params, jnp.asarray(asked[None]), pool, jnp.asarray(tables[3:4]),
+        jnp.int32(0), F32_L3, compute_logits=False)
+    first = int(forward_logits(params, asked[:37], F32_L3)[-1].argmax())
+    state = dict(
+        token=jnp.asarray([[0], [5], [0], [asked[36]], [7]], jnp.int32),
+        positions=jnp.asarray([0, 69, 0, 36, 89], jnp.int32),
+        active=jnp.asarray([False, True, False, True, True]),
+        remaining=jnp.asarray([0, 20, 0, 20, 1], jnp.int32),
+        temps=jnp.asarray([0.0, 0.8, 0.0, 0.0, 0.7]),
+        tops=jnp.asarray([1.0, 0.9, 1.0, 1.0, 0.95]),
+        adapter_ids=jnp.zeros((5,), jnp.int32),
+        tables=jnp.asarray(tables))
+    tokens = jnp.asarray(rng.integers(1, 1024, (1, width)), jnp.int32)
+    return params, state, pool, tokens, first
+
+
+#: (kernels, slice width, steps, sampled): the jnp forms over every
+#: combination, the interpreted kernels (half a minute of compiling each
+#: on the CPU) at both widths.
+MIXED_CASES = [("reference", width, steps, sampled)
+               for width in (16, 64) for steps in (1, 2, 8)
+               for sampled in (False, True)] + [
+    ("interpret", 16, 2, True), ("interpret", 64, 8, False)]
+
+
+@pytest.mark.parametrize(
+    "kernels,width,num_steps,sampled", MIXED_CASES, indirect=["kernels"],
+    ids=[f"{k}-w{w}-s{n}-{'sampled' if d else 'greedy'}"
+         for k, w, n, d in MIXED_CASES])
+def test_a_slice_riding_the_first_step_is_the_slice_then_the_chunk(
+        kernels, width, num_steps, sampled):
+    """The mixed program against the slice followed by the chunk on the
+    same inputs: tokens, emit counts, state, pool (the rows both write;
+    to rounding where a hidden row went through a pass of another
+    height) and the three counters of the decode rows.  Slot 3 emits
+    the EOS id in the merged step itself; slot 4's budget ends there."""
+    params, state, pool, tokens, eos_id = _mixed_scene(width)
+    key = jax.random.PRNGKey(17)
+    copy = functools.partial(jax.tree.map, jnp.copy)
+    row, start = jnp.int32(2), jnp.int32(width)
+    wanted = _slice_then_chunk(params, state, copy(pool), tokens, row,
+                               start, key, num_steps=num_steps,
+                               eos_id=eos_id, sampled=sampled)
+    got = mistral4.serve_chunk_mixed(
+        params, state, copy(pool), tokens, row, start, num_steps, F32_L3,
+        eos_id=eos_id, sampled=sampled, rng_key=key)
+    for name, mine, theirs in zip(("tokens", "emitted"), got, wanted):
+        np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs),
+                                      err_msg=name)
+    emitted = np.asarray(got[1]).tolist()
+    assert emitted[:3] == [0, num_steps, 0] and emitted[3:] == [1, 1]
+    assert np.asarray(got[0])[3, 0] == eos_id
+    for name in wanted[2]:
+        np.testing.assert_array_equal(np.asarray(got[2][name]),
+                                      np.asarray(wanted[2][name]),
+                                      err_msg=name)
+    slice_blocks = np.asarray(state["tables"])[2, width // 16:width // 8]
+    for before, mine, theirs in zip(pool, got[3], wanted[3]):
+        np.testing.assert_allclose(np.asarray(mine["c"]),
+                                   np.asarray(theirs["c"]), atol=1e-5,
+                                   rtol=0)
+        # The slice left its rows in every layer, the last included.
+        assert not np.array_equal(
+            np.asarray(mine["c"])[slice_blocks],
+            np.asarray(before["c"])[slice_blocks])
+    counters, old = got[4], wanted[4]
+    for name in ("moe_pairs", "moe_pairs_here", "moe_experts_hit"):
+        assert int(counters[name]) == int(old[name]), name
+    assert int(counters["moe_pairs"]) == (num_steps + 2) * 3 * 4
+    assert int(counters["moe_slice_rows_merged"]) == width * 2
+    assert int(old["moe_slice_rows_merged"]) == 0
+    assert sorted(counters) == sorted(mistral4.COUNTERS)
+
+
 @pytest.mark.parametrize("lengths", [(100, 70, 133, 65), (81, 97, 64, 190)])
 def test_slices_hits_and_reused_slots_serve_the_forward(lengths, kernels):
     """Requests on both sides of the slice width, two of them asking
@@ -221,6 +333,12 @@ def test_slices_hits_and_reused_slots_serve_the_forward(lengths, kernels):
     committed = sum(item.max_new_tokens for item in requests)
     assert counters["moe_pairs"] == committed * 2 * 4
     assert counters["moe_pairs_here"] == counters["moe_pairs"]
+    # Every mixed slice (16 or 32 tokens here) rode a decode step
+    # through the one layer before the last.
+    assert 16 * counters["prefill_slices_mixed"] \
+        <= counters["moe_slice_rows_merged"] \
+        <= 32 * counters["prefill_slices_mixed"]
+    assert counters["prefill_slices_mixed"] > 0
     assert counters["prefill_key_blocks"] > 0
     assert counters["decode_blocks_read"] > 0
     assert (server.decode_attention_path, server.prefill_attention_path) \

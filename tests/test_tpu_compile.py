@@ -450,11 +450,12 @@ def test_latent_kernels_compile_for_v5e(one_chip, as_on_tpu):
 
 def test_latent_decode_reads_rows_and_expands_nothing(one_chip, as_on_tpu):
     """The decode and the mixed program of the latent module at the
-    published widths (two layers, four held experts): one scan each;
-    in its body a ``latent_append`` and a ``closed_call`` a layer and
-    no copy of the pool; and nowhere an array with the context on an
-    axis — no expanded key or value of a slot's table (16,896
-    positions) exists, per head or otherwise."""
+    published widths (two layers, four held experts): one scan each
+    (the mixed program's over the steps behind its first, which
+    carries the slice); in its body a ``latent_append`` and a
+    ``closed_call`` a layer and no copy of the pool; and nowhere an
+    array with the context on an axis — no expanded key or value of a
+    slot's table (16,896 positions) exists, per head or otherwise."""
     from aiko_services_tpu.models import mistral4
     g = LATENT_CELL
     config = mistral4.Mistral4Config(
@@ -480,9 +481,9 @@ def test_latent_decode_reads_rows_and_expands_nothing(one_chip, as_on_tpu):
     scalar = S((), jnp.int32)
     decode = mistral4.serve_chunk_paged.lower(
         params, state, pool, 2, config).compile()
-    mixed = mistral4._mixed_program.lower(
-        params, state, pool, S((1, 256), jnp.int32), scalar, scalar, 2,
-        config, -1, False, None).compile()
+    mixed, cell_mixed = (mistral4._mixed_program.lower(
+        params, state, pool, S((1, 256), jnp.int32), scalar, scalar, steps,
+        config, -1, False, None).compile() for steps in (3, 2))
     for compiled in (decode, mixed):
         text = compiled.as_text()
         assert text.count(" while(") == 1
@@ -495,9 +496,25 @@ def test_latent_decode_reads_rows_and_expands_nothing(one_chip, as_on_tpu):
                              % (context, table, g["bs"]), text)
         # What a step holds beside weights and pool: megabytes.
         assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
-    # A slice's logits are not computed (the prompt's last token is
-    # the first decode step's), so the LAST layer's attention feeds
-    # nothing and XLA drops it: its rows are appended, and the prefill
-    # kernel runs in the layers before it.
-    assert len(_custom_call_lines(mixed.as_text(),
-                                  "latent_prefill_call")) == 1
+    # The cell's own mixed program (``chunk_steps`` 2): the scan behind
+    # the first step has one trip and XLA inlines it, so the program is
+    # two steps in line.  A slice's logits are not computed (the
+    # prompt's last token is the first decode step's), so its LAST
+    # layer appends its rows and attends nothing: the prefill kernel
+    # runs in the layers before it.  The held experts' matmuls see the
+    # slice's 256 rows only together with the first step's 64: three
+    # fusions a merged layer, and the slots' rows alone in that step's
+    # last layer and in the second step.
+    # Outside a scan: (decode kernels, appends, layers of 64 rows).
+    for compiled, inline in ((mixed, (2, 4, 1)), (cell_mixed, (4, 6, 3))):
+        text = compiled.as_text()
+        assert len(_custom_call_lines(text, "latent_prefill_call")) == 1
+        entry = text[text.index("\nENTRY "):]
+        wide = {rows: len(re.findall(
+            r" = (?:f32|bf16)\[%d,4,2048\]\S* fusion\(" % rows, entry))
+            for rows in (256, 320, 64)}
+        assert wide[256] == 0 and wide[320] == 2, wide
+        assert (len(_custom_call_lines(entry, "closed_call")),
+                len(_custom_call_lines(entry, "latent_append"))) \
+            == inline[:2] and wide[64] >= inline[2], wide
+    assert cell_mixed.as_text().count(" while(") == 0
