@@ -40,10 +40,12 @@ def gaps_of(logits: np.ndarray, served) -> np.ndarray:
 
 
 def served_against_reference(cell, good, seed: int):
+    """``[(short name, what it is, value, limit)]``: the numbers
+    compared, each beside its limit."""
     spec = cell.config["check"]
     chosen = sample(good, seed, spec["sample"])
     if not chosen:
-        return [("requests available to compare", 1, 0)]
+        return [("compared", "requests available to compare", 1, 0)]
     sequences, spans = [], []
     for record in chosen:
         prompt = np.asarray(record.request.prompt, np.int32)
@@ -62,14 +64,15 @@ def served_against_reference(cell, good, seed: int):
           f"reference's own argmax; gap mean {gaps.mean():.5f} "
           f"p99 {np.quantile(gaps, 0.99):.5f} max {gaps.max():.5f}",
           flush=True)
-    verdicts = [("mean gap of the served tokens below the reference's "
-                 "best logit", float(gaps.mean()), spec["mean_gap_limit"])]
+    verdicts = [("mean_gap", "mean gap of the served tokens below the "
+                 "reference's best logit", float(gaps.mean()),
+                 spec["mean_gap_limit"])]
     if "gap_limit" in spec:
         # Not every configuration can hold the widest gap to a limit:
         # where experts are routed, a near-tie between the second and
         # third expert flips under rounding and moves one token's
         # logits by whole units in sound runs too (PERF.md section 2).
-        verdicts.append(("widest gap of a served token below the "
-                         "reference's best logit", float(gaps.max()),
-                         spec["gap_limit"]))
+        verdicts.append(("widest_gap", "widest gap of a served token "
+                         "below the reference's best logit",
+                         float(gaps.max()), spec["gap_limit"]))
     return verdicts

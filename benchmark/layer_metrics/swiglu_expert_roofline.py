@@ -11,31 +11,30 @@ def read(run):
     z = run.sizes
     if run.trace is None or not z.get("experts") or "rank" not in z:
         return None
-    x, ops = run.xplane, run.trace["ops"]
+    ops = run.trace["ops"]
     skip = re.compile(SPEC["skip_pattern"])
     rows_of = re.compile(SPEC["result_rows"])
     names = dict(z, rows_down=z["experts"] * z["f"])
     up = SPEC["up_operand"].format(**names)
     downs = [operand.format(**names) for operand in SPEC["down_operands"]]
-    loops = [(start, start + duration) for _, start, duration
-             in x.matching(ops, SPEC["loop_pattern"])]
-    decoding = {id(event) for event in x.inside(ops, loops)}
     # Choices that fell on held experts, of a layer's rows: counted by
-    # the program for the decode steps of the traced span; for a
-    # prefill slice the routes' expectation.
+    # the program for the decode steps of the traced span (a call on
+    # exactly the slots' rows, in a loop or in line); for a prefill
+    # slice, alone or with a step's rows behind it, the routes'
+    # expectation.
+    decode_rows = run.cell.traffic["slots"]
     steps = run.traced.get("decode_steps", 0) * z["expert_layers"]
     counted = run.traced.get("moe_pairs_here", 0) / steps if steps \
         else None
     expected = z["top_k"] * z["experts"] / z["experts_total"]
     least = spent = 0.0
-    for event in ops:
-        name, _, duration = event
+    for name, _, duration in ops:
         rows = rows_of.match(name)
         if skip.match(name) or not rows:
             continue
         rows = int(rows.group(1))
         pairs = rows * expected
-        if id(event) in decoding and counted is not None:
+        if rows == decode_rows and counted is not None:
             pairs = counted
         matrices = name.count(up)
         if matrices:

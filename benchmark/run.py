@@ -46,8 +46,11 @@ if str(ROOT) not in sys.path:
 from benchmark import (cells, check, layers, stats,  # noqa: E402
                        traffic as traffic_mod)
 
-#: Polling period of the load generator (seconds).
+#: Polling period of the open-loop load generator (seconds).
 TICK = 0.001
+#: How often a sleeping closed-loop generator looks whether the event
+#: loop is still alive (seconds).
+ALIVE_S = 0.25
 WARM_TIMEOUT_S = 1100.0
 
 
@@ -224,15 +227,32 @@ def warm_up(served, mix, vocab, seed):
 
 
 def offer_load(served, cell, mix_stream, seconds, tracer):
-    """Ramp, window and drain.  Returns (records, arrivals, t0)."""
+    """Ramp, window and drain.  Returns (records, arrivals, (t0, t1),
+    marks)."""
     mix = cell.traffic
-    ramp, drain = mix["ramp_s"], mix["drain_s"]
-    closed = mix["loop"] == "closed"
     source = mix_stream.requests()
-    records, arrivals, inflight = [], [], []
+    records, arrivals = [], []
     start = time.monotonic()
-    t0, t1 = start + ramp, start + ramp + seconds
+    t0 = start + mix["ramp_s"]
+    t1 = t0 + seconds
     marks = {}
+    tracer.start(t0)
+    offer = closed_loop if mix["loop"] == "closed" else open_loop
+    offer(served, mix, source, records, arrivals, tracer, marks,
+          (start, t0, t1))
+    tracer.finish()
+    return records, arrivals, (t0, t1), marks
+
+
+def open_loop(served, mix, source, records, arrivals, tracer, marks,
+              times):
+    """Arrivals on the mix's schedule: the generator wakes every
+    ``TICK`` (what ``gen_late_p90_ms`` reads) and sends what is due."""
+    start, t0, t1 = times
+    drain = mix["drain_s"]
+    # Nothing reads ``inflight`` since the closed loop left this
+    # function; its walk stays, as part of what a tick costs.
+    inflight = []
 
     def launch(request, due):
         record = Record(request, due)
@@ -240,11 +260,7 @@ def offer_load(served, cell, mix_stream, seconds, tracer):
         served.send(record, arrivals)
         inflight.append(record)
 
-    tracer.start(t0)
-    upcoming = None if closed else next(source)
-    if closed:
-        for _ in range(mix["clients"]):
-            launch(next(source), time.monotonic())
+    upcoming = next(source)
     while True:
         now = time.monotonic()
         if "t0" not in marks and now >= t0:
@@ -255,9 +271,7 @@ def offer_load(served, cell, mix_stream, seconds, tracer):
             marks["ledger1"] = tracer.ledger()
         for record in [r for r in inflight if r.future.done]:
             inflight.remove(record)
-            if closed and now < t1 + drain:
-                launch(next(source), time.monotonic())
-        while not closed and start + upcoming.due <= now:
+        while start + upcoming.due <= now:
             if now < t1 + drain:
                 launch(upcoming, start + upcoming.due)
             upcoming = next(source)
@@ -267,13 +281,78 @@ def offer_load(served, cell, mix_stream, seconds, tracer):
             if not pending or now >= t1 + drain:
                 break
         served.alive()
-        if closed:
-            time.sleep(TICK)
-        else:
-            time.sleep(max(0.0, min(TICK, start + upcoming.due
-                                    - time.monotonic())))
-    tracer.finish()
-    return records, arrivals, (t0, t1), marks
+        time.sleep(max(0.0, min(TICK, start + upcoming.due
+                                - time.monotonic())))
+
+
+def closed_loop(served, mix, source, records, arrivals, tracer, marks,
+                times):
+    """``clients`` callers, each sending its next request the moment
+    its last completes: the launch runs FROM the completion, on the
+    thread that delivered the response, so nothing polls.  This
+    thread only sleeps to the window's edges to take the marks, and
+    then waits for the window's requests to finish."""
+    _, t0, t1 = times
+    stop = t1 + mix["drain_s"]
+    finished = threading.Event()
+
+    def launch():
+        record = Record(next(source), time.monotonic())
+        records.append(record)
+        served.send(record, arrivals)
+        future = record.future
+        future._event = Completion(future._event, completed)
+        if future.done:
+            # Resolved between the send and the line above.
+            future._event.set()
+
+    def completed():
+        try:
+            if time.monotonic() < stop:
+                launch()
+        except Exception:  # noqa: BLE001 - alive() must hear of it
+            served.loop_errors.append(traceback.format_exc())
+        finished.set()
+
+    def sleep_until(moment):
+        while (left := moment - time.monotonic()) > 0:
+            served.alive()
+            time.sleep(min(left, ALIVE_S))
+
+    for _ in range(mix["clients"]):
+        launch()
+    sleep_until(t0)
+    marks["t0"] = served.counters()
+    marks["ledger0"] = tracer.ledger()
+    sleep_until(t1)
+    marks["t1"] = served.counters()
+    marks["ledger1"] = tracer.ledger()
+    while time.monotonic() < stop:
+        finished.clear()
+        if all(r.future.done for r in list(records)
+               if t0 <= r.due < t1):
+            break
+        served.alive()
+        finished.wait(ALIVE_S)
+    stop = 0.0      # the callers still out send nothing more
+
+
+class Completion:
+    """Stands in for the event an ``InferFuture`` sets when it
+    resolves: setting it also tells the caller, once, on the thread
+    that resolved the future (the client has no done-callback)."""
+
+    def __init__(self, event, callback):
+        self.event, self.callback = event, callback
+
+    def set(self):
+        self.event.set()
+        callback, self.callback = self.callback, None
+        if callback is not None:
+            callback()
+
+    def __getattr__(self, name):
+        return getattr(self.event, name)
 
 
 class Tracer:
@@ -421,7 +500,7 @@ def readings(cell, args, program_name, program_config) -> int:
         print(f"reading: seed {seed} bits {bits}: due {len(due)} "
               f"finished {len(good)}; "
               + "; ".join(f"{what.split(' of ')[0]} {value:.5f}"
-                          for what, value, _ in verdicts), flush=True)
+                          for _, what, value, _ in verdicts), flush=True)
     return 0
 
 
@@ -512,22 +591,25 @@ def run_cell(args) -> int:
                       for r in good)
     failed = len(due) - len(good)
     verdicts = [
-        ("requests due in the window that failed or did not finish",
-         failed, 0),
-        ("compiles inside the window", window_compiles, 0),
-        ("streamed partials equal final tokens, full length",
-         int(not streamed_ok), 0),
-        ("event loop stopped cleanly", int(not stopped), 0),
+        ("failed", "requests due in the window that failed or did not "
+         "finish", failed, 0),
+        ("window_compiles", "compiles inside the window",
+         window_compiles, 0),
+        ("partials_differ", "streamed partials equal final tokens, full "
+         "length", int(not streamed_ok), 0),
+        ("loop_left_running", "event loop stopped cleanly",
+         int(not stopped), 0),
     ]
     if not args.rehearsal:
-        verdicts.append(("attention paths not 'kernel'",
-                         sum(p != "kernel" for p in paths), 0))
+        verdicts.append(("paths_not_kernel", "attention paths not "
+                         "'kernel'", sum(p != "kernel" for p in paths),
+                         0))
     log(f"checking {len(good)} served requests against the reference")
     check_began = time.monotonic()
     verdicts += check.served_against_reference(cell, good, args.seed)
     log(f"reference check took {time.monotonic() - check_began:.1f} s")
     correct = True
-    for what, value, limit in verdicts:
+    for _, what, value, limit in verdicts:
         ok = value <= limit
         correct &= ok
         print(f"check: {what}: {value} (limit {limit}) "
@@ -553,7 +635,14 @@ def run_cell(args) -> int:
                                     "unit": units[name]}
                              for name in units if values.get(name)
                              is not None}
+    # Each number compared beside its limit: last in the line, and
+    # the last lines of standard error.
+    result["checks"] = {key: {"value": value, "limit": limit}
+                        for key, _, value, limit in verdicts}
     print(json.dumps(result), flush=True)
+    for key, _, value, limit in verdicts:
+        print(f"check {key}: {value} (limit {limit})", file=sys.stderr,
+              flush=True)
     return 0
 
 

@@ -5,6 +5,8 @@ The cell driven here (``tiny.chat``) lives wholly under
 mix, found by name like any other.  That is the proof that a cell is
 added as files.  Three runs are started together (each a process of its
 own, as the driver starts them) and every test reads their results.
+
+What ``BENCHMARK.json`` itself must satisfy is ``test_root_file.py``.
 """
 
 import copy
@@ -18,10 +20,11 @@ import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 DATA = "tests/benchmark/data/BENCHMARK.json"
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
 
 sys.path.insert(0, str(ROOT))
 from benchmark import cells  # noqa: E402
+from tests.benchmark.listed import last_json_line  # noqa: E402
 
 #: Breaks the timed path underneath the harness: every decode chunk's
 #: tokens are altered where they are produced.
@@ -74,7 +77,7 @@ def runs(tmp_path_factory):
 def _last_line(runs, name):
     code, output = runs[name]
     assert code == 0, output[-3000:]
-    return json.loads(output.strip().splitlines()[-1])
+    return last_json_line(output)
 
 
 def test_rehearsal_prints_the_contract_line(runs):
@@ -99,6 +102,16 @@ def test_every_number_compared_is_printed_beside_its_limit(runs):
               if row.startswith("check: ") and "(limit " in row]
     assert len(checks) >= 5 and all(row.endswith(" ok")
                                     for row in checks), checks
+    # Each again under a short name: the last key of the result line,
+    # and the last lines of standard error.
+    line = _last_line(runs, "plain")
+    assert next(reversed(line)) == "checks"
+    assert len(line["checks"]) == len(checks)
+    last = output.strip().splitlines()[-len(checks):]
+    for row, (name, compared) in zip(last, line["checks"].items()):
+        assert row == (f"check {name}: {compared['value']} "
+                       f"(limit {compared['limit']})")
+        assert compared["value"] <= compared["limit"]
 
 
 def test_traced_run_reports_the_per_layer_metrics(runs):
@@ -120,9 +133,9 @@ def test_a_median_recorded_per_layer_is_in_the_traced_line(runs):
     assert line["metrics"]["open_req_p50_ms"]["unit"] == "ms"
 
 
-def test_an_end_to_end_metric_is_kept_to_the_cells_it_names():
-    chat = cells.Cell(ROOT, "BENCHMARK.json", "mistral7b.chat")
-    moe = cells.Cell(ROOT, "BENCHMARK.json", "mixtral8x7b.chat")
+def test_an_end_to_end_metric_is_kept_to_the_cells_it_names(listed):
+    chat = listed.cell("mistral7b.chat")
+    moe = listed.cell("mixtral8x7b.chat")
     judged = lambda cell: {m["name"] for m in cell.end_to_end}
     recorded = lambda cell: {m["name"] for m, _, _ in cell.per_layer}
     assert "req_p50_ms" in judged(moe) - judged(chat)
@@ -134,6 +147,8 @@ def test_an_end_to_end_metric_is_kept_to_the_cells_it_names():
 def test_broken_timed_path_is_not_correct(runs):
     line = _last_line(runs, "broken")
     assert line["correct"] is False
+    assert any(compared["value"] > compared["limit"]
+               for compared in line["checks"].values())
     _, output = runs["broken"]
     assert "FAIL" in output
 
@@ -174,21 +189,3 @@ def test_a_metric_without_a_file_fails_loudly(tmp_path, capsys):
     with pytest.raises(SystemExit):
         cells.Cell(ROOT, str(path), "tiny.chat")
     assert "no_such_metric" in capsys.readouterr().out
-
-
-@pytest.mark.parametrize("benchmark_file", ["BENCHMARK.json", DATA])
-def test_names_units_and_files(benchmark_file):
-    bench = json.loads((ROOT / benchmark_file).read_text())
-    assert cells.check_names(bench) == []
-    assert set(bench) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
-    assert any(m["name"] == "setup_s" for m in bench["end_to_end"])
-    for workload in bench["workloads"]:
-        cell = cells.Cell(ROOT, benchmark_file, workload["name"])
-        assert cell.chips in (1, 4) and len(workload["why"]) <= 200
-        assert cell.per_layer, "a cell reports a per-layer metric"
-        moved = {m["name"] for m in cell.end_to_end}
-        assert all(m["moves"] in moved for m, _, _ in cell.per_layer)
-    for config in bench["configs"]:
-        held = json.loads((ROOT / config["file"]).read_text())
-        assert sorted(held["reduced"]) == sorted(config["reduced"])

@@ -6,9 +6,8 @@ layer's experts against the uncut reference layer, the 4-bit control,
 the chip-size configuration's arithmetic from its own keys, the traffic
 mix's multiset, the new per-layer readers on hand-built traces, a
 rehearsal of the tiny twin of ``mistralsmall4.docs``
-(``data/BENCHMARK_mistral4.json``), and the chip-size file that lists
-the cell's own per-layer metrics until the root file can
-(``benchmark/BENCHMARK_latent.json``).
+(``data/BENCHMARK_mistral4.json``).  What ``BENCHMARK.json`` lists for
+the cell is ``test_root_cells.py``.
 
 Tolerance 1e-4 on float32 logits of standard deviation 1, as in
 ``test_reference.py`` (measured 2e-5): the reference is the EXPANDED
@@ -36,14 +35,9 @@ from benchmark import traffic as traffic_mod  # noqa: E402
 from benchmark import xplane  # noqa: E402
 from benchmark.builders import mistral4 as builder  # noqa: E402
 from benchmark.reference import mla_moe as reference  # noqa: E402
+from tests.benchmark.listed import last_json_line  # noqa: E402
 
 DATA = "tests/benchmark/data/BENCHMARK_mistral4.json"
-#: The root's cell with the new per-layer metrics behind the root's own.
-LATENT = "benchmark/BENCHMARK_latent.json"
-HYBRID = "benchmark/BENCHMARK_hybrid.json"
-CELL = "mistralsmall4.docs"
-NEW = {"latent_prefill_roofline", "swiglu_expert_roofline",
-       "latent_decode_roofline", "moe_pairs_per_expert"}
 PUBLISHED = json.loads((ROOT / "benchmark/configs/"
                         "mistral-small-4-119b-l4e32.json").read_text())
 TWIN = json.loads((ROOT / "tests/benchmark/data/configs/"
@@ -437,7 +431,7 @@ def rehearsal(tmp_path_factory):
         cwd=ROOT, env=env, text=True, timeout=900,
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
     assert done.returncode == 0, done.stdout[-3000:]
-    return done.stdout, json.loads(done.stdout.strip().splitlines()[-1])
+    return done.stdout, last_json_line(done.stdout)
 
 
 def test_the_twin_cell_rehearses_correct_with_its_counters(rehearsal):
@@ -456,81 +450,3 @@ def test_the_twin_cell_rehearses_correct_with_its_counters(rehearsal):
     assert not {"latent_decode_roofline", "latent_prefill_roofline",
                 "swiglu_expert_roofline", "decode_attn_roofline"} \
         & set(metrics)
-
-
-def test_names_units_and_files_of_the_twin_and_the_new_entries():
-    twin = json.loads((ROOT / DATA).read_text())
-    assert cells.check_names(twin) == []
-    cell = cells.Cell(ROOT, DATA, "tiny.docs")
-    assert NEW <= {metric["name"] for metric, _, _ in cell.per_layer}
-    cell = cells.Cell(ROOT, "BENCHMARK.json", CELL)
-    assert {m["name"] for m in cell.end_to_end} == {
-        "ttft_p50_ms", "tpot_p50_ms", "out_tokens_per_s", "setup_s"}
-    assert (cell.chips, cell.config["builder"], cell.config["reference"]) \
-        == (1, "mistral4", "mla_moe")
-    # The fourteen accepted metrics that apply to the cell.
-    assert len(cell.per_layer) == 14
-    for metric in json.loads((ROOT / LATENT).read_text())["per_layer"]:
-        described = json.loads(
-            (ROOT / "benchmark" / "layer_metrics"
-             / f"{metric['name']}.json").read_text())
-        assert (described["layer"], described["unit"],
-                described["moves"], described["source"]) == (
-            metric["layer"], metric["unit"], metric["moves"],
-            metric["source"])
-        if metric["name"] in NEW:
-            assert metric["workloads"] == [CELL]
-
-
-def test_the_chip_size_file_is_the_roots_cell_plus_the_new_metrics():
-    """``BENCHMARK.json`` cannot list the new metrics (see
-    ``test_nemotron_h.py``: later entries go at the end, and two tests
-    pin PR 24's seven as the last seven), so
-    ``benchmark/BENCHMARK_latent.json`` holds the root's cell with the
-    root's entries and the four behind them, as
-    ``BENCHMARK_hybrid.json`` does for its cell: ``run.py
-    --benchmark`` reads them on the chip until a ``benchmark`` PR
-    folds both files into the root."""
-    root = json.loads((ROOT / "BENCHMARK.json").read_text())
-    latent = json.loads((ROOT / LATENT).read_text())
-    assert cells.check_names(latent) == []
-    assert not (NEW - {"moe_pairs_per_expert"}) \
-        & {m["name"] for m in root["per_layer"]}
-    for key in ("command", "paths", "run_seconds", "end_to_end"):
-        assert latent[key] == root[key]
-    assert latent["workloads"] == [root["workloads"][-1]]
-    assert latent["workloads"][0]["name"] == CELL
-    assert latent["configs"] == [root["configs"][-1]]
-    assert latent["configs"][0]["name"] == latent["workloads"][0]["config"]
-    assert latent["per_layer"][:-4] == root["per_layer"]
-    assert {m["name"] for m in latent["per_layer"][-4:]} == NEW
-    # The accepted reader listed for this cell is the hybrid file's
-    # entry but for the cell it names.
-    hybrid = {m["name"]: m for m in json.loads(
-        (ROOT / HYBRID).read_text())["per_layer"]}
-    listed = latent["per_layer"][-1]
-    assert listed["name"] == "moe_pairs_per_expert"
-    assert {k: v for k, v in listed.items() if k != "workloads"} == \
-        {k: v for k, v in hybrid[listed["name"]].items()
-         if k != "workloads"}
-    cell = cells.Cell(ROOT, LATENT, CELL)
-    root_cell = cells.Cell(ROOT, "BENCHMARK.json", CELL)
-    assert [m["name"] for m, _, _ in cell.per_layer] == \
-        [m["name"] for m, _, _ in root_cell.per_layer] + \
-        [m["name"] for m in latent["per_layer"][-4:]]
-    # The root gained one configuration and one cell, at the ends.
-    assert [c["name"] for c in root["configs"]][-1] == \
-        "mistral-small-4-119b-l4e32"
-    assert [w["name"] for w in root["workloads"]][-1] == CELL
-
-
-def test_the_twins_benchmark_file_holds_the_latent_files_entries():
-    bench = json.loads((ROOT / DATA).read_text())
-    wanted = {m["name"]: m for m in json.loads(
-        (ROOT / LATENT).read_text())["per_layer"]}
-    cell = cells.Cell(ROOT, DATA, "tiny.docs")
-    for metric, _, _ in cell.per_layer:
-        assert {k: v for k, v in metric.items() if k != "workloads"} == \
-            {k: v for k, v in wanted[metric["name"]].items()
-             if k != "workloads"}
-    assert cells.check_names(bench) == []

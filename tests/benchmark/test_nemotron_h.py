@@ -3,9 +3,8 @@ CPU: the plain reference against the program's float32 forward and
 against what the paged server serves, the chip's share of an expert
 layer against the uncut reference layer, the 4-bit control, the new
 per-layer readers on hand-built traces, a rehearsal of the tiny
-twin of ``nemotron3super.reason`` (``data/BENCHMARK_nemotron.json``),
-and the chip-size file that lists the cell's four own per-layer
-metrics until the root file can (``benchmark/BENCHMARK_hybrid.json``).
+twin of ``nemotron3super.reason`` (``data/BENCHMARK_nemotron.json``).
+What ``BENCHMARK.json`` lists for the cell is ``test_root_cells.py``.
 
 Tolerance 1e-4 on float32 logits of standard deviation 1, as in
 ``test_reference.py``: both sides compute float32 arithmetic from the
@@ -32,13 +31,9 @@ from benchmark import cells, check, hybrid_shapes, peaks, shapes  # noqa: E402
 from benchmark import xplane  # noqa: E402
 from benchmark.builders import nemotron_h as builder  # noqa: E402
 from benchmark.reference import nemotron_h as reference  # noqa: E402
+from tests.benchmark.listed import last_json_line  # noqa: E402
 
 DATA = "tests/benchmark/data/BENCHMARK_nemotron.json"
-#: The root's cell with the four new per-layer metrics behind the
-#: root's own (see the test of that file below).
-HYBRID = "benchmark/BENCHMARK_hybrid.json"
-NEW = {"moe_expert_roofline", "ssm_update_roofline",
-       "moe_pairs_per_expert", "ssm_prefill_ms_per_ktok"}
 TWIN = json.loads((ROOT / "tests/benchmark/data/configs/"
                    "nemotron-tiny-test.json").read_text())
 #: The uncut model the twin is a share of: all 16 experts held.
@@ -293,7 +288,7 @@ def rehearsal(tmp_path_factory):
         cwd=ROOT, env=env, text=True, timeout=600,
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
     assert done.returncode == 0, done.stdout[-3000:]
-    return done.stdout, json.loads(done.stdout.strip().splitlines()[-1])
+    return done.stdout, last_json_line(done.stdout)
 
 
 def test_the_twin_cell_rehearses_correct_with_its_counters(rehearsal):
@@ -308,97 +303,3 @@ def test_the_twin_cell_rehearses_correct_with_its_counters(rehearsal):
     assert line["metrics"]["moe_pairs_per_expert"]["unit"] == "tokens"
     # No device plane on the CPU: the trace readers leave theirs out.
     assert "moe_expert_roofline" not in metrics
-
-
-def test_names_units_and_files_of_the_twin_and_the_new_entries():
-    twin = json.loads((ROOT / DATA).read_text())
-    assert cells.check_names(twin) == []
-    cell = cells.Cell(ROOT, DATA, "tiny.reason")
-    assert NEW <= {metric["name"] for metric, _, _ in cell.per_layer}
-    cell = cells.Cell(ROOT, "BENCHMARK.json", "nemotron3super.reason")
-    assert {m["name"] for m in cell.end_to_end} == {
-        "ttft_p50_ms", "tpot_p50_ms", "out_tokens_per_s", "setup_s"}
-    for listed in ("BENCHMARK.json", HYBRID):
-        for metric in json.loads((ROOT / listed).read_text())["per_layer"]:
-            described = json.loads(
-                (ROOT / "benchmark" / "layer_metrics"
-                 / f"{metric['name']}.json").read_text())
-            assert (described["layer"], described["unit"],
-                    described["moves"], described["source"]) == (
-                metric["layer"], metric["unit"], metric["moves"],
-                metric["source"])
-            if metric["name"] in NEW:
-                assert metric["workloads"] == ["nemotron3super.reason"]
-
-
-def test_the_chip_size_file_is_the_roots_cell_plus_the_four_metrics():
-    """``BENCHMARK.json`` cannot list the four new metrics yet (two
-    tests of ``test_scheduler_metrics.py`` pin PR 24's seven as its
-    last seven, and later entries go at the end), so
-    ``benchmark/BENCHMARK_hybrid.json`` holds the root's cell with
-    the root's entries and the four behind them: ``run.py --benchmark``
-    reads them on the chip until a ``benchmark`` PR moves them."""
-    root = json.loads((ROOT / "BENCHMARK.json").read_text())
-    hybrid = json.loads((ROOT / HYBRID).read_text())
-    assert cells.check_names(hybrid) == []
-    assert not NEW & {m["name"] for m in root["per_layer"]}
-    for key in ("command", "paths", "run_seconds", "end_to_end"):
-        assert hybrid[key] == root[key]
-    assert hybrid["workloads"] == [
-        w for w in root["workloads"]
-        if w["name"] == "nemotron3super.reason"]
-    assert hybrid["configs"] == [
-        c for c in root["configs"]
-        if c["name"] == hybrid["workloads"][0]["config"]]
-    assert hybrid["per_layer"][:-4] == root["per_layer"]
-    assert {m["name"] for m in hybrid["per_layer"][-4:]} == NEW
-    cell = cells.Cell(ROOT, HYBRID, "nemotron3super.reason")
-    root_cell = cells.Cell(ROOT, "BENCHMARK.json",
-                           "nemotron3super.reason")
-    assert [m["name"] for m, _, _ in cell.per_layer] == \
-        [m["name"] for m, _, _ in root_cell.per_layer] + \
-        [m["name"] for m in hybrid["per_layer"][-4:]]
-
-
-# --- what test_scheduler_metrics.py checks by position, here by name ---- #
-
-SCHEDULER_METRICS = (
-    "queue_wait_mean_ms", "slice_wait_mean_ms", "first_chunk_mean_ms",
-    "prefill_slices_per_chunk", "prefill_backlog_slots",
-    "prefill_useful_tokens", "engine_host_ms_per_chunk")
-
-
-def test_every_chip_cell_reports_the_scheduler_metrics_by_name():
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    for workload in bench["workloads"]:
-        cell = cells.Cell(ROOT, "BENCHMARK.json", workload["name"])
-        held = {metric["name"]: (metric, described)
-                for metric, described, _ in cell.per_layer}
-        assert set(SCHEDULER_METRICS) <= set(held)
-        for name in SCHEDULER_METRICS:
-            metric, described = held[name]
-            assert described["layer"] == metric["layer"] == \
-                "replica actor and scheduler"
-            assert (described["unit"], described["moves"]) == (
-                metric["unit"], metric["moves"])
-
-
-@pytest.mark.parametrize("data, workload", [
-    ("tests/benchmark/data/BENCHMARK_scheduler.json", "tiny.sched"),
-    (DATA, "tiny.reason")])
-def test_a_rehearsals_benchmark_file_holds_the_roots_entries(data,
-                                                             workload):
-    """A rehearsal file's per-layer entries are the root file's own
-    (the four new ones: the chip-size file's), but for the cells they
-    list."""
-    bench = json.loads((ROOT / data).read_text())
-    assert cells.check_names(bench) == []
-    root = {m["name"]: m for m in json.loads(
-        (ROOT / HYBRID).read_text())["per_layer"]}
-    cell = cells.Cell(ROOT, data, workload)
-    for metric, _, _ in cell.per_layer:
-        wanted = root[metric["name"]]
-        assert {k: v for k, v in metric.items() if k != "workloads"} == \
-            {k: v for k, v in wanted.items() if k != "workloads"}
-    assert set(SCHEDULER_METRICS) <= {m["name"]
-                                      for m, _, _ in cell.per_layer}

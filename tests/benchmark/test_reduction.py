@@ -4,13 +4,14 @@ due-time and lateness arithmetic on fixed inputs."""
 
 import pathlib
 import sys
+import types
 
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from benchmark import stats, traffic, xplane  # noqa: E402
+from benchmark import cells, stats, traffic, xplane  # noqa: E402
 
 #: (name, start_ns, duration_ns): two overlapping ops, a gap of 30, a
 #: lone op, a gap of 100, an op that runs past the window.
@@ -172,3 +173,54 @@ def test_label_shortens_an_instruction_to_kind_and_shape():
         == "while (s32[]"
     assert xplane.label("jit_serve_chunk_mixed(123)") == \
         "jit_serve_chunk_mixed(123)"
+
+
+# --- decode_step_ms: loops if there are loops, else the program --------- #
+
+MS = 1_000_000
+LOOP = "%while.4 = (s32[], bf16[64,4096]{1,0}) while(...)"
+PAGED = "jit_serve_chunk_paged(1234)"
+MIXED = "jit_serve_chunk_mixed(5678)"
+PREFILL = "jit_prefill_append_paged(9)"
+#: Three chunks of a program that scans (a loop inside each program
+#: run), and one standalone prefill program, which is no serving step.
+LOOPED = {"ops": [(LOOP, 1 * MS, 30 * MS), (LOOP, 41 * MS, 34 * MS),
+                  (LOOP, 81 * MS, 32 * MS)],
+          "modules": [(PAGED, 0, 38 * MS), (MIXED, 40 * MS, 40 * MS),
+                      (PREFILL, 80 * MS, 1 * MS), (PAGED, 80 * MS, 35 * MS)]}
+#: A mixed program whose steps stand in line: no loop anywhere.
+UNROLLED = {"ops": [("%fusion.3 = bf16[320,4096]{1,0} fusion(...)", 0,
+                     2 * MS)],
+            "modules": [(MIXED, 0, 33 * MS), (MIXED, 34 * MS, 35 * MS),
+                        (PREFILL, 70 * MS, 50 * MS),
+                        (MIXED, 120 * MS, 34 * MS)]}
+#: Mostly unrolled, one chunk that scanned (a chunk without a slice):
+#: the span is read as what most of its programs are, in line.
+BOTH = {"ops": [(LOOP, 71 * MS, 28 * MS)],
+        "modules": UNROLLED["modules"][:2] + [(PAGED, 70 * MS, 29 * MS),
+                                              (MIXED, 120 * MS, 34 * MS)]}
+#: Loops in all but a run cut short by the span's edge: read as before.
+MOSTLY_LOOPED = {"ops": LOOPED["ops"],
+                 "modules": LOOPED["modules"] + [(MIXED, 120 * MS, 3 * MS)]}
+NEITHER = {"ops": UNROLLED["ops"], "modules": [(PREFILL, 0, 50 * MS)]}
+
+
+@pytest.mark.parametrize("trace, steps, wanted", [
+    (LOOPED, 8, 32 / 8), (LOOPED, 2, 32 / 2), (UNROLLED, 2, 34 / 2),
+    (BOTH, 2, 34 / 2), (MOSTLY_LOOPED, 8, 32 / 8), (NEITHER, 2, None),
+    (None, 2, None)])
+def test_decode_step_ms_reads_loops_or_the_loopless_program(trace, steps,
+                                                            wanted):
+    read = cells._import(ROOT / "benchmark" / "layer_metrics"
+                         / "decode_step_ms.py").read
+    cell = types.SimpleNamespace(
+        config={"serving": {"chunk_steps": steps}})
+    run = types.SimpleNamespace(trace=trace, xplane=xplane, stats=stats,
+                                cell=cell)
+    got = read(run)
+    assert got == (pytest.approx(wanted) if wanted else None)
+    if trace in (LOOPED, MOSTLY_LOOPED):
+        # With loops in every serving program the reading is what it
+        # was before the second case existed: the median loop.
+        loops = xplane.durations_of(trace["ops"], r"^%while\.\d+ = ")
+        assert got == stats.quantile(loops, 0.5) / 1e6 / steps

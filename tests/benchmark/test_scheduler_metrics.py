@@ -4,7 +4,6 @@ that lists them (``data/BENCHMARK_scheduler.json``: ``tiny.chat``'s
 configuration and traffic as the cell ``tiny.sched``, whose trace
 directory is then its own), and their readers on hand-built inputs."""
 
-import json
 import os
 import pathlib
 import subprocess
@@ -17,10 +16,12 @@ DATA = "tests/benchmark/data/BENCHMARK_scheduler.json"
 
 sys.path.insert(0, str(ROOT))
 from benchmark import cells, counter_ratio, xplane  # noqa: E402
+from tests.benchmark.listed import (SCHEDULER_METRICS, Listed,  # noqa: E402
+                                    by_name, held_to, last_json_line)
 
-COUNTER_METRICS = ("queue_wait_mean_ms", "slice_wait_mean_ms",
-                   "first_chunk_mean_ms", "prefill_slices_per_chunk",
-                   "prefill_backlog_slots", "prefill_useful_tokens")
+SEVEN = SCHEDULER_METRICS
+COUNTER_METRICS = tuple(name for name in SEVEN
+                        if name != "engine_host_ms_per_chunk")
 
 
 @pytest.fixture(scope="module")
@@ -36,7 +37,7 @@ def traced(tmp_path_factory):
         cwd=ROOT, env=env, text=True, timeout=300,
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
     assert done.returncode == 0, done.stdout[-3000:]
-    return json.loads(done.stdout.strip().splitlines()[-1])
+    return last_json_line(done.stdout)
 
 
 def test_traced_rehearsal_prints_the_scheduler_metrics(traced):
@@ -87,27 +88,32 @@ def test_engine_phases_are_in_the_profiles_host_plane(traced):
                 {key for key, _ in event.stats}
 
 
-def test_every_chip_cell_reports_the_seven_metrics():
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    added = [m["name"] for m in bench["per_layer"][-7:]]
-    assert added == list(COUNTER_METRICS) + ["engine_host_ms_per_chunk"]
-    for workload in bench["workloads"]:
-        cell = cells.Cell(ROOT, "BENCHMARK.json", workload["name"])
-        assert set(added) <= {m["name"] for m, _, _ in cell.per_layer}
-        for metric, described, _ in cell.per_layer[-7:]:
+def test_every_chip_cell_reports_the_seven_metrics(listed):
+    """Found by name, wherever in the list they stand: each of the
+    seven is listed once, for every cell, and every cell loads it."""
+    for entry in listed.per_layer(SEVEN):
+        assert "workloads" not in entry
+    for cell in listed.cells():
+        held = by_name(cell)
+        assert set(SEVEN) <= set(held)
+        for name in SEVEN:
+            metric, described, _ = held[name]
             assert described["layer"] == metric["layer"] == \
                 "replica actor and scheduler"
             assert described["unit"] == metric["unit"]
             assert described["moves"] == metric["moves"]
 
 
-def test_the_rehearsals_benchmark_file_is_sound():
-    bench = json.loads((ROOT / DATA).read_text())
-    assert cells.check_names(bench) == []
-    cell = cells.Cell(ROOT, DATA, "tiny.sched")
-    root = json.loads((ROOT / "BENCHMARK.json").read_text())
-    assert [m for m, _, _ in cell.per_layer][-7:] == \
-        root["per_layer"][-7:]
+def test_the_rehearsals_benchmark_file_is_sound(listed):
+    """The twin is held to the root by name: each of its per-layer
+    entries is the root's entry of that name but for the cells it
+    lists, and the seven are among them."""
+    twin = Listed(DATA)
+    assert cells.check_names(twin.bench) == []
+    held = by_name(twin.cell("tiny.sched"))
+    assert set(SEVEN) <= set(held)
+    for metric, _, _ in held.values():
+        assert held_to(listed, metric), metric
 
 
 def test_a_counter_ratio_reads_nothing_from_an_older_program():

@@ -1,11 +1,11 @@
 """The per-layer metrics beneath ``setup_s`` (PR 36): they read the
 compile ledger's totals from the snapshots the run already marks at
-the window's edges.  Their readers on hand-made marks, the chip-size
-file that lists them held to the root, and one traced rehearsal under
+the window's edges.  Their readers on hand-made marks, the root's
+entries for them (found by name, on the root as it is and on a grown
+copy: the ``listed`` fixture), and one traced rehearsal under
 ``data/BENCHMARK_setup.json`` (``tiny.sched``'s cell as ``tiny.setup``,
 whose trace directory is then its own)."""
 
-import json
 import os
 import pathlib
 import subprocess
@@ -15,11 +15,12 @@ import types
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
-SETUP = "benchmark/BENCHMARK_setup.json"
 DATA = "tests/benchmark/data/BENCHMARK_setup.json"
 
 sys.path.insert(0, str(ROOT))
 from benchmark import cells, ledger_totals  # noqa: E402
+from tests.benchmark.listed import (Listed, by_name, held_to,  # noqa: E402
+                                    last_json_line)
 
 NEW = ("setup_trace_lower_s", "setup_backend_s", "setup_programs",
        "setup_gc_s", "window_gc_ms")
@@ -34,13 +35,13 @@ LEDGER0 = {"compiles": 2, "cache_hits": 28, "cache_misses": 2,
 LEDGER1 = dict(LEDGER0, gc_full_pauses=10, gc_full_pause_ms=4203.5)
 
 
-def readers(benchmark_file, cell):
-    return {metric["name"]: read for metric, _, read
-            in cells.Cell(ROOT, benchmark_file, cell).per_layer}
+def readers(listed, cell):
+    return {name: read for name, (_, _, read)
+            in by_name(listed.cell(cell)).items()}
 
 
-def test_the_five_readers_on_hand_made_marks():
-    read = readers(SETUP, "mixtral8x7b.chat")
+def test_the_five_readers_on_hand_made_marks(listed):
+    read = readers(listed, "mixtral8x7b.chat")
     run = types.SimpleNamespace(
         marks={"ledger0": LEDGER0, "ledger1": LEDGER1})
     assert {name: read[name](run) for name in NEW} == {
@@ -51,7 +52,7 @@ def test_the_five_readers_on_hand_made_marks():
         "window_gc_ms": pytest.approx(3.5)}
 
 
-def test_the_readers_read_nothing_from_an_older_programs_ledger():
+def test_the_readers_read_nothing_from_an_older_programs_ledger(listed):
     """The parent's snapshot has the counts and one total: every new
     metric is left out of its line, and none raises."""
     older = {key: LEDGER0[key] for key in (
@@ -59,60 +60,47 @@ def test_the_readers_read_nothing_from_an_older_programs_ledger():
         "compile_wall_ms_total")}
     run = types.SimpleNamespace(marks={"ledger0": older,
                                        "ledger1": dict(older)})
-    read = readers(SETUP, "mistral7b.chat")
+    read = readers(listed, "mistral7b.chat")
     assert [read[name](run) for name in NEW] == [None] * 5
     spec = {"totals": ["compile_wall_ms_total"], "scale": 0.001,
             "over": "setup"}
     assert ledger_totals.of(run.marks, spec) == pytest.approx(6.5)
 
 
-def test_the_chip_size_file_is_the_root_plus_the_five():
-    """``BENCHMARK.json`` cannot list the new metrics (later entries go
-    at the end, and two tests of ``test_scheduler_metrics.py`` pin
-    PR 24's seven as the last seven), so
-    ``benchmark/BENCHMARK_setup.json`` holds the root's four cells and
-    its entries with the five behind them: ``run.py --benchmark``
-    reads them on the chip until a ``benchmark`` PR folds this file
-    and its two siblings into the root."""
-    root = json.loads((ROOT / "BENCHMARK.json").read_text())
-    setup = json.loads((ROOT / SETUP).read_text())
-    assert cells.check_names(setup) == []
-    assert {key: value for key, value in setup.items()
-            if key != "per_layer"} == \
-        {key: value for key, value in root.items() if key != "per_layer"}
-    assert setup["per_layer"][:-5] == root["per_layer"]
-    assert tuple(m["name"] for m in setup["per_layer"][-5:]) == NEW
-    assert not set(NEW) & {m["name"] for m in root["per_layer"]}
-    judged = {m["name"] for m in root["end_to_end"]}
-    for metric in setup["per_layer"][-5:]:
+def test_the_root_lists_the_five_and_every_cell_loads_them(listed):
+    """``BENCHMARK.json`` lists the five itself (since PR 38; until
+    then a sibling file held them behind a copy of the root's
+    entries).  Found by name: each is listed once, for every cell,
+    with the contract's six keys, and every cell loads it as the
+    entry describes it."""
+    judged = {m["name"] for m in listed.bench["end_to_end"]}
+    entries = listed.per_layer(NEW)
+    for metric in entries:
         assert set(metric) == {"name", "unit", "better", "source",
                                "layer", "moves"}
         assert (metric["layer"], metric["source"], metric["better"]) == \
             ("compile", "program_counter", "lower")
         assert metric["moves"] in judged
-    assert [m["moves"] for m in setup["per_layer"][-5:]] == \
+    assert [m["moves"] for m in entries] == \
         ["setup_s"] * 4 + ["out_tokens_per_s"]
-    # Every cell reports setup_s and out_tokens_per_s: each lists the
+    # Every cell reports setup_s and out_tokens_per_s: each loads the
     # five, described as the entry says.
-    for workload in root["workloads"]:
-        cell = cells.Cell(ROOT, SETUP, workload["name"])
-        root_cell = cells.Cell(ROOT, "BENCHMARK.json", workload["name"])
-        assert [m["name"] for m, _, _ in cell.per_layer] == \
-            [m["name"] for m, _, _ in root_cell.per_layer] + list(NEW)
-        for metric, described, _ in cell.per_layer[-5:]:
+    for cell in listed.cells():
+        held = by_name(cell)
+        assert set(NEW) <= set(held)
+        for name in NEW:
+            metric, described, _ = held[name]
             for key in ("layer", "unit", "moves", "source"):
                 assert described[key] == metric[key]
 
 
-def test_the_twins_benchmark_file_holds_the_setup_files_entries():
-    bench = json.loads((ROOT / DATA).read_text())
-    assert cells.check_names(bench) == []
-    wanted = {m["name"]: m for m in json.loads(
-        (ROOT / SETUP).read_text())["per_layer"]}
-    cell = cells.Cell(ROOT, DATA, "tiny.setup")
-    assert [m["name"] for m, _, _ in cell.per_layer][-5:] == list(NEW)
-    for metric, _, _ in cell.per_layer:
-        assert metric == wanted[metric["name"]]
+def test_the_twins_benchmark_file_holds_the_roots_setup_entries(listed):
+    twin = Listed(DATA)
+    assert cells.check_names(twin.bench) == []
+    held = by_name(twin.cell("tiny.setup"))
+    assert set(NEW) <= set(held)
+    for metric, _, _ in held.values():
+        assert held_to(listed, metric), metric
 
 
 @pytest.fixture(scope="module")
@@ -129,12 +117,13 @@ def traced(tmp_path_factory):
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
     assert done.returncode == 0, done.stdout[-3000:]
     lines = done.stdout.strip().splitlines()
+    result = last_json_line(done.stdout)
     # A traced run's line holds the per-layer metrics only: set-up
     # ended, but for the ramp, when the log said the programs were
     # warm (``[ seconds since the process began] warm: ...``).
     warm_s = next(float(line[1:].split("]")[0]) for line in lines
                   if "] warm: compiles" in line)
-    return json.loads(lines[-1]), warm_s
+    return result, warm_s
 
 
 def test_traced_rehearsal_prints_the_setup_metrics(traced):
