@@ -2,6 +2,7 @@ from . import llama
 from . import moe
 from . import nemotron_h
 from . import mistral4
+from . import sdar
 from . import classifier
 from . import detector
 from . import asr
@@ -17,8 +18,16 @@ from . import lora
 #: ``state_bytes_per_slot``, ``layer_kinds``; ``RECURRENT_STATE``,
 #: ``COUNTERS``, ``UNSUPPORTED``; and, where the K/V kernels' own
 #: dispatch does not describe its pool, ``attention_paths`` and
-#: ``slice_key_blocks``).
-SERVING_MODULES = (llama, nemotron_h, mistral4)
+#: ``slice_key_blocks``).  A module that generates by BLOCK PASSES
+#: (``sdar``) also gives ``block_slot_state`` (the leaves a slot's
+#: resident state holds for the block in progress: ``window``,
+#: ``masked``, ``delivered``, ``passes``, and the slot's schedule),
+#: ``MARK_LIVE`` / ``MARK_STORE``, and a config with ``block_length``;
+#: its ``serve_chunk_paged`` / ``serve_chunk_mixed`` return, where the
+#: others return ``(slots, steps)`` tokens and ``(slots,)`` counts,
+#: the ``(slots, passes, block)`` windows and ``(slots, passes)`` marks
+#: the engine's ``_commit_block_passes`` reads.
+SERVING_MODULES = (llama, nemotron_h, mistral4, sdar)
 
 
 def serving_model(config_name: str):

@@ -514,6 +514,10 @@ def install(service: str = "", max_records: int = 256,
     if LEDGER is None:
         LEDGER = ledger or CompileLedger(service=service,
                                          max_records=max_records)
+        # A label names work for the ledger that was on when it was
+        # set: one left on this thread by an engine that ran under an
+        # earlier ledger (or under none) names nothing of this one's.
+        clear_label()
         _register_listeners()
         gc.callbacks.append(_on_gc)
     return LEDGER

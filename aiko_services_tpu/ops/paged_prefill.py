@@ -73,7 +73,7 @@ from .paged_attention import (MXU_PRECISION, _contract_pool_rows,
                               kernel_serves, load_head_rows, runs_kernel)
 
 __all__ = ["paged_prefill_attention", "paged_prefill_reference",
-           "paged_verify_attention",
+           "paged_verify_attention", "block_causal_positions",
            "paged_prefill_call", "prefill_key_blocks",
            "prefill_kernel_mode", "prefill_dispatch",
            "verify_dispatch", "prefill_attention_path"]
@@ -221,12 +221,26 @@ def _write_rows(pool, k_new, v_new, tables, positions, chunk_lens=None):
             for key, src in _pool_rows(pool, k_new, v_new).items()}
 
 
+def block_causal_positions(positions, mask_block: Optional[int]):
+    """The position whose causal mask IS the block-causal one of
+    ``positions``: the last position of each query's block of
+    ``mask_block`` positions (key ``j`` is visible to query ``i`` iff
+    ``j // mask_block <= i // mask_block``).  ``None``: causal, the
+    positions as they are."""
+    if mask_block is None:
+        return positions
+    return (positions // mask_block + 1) * mask_block - 1
+
+
 def paged_prefill_reference(q, k_new, v_new, pool, tables, cached_lens,
-                            chunk_lens, window: Optional[int] = None):
+                            chunk_lens, window: Optional[int] = None,
+                            mask_block: Optional[int] = None):
     """Write-then-gather-then-attend oracle for the append kernel:
     scatter the chunk's K/V into the pool, view ``pool[tables]`` as
     per-row contiguous caches, and run :func:`cached_gqa_attention`
-    with query positions ``cached + [0, T)``.
+    with query positions ``cached + [0, T)`` (each moved to the end of
+    its block of ``mask_block`` positions under the block-causal
+    mask, :func:`block_causal_positions`).
 
     ``q`` (batch, T, kv, group, hd); ``k_new``/``v_new`` (batch, T, kv,
     hd); ``pool`` the per-layer dict (``k``/``v`` + optional
@@ -246,8 +260,9 @@ def paged_prefill_reference(q, k_new, v_new, pool, tables, cached_lens,
                                 + gathered.shape[3:])
 
     cache_layer = {key: view(buf) for key, buf in new_pool.items()}
-    out = cached_gqa_attention(q, cache_layer, positions, hd,
-                               window=window)
+    out = cached_gqa_attention(
+        q, cache_layer, block_causal_positions(positions, mask_block),
+        hd, window=window)
     return out, new_pool
 
 
@@ -412,7 +427,8 @@ def _prefill_attention_kernel(bands_ref, blocks_ref,   # scalar prefetch
                               q_ref, *rest,
                               blocks_per_step: int, group: int,
                               sm_scale: float, window: Optional[int],
-                              quantized: bool):
+                              quantized: bool,
+                              mask_block: Optional[int] = None):
     """Grid: (batch, q_tiles, band steps); band steps fastest.
 
     One program sweeps one (row, query-tile), every kv head, through
@@ -425,7 +441,10 @@ def _prefill_attention_kernel(bands_ref, blocks_ref,   # scalar prefetch
     The tile's row axis interleaves queries and their group heads
     (``row = token·group + head``), so per-row masking by absolute ids
     covers ragged causality, the sliding window AND the entries a short
-    last step clamps, in one 2D tile.
+    last step clamps, in one 2D tile.  ``mask_block`` (a divisor of the
+    pool block, so a tile's band ends where it did) makes the mask
+    block-causal: a query sees every key of its own block of
+    ``mask_block`` positions (:func:`block_causal_positions`).
 
     Running max and denominator are kept lane-replicated, as wide as
     the score tile: every vector op of the update is a full-lane op on
@@ -452,8 +471,9 @@ def _prefill_attention_kernel(bands_ref, blocks_ref,   # scalar prefetch
 
     @pl.when(entry <= last)
     def _compute():
-        q_ids = q_min + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, keys), 0) // group
+        q_ids = block_causal_positions(
+            q_min + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, keys), 0) // group, mask_block)
         key_ids = entry * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (rows, keys), 1)
         # Entries past `last` inside the band's last step were clamped
@@ -529,11 +549,13 @@ def _prefill_attention_kernel(bands_ref, blocks_ref,   # scalar prefetch
 
 @functools.partial(jax.jit,
                    static_argnames=("window", "sm_scale", "q_tile",
-                                    "kv_blocks", "interpret"))
+                                    "kv_blocks", "interpret",
+                                    "mask_block"))
 def paged_prefill_call(q, pool, tables, cached_lens, *,
                        window: Optional[int],
                        sm_scale: float, q_tile: int, kv_blocks: int,
-                       interpret: bool):
+                       interpret: bool,
+                       mask_block: Optional[int] = None):
     """The attention sweep over the (already appended) pool, behind a
     jit of its own so that the layers of a program share ONE trace of
     the kernel and ONE Mosaic lowering, and the device trace prints
@@ -581,7 +603,8 @@ def paged_prefill_call(q, pool, tables, cached_lens, *,
 
     kernel = functools.partial(
         _prefill_attention_kernel, blocks_per_step=P, group=group,
-        sm_scale=sm_scale, window=window, quantized=quantized)
+        sm_scale=sm_scale, window=window, quantized=quantized,
+        mask_block=mask_block)
     stat = pltpu.VMEM((kv_heads, rows, P * block_size), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -611,7 +634,8 @@ def paged_prefill_attention(q, k_new, v_new, pool, tables, cached_lens,
                             sm_scale: Optional[float] = None,
                             interpret: bool = False,
                             q_tile: Optional[int] = None,
-                            kv_limit: Optional[int] = None):
+                            kv_limit: Optional[int] = None,
+                            mask_block: Optional[int] = None):
     """Ragged paged append attention: write the chunk's K/V into the
     pool in-kernel, then attend the chunk's queries over cached prefix
     blocks + the causally-visible chunk itself.
@@ -644,6 +668,9 @@ def paged_prefill_attention(q, k_new, v_new, pool, tables, cached_lens,
       kv_limit: static bound on the kv-block sweep (e.g. the padded
         bucket's block count) — trims dead grid steps when the table
         is much longer than the row can be.
+      mask_block: ``None`` for the causal mask; a block length that
+        divides the pool block for the block-causal one (generation by
+        diffusion over blocks: a query sees its whole block).
 
     Returns ``(out (batch, T, kv_heads, group, head_dim) in q.dtype,
     new_pool)``.  Off the TPU backend (and not interpreting) this IS
@@ -656,10 +683,16 @@ def paged_prefill_attention(q, k_new, v_new, pool, tables, cached_lens,
     if sm_scale is None:
         sm_scale = head_dim ** -0.5
 
+    if mask_block is not None and block_size % mask_block:
+        raise ValueError(
+            f"mask_block {mask_block} must divide the pool block "
+            f"{block_size}: a query tile's band ends with its last "
+            "query's pool block")
     if not runs_kernel(interpret):
         return paged_prefill_reference(q, k_new, v_new, pool, tables,
                                        cached_lens, chunk_lens,
-                                       window=window)
+                                       window=window,
+                                       mask_block=mask_block)
     if T % block_size or not kernel_serves(head_dim, kv_heads,
                                            pool["k"].dtype, interpret):
         raise ValueError(
@@ -682,7 +715,8 @@ def paged_prefill_attention(q, k_new, v_new, pool, tables, cached_lens,
     out = paged_prefill_call(q, new_pool, tables, meta[:, 0],
                              window=window, sm_scale=sm_scale,
                              q_tile=q_tile,
-                             kv_blocks=kv_blocks, interpret=interpret)
+                             kv_blocks=kv_blocks, interpret=interpret,
+                             mask_block=mask_block)
     return out, new_pool
 
 

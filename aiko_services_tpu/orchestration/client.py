@@ -98,13 +98,18 @@ class InferClient:
                temperature: float = 0.0, top_p: float = 1.0,
                on_partial=None,
                deadline_s: Optional[float] = None,
-               request_id: Optional[str] = None) -> InferFuture:
+               request_id: Optional[str] = None,
+               denoise_steps: Optional[int] = None,
+               denoise_rule: Optional[str] = None,
+               denoise_threshold: Optional[float] = None) -> InferFuture:
         """Send one ``(infer …)``; returns the future immediately.
 
         ``deadline_s`` is a client-relative budget: the replica rejects
         the request at admission or evicts it from its slot once the
         budget elapses (``error="deadline_exceeded"``), and routers
-        stop re-dispatching it.
+        stop re-dispatching it.  ``denoise_*``: the schedule of a model
+        that generates by block passes (``DecodeRequest``); left out,
+        the replica's config decides.
         """
         swag: Dict = {"tokens": np.asarray(tokens, np.int32),
                       "max_new_tokens": int(max_new_tokens)}
@@ -117,6 +122,12 @@ class InferClient:
             swag["top_p"] = float(top_p)
         if deadline_s is not None:
             swag["deadline_ms"] = int(float(deadline_s) * 1e3)
+        if denoise_steps is not None:
+            swag["denoise_steps"] = int(denoise_steps)
+        if denoise_rule:
+            swag["denoise_rule"] = str(denoise_rule)
+        if denoise_threshold is not None:
+            swag["denoise_threshold"] = float(denoise_threshold)
         return self._send("infer", swag, on_partial=on_partial,
                           request_id=request_id)
 

@@ -109,6 +109,14 @@ class DecodeRequest:
     #: unconstrained (the automaton applies to GENERATED tokens only,
     #: never the prompt).
     automaton: Optional[str] = None
+    #: Generation by block passes (a model module with
+    #: ``block_slot_state``): denoise passes a block (1 .. block
+    #: length), ``"static"`` or ``"dynamic"``, and the dynamic rule's
+    #: confidence threshold.  None: the registered config's value.
+    #: Requests with different schedules share one batch.
+    denoise_steps: Optional[int] = None
+    denoise_rule: Optional[str] = None
+    denoise_threshold: Optional[float] = None
     #: Absolute host-monotonic deadline (``deadline_ms`` on the wire
     #: travels as a RELATIVE budget — clocks never cross processes).
     #: Expired requests are rejected at admission and evicted from
@@ -238,6 +246,14 @@ class ContinuousBatchingServer:
             if hasattr(entry, "_cache_size")]
         self._programs_settled = 0
         self._llama = llama
+        #: Positions of a block where the module generates by block
+        #: passes (it has ``block_slot_state``), else 0: a slot's unit
+        #: of work is then a block that takes several passes, a pass
+        #: commits 0 .. block tokens of it in any order, and what is
+        #: delivered is the contiguous committed prefix.
+        self._block_length = (
+            int(self.config.block_length)
+            if hasattr(self._model, "block_slot_state") else 0)
         self._refuse_unsupported(
             mesh=mesh is not None, replica_mesh=replica_mesh is not None,
             adapters=bool(adapters),
@@ -486,6 +502,12 @@ class ContinuousBatchingServer:
         # admission/retirement actually touched — so the steady-state
         # decode loop performs ZERO host→device uploads.
         self._remaining = np.zeros(slots, np.int32)
+        #: Host mirrors of the block in progress and the slot's
+        #: schedule (the module's ``block_slot_state`` leaves), kept
+        #: exact as passes are consumed.
+        self._block_state = (
+            self._model.block_slot_state(self.config, slots)
+            if self._block_length else {})
         self._state = self._init_device_state()
         if self._mesh is not None:
             # Slot state (and the paged layout's block tables) must be
@@ -578,6 +600,14 @@ class ContinuousBatchingServer:
             # What a serve chunk of this model module returns beside
             # its tokens; added when the chunk is read (_consume_ready).
             self.counters[name] = 0
+        if self._block_length:
+            # Live slot-passes; those that only stored a finished
+            # block's K/V; blocks whose K/V became final.
+            # ``decode_steps`` counts passes here, and
+            # ``tokens_committed`` what the passes delivered.
+            for name in ("block_pass_rows", "block_store_rows",
+                         "blocks_finished"):
+                self.counters[name] = 0
         if self._model.RECURRENT_STATE:
             # Prompt tokens that advanced a slot's recurrent state, and
             # prompts that began from a zero state (paged._state_slice).
@@ -667,6 +697,8 @@ class ContinuousBatchingServer:
             "temps": jnp.zeros((slots,), jnp.float32),
             "tops": jnp.ones((slots,), jnp.float32),
             "adapter_ids": jnp.zeros((slots,), jnp.int32),
+            **{name: jnp.asarray(leaf)
+               for name, leaf in self._block_state.items()},
         }
 
     def _host_state(self) -> Dict:
@@ -680,6 +712,7 @@ class ContinuousBatchingServer:
             "temps": self._temperatures,
             "tops": self._top_ps,
             "adapter_ids": self._adapter_ids,
+            **self._block_state,
         }
 
     def _sync_dirty(self) -> None:
@@ -837,7 +870,10 @@ class ContinuousBatchingServer:
         sched_live = sched[live]
         if self.decode_attention_path == "kernel":
             block_size = self._attn_block_size
-            blocks = (self.positions[live]
+            # A step reads up to its own row; a block pass up to its
+            # block's last, once for all the block's queries.
+            reach = max(self._block_length, 1)
+            blocks = (self.positions[live] + reach - 1
                       + block_size) // block_size   # ceil((pos+1)/bs)
             window = self.config.sliding_window
             if window:
@@ -1022,6 +1058,20 @@ class ContinuousBatchingServer:
         if request.adapter is not None \
                 and request.adapter not in self._adapter_index:
             return "unknown_adapter"
+        asked = (request.denoise_steps, request.denoise_rule,
+                 request.denoise_threshold)
+        if any(value is not None for value in asked):
+            if not self._block_length:
+                return "no_block_passes"
+            if request.denoise_steps is not None and not (
+                    1 <= request.denoise_steps <= self._block_length):
+                return "bad_denoise_steps"
+            if request.denoise_rule not in (None, "static", "dynamic"):
+                return "bad_denoise_rule"
+        if self._block_length and prompt_len + request.max_new_tokens \
+                + self._block_length > self.max_seq:
+            # The answer's last block is generated whole and cut.
+            return "prompt_too_long"
         if request.automaton is not None \
                 and (self._automata is None
                      or request.automaton
@@ -1170,6 +1220,9 @@ class ContinuousBatchingServer:
         self._slot_serial[slot] += 1
         self._dirty[slot] = True
         self._any_sampled = bool((self._temperatures > 0).any())
+        if self._block_length:
+            self._open_first_block(slot, request, prompt_padded,
+                                   prompt_len)
         if self._spec is not None \
                 and self._spec["controller"] is not None:
             # New occupant: forget the previous request's acceptance
@@ -1183,6 +1236,35 @@ class ContinuousBatchingServer:
         if span is not None:
             span.end(temperature=float(request.temperature),
                      top_p=float(request.top_p))
+
+    def _open_first_block(self, slot: int, request, prompt_padded,
+                          prompt_len: int) -> None:
+        """Generation by block passes: seed the slot with the first
+        block it generates.  The prompt's whole blocks are in the
+        cache; its last ``prompt_len mod block`` tokens open the block
+        as given (its passes write their rows again), the rest of it
+        is masked.  The schedule is the request's, else the
+        config's."""
+        B, state, config = self._block_length, self._block_state, \
+            self.config
+        base = prompt_len // B * B
+        given = prompt_len - base
+        self.positions[slot] = base
+        state["window"][slot] = 0
+        state["window"][slot, :given] = prompt_padded[0, base:prompt_len]
+        state["masked"][slot] = np.arange(B) >= given
+        state["delivered"][slot] = given
+        state["passes"][slot] = 0
+        state["denoise_steps"][slot] = (
+            config.denoise_steps if request.denoise_steps is None
+            else request.denoise_steps)
+        state["dynamic"][slot] = (
+            config.denoise_dynamic if request.denoise_rule is None
+            else request.denoise_rule == "dynamic")
+        state["threshold"][slot] = (
+            config.denoise_threshold
+            if request.denoise_threshold is None
+            else request.denoise_threshold)
 
     def _begin_chunked_prefill(self, slot: int, request, prompt_padded,
                                prompt_len: int) -> None:
@@ -1867,8 +1949,16 @@ class ContinuousBatchingServer:
             request = self._requests[slot]
             if request is None or not self.active[slot]:
                 continue
-            plan[slot] = (request.max_new_tokens - self._emitted[slot]
-                          - self._inflight_sched[slot])
+            left = request.max_new_tokens - self._emitted[slot]
+            if self._block_length:
+                # In PASSES, and an upper bound: every block that
+                # holds an undelivered token takes at most its denoise
+                # passes and a store pass.  The device retires the
+                # lane itself; a pass too many runs it as an idle row.
+                B = self._block_length
+                left = -(-(left + B - 1) // B) * (int(
+                    self._block_state["denoise_steps"][slot]) + 1)
+            plan[slot] = left - self._inflight_sched[slot]
         return plan
 
     @staticmethod
@@ -1920,10 +2010,16 @@ class ContinuousBatchingServer:
         if not live.any():
             return False
         steps = int(min(self.chunk_steps, int(plan[live].max())))
+        fields = {}
+        if self._block_length:
+            # One program whatever is left: the plan is an upper bound
+            # of passes, and a short chunk would be a compile.
+            steps = int(self.chunk_steps)
+            fields["passes"] = steps
         if steplog.RECORDER is not None:
             self._dispatch_span = steplog.RECORDER.begin(
                 "dispatch", chunk=self.counters["dispatches"] + 1,
-                steps=steps, live_rows=int(live.sum()))
+                steps=steps, live_rows=int(live.sum()), **fields)
         self._sync_dirty()
         rng_key = None
         if self._any_sampled:
@@ -1940,7 +2036,9 @@ class ContinuousBatchingServer:
         # retiring it with zero tokens.
         serial = self._slot_serial.copy()
         if compiles.LEDGER is not None:
-            compiles.set_label("serve_chunk", f"s{steps}")
+            compiles.set_label(*(("serve_block_chunk", f"p{steps}")
+                                 if self._block_length
+                                 else ("serve_chunk", f"s{steps}")))
         tokens_d, counts_d, self._state = self._serve_chunk(
             self._state, steps,
             -1 if self.eos_id is None else int(self.eos_id),
@@ -1948,8 +2046,12 @@ class ContinuousBatchingServer:
         sched = np.where(live, np.minimum(steps, plan), 0)
         self._inflight_sched += sched
         self._note_decode_blocks(live, sched)
+        # A block entry's ``tokens`` are (slots, passes, block) windows
+        # and its ``counts`` (slots, passes) marks: which positions
+        # are still masked, live, store pass (_commit_block_passes).
         self._ring.append(dict(
-            kind="chunk", tokens=tokens_d, counts=counts_d,
+            kind="block" if self._block_length else "chunk",
+            tokens=tokens_d, counts=counts_d,
             active_after=self._state["active"], steps=steps,
             sched=sched, serial=serial,
             model_counters=self._chunk_counters))
@@ -2396,7 +2498,9 @@ class ContinuousBatchingServer:
             full_list = (entry["counts_full"].tolist() if spec
                          else count_list)
             active_list = entry["active_after"].tolist()
-            committed_upper += int(entry["counts"].sum())
+            block = entry["kind"] == "block"
+            if not block:
+                committed_upper += int(entry["counts"].sum())
             cons_mask = entry.get("cons") if spec else None
             forced_ct = entry.get("forced") if spec else None
             caps_snap = entry.get("caps") if spec else None
@@ -2404,6 +2508,16 @@ class ContinuousBatchingServer:
                 slot = int(slot)
                 touched_slots.add(slot)
                 request = self._requests[slot]
+                if block:
+                    count = self._commit_block_passes(
+                        slot, request, token_rows[slot],
+                        count_list[slot], now)
+                    delivered += count
+                    committed_upper += count
+                    if not active_list[slot]:
+                        self._retire(slot)
+                        batch_live[index + 1:, slot] = False
+                    continue
                 count = count_list[slot]
                 constrained = (cons_mask is not None
                                and bool(cons_mask[slot]))
@@ -2479,6 +2593,70 @@ class ContinuousBatchingServer:
             # Device-reported emit counts: stale-serial lanes may be
             # excluded above, so this is an upper bound on committed.
             commit_span.end(tokens=committed_upper)
+
+    def _commit_block_passes(self, slot: int, request, windows, marks,
+                             now: float) -> int:
+        """Apply one chunk's passes to a slot that generates by block
+        passes: ``windows[p]`` is the slot's block after pass ``p`` and
+        ``marks[p]`` says which of its positions were still masked,
+        whether the slot was live at the pass's start and whether the
+        pass was the block's store pass (the model module's
+        ``MARK_LIVE`` / ``MARK_STORE``).  Delivers the contiguous
+        committed prefix past what the block has delivered, cut to the
+        asked length and at the end-of-sequence id (the device retired
+        the lane by the same rule), and keeps the block's mirrors as
+        the device has them.  Returns the tokens delivered."""
+        B, state = self._block_length, self._block_state
+        live_bit = 1 << (B + self._model.MARK_LIVE)
+        store_bit = 1 << (B + self._model.MARK_STORE)
+        eos = -1 if self.eos_id is None else int(self.eos_id)
+        room = request.max_new_tokens - int(self._emitted[slot])
+        at = int(state["delivered"][slot])
+        passes = int(state["passes"][slot])
+        rows = stores = count = 0
+        masked = last = None
+        for window, mark in zip(windows, marks):
+            if not mark & live_bit:
+                continue
+            rows += 1
+            if mark & store_bit:
+                # The block's K/V rows are final: on to the next.
+                stores += 1
+                at = passes = 0
+                masked, last = live_bit - 1, None
+                continue
+            masked, last = mark & (live_bit - 1), window
+            passes += 1
+            prefix = (masked & -masked).bit_length() - 1 if masked else B
+            if prefix <= at:
+                continue
+            new = window[at:prefix][:room - count]
+            at = prefix
+            if eos >= 0 and eos in new:
+                new = new[:new.index(eos) + 1]
+            if new:
+                request.tokens.extend(new)
+                count += len(new)
+        if not rows:
+            return 0
+        self.positions[slot] += stores * B
+        state["delivered"][slot] = at
+        state["passes"][slot] = passes
+        state["masked"][slot] = [masked >> i & 1 for i in range(B)]
+        if last is not None:
+            state["window"][slot] = last
+        counters = self.counters
+        counters["block_pass_rows"] += rows
+        counters["block_store_rows"] += stores
+        counters["blocks_finished"] += stores
+        if count:
+            if request.first_token_ts is None:
+                request.first_token_ts = now
+                self._note_first_token(request)
+            self._emitted[slot] += count
+            self._remaining[slot] = (request.max_new_tokens
+                                     - self._emitted[slot])
+        return count
 
     def _trip_watchdog(self) -> None:
         """Mark the replica wedged (idempotent; callable from the
@@ -2844,6 +3022,14 @@ class ContinuousReplica(Actor):
             request.adapter = str(adapter) if adapter else None
             automaton = inputs.get("automaton")
             request.automaton = str(automaton) if automaton else None
+            if inputs.get("denoise_steps") is not None:
+                request.denoise_steps = int(
+                    np.asarray(inputs["denoise_steps"]))
+            if inputs.get("denoise_rule"):
+                request.denoise_rule = str(inputs["denoise_rule"])
+            if inputs.get("denoise_threshold") is not None:
+                request.denoise_threshold = float(
+                    np.asarray(inputs["denoise_threshold"]))
             deadline_ms = inputs.get("deadline_ms")
             if deadline_ms is not None:
                 # Relative budget → local monotonic deadline (wall

@@ -638,6 +638,10 @@ class PagedContinuousServer(ContinuousBatchingServer):
         worst case (the admission check already bounds prompt + new +
         k + 1 by max_seq, so this never overflows a table).  Sized by
         the LADDER TOP — adaptive rounds can only narrow."""
+        if self._block_length:
+            # Generation by block passes: the answer's last block is
+            # written whole, up to a block past the asked length.
+            return self._block_length
         return self._spec["k"] + 1 if self._spec is not None else 0
 
     def _worst_case_blocks(self, prompt_len: int, max_new: int) -> int:

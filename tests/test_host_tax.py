@@ -333,3 +333,23 @@ def test_scatter_state_rows_duplicate_padding_benign():
     np.testing.assert_allclose(
         np.asarray(merged["temps"]), [0.0, 0.5, 0.0, 0.25])
     assert merged["token"].dtype == jnp.int32
+
+
+def test_a_fresh_ledger_finds_no_label_an_engine_left_on_the_thread():
+    """An engine that served under an earlier ledger leaves its last
+    dispatch's label (``serve_chunk``) on the thread; a ledger
+    installed afterwards must not book its first compile under it.
+    Dealt to one xdist worker before ``test_compiles.py``, this file
+    made ``test_two_threads_stacks_do_not_mix`` read ``'serve_chunk'
+    != 'unlabeled'`` (PERF.md section 7, after PR 38 (0))."""
+    previous = compiles.LEDGER
+    if previous is not None:
+        compiles.uninstall()
+    try:
+        compiles.set_label("serve_chunk", "s8")
+        compiles.install(service="fresh")
+        assert compiles.current_label() == ("unlabeled", "")
+    finally:
+        compiles.uninstall()
+        if previous is not None:
+            compiles.install(ledger=previous)

@@ -518,3 +518,56 @@ def test_latent_decode_reads_rows_and_expands_nothing(one_chip, as_on_tpu):
                 len(_custom_call_lines(entry, "latent_append"))) \
             == inline[:2] and wide[64] >= inline[2], wide
     assert cell_mixed.as_text().count(" while(") == 0
+
+
+# --------------------------------------------------------------------------- #
+# The block-pass programs at sdar30b.fixedlen's sizes
+
+
+def test_block_pass_programs_compile_for_v5e(one_chip, as_on_tpu):
+    """``models/sdar.py`` at the cell's widths (2 of its 12 layers): the
+    chunk of passes is ONE ``while`` whose body writes a block's rows
+    by scatter and attends with one decode-kernel call a layer (a 3-D
+    bfloat16 result, ``B x heads`` query rows a slot: what
+    ``decode_attn_roofline`` finds), the slices run the append kernel
+    under the block-causal mask, and nothing copies or transposes a
+    K/V pool."""
+    from benchmark.builders import sdar_moe as builder
+    from aiko_services_tpu.models import sdar
+    cfg = json.loads((pathlib.Path(__file__).resolve().parents[1]
+                      / "benchmark/configs/"
+                      "sdar-30b-a3b-chat-l12e32.json").read_text())
+    cfg = dict(cfg, num_hidden_layers=2)
+    config = builder.program_config("sdar_compile_test", cfg)
+    slots, n_blocks, table = 64, 1025, 129
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = _shaped(jax.eval_shape(
+        lambda: builder.build_params(cfg, 1)), one_chip)
+    pool = _shaped(jax.eval_shape(
+        lambda: sdar.init_paged_cache(config, n_blocks, 16)), one_chip)
+    state = {"token": S((slots, 1), jnp.int32),
+             "positions": S((slots,), jnp.int32),
+             "active": S((slots,), jnp.bool_),
+             "remaining": S((slots,), jnp.int32),
+             "temps": S((slots,), jnp.float32),
+             "tops": S((slots,), jnp.float32),
+             "adapter_ids": S((slots,), jnp.int32),
+             "tables": S((slots, table), jnp.int32)}
+    state.update({name: S(leaf.shape, leaf.dtype) for name, leaf
+                  in sdar.block_slot_state(config, slots).items()})
+    tokens, scalar = S((1, 256), jnp.int32), S((), jnp.int32)
+    chunk = sdar.serve_chunk_paged.lower(
+        params, state, pool, 3, config).compile().as_text()
+    mixed = sdar._mixed_program.lower(
+        params, state, pool, tokens, scalar, scalar, 3, config, -1,
+        False, None, 64).compile().as_text()
+    for text in (chunk, mixed):
+        assert text.count(" while(") == 1
+        assert not _pool_shaped_ops(text, n_blocks, 4)
+        calls = _custom_call_lines("\n".join(_scan_body(text)),
+                                   "closed_call")
+        assert len(calls) == config.n_layers
+        for line in calls:
+            assert re.search(r"= bf16\[64,128,128\]", line)
+    assert "%paged_prefill_call" in mixed
+    assert "%paged_prefill_call" not in chunk
