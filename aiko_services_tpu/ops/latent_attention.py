@@ -56,7 +56,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import NEG_INF
-from .paged_attention import (LANES, MXU_PRECISION, _bf16_terms,
+from .paged_attention import (LANES, _contract_terms, _mxu_terms,
                               decode_kernel_mode, runs_kernel)
 from .paged_prefill import prefill_kernel_mode
 
@@ -176,33 +176,6 @@ def latent_append_reference(pool, rows, block_ids, offsets=None):
 
 # --------------------------------------------------------------------------- #
 # The attention kernel: one body, two grids
-
-
-def _mxu_terms(x, row_dtype):
-    """``x`` as the MXU takes it against pool rows of ``row_dtype`` at
-    f32 contract precision: against bf16 rows an f32 ``x (n, m)`` is
-    its three bf16 terms stacked ``(3 n, m)`` (every product of a term
-    with a row is exact and the MXU accumulates in f32, so ONE pass
-    over the rows carries f32's 24 bits:
-    :func:`.paged_attention._contract_pool_rows`); a bf16 ``x`` is its
-    own single term; against f32 rows ``x`` goes as f32."""
-    if row_dtype != jnp.bfloat16:
-        return x.astype(jnp.float32)
-    return x if x.dtype == jnp.bfloat16 else _bf16_terms(x)
-
-
-def _contract_terms(terms, n: int, rows, dims):
-    """``terms`` (:func:`_mxu_terms` of an ``(n, m)`` operand) against
-    pool ``rows`` -> f32 ``(n, ...)``."""
-    if rows.dtype != jnp.bfloat16:
-        return jax.lax.dot_general(
-            terms, rows, (dims, ((), ())), precision=MXU_PRECISION,
-            preferred_element_type=jnp.float32)
-    out = jax.lax.dot_general(terms, rows, (dims, ((), ())),
-                              preferred_element_type=jnp.float32)
-    if terms.shape[0] == n:
-        return out
-    return out[:n] + out[n:2 * n] + out[2 * n:]
 
 
 def _latent_attention_kernel(tables_ref, lengths_ref,   # scalar prefetch

@@ -14,23 +14,30 @@ costs time in proportion to them:
 * grid ``(batch-row,)``: one program per row, every head.  Inside it a
   ``fori_loop`` runs over the row's LIVE table entries alone — from the
   first block a sliding window still reaches to the block holding the
-  row's position — ``P`` pool blocks an iteration
-  (:func:`decode_blocks_per_iteration`: 128 keys' worth, 8 blocks of
-  16, 1 of 128).  A row that holds nothing (an idle serving slot: zero
-  table, position under a block) is one iteration; the table's width
-  (what the server *could* hold) costs nothing.
+  row's position — ``W`` keys an iteration
+  (:func:`decode_keys_per_iteration`: 128 to 512, the fewer the more
+  kv heads).  A row that holds nothing (an idle
+  serving slot: zero table, position under a block) is one iteration
+  over one block; the table's width (what the server *could* hold)
+  costs nothing.
 * the pools stay in HBM (``memory_space=pl.ANY``); an iteration's
-  blocks are copied one by one (``make_async_copy``, one contiguous
-  ``(block_size, kv_heads, head_dim)`` DMA each) into one of two VMEM
-  key buffers while the other is attended over.  The block table and
-  per-row positions ride scalar prefetch
+  live blocks are copied one by one (``make_async_copy``, one
+  contiguous ``(block_size, kv_heads, head_dim)`` DMA each) into one
+  of two VMEM key buffers while the other is attended over.  The block
+  table and per-row positions ride scalar prefetch
   (``PrefetchScalarGridSpec``), so the copies are addressed from SMEM
   before any vector work.
-* table entries past the row's last live block inside its last group
-  are clamped to that block and masked by absolute key id, so an entry
-  the row does not own is never dereferenced and no buffer row that is
-  attended over is stale.  A group holding ONE live block (a row's
-  last, an idle slot's only) copies and attends over that block alone.
+* an iteration attends over the narrowest of three static tiles that
+  holds its live blocks (:func:`decode_tiles`: one block, a group of
+  128 keys, ``W``), so the chain a kv head pays a pass (strided head
+  read, score matmul, mask, max, exp, sum, weighted-sum matmul,
+  scratch update) is paid once per ``W`` keys and a row's tail costs
+  what it holds.  Table entries past the row's last live block inside
+  its last group are clamped to that block and masked by absolute key
+  id, so an entry the row does not own is never dereferenced; a wide
+  tile's groups past the last one copied hold an earlier row's values,
+  masked like them and zeroed in the V buffer first (a zero weight has
+  to meet something finite).
 * online-softmax state for every head lives in VMEM scratch across the
   loop (flash-decoding style — running max ``m``, denominator ``l``,
   accumulator ``acc`` in f32).  Heads are split in-kernel
@@ -39,7 +46,9 @@ costs time in proportion to them:
   matmul per iteration instead of ``group`` skinny dot products.
   Contract precision is f32's: six MXU passes over f32 pool rows, ONE
   over rows that are bf16 values (bf16 pools, widened int8), with the
-  f32 side's three bf16 terms stacked (:func:`_contract_pool_rows`).
+  f32 side's three bf16 terms stacked (:func:`_contract_terms`); the
+  queries' terms are made once a program, and queries that ARE bf16
+  values are their own single term.
 * int8 KV dequantizes in-kernel: the per-(token, head) scales factor
   out of the q·k contraction and into the softmax weights, so they
   multiply the lane-dense (group, keys) tiles — the cache is read at
@@ -65,6 +74,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -75,7 +85,9 @@ __all__ = ["paged_decode_attention", "paged_decode_reference",
            "decode_kernel_mode",
            "decode_dispatch", "decode_attention_path",
            "contiguous_block_size", "kernel_serves", "load_head_rows",
-           "decode_blocks_per_iteration", "decode_scale_row",
+           "blocks_per_group", "decode_keys_per_iteration", "decode_tiles",
+           "decode_tile_index", "decode_loop_bounds",
+           "decode_iteration_counts", "decode_scale_row",
            "decode_append_dispatch", "decode_scale_append_path",
            "paged_decode_append"]
 
@@ -367,29 +379,45 @@ def _bf16_terms(x):
     return jnp.concatenate([hi, mid, lo], axis=0).astype(jnp.bfloat16)
 
 
-def _contract_pool_rows(lhs, rows, dims):
-    """``lhs`` (f32 or bf16) against pool rows at f32 contract
-    precision.
+def _mxu_terms(x, row_dtype):
+    """``x`` as the MXU takes it against pool rows of ``row_dtype`` at
+    f32 contract precision: against bf16 rows an f32 ``x (n, m)`` is
+    its three bf16 terms stacked ``(3 n, m)``; a bf16 ``x`` is its own
+    single term; against f32 rows ``x`` goes as f32."""
+    if row_dtype != jnp.bfloat16:
+        return x.astype(jnp.float32)
+    return x if x.dtype == jnp.bfloat16 else _bf16_terms(x)
+
+
+def _contract_terms(terms, n: int, rows, dims):
+    """``terms`` (:func:`_mxu_terms` of an ``(n, m)`` operand) against
+    pool ``rows`` -> f32 ``(n, ...)``.
 
     f32 rows: the MXU at :data:`MXU_PRECISION`, six bf16 passes over
     the rows.  bf16 rows (a bf16 pool's, or an int8 pool's widened:
-    both exact in bf16): every product of a bf16 term of ``lhs`` with
-    a row is exact and the MXU accumulates in f32, so ONE pass over
-    the rows with the three terms of ``lhs`` stacked gives the same
-    precision — the decode kernel's time is in those passes and in
-    widening the rows, not in bytes (v5e, PERF.md PR 25).  A bf16
-    ``lhs`` (the append kernel's queries) is its own single term."""
+    both exact in bf16): every product of a bf16 term with a row is
+    exact and the MXU accumulates in f32, so ONE pass over the rows
+    with the three terms stacked gives the same precision, and a
+    single term (an operand that IS bf16) the same result for a third
+    of the rows."""
     if rows.dtype != jnp.bfloat16:
         return jax.lax.dot_general(
-            lhs.astype(jnp.float32), rows, (dims, ((), ())),
-            precision=MXU_PRECISION, preferred_element_type=jnp.float32)
-    if lhs.dtype == jnp.bfloat16:
-        return jax.lax.dot_general(lhs, rows, (dims, ((), ())),
-                                   preferred_element_type=jnp.float32)
-    n = lhs.shape[0]
-    out = jax.lax.dot_general(_bf16_terms(lhs), rows, (dims, ((), ())),
+            terms, rows, (dims, ((), ())), precision=MXU_PRECISION,
+            preferred_element_type=jnp.float32)
+    out = jax.lax.dot_general(terms, rows, (dims, ((), ())),
                               preferred_element_type=jnp.float32)
+    if terms.shape[0] == n:
+        return out
     return out[:n] + out[n:2 * n] + out[2 * n:]
+
+
+def _contract_pool_rows(lhs, rows, dims):
+    """``lhs`` (f32 or bf16) against pool rows at f32 contract
+    precision: :func:`_contract_terms` of its :func:`_mxu_terms`, made
+    where they are used (the prefill kernel's step; the decode kernel
+    makes its queries' terms once a program)."""
+    return _contract_terms(_mxu_terms(lhs, rows.dtype), lhs.shape[0],
+                           rows, dims)
 
 
 def load_head_rows(block_ref, head: int, dtype=jnp.float32):
@@ -402,22 +430,111 @@ def load_head_rows(block_ref, head: int, dtype=jnp.float32):
     return block_ref[:, head, :].astype(dtype)
 
 
-#: Keys one iteration of the decode kernel's block-table loop holds in
-#: VMEM: a lane-wide score tile, and enough bytes per DMA group that
-#: the fixed cost of an iteration is paid for 128 keys, not for 16.
-DECODE_KEYS_PER_ITERATION = 128
+#: Keys of one GROUP of block copies: a lane-wide score tile, what the
+#: prefill kernel's step covers, the least a decode iteration covers
+#: past a lone block, and the step its width grows in.
+KEYS_PER_GROUP = 128
 
 #: Lanes of one VMEM row: the int8 scale planes ride the decode kernel's
 #: DMAs as rows of this many f32 (see :func:`decode_scale_row`).
 LANES = 128
 
+#: The most keys one iteration of the decode kernel's block-table loop
+#: covers (:func:`decode_keys_per_iteration`).
+MAX_DECODE_KEYS_PER_ITERATION = 512
 
-def decode_blocks_per_iteration(block_size: int) -> int:
-    """Pool blocks ``P`` one loop iteration of the decode kernel copies
-    and attends over: :data:`DECODE_KEYS_PER_ITERATION` keys' worth, at
-    least one block — 8 at the paged pool's block 16, 1 at the
-    contiguous view's 128."""
-    return max(1, DECODE_KEYS_PER_ITERATION // block_size)
+#: The most head tiles (one kv head's group of :data:`KEYS_PER_GROUP`
+#: keys) an iteration attends over: what the widest geometry served (8
+#: kv heads) had at one group an iteration.
+DECODE_HEAD_TILES_PER_ITERATION = 8
+
+
+def blocks_per_group(block_size: int) -> int:
+    """Pool blocks of one group of :data:`KEYS_PER_GROUP` keys, at
+    least one — 8 at the paged pool's block 16, 1 at the contiguous
+    view's 128.  One step of the prefill kernel covers a group."""
+    return max(1, KEYS_PER_GROUP // block_size)
+
+
+def decode_keys_per_iteration(table_keys: int, block_size: int,
+                              kv_heads: int) -> int:
+    """Keys ``W`` one pass through the decode kernel's block-table loop
+    copies and attends over, from what the call can see: the keys a
+    table row can hold, the pool's block size and its kv heads.
+
+    An iteration pays a serial chain once a kv head whatever its width
+    (strided head read, score matmul, mask, lane max, exp, lane sum,
+    weighted-sum matmul, scratch update: PERF.md section 6, PR 40), so
+    a wider one pays it less often.  But its attend is unrolled, a
+    head tile (a kv head's :data:`KEYS_PER_GROUP` keys) after another,
+    and its code and buffers grow with heads times keys: 512 keys of 8
+    int8 heads cost a serving step with one or two live rows more,
+    between its other programs, than the rows gained
+    (``mistral7b.chat``, PERF.md section 6, PR 40, review round; the
+    kernel timed alone does not show it).  So an iteration covers
+    whole groups, at most
+    :data:`DECODE_HEAD_TILES_PER_ITERATION` head tiles — 128 keys of 8
+    kv heads, what it covered before it was widened, 256 of 4, 512 of
+    2 — at most :data:`MAX_DECODE_KEYS_PER_ITERATION` keys, at least
+    one group, and never more than the table can hold."""
+    group = max(KEYS_PER_GROUP, block_size)
+    keys = min(DECODE_HEAD_TILES_PER_ITERATION // kv_heads * KEYS_PER_GROUP,
+               MAX_DECODE_KEYS_PER_ITERATION, -(-table_keys // group) * group)
+    return max(group, keys // group * group)
+
+
+def decode_tiles(block_size: int, wide_keys: int) -> Tuple[int, ...]:
+    """The static tile widths (keys) an iteration of the decode kernel
+    attends over, ascending: one block, one group, ``wide_keys`` — the
+    narrowest that holds the iteration's live blocks is taken at run
+    time (:func:`decode_tile_index`)."""
+    return tuple(sorted({block_size, max(KEYS_PER_GROUP, block_size),
+                         wide_keys}))
+
+
+def decode_tile_index(held_blocks, block_size: int, tiles):
+    """Index into ``tiles`` of the tile an iteration holding
+    ``held_blocks`` live blocks attends over (int, numpy or traced)."""
+    return sum((held_blocks * block_size > keys) * 1 for keys in tiles[:-1])
+
+
+def decode_loop_bounds(positions, *, block_size: int, table_blocks: int,
+                       wide_keys: int, window: Optional[int], xp=jnp):
+    """``(first_live, last_live, iterations)`` of the decode kernel's
+    loop for rows at ``positions``: the live band of table entries
+    (from the first block a sliding window still reaches to the block
+    holding the position) and the passes of ``wide_keys`` keys that
+    cover it.  ``xp`` is the array module: the kernel calls it on a
+    traced scalar, the host's counters on numpy arrays."""
+    last_live = xp.minimum(positions // block_size, table_blocks - 1)
+    first_live = xp.zeros_like(last_live)
+    if window is not None:
+        first_live = xp.maximum(positions - window + 1, 0) // block_size
+    iterations = (last_live - first_live) // (wide_keys // block_size) + 1
+    return first_live, last_live, iterations
+
+
+def decode_iteration_counts(positions, *, block_size: int,
+                            table_blocks: int, kv_heads: int,
+                            window: Optional[int]):
+    """``(iterations, wide iterations)`` a row, numpy, of a decode call
+    over rows at ``positions``, as the kernel's own loop bounds give
+    them: the serving counters ``decode_iterations`` and
+    ``decode_wide_iterations``.  A wide iteration attends over the
+    whole ``W``-key tile; every iteration of a row but its last is
+    one."""
+    wide_keys = decode_keys_per_iteration(table_blocks * block_size,
+                                          block_size, kv_heads)
+    tiles = decode_tiles(block_size, wide_keys)
+    first_live, last_live, iterations = decode_loop_bounds(
+        np.asarray(positions), block_size=block_size,
+        table_blocks=table_blocks, wide_keys=wide_keys, window=window,
+        xp=np)
+    held_last = (last_live - first_live + 1
+                 - (iterations - 1) * (wide_keys // block_size))
+    last_is_wide = decode_tile_index(held_last, block_size,
+                                     tiles) == len(tiles) - 1
+    return iterations, iterations - 1 + last_is_wide
 
 
 def decode_scale_row(block_size: int, kv_heads: int) -> int:
@@ -463,27 +580,43 @@ def _head_scale_rows(flat, kv_heads: int, keys: int):
 
 def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
                          q_ref, k_hbm, v_hbm, *rest,
-                         block_size: int, blocks_per_iter: int,
-                         group: int, sm_scale: float,
-                         window: Optional[int], quantized: bool):
+                         block_size: int, group: int, sm_scale: float,
+                         window: Optional[int], quantized: bool,
+                         q_is_bf16: bool):
     """Grid: (batch,).  One program = one row, every head, and a loop
-    over the row's LIVE table entries, ``blocks_per_iter`` pool blocks
-    an iteration.
+    over the row's LIVE table entries, ``W`` keys an iteration (the key
+    buffers' length, :func:`decode_keys_per_iteration`).
 
     ``tables_ref`` / ``positions_ref`` are the scalar-prefetched block
     table and per-row positions; the pools (and int8 scale planes) stay
     in HBM and are copied, block by block, into one of two VMEM key
-    buffers of ``blocks_per_iter · block_size`` keys while the other is
-    being attended over.  Scratch carries the online-softmax state of
-    all ``kv_heads · group`` query heads (one scratch row each, kv-head
-    major) across the loop."""
+    buffers of ``W`` keys while the other is being attended over.
+    Scratch carries the online-softmax state of all ``kv_heads · group``
+    query heads (one scratch row each, kv-head major) across the loop.
+
+    An iteration copies the live blocks it holds — a lone block alone,
+    else whole groups of :data:`KEYS_PER_GROUP` keys, entries past the
+    row's last live block clamped to it — and attends over the
+    narrowest of :func:`decode_tiles` that holds them: the chain a kv
+    head pays an iteration (module docstring) is paid once per ``W``
+    keys, a row's tail pays for what it holds, and an idle slot pays
+    what it paid before the loop was widened: one block's copies behind
+    one comparison, one 16-key attend behind another.  A wide tile's
+    groups past the last one copied hold what an EARLIER row left
+    there; their keys are masked and weigh zero, and what a zero
+    multiplies has to be finite, so that pass first zeroes them in the
+    V buffer (an int8 row is finite as it is: its scales are zeroed
+    where they are used).
+
+    The queries' MXU terms are the program's, not an iteration's
+    (``q_is_bf16``: the rows ARE bf16 values, their own single term)."""
     def of_block(pool_hbm):
         return lambda block: pool_hbm.at[block]
 
     if quantized:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
          m_scr, l_scr, acc_scr) = rest
-        plane_rows = ks_buf.shape[1] // blocks_per_iter
+        plane_rows = ks_buf.shape[1] * block_size // k_buf.shape[1]
 
         def of_plane(rows_hbm):
             return lambda block: rows_hbm.at[
@@ -501,83 +634,101 @@ def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
     row = pl.program_id(0)
     pos = positions_ref[row]
     kv_heads = k_buf.shape[2]
-    keys = blocks_per_iter * block_size
+    wide_keys = k_buf.shape[1]
+    wide_blocks = wide_keys // block_size
+    group_blocks = blocks_per_group(block_size)
+    group_keys = group_blocks * block_size
+    wide_groups = wide_blocks // group_blocks
+    tiles = decode_tiles(block_size, wide_keys)
 
     # Liveness, per table entry: an entry past the row's length holds
     # nothing, and with a sliding window neither does one whose LAST
     # key is already out of the window.  The loop runs over the live
     # band [first_live, last_live] alone, so a row costs iterations in
     # proportion to what it holds (an inactive slot: one).
-    last_live = jnp.minimum(pos // block_size, tables_ref.shape[1] - 1)
-    first_live = 0
-    if window is not None:
-        first_live = jnp.maximum(pos - window + 1, 0) // block_size
-    iterations = (last_live - first_live) // blocks_per_iter + 1
+    first_live, last_live, iterations = decode_loop_bounds(
+        pos, block_size=block_size, table_blocks=tables_ref.shape[1],
+        wide_keys=wide_keys, window=window)
 
-    def lone(c):
-        """Does iteration ``c`` hold ONE live block?  (A row's last
-        group may; an idle serving slot's only group does.)"""
-        return first_live + c * blocks_per_iter >= last_live
+    def held_groups(first_entry):
+        """Groups an iteration from ``first_entry`` on copies."""
+        held = jnp.minimum(last_live - first_entry + 1, wide_blocks)
+        return (held + group_blocks - 1) // group_blocks
 
-    def group_copies(c, slot, resolve: bool):
-        """The DMAs of loop iteration ``c`` into buffer ``slot``: its
-        first block's, and the rest's.  Table entries past
-        ``last_live`` inside the last group are clamped to it (their
-        keys are masked by absolute id below), so an entry the row does
-        not own is never dereferenced and no buffer row attended over
-        is ever stale.  A descriptor built only to be waited on
+    def for_copies(c, slot, resolve: bool, act):
+        """``act`` on the DMAs of iteration ``c`` into buffer ``slot``:
+        its first block's, and unless that block is alone the rest of
+        its group's and every further live group's, table entries past
+        the row's last live block clamped to it (their keys are masked
+        by absolute id below), so an entry the row does not own is
+        never dereferenced.  A descriptor built only to be waited on
         (``resolve`` false) names block 0: a wait needs the copy's
         shape and semaphore, not its source."""
-        first, rest = [], []
-        for i in range(blocks_per_iter):
+        first_entry = first_live + c * wide_blocks
+
+        def entry(at):
             block = 0
             if resolve:
-                entry = jnp.minimum(
-                    first_live + c * blocks_per_iter + i, last_live)
-                block = tables_ref[row, entry]
-            (rest if i else first).extend(
-                pltpu.make_async_copy(
-                    source(block), buf.at[slot, pl.ds(i * rows, rows)],
-                    sems.at[slot]) for source, buf, rows in streams)
-        return first, rest
+                block = tables_ref[row, jnp.minimum(first_entry + at,
+                                                    last_live)]
+            for source, buf, rows in streams:
+                start = at * rows
+                if not isinstance(at, int):
+                    start = pl.multiple_of(start, rows)
+                act(pltpu.make_async_copy(
+                    source(block), buf.at[slot, pl.ds(start, rows)],
+                    sems.at[slot]))
 
-    def for_group(c, slot, resolve: bool, act):
-        """``act`` on iteration ``c``'s copies — all of them, or for a
-        lone block (which is attended over alone) only its own."""
-        first, rest = group_copies(c, slot, resolve)
-        for copy in first:
-            act(copy)
-        if rest:
-            @pl.when(jnp.logical_not(lone(c)))
-            def _rest():
-                for copy in rest:
-                    act(copy)
+        entry(0)
+        if wide_blocks == 1:
+            return
+
+        @pl.when(first_entry < last_live)
+        def _rest():
+            for at in range(1, group_blocks):
+                entry(at)
+            if wide_groups > 1:
+                def one_group(g, carry):
+                    for i in range(group_blocks):
+                        entry(g * group_blocks + i)
+                    return carry
+                jax.lax.fori_loop(1, held_groups(first_entry),
+                                  one_group, 0)
 
     m_scr[:] = jnp.full_like(m_scr, NEG_INF)
     l_scr[:] = jnp.zeros_like(l_scr)
     acc_scr[:] = jnp.zeros_like(acc_scr)
-    for_group(0, 0, True, lambda copy: copy.start())
+    for_copies(0, 0, True, lambda copy: copy.start())
     # Pool rows that are bf16 values (bf16 and widened int8) meet the
-    # MXU as bf16: see _contract_pool_rows.
+    # MXU as bf16: see _contract_terms.
     row_dtype = (jnp.float32 if k_buf.dtype == jnp.float32
                  else jnp.bfloat16)
+    q_terms = []
+    for head in range(kv_heads):
+        q = q_ref[0, head * group:(head + 1) * group, :]   # (group, hd)
+        if q_is_bf16:
+            q = q.astype(jnp.bfloat16)
+        q_terms.append(_mxu_terms(q, row_dtype))
 
     def body(c, carry):
         slot = c % 2
 
         @pl.when(c + 1 < iterations)
         def _prefetch():
-            for_group(c + 1, 1 - slot, True, lambda copy: copy.start())
+            for_copies(c + 1, 1 - slot, True, lambda copy: copy.start())
 
-        for_group(c, slot, False, lambda copy: copy.wait())
-        first_key = (first_live + c * blocks_per_iter) * block_size
+        for_copies(c, slot, False, lambda copy: copy.wait())
+        first_entry = first_live + c * wide_blocks
+        first_key = first_entry * block_size
 
         def attend(n_keys: int):
             """Online-softmax update from the buffer's first ``n_keys``
-            keys.  Every group provably contains >= 1 visible key (its
-            first entry is live), so no bogus softmax mass is ever
+            keys.  Every iteration provably contains >= 1 visible key
+            (its first entry is live), so no bogus softmax mass is ever
             accumulated (NEG_INF stays finite regardless — see
             ops/attention.py)."""
+            # Does the tile reach past the groups this pass copied?
+            ragged = n_keys > group_keys
             key_ids = first_key + jax.lax.broadcasted_iota(
                 jnp.int32, (group, n_keys), 1)
             visible = key_ids <= pos
@@ -595,14 +746,25 @@ def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
                                             kv_heads, n_keys)
                 v_scales = _head_scale_rows(vs_buf[slot, :scale_rows],
                                             kv_heads, n_keys)
+                if ragged:
+                    v_scales = jnp.where(visible[:1], v_scales, 0.0)
+            elif ragged:
+                def clear(g, carry):
+                    v_buf[slot, pl.ds(
+                        pl.multiple_of(g * group_keys, group_keys),
+                        group_keys)] = jnp.zeros(
+                            (group_keys,) + v_buf.shape[2:], v_buf.dtype)
+                    return carry
+                jax.lax.fori_loop(held_groups(first_entry), wide_groups,
+                                  clear, 0)
             for head in range(kv_heads):
                 rows = slice(head * group, (head + 1) * group)
-                q = q_ref[0, rows, :]                  # (group, hd) f32
                 k = load_head_rows(k_buf.at[slot, :n_keys], head,
                                    row_dtype)          # (n_keys, hd)
                 v = load_head_rows(v_buf.at[slot, :n_keys], head,
                                    row_dtype)
-                s = _contract_pool_rows(q, k, ((1,), (1,))) * sm_scale
+                s = _contract_terms(q_terms[head], group, k,
+                                    ((1,), (1,))) * sm_scale
                 if quantized:
                     s = s * k_scales[head:head + 1, :]
                 s = jnp.where(visible, s, NEG_INF)     # (group, n_keys)
@@ -618,16 +780,25 @@ def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
                     p = p * v_scales[head:head + 1, :]
                 acc_scr[rows, :] = (
                     acc_scr[rows, :] * correction
-                    + _contract_pool_rows(p, v, ((1,), (0,))))
+                    + _contract_terms(_mxu_terms(p, row_dtype), group, v,
+                                      ((1,), (0,))))
                 m_scr[rows, :] = m_new
 
-        if blocks_per_iter == 1:
-            attend(keys)
-        else:
-            # A lone block costs a block's work, not a group's: 29 of
-            # the 32 rows of the benchmark's chat cells are idle slots.
-            jax.lax.cond(lone(c), lambda: attend(block_size),
-                         lambda: attend(keys))
+        def narrowest(tiles):
+            """Attend over the first of ``tiles`` (ascending) that
+            holds the iteration's live blocks: a lone block costs a
+            block's work and a row's tail a group's, not an
+            iteration's (29 of the 32 rows of the benchmark's chat
+            cells are idle slots, and theirs is the first test)."""
+            if len(tiles) == 1:
+                attend(tiles[0])
+            else:
+                jax.lax.cond(
+                    last_live - first_entry < tiles[0] // block_size,
+                    lambda: attend(tiles[0]),
+                    lambda: narrowest(tiles[1:]))
+
+        narrowest(tiles)
         return carry
 
     jax.lax.fori_loop(0, iterations, body, 0)
@@ -711,15 +882,15 @@ def closed_call(q, k_pool, v_pool, tables, positions, ks, vs, *,
     tables = tables.astype(jnp.int32)
     positions = positions.astype(jnp.int32)
     heads = kv_heads * group
-    blocks_per_iter = decode_blocks_per_iteration(block_size)
-    keys = blocks_per_iter * block_size
+    keys = decode_keys_per_iteration(tables.shape[1] * block_size,
+                                     block_size, kv_heads)
 
     def q_index(row, tables_ref, positions_ref):
         return (row, 0, 0)
 
     # Queries ride as f32 rows, kv-head major (row = kv_head·group + g):
-    # a few KB that XLA widens once, so a head's rows are a plain
-    # sublane slice of an unpacked tile.
+    # XLA widens them once, so a head's rows are a plain sublane slice
+    # of an unpacked tile; the kernel is told when they are bf16 values.
     q_rows = q.reshape(batch, heads, head_dim).astype(jnp.float32)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [pl.BlockSpec((1, heads, head_dim), q_index),
@@ -742,7 +913,7 @@ def closed_call(q, k_pool, v_pool, tables, positions, ks, vs, *,
         in_specs += [in_hbm, in_hbm]
         operands += [ks.reshape(-1, scale_row), vs.reshape(-1, scale_row)]
         scale_buffer = pltpu.VMEM(
-            (2, blocks_per_iter * plane_rows, scale_row), ks.dtype)
+            (2, keys // block_size * plane_rows, scale_row), ks.dtype)
         scratch_shapes += [scale_buffer, scale_buffer]
     scratch_shapes += [
         pltpu.SemaphoreType.DMA((2,)),
@@ -752,9 +923,9 @@ def closed_call(q, k_pool, v_pool, tables, positions, ks, vs, *,
     ]
 
     kernel = functools.partial(
-        _paged_decode_kernel, block_size=block_size,
-        blocks_per_iter=blocks_per_iter, group=group,
-        sm_scale=sm_scale, window=window, quantized=quantized)
+        _paged_decode_kernel, block_size=block_size, group=group,
+        sm_scale=sm_scale, window=window, quantized=quantized,
+        q_is_bf16=q.dtype == jnp.bfloat16)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -69,7 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .attention import NEG_INF
 from .paged_attention import (MXU_PRECISION, _contract_pool_rows,
                               cached_gqa_attention,
-                              decode_blocks_per_iteration, kernel_mode,
+                              blocks_per_group, kernel_mode,
                               kernel_serves, load_head_rows, runs_kernel)
 
 __all__ = ["paged_prefill_attention", "paged_prefill_reference",
@@ -565,7 +565,7 @@ def paged_prefill_call(q, pool, tables, cached_lens, *,
     batch, T, kv_heads, group, head_dim = q.shape
     block_size = pool["k"].shape[1]
     quantized = "ks" in pool
-    P = decode_blocks_per_iteration(block_size)
+    P = blocks_per_group(block_size)
     # All group heads of a query stack into the tile row axis: 2D tiles
     # everywhere in-kernel, one (q_tile*group, hd) x (hd, keys) matmul
     # per head per step.

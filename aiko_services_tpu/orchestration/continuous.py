@@ -576,7 +576,8 @@ class ContinuousBatchingServer:
             host_syncs=0, sync_wait_ms=0.0, sync_elements=0,
             state_uploads=0, dirty_rows_uploaded=0, max_in_flight=0,
             ring_starved_steps=0, admission_deferred=0,
-            decode_blocks_read=0, prefill_tokens=0,
+            decode_blocks_read=0, decode_iterations=0,
+            decode_wide_iterations=0, prefill_tokens=0,
             sp_prefill_dispatches=0,
             # Time to first token, accounted where it is spent: the
             # six below are added TOGETHER when a request's first
@@ -878,6 +879,20 @@ class ContinuousBatchingServer:
             window = self.config.sliding_window
             if window:
                 blocks = np.minimum(blocks, window // block_size + 1)
+            if not hasattr(self._model, "attention_paths"):
+                # The K/V decode kernel's own loop bounds at the same
+                # positions: passes through its block-table loop, and
+                # those that attend over a whole W-key tile.
+                from ..ops.paged_attention import decode_iteration_counts
+                _, kv_heads, _ = self._kv_geometry()
+                for rows, counter in zip(decode_iteration_counts(
+                        self.positions[live] + reach - 1,
+                        block_size=block_size,
+                        table_blocks=self._attn_total_blocks,
+                        kv_heads=kv_heads, window=window or None),
+                        ("decode_iterations", "decode_wide_iterations")):
+                    self.counters[counter] += int(
+                        (rows * sched_live).sum())
         else:
             blocks = np.full(sched_live.shape, self._attn_total_blocks,
                              np.int64)
@@ -2783,6 +2798,12 @@ class ContinuousBatchingServer:
                 round(self.counters["decode_blocks_read"]
                       / (steps * self.slots * self._attn_total_blocks),
                       4) if steps else 0.0),
+            # Share of the K/V decode kernel's loop passes that attend
+            # over a whole W-key tile (the rest are rows' tails).
+            decode_wide_iteration_share=(
+                round(self.counters["decode_wide_iterations"]
+                      / self.counters["decode_iterations"], 4)
+                if self.counters["decode_iterations"] else 0.0),
             decode_steps_per_sec=(
                 round(steps / elapsed, 1) if elapsed > 0 else 0.0),
             prefill_tokens_per_sec=(

@@ -64,9 +64,11 @@ def _parity(q, k, v, tables, positions, tol, window=None, **kv_args):
                                rtol=tol)
 
 
-def test_kernel_matches_reference_ragged_lengths():
-    q, k, v, tables, kv_args = _pool_case()
-    _parity(q, k, v, tables, [0, 17, 63], 2e-5, **kv_args)
+@pytest.mark.parametrize("max_blocks,positions", [
+    (4, [0, 17, 63]), (72, [0, 545, 1151])])
+def test_kernel_matches_reference_ragged_lengths(max_blocks, positions):
+    q, k, v, tables, kv_args = _pool_case(max_blocks=max_blocks)
+    _parity(q, k, v, tables, positions, 2e-5, **kv_args)
 
 
 @pytest.mark.parametrize("heads,kv_heads", [(1, 1), (4, 1), (8, 1),
@@ -92,10 +94,14 @@ def test_kernel_block_boundary_edges():
     _parity(q1, k1, v1, tables1, [0, 7, 15], 2e-5, **kv1)
 
 
-def test_kernel_int8_kv_parity():
-    q, k, v, tables, kv_args = _pool_case(quant=True)
-    _parity(q, k, v, tables, [4, 29, 63], 1e-4, **kv_args)
-    _parity(q, k, v, tables, [11, 50, 63], 1e-4, window=13, **kv_args)
+@pytest.mark.parametrize("max_blocks,positions", [
+    (4, ([4, 29, 63], [11, 50, 63])),
+    (68, ([510, 529, 1087], [511, 512, 1040]))])
+def test_kernel_int8_kv_parity(max_blocks, positions):
+    q, k, v, tables, kv_args = _pool_case(quant=True,
+                                          max_blocks=max_blocks)
+    _parity(q, k, v, tables, positions[0], 1e-4, **kv_args)
+    _parity(q, k, v, tables, positions[1], 1e-4, window=13, **kv_args)
 
 
 def test_kernel_matches_attention_reference():
@@ -169,9 +175,55 @@ def _typed_parity(q, k, v, exact, tables, positions, kv_args, dtype,
 
 @pytest.mark.parametrize("bs", [16, 32, 128])
 def test_blocks_per_iteration_follows_block_size(bs):
-    per_iter = pa.decode_blocks_per_iteration(bs)
-    assert per_iter == {16: 8, 32: 4, 128: 1}[bs]
-    assert per_iter * bs == pa.DECODE_KEYS_PER_ITERATION
+    """A group of copies is 128 keys whatever the block; the keys an
+    iteration covers are whole groups, from what the call can see."""
+    per_group = pa.blocks_per_group(bs)
+    assert per_group == {16: 8, 32: 4, 128: 1}[bs]
+    assert per_group * bs == pa.KEYS_PER_GROUP
+    wide = pa.decode_keys_per_iteration(4096, bs, 2)
+    assert wide == pa.MAX_DECODE_KEYS_PER_ITERATION == 512
+    assert wide % (per_group * bs) == 0
+    assert pa.decode_tiles(bs, wide) == {16: (16, 128, 512),
+                                         32: (32, 128, 512),
+                                         128: (128, 512)}[bs]
+    assert pa.decode_tiles(bs, 128) == ((bs, 128) if bs < 128 else (128,))
+
+
+@pytest.mark.parametrize("table_keys,kv,wide", [
+    # What the table can hold caps the width, in whole groups.
+    (64, 2, 128), (128, 1, 128), (320, 2, 384), (320, 4, 256),
+    # Eight head tiles an iteration: the benchmark's cells (sdar's 4
+    # kv heads, nemotron_h's 2, mistral's and mixtral's 8) ...
+    (2064, 4, 256), (2304, 2, 512), (2560, 8, 128),
+    # ... never past 512 keys, never under a group.
+    (4096, 1, 512), (4096, 3, 256), (4096, 16, 128), (4096, 32, 128),
+    (4096, 5, 128), (4096, 40, 128)])
+def test_keys_per_iteration_follows_the_call(table_keys, kv, wide):
+    assert pa.decode_keys_per_iteration(table_keys, 16, kv) == wide
+
+
+def test_prefill_kernel_keeps_a_group_of_keys_a_step():
+    """The prefill kernel shares the decode kernel's helpers and not
+    its width: a step's key buffers hold 128 keys."""
+    from aiko_services_tpu.ops import paged_prefill as pp
+    batch, T, kv, group, hd, bs, max_blocks = 1, 32, 2, 2, 16, 16, 40
+    pool = {"k": jnp.zeros((max_blocks + 1, bs, kv, hd), jnp.bfloat16),
+            "v": jnp.zeros((max_blocks + 1, bs, kv, hd), jnp.bfloat16)}
+    q = jnp.zeros((batch, T, kv, group, hd), jnp.bfloat16)
+    tables = jnp.arange(1, max_blocks + 1, dtype=jnp.int32)[None]
+    jaxpr = jax.make_jaxpr(
+        lambda q, pool: pp.paged_prefill_call(
+            q, pool, tables, jnp.asarray([512], jnp.int32), window=None,
+            sm_scale=0.25, q_tile=T, kv_blocks=max_blocks,
+            interpret=True))(q, pool)
+    call, = [eqn for eqn in _iter_eqns(jaxpr.jaxpr)
+             if eqn.primitive.name == "pallas_call"]
+    key_buffers = [aval.shape for aval in call.params[
+        "grid_mapping"].scratch_avals if len(aval.shape) == 3
+        and aval.shape[1:] == (kv, hd)]
+    assert key_buffers == [(pa.KEYS_PER_GROUP, kv, hd)] * 2
+    # ... while a decode call over the same table holds 512.
+    assert pa.decode_keys_per_iteration(max_blocks * bs, bs, kv) == 512
 
 
 @pytest.mark.parametrize("dtype", list(DTYPES))
@@ -180,7 +232,7 @@ def test_kernel_live_blocks_not_a_multiple_of_group(bs, dtype):
     """Rows holding 1, P-1, P, P+1 and 2P+1 live blocks (P = blocks an
     iteration), each ending mid-block: the last group is clamped and
     masked, never short."""
-    per_iter = pa.decode_blocks_per_iteration(bs)
+    per_iter = pa.blocks_per_group(bs)
     counts = sorted({1, max(per_iter - 1, 1), per_iter, per_iter + 1,
                      2 * per_iter + 1})
     case = _typed_case(dtype, bs, max_blocks=2 * per_iter + 2,
@@ -192,38 +244,53 @@ def test_kernel_live_blocks_not_a_multiple_of_group(bs, dtype):
 
 
 @pytest.mark.parametrize("dtype", list(DTYPES))
-def test_kernel_idle_rows_beside_long_rows(dtype):
+@pytest.mark.parametrize("max_blocks,positions", [
+    (20, [300, 0, 5, 319]), (72, [1100, 0, 5, 530])])
+def test_kernel_idle_rows_beside_long_rows(dtype, max_blocks, positions):
     """A row at position 0 and an inactive-style row (zero table,
     position < block_size: what serve_chunk_paged hands over for an
-    idle slot) between rows of several groups."""
-    bs, max_blocks = 16, 20
+    idle slot) between rows of several groups (a table of 320 keys,
+    one wide tile of 384) and of several wide iterations (512)."""
+    bs = 16
     q, k, v, exact, tables, kv_args = _typed_case(
         dtype, bs, max_blocks, batch=4)
     tables = tables.at[2].set(0)
-    _typed_parity(q, k, v, exact, tables, [300, 0, 5, 319], kv_args,
-                  dtype)
+    _typed_parity(q, k, v, exact, tables, positions, kv_args, dtype)
 
 
 @pytest.mark.parametrize("dtype", list(DTYPES))
-@pytest.mark.parametrize("window", [40, 100, 129, 200])
-def test_kernel_window_starts_mid_group(window, dtype):
+@pytest.mark.parametrize("max_blocks,positions,window", [
+    (24, [383, 250, 37, 129], 40), (24, [383, 250, 37, 129], 100),
+    (24, [383, 250, 37, 129], 129), (24, [383, 250, 37, 129], 200),
+    (80, [1279, 900, 37, 641], 200), (80, [1279, 900, 37, 641], 530),
+    (80, [1279, 900, 37, 641], 1030)])
+def test_kernel_window_starts_mid_group(max_blocks, positions, window,
+                                        dtype):
     """Sliding windows whose first live block is neither a multiple of
-    P nor at a block edge; rows shorter than the window beside them."""
-    bs, max_blocks = 16, 24
+    P nor at a block edge; rows shorter than the window beside them;
+    at one wide tile a table (384 keys) and at several iterations of
+    512."""
+    bs = 16
     q, k, v, exact, tables, kv_args = _typed_case(
         dtype, bs, max_blocks, batch=4)
-    _typed_parity(q, k, v, exact, tables, [383, 250, 37, 129], kv_args,
-                  dtype, window=window)
+    _typed_parity(q, k, v, exact, tables, positions, kv_args, dtype,
+                  window=window)
 
 
 @pytest.mark.parametrize("dtype", list(DTYPES))
 @pytest.mark.parametrize("window", [None, 50])
-def test_kernel_never_reads_entries_past_the_row(window, dtype):
+@pytest.mark.parametrize("max_blocks,lengths", [
+    (20, (0, 5 * 16 + 3, 17 * 16 - 1)),
+    (70, (0, 33 * 16 + 3, 67 * 16 - 1))])
+def test_kernel_never_reads_entries_past_the_row(max_blocks, lengths,
+                                                 window, dtype):
     """Table entries past a row's last live block hold out-of-range
     garbage.  The interpreter clamps an out-of-range block id to the
     pool's first or last block, and both hold NaN here (no row owns
-    them), so one dereference would poison the output."""
-    bs, max_blocks, batch = 16, 20, 3
+    them), so one dereference would poison the output.  The wider
+    table's rows end one block into a second wide iteration and three
+    blocks into a third."""
+    bs, batch = 16, 3
     q, k, v, exact, tables, kv_args = _typed_case(
         dtype, bs, max_blocks + 1, batch=batch)
     n_blocks = k.shape[0]
@@ -241,7 +308,7 @@ def test_kernel_never_reads_entries_past_the_row(window, dtype):
 
     k, v = poison(k), poison(v)
     kv_args = {key: poison(val) for key, val in kv_args.items()}
-    positions = [0, 5 * bs + 3, 17 * bs - 1]
+    positions = list(lengths)
     live = np.asarray(positions)[:, None] // bs
     column = np.arange(max_blocks)[None, :]
     garbage = np.where(column % 2, 2 ** 30, -7)
@@ -721,6 +788,13 @@ def test_serving_stats_decode_attention_counters():
         stats["decode_blocks_read"] / (stats["decode_steps"] * 2 * 1),
         abs=1e-4)
     assert 0 < stats["decode_table_live_share"] <= 1
+    if stats["decode_attention_path"] == "kernel":
+        assert stats["decode_iterations"] >= stats["decode_steps"]
+    else:
+        assert stats["decode_iterations"] == 0
+    assert stats["decode_wide_iteration_share"] == pytest.approx(
+        stats["decode_wide_iterations"]
+        / max(stats["decode_iterations"], 1), abs=1e-4)
     telemetry = serving_telemetry(stats)
     assert telemetry["decode_attention_path"] == \
         stats["decode_attention_path"]

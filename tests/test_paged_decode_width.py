@@ -1,0 +1,162 @@
+"""The decode kernel's width (ops/paged_attention.py): rows whose lengths
+sit on every edge of the tiles an iteration can take, for each width
+:func:`decode_keys_per_iteration` can return and each pool dtype, the
+benchmark cells' geometries among them; and the host's count of the
+kernel's iterations.  Interpreted, so CPU-green; beside
+tests/test_paged_attention.py, whose cases it shares, in a file of its
+own so that two workers share the interpreter's time."""
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from aiko_services_tpu.ops import paged_attention as pa
+
+from .test_paged_attention import DTYPES, _typed_case, _typed_parity
+
+#: name -> (pool dtype, kv heads, group, block size, the width W the
+#: call gets): the geometries the width function tells apart, the
+#: benchmark's cells among them.
+WIDTH_CASES = {
+    "f32": ("f32", 2, 2, 16, 512),
+    "sdar_bf16_4x32": ("bf16", 4, 32, 16, 256),
+    "nemotron_bf16_2x16": ("bf16", 2, 16, 16, 512),
+    "mistral_int8_8x4": ("int8", 8, 4, 16, 128),
+    "int8_2x8": ("int8", 2, 8, 16, 512),
+    "block32_int8": ("int8", 4, 2, 32, 256),
+    "contiguous_view_block128_bf16": ("bf16", 2, 2, 128, 512),
+    "bf16_one_head": ("bf16", 1, 8, 16, 512),
+    "f32_16_heads": ("f32", 16, 1, 16, 128),
+}
+
+
+def _edge_case(name):
+    """Rows whose lengths sit on every tile edge of the geometry's
+    width W — W - 1, W, W + 1, W + 16, 2W + 17 keys, one block, and an
+    idle slot (zero table, a position inside scratch block 0)."""
+    dtype, kv, group, bs, wide = WIDTH_CASES[name]
+    lengths = [wide - 1, wide, wide + 1, wide + 16, 2 * wide + 17, bs, 6]
+    max_blocks = -(-max(lengths) // bs) + 1
+    assert pa.decode_keys_per_iteration(max_blocks * bs, bs, kv) == wide
+    q, k, v, exact, tables, kv_args = _typed_case(
+        dtype, bs, max_blocks, batch=len(lengths), kv=kv, group=group,
+        hd=16)
+    tables = tables.at[len(lengths) - 1].set(0)
+    positions = [length - 1 for length in lengths]
+    return dtype, wide, (q, k, v, exact, tables, positions, kv_args)
+
+
+@pytest.mark.parametrize("name", sorted(WIDTH_CASES))
+def test_kernel_row_lengths_at_every_tile_edge(name):
+    dtype, _, (q, k, v, exact, tables, positions, kv_args) = \
+        _edge_case(name)
+    _typed_parity(q, k, v, exact, tables, positions, kv_args, dtype)
+
+
+@pytest.mark.parametrize("name", ["f32", "sdar_bf16_4x32",
+                                  "mistral_int8_8x4", "int8_2x8"])
+@pytest.mark.parametrize("window", ["W-1", "W+24", 100])
+def test_kernel_window_at_every_tile_edge(name, window):
+    """The same rows under sliding windows that end a band one key
+    short of a wide tile, a block and a half past it, and inside one
+    group."""
+    dtype, wide, (q, k, v, exact, tables, positions, kv_args) = \
+        _edge_case(name)
+    window = {"W-1": wide - 1, "W+24": wide + 24}.get(window, window)
+    _typed_parity(q, k, v, exact, tables, positions, kv_args, dtype,
+                  window=window)
+
+
+@pytest.mark.parametrize("name", ["f32", "sdar_bf16_4x32", "int8_2x8",
+                                  "contiguous_view_block128_bf16"])
+@pytest.mark.parametrize("tail_blocks", [9, 17, 31])
+def test_wide_tile_never_weighs_another_rows_values(name, tail_blocks):
+    """A wide tile reaches past the groups its pass copied, into buffer
+    rows an EARLIER row's copies left: that row's own V (and V scales)
+    are NaN and Inf here, in blocks it owns and attends over, and the
+    rows after it, which end 9 to 31 blocks into a wide tile (of 32
+    blocks, or of sdar's 16), must not see them (a masked key weighs
+    zero, and zero times NaN is NaN)."""
+    dtype, kv, group, bs, wide = WIDTH_CASES[name]
+    keys = tail_blocks * bs - 3 if bs < wide else 2 * bs - 3
+    lengths = [wide, keys, 2 * wide, wide + keys, keys]
+    max_blocks = -(-max(lengths) // bs) + 1
+    q, k, v, exact, tables, kv_args = _typed_case(
+        dtype, bs, max_blocks, batch=len(lengths), kv=kv, group=group,
+        hd=16)
+    owned = np.asarray(tables)
+    bad = np.concatenate([owned[0, :wide // bs], owned[2, :2 * wide // bs]])
+    half = len(bad) // 2
+    if dtype == "int8":
+        vs = kv_args["vs"].at[bad[:half]].set(jnp.nan)
+        kv_args = dict(kv_args, vs=vs.at[bad[half:]].set(jnp.inf))
+    else:
+        v = v.at[bad[:half]].set(jnp.nan).at[bad[half:]].set(jnp.inf)
+        exact = (exact[0], v.astype(jnp.float32))
+    positions = jnp.asarray([length - 1 for length in lengths], jnp.int32)
+    out = np.asarray(pa.paged_decode_attention(
+        q, k, v, tables, positions, interpret=True, **kv_args))
+    ref = np.asarray(pa.paged_decode_reference(
+        q, exact[0], exact[1], tables, positions, **kv_args))
+    clean = [1, 3, 4]
+    assert not np.isfinite(out[[0, 2]]).any()
+    assert np.isfinite(out[clean]).all()
+    tol = 1e-4 if dtype == "int8" else 2e-5
+    np.testing.assert_allclose(out[clean], ref[clean], atol=tol, rtol=tol)
+
+
+def test_bf16_queries_are_their_own_single_term():
+    """Queries that ARE bf16 values ride the score matmul as one term;
+    the same values as f32 ride as three, of which two are zero: the
+    results agree bit for bit.  (Queries and keys are small whole
+    numbers here, so that every score is exact in whatever order the
+    interpreter's CPU matmul adds 32 or 96 rows' products; the MXU's
+    order does not depend on the rows.)"""
+    _, _, (q, k, v, _, tables, positions, kv_args) = _edge_case(
+        "sdar_bf16_4x32")
+    q16 = jnp.round(2 * q).astype(jnp.bfloat16)
+    k = jnp.round(2 * k.astype(jnp.float32)).astype(k.dtype)
+    positions = jnp.asarray(positions, jnp.int32)
+    out16 = pa.paged_decode_attention(q16, k, v, tables, positions,
+                                      interpret=True, **kv_args)
+    out32 = pa.paged_decode_attention(q16.astype(jnp.float32), k, v,
+                                      tables, positions, interpret=True,
+                                      **kv_args)
+    assert out16.dtype == jnp.bfloat16 and out32.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(out16), np.asarray(out32.astype(jnp.bfloat16)))
+
+
+@pytest.mark.parametrize("window", [None, 200, 700])
+@pytest.mark.parametrize("kv", [2, 4, 8])
+def test_host_iteration_counts_are_the_kernels_loop_bounds(kv, window):
+    """``decode_iterations`` / ``decode_wide_iterations`` as the host
+    reckons them equal a walk of the kernel's own loop — its bounds,
+    the live blocks each pass holds and the tile it takes — for a
+    table of mixed lengths: idle, one block, tails of a block, a group
+    and more, rows at a wide tile's edges, the table's last key."""
+    bs, table_blocks = 16, 129
+    positions = np.array([0, 5, 15, 16, 127, 128, 300, 511, 512, 513,
+                          545, 639, 640, 1023, 1024, 1500, 2063])
+    wide = pa.decode_keys_per_iteration(table_blocks * bs, bs, kv)
+    tiles = pa.decode_tiles(bs, wide)
+    per_pass = wide // bs
+    want_all = want_wide = 0
+    for pos in positions:
+        first, last, iterations = (int(x) for x in pa.decode_loop_bounds(
+            jnp.int32(pos), block_size=bs, table_blocks=table_blocks,
+            wide_keys=wide, window=window))
+        for c in range(iterations):
+            held = min(last - first + 1 - c * per_pass, per_pass)
+            assert held >= 1
+            want_all += 1
+            want_wide += int(pa.decode_tile_index(held, bs, tiles)
+                             == len(tiles) - 1)
+    rows, wide_rows = pa.decode_iteration_counts(
+        positions, block_size=bs, table_blocks=table_blocks, kv_heads=kv,
+        window=window)
+    assert (int(rows.sum()), int(wide_rows.sum())) == (want_all,
+                                                       want_wide)
+    assert rows.shape == wide_rows.shape == positions.shape
+    assert (wide_rows <= rows).all() and wide_rows.sum() > 0

@@ -128,6 +128,58 @@ def _custom_call_lines(text, name):
                         line)]
 
 
+#: name → (slots, kv heads, group, pool dtype, window, table width, pool
+#: blocks, block, the width ``decode_keys_per_iteration`` gives): the
+#: cells that call the K/V decode kernel, the contiguous view, and
+#: the widest tile with int8 and f32 rows.
+DECODE_GEOMETRIES = {
+    "sdar30b_fixedlen_bf16_4x32": (64, 4, 32, jnp.bfloat16, None, 129,
+                                   8193, 16, 256),
+    "mistral7b_chat_int8_8x4_window": (32, 8, 4, jnp.int8, 4096, 160,
+                                       4609, 16, 128),
+    "nemotron3super_bf16_2x16": (64, 2, 16, jnp.bfloat16, None, 144, 9217,
+                                 16, 512),
+    "contiguous_view_block128_int8": (8, 8, 4, jnp.int8, None, 16, 129,
+                                      128, 128),
+    "contiguous_view_block128_bf16_2_heads": (8, 2, 16, jnp.bfloat16, None,
+                                              16, 129, 128, 512),
+    "int8_pool_block32_4_heads": (8, 4, 8, jnp.int8, None, 32, 513, 32, 256),
+    "contiguous_view_block128_int8_4_heads": (8, 4, 8, jnp.int8, None, 16,
+                                              129, 128, 256),
+    "f32_pool_2_heads": (8, 2, 16, jnp.float32, None, 64, 1025, 16, 512),
+    "f32_pool_32_heads": (8, 32, 1, jnp.float32, None, 64, 1025, 16, 128),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DECODE_GEOMETRIES))
+def test_decode_kernel_compiles_for_v5e(name, one_chip, as_on_tpu):
+    """The decode kernel at its width through the TPU compiler: the
+    key buffers inside Mosaic's default scoped VMEM, copies from a
+    loop into dynamic buffer rows, V groups zeroed from a loop, and
+    the call still named ``closed_call`` with a 3-D result in the
+    queries' dtype (what ``decode_attn_roofline`` looks for in a trace,
+    where the line also prints the block table's type first)."""
+    from aiko_services_tpu.ops import paged_attention as pa
+    (slots, kv, group, pool_dt, window, table, n_blocks, bs,
+     wide) = DECODE_GEOMETRIES[name]
+    hd = 128
+    assert pa.decode_keys_per_iteration(table * bs, bs, kv) == wide
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    pool = S((n_blocks, bs, kv, hd), pool_dt)
+    scales = S((n_blocks, bs, kv), jnp.float32) \
+        if pool_dt == jnp.int8 else None
+    text = jax.jit(functools.partial(
+        pa.paged_decode_attention, window=window)).lower(
+        S((slots, kv, group, hd), jnp.bfloat16), pool, pool,
+        S((slots, table), jnp.int32), S((slots,), jnp.int32), scales,
+        scales).compile().as_text()
+    calls = _custom_call_lines(text, "closed_call")
+    assert len(calls) == 1
+    assert re.search(r"= bf16\[%d,%d,128\]" % (slots, kv * group),
+                     calls[0])
+    assert " while(" not in text
+
+
 #: name → (block, pool blocks): an int8 pool layer of 8 kv heads, 32 rows.
 APPEND_GEOMETRIES_DECODE = {
     "mistral7b_chat_pool": (16, 4609),
