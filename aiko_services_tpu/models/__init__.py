@@ -3,6 +3,7 @@ from . import moe
 from . import nemotron_h
 from . import mistral4
 from . import sdar
+from . import evabyte
 from . import classifier
 from . import detector
 from . import asr
@@ -26,8 +27,18 @@ from . import lora
 #: its ``serve_chunk_paged`` / ``serve_chunk_mixed`` return, where the
 #: others return ``(slots, steps)`` tokens and ``(slots,)`` counts,
 #: the ``(slots, passes, block)`` windows and ``(slots, passes)`` marks
-#: the engine's ``_commit_block_passes`` reads.
-SERVING_MODULES = (llama, nemotron_h, mistral4, sdar)
+#: the engine's ``_commit_block_passes`` reads.  A module that keeps TWO
+#: KINDS OF ROW in one pool (``evabyte``: a window's exact rows and one
+#: summary row for every chunk behind it) also gives ``check_layout``,
+#: ``table_blocks`` and ``slot_blocks`` (how wide a slot's table row is
+#: and how many blocks a request holds, laid out ``[ring ‖
+#: summaries]``), ``block_kinds``, ``composed_tables`` /
+#: ``composed_positions`` (what its programs hand the K/V kernels,
+#: reckoned on the device from the absolute position),
+#: ``cache_rows`` and ``cache_events`` (what a position holds and
+#: reads, and what happened between two, on the host) and
+#: ``CACHE_COUNTERS`` (the engine's counters those two feed).
+SERVING_MODULES = (llama, nemotron_h, mistral4, sdar, evabyte)
 
 
 def serving_model(config_name: str):

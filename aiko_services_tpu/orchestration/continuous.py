@@ -254,6 +254,11 @@ class ContinuousBatchingServer:
         self._block_length = (
             int(self.config.block_length)
             if hasattr(self._model, "block_slot_state") else 0)
+        #: The module keeps two kinds of row in one pool (a window's
+        #: exact rows, summaries of what lies behind it) and composes
+        #: the table the K/V kernels walk from the absolute position:
+        #: the engine asks it what a position holds and reads.
+        self._composed = hasattr(self._model, "composed_tables")
         self._refuse_unsupported(
             mesh=mesh is not None, replica_mesh=replica_mesh is not None,
             adapters=bool(adapters),
@@ -601,6 +606,10 @@ class ContinuousBatchingServer:
             # What a serve chunk of this model module returns beside
             # its tokens; added when the chunk is read (_consume_ready).
             self.counters[name] = 0
+        for name in getattr(self._model, "CACHE_COUNTERS", ()):
+            # What a cache of two kinds of row did, reckoned from the
+            # host's position mirrors (_note_rows, _note_decode_blocks).
+            self.counters[name] = 0
         if self._block_length:
             # Live slot-passes; those that only stored a finished
             # block's K/V; blocks whose K/V became final.
@@ -869,12 +878,20 @@ class ContinuousBatchingServer:
         every step — the counter makes the O(max_seq) → O(len) traffic
         difference a tracked number."""
         sched_live = sched[live]
+        positions = self.positions[live]
+        if self._composed:
+            # The kernel walks the composed row: summaries of the
+            # windows behind, then the window's own rows.
+            positions, held = self._model.cache_rows(
+                self.config, positions, self._attn_block_size)
+            for name, a_step in held.items():
+                self.counters[name] += int((a_step * sched_live).sum())
         if self.decode_attention_path == "kernel":
             block_size = self._attn_block_size
             # A step reads up to its own row; a block pass up to its
             # block's last, once for all the block's queries.
             reach = max(self._block_length, 1)
-            blocks = (self.positions[live] + reach - 1
+            blocks = (positions + reach - 1
                       + block_size) // block_size   # ceil((pos+1)/bs)
             window = self.config.sliding_window
             if window:
@@ -886,7 +903,7 @@ class ContinuousBatchingServer:
                 from ..ops.paged_attention import decode_iteration_counts
                 _, kv_heads, _ = self._kv_geometry()
                 for rows, counter in zip(decode_iteration_counts(
-                        self.positions[live] + reach - 1,
+                        positions + reach - 1,
                         block_size=block_size,
                         table_blocks=self._attn_total_blocks,
                         kv_heads=kv_heads, window=window or None),
@@ -2377,6 +2394,23 @@ class ContinuousBatchingServer:
             if mixed:
                 self.counters["prefill_slices_mixed"] += 1
 
+    def _note_rows(self, slot: int, before: int, after: int) -> None:
+        """The rows a slot has written went from ``before`` to
+        ``after`` (a prefill slice dispatched, decode steps read back):
+        where the module keeps two kinds of row, count the chunks that
+        summarised and the windows that closed, and log a window's
+        end.  Nothing, for any other module."""
+        if not self._composed or after <= before:
+            return
+        events = self._model.cache_events(
+            self.config, int(before), int(after), self._attn_block_size)
+        for name, count in events.items():
+            self.counters[name] += count
+        if events.get("eva_windows_closed") \
+                and steplog.RECORDER is not None:
+            steplog.RECORDER.record("window_end", slot=slot,
+                                    rows=int(after), **events)
+
     def _note_first_token(self, request: DecodeRequest) -> None:
         """A request's first token was committed: add its time to
         first token and the parts that tile it to the counters, all at
@@ -2563,6 +2597,8 @@ class ContinuousBatchingServer:
                         if constrained:
                             self.spec_stats.jump_forward_tokens += min(
                                 int(forced_ct[slot]), count)
+                    self._note_rows(slot, self.positions[slot],
+                                    self.positions[slot] + advance)
                     self.positions[slot] += advance
                     self.tokens[slot, 0] = token_rows[slot][advance - 1] \
                         if spec else token_rows[slot][count - 1]

@@ -195,6 +195,13 @@ class PagedContinuousServer(ContinuousBatchingServer):
                 f"must be a multiple of block_size {block_size} on "
                 "the paged backend (slices land on block boundaries)")
         max_blocks = self.max_seq // block_size
+        if self._composed:
+            # Two kinds of row in one pool: a slot's table row is the
+            # module's own layout, as wide as max_seq can make it.
+            self._model.check_layout(self.config, block_size,
+                                     self.chunk_prefill_tokens)
+            max_blocks = self._model.table_blocks(
+                self.config, self.max_seq, block_size)
         if self._requested_blocks is None:
             usable = max(max_blocks,
                          self.slots * max_blocks // 2)
@@ -547,7 +554,17 @@ class PagedContinuousServer(ContinuousBatchingServer):
         adapter_section = dict(
             pages=self._adapter_page_counts(),
             slots=self.adapter_slot_counts())
+        # Two kinds of row in one pool: of every slot's private blocks,
+        # the ring of its window's exact rows and the rest, summaries
+        # (a split of ``states.private``, not a tier).
+        kinds = None
+        if self._composed:
+            ring, _ = self._model.block_kinds(self.config,
+                                              self.block_size)
+            exact = sum(min(len(blocks), ring) for blocks in self._owned)
+            kinds = dict(window=exact, summary=private - exact)
         return dict(
+            kinds=kinds,
             ts=time.time(), dtype=dtype, block_bytes=block_bytes,
             total_blocks=self.total_blocks,
             evict_clock=self._evict_clock,
@@ -648,6 +665,25 @@ class PagedContinuousServer(ContinuousBatchingServer):
         from .continuous import _bucket
         padded = min(_bucket(prompt_len, self._bucket_minimum),
                      self.max_seq)
+        return self._slot_blocks(padded, prompt_len, max_new)
+
+    def _slot_blocks(self, padded: int, prompt_len: int,
+                     max_new: int) -> int:
+        """Blocks a request holds from admission to release: the rows
+        it can ever touch — the padded prompt bucket (prefill writes
+        all its rows) plus every generated token, plus the speculative
+        verify window's k+1 rows when a draft is configured — and
+        never more than max_seq (submit() bounds prompt+new to
+        max_seq-1, so the bucket-rounded sum may overshoot max_seq
+        while the rows actually touched cannot).  Where the module
+        keeps two kinds of row, its own count for the positions the
+        request writes: a bucket's padding lands in the ring and in
+        summary rows of the prompt's last window, which it holds
+        anyway."""
+        if self._composed:
+            return self._model.slot_blocks(
+                self.config, min(prompt_len + max_new, self.max_seq),
+                self.block_size)
         return self._blocks_for(min(
             padded + max_new + self._spec_headroom(), self.max_seq))
 
@@ -1267,18 +1303,10 @@ class PagedContinuousServer(ContinuousBatchingServer):
                 break
 
     def _reserve_slot(self, slot: int, padded: int, request) -> bool:
-        # Worst case rows this request can ever touch: the padded
-        # prompt bucket (prefill writes all its rows) or the prompt +
-        # every generated token — plus the speculative verify window's
-        # k+1 rows when a draft is configured — whichever is larger,
-        # and never more than max_seq (submit() bounds prompt+new to
-        # max_seq-1, so the bucket-rounded sum may overshoot max_seq
-        # while the rows actually touched cannot).
-        rows = min(padded + request.max_new_tokens
-                   + self._spec_headroom(), self.max_seq)
-        needed = self._blocks_for(rows)
-
         prompt = np.asarray(request.prompt)
+        needed = self._slot_blocks(padded, len(prompt),
+                                   request.max_new_tokens)
+
         shared: List[int] = []
         keys: List = []
         adapter_id = self._adapter_id(request)
@@ -1725,6 +1753,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
             self._note_prefill(
                 width, (request,),
                 key_blocks=self._slice_key_blocks(start, width))
+            self._note_slice_rows(slot, start, width, len(request.prompt))
             if self._tp_engine is not None:
                 _, self.pool = self._tp_engine.prefill_append_paged(
                     self.params, jnp.asarray(chunk), self.pool,
@@ -1778,6 +1807,15 @@ class PagedContinuousServer(ContinuousBatchingServer):
             request=request, prompt_padded=prompt_padded,
             prompt_len=prompt_len, start=n_shared * self.block_size,
             kv_limit=prompt_padded.shape[1] // self.block_size)
+
+    def _note_slice_rows(self, slot: int, start: int, width: int,
+                         prompt_len: int) -> None:
+        """A prefill slice ``[start, start + width)`` is about to be
+        dispatched: the rows it writes of the prompt.  The prompt's
+        last token is the first decode step's (:meth:`_activate_slot`),
+        and a bucket's padding is nobody's."""
+        last = prompt_len - 1
+        self._note_rows(slot, min(start, last), min(start + width, last))
 
     def _next_slice_width(self, prefill) -> int:
         """Next chunked-prefill slice: the largest power-of-two block
@@ -1843,6 +1881,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
             self._note_prefill(
                 width, (state["request"],), sliced=True,
                 key_blocks=self._slice_key_blocks(start, width))
+            self._note_slice_rows(slot, start, width, state["prompt_len"])
             if sp_width:
                 if compiles.LEDGER is not None:
                     # ONE window shape per (sp, cap) — the sp ladder
@@ -1918,8 +1957,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
         sp = getattr(self._tp_engine, "sp", 1) \
             if self._tp_engine is not None else 1
         dispatched = 0
-        tables_row = jnp.zeros((1, self.max_seq // block_size),
-                               jnp.int32)
+        tables_row = jnp.zeros((1, self.tables.shape[1]), jnp.int32)
         for bucket in buckets:
             kv_limit = bucket // block_size
             widths = []
@@ -2034,6 +2072,7 @@ class PagedContinuousServer(ContinuousBatchingServer):
         self._note_prefill(
             width, (prefill["request"],), sliced=True, mixed=True,
             key_blocks=self._slice_key_blocks(start, width))
+        self._note_slice_rows(slot, start, width, prefill["prompt_len"])
         if self._dispatch_span is not None:
             self._dispatch_span.note(
                 slice_slot=slot, slice_width=width,

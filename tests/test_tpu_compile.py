@@ -78,6 +78,8 @@ APPEND_GEOMETRIES = {
                                       False),
     "verify_int8_32_slots": (32, 5, 8, 4, 16, 4609, 160, jnp.int8,
                              jnp.bfloat16, 4096, None, True),
+    "evabyte_int8_32_kv_heads": (1, 256, 32, 1, 16, 1537, 184, jnp.int8,
+                                 jnp.bfloat16, None, None, False),
 }
 
 
@@ -148,6 +150,8 @@ DECODE_GEOMETRIES = {
                                               129, 128, 256),
     "f32_pool_2_heads": (8, 2, 16, jnp.float32, None, 64, 1025, 16, 512),
     "f32_pool_32_heads": (8, 32, 1, jnp.float32, None, 64, 1025, 16, 128),
+    "evabyte_files_int8_32x1": (8, 32, 1, jnp.int8, None, 184, 1537, 16,
+                                128),
 }
 
 
@@ -180,11 +184,12 @@ def test_decode_kernel_compiles_for_v5e(name, one_chip, as_on_tpu):
     assert " while(" not in text
 
 
-#: name → (block, pool blocks): an int8 pool layer of 8 kv heads, 32 rows.
+#: name → (block, pool blocks, kv heads, rows): an int8 pool layer.
 APPEND_GEOMETRIES_DECODE = {
-    "mistral7b_chat_pool": (16, 4609),
-    "mixtral8x7b_chat_pool": (16, 6145),
-    "contiguous_view_block128": (128, 65),
+    "mistral7b_chat_pool": (16, 4609, 8, 32),
+    "mixtral8x7b_chat_pool": (16, 6145, 8, 32),
+    "contiguous_view_block128": (128, 65, 8, 32),
+    "evabyte_files_pool_32_kv_heads": (16, 1537, 32, 8),
 }
 
 
@@ -199,8 +204,8 @@ def test_decode_append_compiles_for_v5e(name, one_chip):
     named ``paged_decode_append`` that the decode kernel's roofline
     metric does not take for its own."""
     from aiko_services_tpu.ops import paged_attention as pa
-    bs, n_blocks = APPEND_GEOMETRIES_DECODE[name]
-    kv, hd, batch = 8, 128, 32
+    bs, n_blocks, kv, batch = APPEND_GEOMETRIES_DECODE[name]
+    hd = 128
     n_rows = n_blocks * bs * kv // pa.decode_scale_row(bs, kv)
     S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     pool = {"k": S((n_blocks, bs, kv, hd), jnp.int8),
@@ -623,3 +628,76 @@ def test_block_pass_programs_compile_for_v5e(one_chip, as_on_tpu):
             assert re.search(r"= bf16\[64,128,128\]", line)
     assert "%paged_prefill_call" in mixed
     assert "%paged_prefill_call" not in chunk
+
+
+# --------------------------------------------------------------------------- #
+# Two kinds of row in one pool, at evabyte.files' sizes
+
+
+def test_two_kinds_of_row_programs_compile_for_v5e(one_chip, as_on_tpu):
+    """``models/evabyte.py`` at the cell's widths (2 of its 32 layers,
+    32 int8 kv heads, tables of 184, a pool of 1,536 blocks): the chunk
+    of steps is ONE ``while`` whose body appends twice a layer (the
+    step's row, the chunk's summary) and attends with one decode-kernel
+    call a layer over the composed table (a 3-D bfloat16 result: what
+    ``decode_attn_roofline`` finds); the slices run the append kernels
+    at the composed position; nothing copies or transposes a K/V pool,
+    and the scale planes are re-laid-out at the scan's entry and exit
+    alone, as :mod:`llama`'s are."""
+    from benchmark.builders import evabyte as builder
+    from aiko_services_tpu.models import evabyte
+    cfg = json.loads((pathlib.Path(__file__).resolve().parents[1]
+                      / "benchmark/configs/evabyte-6.5b.json").read_text())
+    cfg = dict(cfg, num_hidden_layers=2)
+    config = builder.program_config("evabyte_compile_test", cfg)
+    layers, slots, n_blocks = config.n_layers, 8, 1537
+    table = evabyte.table_blocks(config, 12800, 16)
+    assert table == 184
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params = _shaped(jax.eval_shape(
+        lambda: builder.build_params(cfg, 1)), one_chip)
+    pool = _shaped(jax.eval_shape(
+        lambda: evabyte.init_paged_cache(config, n_blocks, 16,
+                                         quantize_kv=True)), one_chip)
+    state = {"token": S((slots, 1), jnp.int32),
+             "positions": S((slots,), jnp.int32),
+             "active": S((slots,), jnp.bool_),
+             "remaining": S((slots,), jnp.int32),
+             "temps": S((slots,), jnp.float32),
+             "tops": S((slots,), jnp.float32),
+             "adapter_ids": S((slots,), jnp.int32),
+             "tables": S((slots, table), jnp.int32)}
+    tokens, scalar = S((1, 256), jnp.int32), S((), jnp.int32)
+    chunk = evabyte.serve_chunk_paged.lower(
+        params, state, pool, 8, config).compile().as_text()
+    mixed = evabyte._mixed_program.lower(
+        params, state, pool, tokens, scalar, scalar, 8, config, -1,
+        False, None).compile().as_text()
+    standalone = evabyte._prefill_program.lower(
+        params, tokens, pool, S((1, table), jnp.int32), scalar, config,
+        False).compile().as_text()
+    assert standalone.count(" while(") == 0
+    for text in (mixed, standalone):
+        assert "%paged_prefill_call" in text       # the kernel path ran
+    for text in (chunk, mixed, standalone):
+        assert not _pool_shaped_ops(text, n_blocks, 4)
+    for text, budget in ((chunk, 4 * layers), (mixed, 8 * layers)):
+        assert text.count(" while(") == 1
+        assert (len(_pool_shaped_ops(text, n_blocks, 3))
+                + len(_pool_shaped_ops(text, n_blocks, 2))) <= budget
+        body = "\n".join(_scan_body(text))
+        assert len(_custom_call_lines(body, "paged_decode_append")) \
+            == 2 * layers
+        calls = _custom_call_lines(body, "closed_call")
+        assert len(calls) == layers
+        for line in calls:
+            assert re.search(r"= bf16\[8,32,128\]", line)
+        # The chunk's summary: one kernel call a layer, named as
+        # ``eva_summarise_roofline`` looks for it, reading the pools
+        # where they are.
+        assert len(_custom_call_lines(body, "eva_summarise")) == layers
+    # The decode chunk parks no pool on chip (the mixed program's slice
+    # half still does: PERF.md section 7).
+    assert not re.search(r"= \(s8\[1537,16,32,128\][^=]*copy-start\(",
+                         chunk)
+
