@@ -49,6 +49,16 @@ costs time in proportion to them:
   f32 side's three bf16 terms stacked (:func:`_contract_terms`); the
   queries' terms are made once a program, and queries that ARE bf16
   values are their own single term.
+* with ONE query row a kv head (no grouped queries: ``group == 1``)
+  there is nothing to share a head's strided read and weight load
+  over, and a key's ``(kv_heads, head_dim)`` tile has the queries' own
+  shape: the kernel then attends over ALL kv heads of a key at once,
+  in the buffer's own layout (:func:`decode_attend_form`,
+  ``"all_heads"``) — the tile's ``(key, head)`` rows as they lie are
+  the score matmul's transposed weights, the softmax runs on lane rows
+  in the scale planes' ``[key, head]`` order, the XLU turns the
+  weights to sublane rows and the weighted sum is the vector unit's.
+  Loop, copies, bounds and tiles are the same algorithm.
 * int8 KV dequantizes in-kernel: the per-(token, head) scales factor
   out of the q·k contraction and into the softmax weights, so they
   multiply the lane-dense (group, keys) tiles — the cache is read at
@@ -57,7 +67,8 @@ costs time in proportion to them:
   minor dimension must fill lane rows), so a block's scales travel as
   their own bytes seen as rows of 128 (:func:`decode_scale_row`) and
   one one-hot contraction per iteration sorts them into per-head rows
-  (:func:`_head_scale_rows`).
+  (:func:`_head_scale_rows`; the all-heads form multiplies them as
+  copied).
 
 The contiguous ragged cache is the degenerate case: reshape
 ``(batch, S, kv, hd)`` to ``(batch·S/bs, bs, kv, hd)`` with iota block
@@ -88,6 +99,7 @@ __all__ = ["paged_decode_attention", "paged_decode_reference",
            "blocks_per_group", "decode_keys_per_iteration", "decode_tiles",
            "decode_tile_index", "decode_loop_bounds",
            "decode_iteration_counts", "decode_scale_row",
+           "decode_attend_form",
            "decode_append_dispatch", "decode_scale_append_path",
            "paged_decode_append"]
 
@@ -449,6 +461,24 @@ MAX_DECODE_KEYS_PER_ITERATION = 512
 DECODE_HEAD_TILES_PER_ITERATION = 8
 
 
+def decode_attend_form(group: int, kv_heads: int, block_size: int) -> str:
+    """Which of the decode kernel's two ``attend`` bodies a call takes,
+    from what it can see: ``"all_heads"`` when a kv head carries ONE
+    query row and a block's ``(key, head)`` pairs fill whole lane rows
+    (:func:`decode_scale_row`: the layout the int8 scale planes already
+    ride in), else ``"per_head"``.
+
+    Many rows a head want the MXU head by head (one strided head read
+    and one weight load serve ``group`` rows); one row a head has
+    nothing to amortise them over, and a key's ``(kv_heads, head_dim)``
+    tile has the queries' own shape, so every head of a key is attended
+    over at once, in the buffer's own layout (docs/KERNELS.md).  The
+    serving counter ``decode_attend_form`` is this same answer."""
+    if group == 1 and decode_scale_row(block_size, kv_heads):
+        return "all_heads"
+    return "per_head"
+
+
 def blocks_per_group(block_size: int) -> int:
     """Pool blocks of one group of :data:`KEYS_PER_GROUP` keys, at
     least one — 8 at the paged pool's block 16, 1 at the contiguous
@@ -457,29 +487,40 @@ def blocks_per_group(block_size: int) -> int:
 
 
 def decode_keys_per_iteration(table_keys: int, block_size: int,
-                              kv_heads: int) -> int:
+                              kv_heads: int,
+                              form: str = "per_head") -> int:
     """Keys ``W`` one pass through the decode kernel's block-table loop
     copies and attends over, from what the call can see: the keys a
-    table row can hold, the pool's block size and its kv heads.
+    table row can hold, the pool's block size, its kv heads and which
+    ``attend`` body the call takes (:func:`decode_attend_form`).
 
-    An iteration pays a serial chain once a kv head whatever its width
-    (strided head read, score matmul, mask, lane max, exp, lane sum,
-    weighted-sum matmul, scratch update: PERF.md section 6, PR 40), so
-    a wider one pays it less often.  But its attend is unrolled, a
-    head tile (a kv head's :data:`KEYS_PER_GROUP` keys) after another,
-    and its code and buffers grow with heads times keys: 512 keys of 8
-    int8 heads cost a serving step with one or two live rows more,
-    between its other programs, than the rows gained
-    (``mistral7b.chat``, PERF.md section 6, PR 40, review round; the
-    kernel timed alone does not show it).  So an iteration covers
-    whole groups, at most
+    ``per_head``: an iteration pays a serial chain once a kv head
+    whatever its width (strided head read, score matmul, mask, lane
+    max, exp, lane sum, weighted-sum matmul, scratch update: PERF.md
+    section 6, PR 40), so a wider one pays it less often.  But its
+    attend is unrolled, a head tile (a kv head's
+    :data:`KEYS_PER_GROUP` keys) after another, and its code and
+    buffers grow with heads times keys: 512 keys of 8 int8 heads cost
+    a serving step with one or two live rows more, between its other
+    programs, than the rows gained (``mistral7b.chat``, PERF.md
+    section 6, PR 40, review round; the kernel timed alone does not
+    show it).  So an iteration covers whole groups, at most
     :data:`DECODE_HEAD_TILES_PER_ITERATION` head tiles — 128 keys of 8
     kv heads, what it covered before it was widened, 256 of 4, 512 of
     2 — at most :data:`MAX_DECODE_KEYS_PER_ITERATION` keys, at least
-    one group, and never more than the table can hold."""
+    one group, and never more than the table can hold.
+
+    ``all_heads``: one group, whatever the heads — the attend takes a
+    tile whole in one step, whose softmax and transposes are a serial
+    chain paid once a step, so the longest step that holds only copied
+    keys is the best one, and a wider pass than its step only delays a
+    row's first, uncovered copies (PERF.md section 6, PR 42: steps of
+    16 to 128 keys alone, W 128 / 256 / 512 in the cell)."""
     group = max(KEYS_PER_GROUP, block_size)
-    keys = min(DECODE_HEAD_TILES_PER_ITERATION // kv_heads * KEYS_PER_GROUP,
-               MAX_DECODE_KEYS_PER_ITERATION, -(-table_keys // group) * group)
+    keys = (KEYS_PER_GROUP if form == "all_heads" else
+            DECODE_HEAD_TILES_PER_ITERATION // kv_heads * KEYS_PER_GROUP)
+    keys = min(keys, MAX_DECODE_KEYS_PER_ITERATION,
+               -(-table_keys // group) * group)
     return max(group, keys // group * group)
 
 
@@ -516,15 +557,17 @@ def decode_loop_bounds(positions, *, block_size: int, table_blocks: int,
 
 def decode_iteration_counts(positions, *, block_size: int,
                             table_blocks: int, kv_heads: int,
-                            window: Optional[int]):
+                            window: Optional[int],
+                            form: str = "per_head"):
     """``(iterations, wide iterations)`` a row, numpy, of a decode call
     over rows at ``positions``, as the kernel's own loop bounds give
     them: the serving counters ``decode_iterations`` and
     ``decode_wide_iterations``.  A wide iteration attends over the
     whole ``W``-key tile; every iteration of a row but its last is
-    one."""
+    one.  ``form`` is the call's :func:`decode_attend_form`, which
+    ``W`` follows."""
     wide_keys = decode_keys_per_iteration(table_blocks * block_size,
-                                          block_size, kv_heads)
+                                          block_size, kv_heads, form)
     tiles = decode_tiles(block_size, wide_keys)
     first_live, last_live, iterations = decode_loop_bounds(
         np.asarray(positions), block_size=block_size,
@@ -578,6 +621,31 @@ def _head_scale_rows(flat, kv_heads: int, keys: int):
         preferred_element_type=jnp.float32)
 
 
+#: log2(e): the all-heads form keeps its running maximum in the log2
+#: domain, so the factor rides the scores' one multiply and ``exp`` is
+#: the exponent unit's own ``exp2``.
+LOG2_E = 1.4426950408889634
+
+
+def _across_lane_groups(row, kv_heads: int, combine):
+    """``combine`` (max, add) of a ``(1, 128)`` lane row's entries over
+    the ``128 / kv_heads`` keys it holds, per head: lane ``c`` comes
+    back holding the result of head ``c % kv_heads`` — log2(keys)
+    lane rotations."""
+    shift = kv_heads
+    while shift < LANES:
+        row = combine(row, pltpu.roll(row, shift, 1))
+        shift *= 2
+    return row
+
+
+def _lanes_to_sublanes(row, width: int):
+    """``(1, 128)`` lane row -> ``(128, width)``: lane ``c``'s entry on
+    sublane row ``c``, repeated along the lanes — one pass through the
+    XLU's transpose, no vector-unit work."""
+    return jnp.broadcast_to(row, (width, LANES)).T
+
+
 def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
                          q_ref, k_hbm, v_hbm, *rest,
                          block_size: int, group: int, sm_scale: float,
@@ -609,7 +677,18 @@ def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
     where they are used).
 
     The queries' MXU terms are the program's, not an iteration's
-    (``q_is_bf16``: the rows ARE bf16 values, their own single term)."""
+    (``q_is_bf16``: the rows ARE bf16 values, their own single term).
+
+    Two ``attend`` bodies share the loop, the copies, the bounds and
+    the tiles (:func:`decode_attend_form`).  ``per_head``: a kv head
+    after another, its ``group`` query rows against its strided rows of
+    the tile, scratch one column a query head.  ``all_heads`` (one
+    query row a kv head): every head of a key at once — the tile's
+    ``(key, head)`` rows as they lie are the score matmul's transposed
+    weights, the softmax runs on lane rows in the scale planes' own
+    ``[key, head]`` order (``m_scr`` / ``l_scr`` are one such row, the
+    maximum in the log2 domain), and the XLU turns the weights to
+    sublane rows for a weighted sum on the vector unit."""
     def of_block(pool_hbm):
         return lambda block: pool_hbm.at[block]
 
@@ -703,12 +782,27 @@ def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
     # MXU as bf16: see _contract_terms.
     row_dtype = (jnp.float32 if k_buf.dtype == jnp.float32
                  else jnp.bfloat16)
-    q_terms = []
-    for head in range(kv_heads):
-        q = q_ref[0, head * group:(head + 1) * group, :]   # (group, hd)
-        if q_is_bf16:
-            q = q.astype(jnp.bfloat16)
-        q_terms.append(_mxu_terms(q, row_dtype))
+    all_heads = decode_attend_form(group, kv_heads,
+                                   block_size) == "all_heads"
+    if all_heads:
+        head_dim = k_buf.shape[3]
+        keys_per_row = LANES // kv_heads
+        q = q_ref[0]                                   # (kv_heads, hd)
+        all_q_terms = _mxu_terms(q.astype(jnp.bfloat16) if q_is_bf16
+                                 else q, row_dtype)
+        # Lane c of a lane row is head c % kv_heads's: the one entry
+        # of a score column that is that row's own.
+        own_head = (
+            jax.lax.broadcasted_iota(jnp.int32, (kv_heads, LANES), 1)
+            % kv_heads
+            == jax.lax.broadcasted_iota(jnp.int32, (kv_heads, LANES), 0))
+    else:
+        q_terms = []
+        for head in range(kv_heads):
+            q = q_ref[0, head * group:(head + 1) * group, :]   # (group, hd)
+            if q_is_bf16:
+                q = q.astype(jnp.bfloat16)
+            q_terms.append(_mxu_terms(q, row_dtype))
 
     def body(c, carry):
         slot = c % 2
@@ -784,6 +878,65 @@ def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
                                       ((1,), (0,))))
                 m_scr[rows, :] = m_new
 
+        def attend_all_heads(n_keys: int):
+            """The same update for one query row a kv head, every head
+            of a key at once, the whole tile in one step (a tile is a
+            lone block or a group here: W is one group, every key of
+            which was copied).
+
+            The tile's ``(key, head)`` rows, as they lie in the buffer,
+            are the MXU's transposed weights for all the queries' rows
+            at once; lane ``c`` of lane row ``j`` of the result's own
+            entries is pair ``128 j + c`` — the scale planes' order, so
+            the scales multiply as copied.  Softmax state is one lane
+            row (a head's entry repeated for each key of a row)."""
+            rows = n_keys * kv_heads // LANES           # lane rows of pairs
+            pairs = k_buf[slot, :n_keys].reshape(
+                n_keys * kv_heads, head_dim).astype(row_dtype)
+            scores = _contract_terms(all_q_terms, kv_heads, pairs,
+                                     ((1,), (1,)))      # (kv_heads, pairs)
+            s = jnp.concatenate([
+                jnp.sum(jnp.where(
+                    own_head, scores[:, j * LANES:(j + 1) * LANES], 0.0),
+                    axis=0, keepdims=True)
+                for j in range(rows)], axis=0)          # (rows, 128)
+            key_ids = (
+                first_key
+                + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                * keys_per_row
+                + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                // kv_heads)
+            visible = key_ids <= pos
+            if window is not None:
+                visible &= key_ids > pos - window
+            if quantized:
+                s = s * (ks_buf[slot, :rows] * (sm_scale * LOG2_E))
+            else:
+                s = s * (sm_scale * LOG2_E)
+            s = jnp.where(visible, s, NEG_INF)
+            m_prev = m_scr[:]
+            m_new = jnp.maximum(m_prev, _across_lane_groups(
+                jnp.max(s, axis=0, keepdims=True), kv_heads, jnp.maximum))
+            p = jnp.exp2(s - m_new)
+            correction = jnp.exp2(m_prev - m_new)
+            l_scr[:] = correction * l_scr[:] + _across_lane_groups(
+                jnp.sum(p, axis=0, keepdims=True), kv_heads, jnp.add)
+            if quantized:
+                p = p * vs_buf[slot, :rows]
+            weights = jnp.concatenate(
+                [_lanes_to_sublanes(p[j:j + 1], head_dim)
+                 for j in range(rows)], axis=0)
+            v = v_buf[slot, :n_keys]
+            acc_scr[:] = (
+                acc_scr[:] * _lanes_to_sublanes(correction,
+                                                head_dim)[:kv_heads]
+                + jnp.sum(weights.reshape(v.shape) * v.astype(jnp.float32),
+                          axis=0))
+            m_scr[:] = m_new
+
+        if all_heads:
+            attend = attend_all_heads
+
         def narrowest(tiles):
             """Attend over the first of ``tiles`` (ascending) that
             holds the iteration's live blocks: a lone block costs a
@@ -802,7 +955,10 @@ def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
         return carry
 
     jax.lax.fori_loop(0, iterations, body, 0)
-    denom = jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
+    if all_heads:
+        denom = _lanes_to_sublanes(l_scr[:], acc_scr.shape[1])[:kv_heads]
+    else:
+        denom = jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
     o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
 
 
@@ -882,8 +1038,9 @@ def closed_call(q, k_pool, v_pool, tables, positions, ks, vs, *,
     tables = tables.astype(jnp.int32)
     positions = positions.astype(jnp.int32)
     heads = kv_heads * group
+    form = decode_attend_form(group, kv_heads, block_size)
     keys = decode_keys_per_iteration(tables.shape[1] * block_size,
-                                     block_size, kv_heads)
+                                     block_size, kv_heads, form)
 
     def q_index(row, tables_ref, positions_ref):
         return (row, 0, 0)
@@ -915,10 +1072,13 @@ def closed_call(q, k_pool, v_pool, tables, positions, ks, vs, *,
         scale_buffer = pltpu.VMEM(
             (2, keys // block_size * plane_rows, scale_row), ks.dtype)
         scratch_shapes += [scale_buffer, scale_buffer]
+    # Running maximum and denominator: a column, one query head a row;
+    # in the all-heads form one lane row (see the kernel).
+    state = (1, LANES) if form == "all_heads" else (heads, 1)
     scratch_shapes += [
         pltpu.SemaphoreType.DMA((2,)),
-        pltpu.VMEM((heads, 1), jnp.float32),
-        pltpu.VMEM((heads, 1), jnp.float32),
+        pltpu.VMEM(state, jnp.float32),
+        pltpu.VMEM(state, jnp.float32),
         pltpu.VMEM((heads, head_dim), jnp.float32),
     ]
 

@@ -452,6 +452,7 @@ class ContinuousBatchingServer:
         self.decode_attention_path, self.prefill_attention_path = \
             self._attention_paths()
         self.decode_scale_append_path = self._scale_append_path()
+        self.decode_attend_form = self._attend_form()
         # Bookkeeping state lives HOST-side (numpy): admissions and
         # retirements mutate it for free, and it rides into the chunk
         # dispatch as three tiny h2d transfers.  The device-returned
@@ -869,6 +870,18 @@ class ContinuousBatchingServer:
         ``"none"`` (a float cache has no scales)."""
         return "scatter" if self.quantize_kv else "none"
 
+    def _attend_form(self) -> str:
+        """Which ``attend`` body of the K/V decode kernel this
+        geometry takes, by the kernel's own deciding function:
+        ``"all_heads"`` (one query row a kv head: every head of a key
+        at once) or ``"per_head"``.  Static a server."""
+        from ..ops.paged_attention import decode_attend_form
+        _, kv_heads, _ = self._kv_geometry()
+        query_rows = (self.config.n_heads // (kv_heads * self.tp_degree)
+                      * max(self._block_length, 1))
+        return decode_attend_form(query_rows, kv_heads,
+                                  self._attn_block_size)
+
     def _note_decode_blocks(self, live, sched) -> None:
         """Estimate the KV blocks each dispatched decode step reads,
         from the host position mirrors (positions as of dispatch;
@@ -906,7 +919,8 @@ class ContinuousBatchingServer:
                         positions + reach - 1,
                         block_size=block_size,
                         table_blocks=self._attn_total_blocks,
-                        kv_heads=kv_heads, window=window or None),
+                        kv_heads=kv_heads, window=window or None,
+                        form=self.decode_attend_form),
                         ("decode_iterations", "decode_wide_iterations")):
                     self.counters[counter] += int(
                         (rows * sched_live).sum())
@@ -2823,6 +2837,7 @@ class ContinuousBatchingServer:
             mesh_shape=self.mesh_shape,
             decode_attention_path=self.decode_attention_path,
             decode_scale_append_path=self.decode_scale_append_path,
+            decode_attend_form=self.decode_attend_form,
             prefill_attention_path=self.prefill_attention_path,
             blocks_read_per_step=(
                 round(self.counters["decode_blocks_read"] / steps, 2)

@@ -79,6 +79,7 @@ TELEMETRY_KEYS = (
     "kv_disk_restores", "kv_checksum_failures", "kv_adopted_chains",
     "kv_prefetch_promotions",
     "decode_attention_path", "decode_scale_append_path",
+    "decode_attend_form",
     "blocks_read_per_step",
     "prefill_tokens_per_sec", "prefill_queue_depth",
     "prefill_attention_path",

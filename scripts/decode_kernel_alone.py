@@ -46,6 +46,9 @@ GEOMETRIES = {
     "mistral7b.idle": (32, 8, 4, jnp.int8, 4096, 160, 4609),
     "mistral7b.full_batch": (32, 8, 4, jnp.int8, 4096, 160, 4609),
     "nemotron3super.reason": (64, 2, 16, jnp.bfloat16, None, 144, 9217),
+    # one query row a kv head: the all-heads form of the kernel
+    "evabyte.files": (8, 32, 1, jnp.int8, None, 184, 1537),
+    "evabyte.full": (8, 32, 1, jnp.int8, None, 184, 1537),
 }
 BLOCK, HEAD_DIM, HBM_BYTES_PER_S = 16, 128, 819e9
 
@@ -66,6 +69,13 @@ def row_lengths(name, slots, rng):
         return np.arange(slots) % BLOCK
     if name == "mistral7b.full_batch":
         return np.full(slots, 1000)
+    if name == "evabyte.files":
+        # 6 live rows of 600-2,816 composed rows (a window's 2,048 and
+        # the summaries behind it), 2 idle slots at scratch positions
+        return np.concatenate([rng.integers(600, 2817, 6) - 1,
+                               np.arange(6, 8) % BLOCK])
+    if name == "evabyte.full":
+        return np.full(slots, 2815)
     lengths = np.clip(rng.normal(490, 150, slots).astype(np.int64),
                       40, 2000)
     lengths[:3] = np.arange(3)              # three idle
@@ -83,7 +93,7 @@ def case(name, tiny):
     tables = np.zeros((slots, table), np.int32)
     at = 0
     for r in range(slots):
-        if name.startswith("mistral7b") and positions[r] < BLOCK:
+        if name.startswith(("mistral7b", "evabyte")) and positions[r] < BLOCK:
             continue                        # idle: scratch block 0
         tables[r, :need[r]] = ids[at:at + need[r]]
         at += need[r]

@@ -131,7 +131,8 @@ def test_inside_one_window_the_model_is_dense_causal_attention(params):
 # --- (c): the served path against the reference's full forward ----------- #
 
 
-def _through_the_cache(params, tokens, prompt_len, quantize_kv, slice_width):
+def _through_the_cache(params, tokens, prompt_len, quantize_kv, slice_width,
+                       CONFIG=CONFIG):
     """Next-byte logits at positions ``prompt_len - 1 ..`` of ``tokens``:
     the prompt in slices (its bucket padded), then one decode step a
     position with the true byte fed back, in slot 1 of 2."""
@@ -182,6 +183,33 @@ def test_slices_then_decode_through_the_composed_cache(
     want = _reference(params, tokens)[prompt_len - 1:]
     jax.clear_caches()
     assert np.abs(got - want).max() < (0.08 if quantize_kv else 2e-4)
+
+
+@pytest.mark.parametrize("quantize_kv", [False, True])
+def test_decode_at_32_heads_takes_the_all_heads_form(monkeypatch,
+                                                     quantize_kv):
+    """EvaByte's own head count (no grouped queries): a block of 4 keys
+    is one lane row of (key, head) pairs, so the decode kernel attends
+    over every head of a key at once — through the composed table,
+    across a window's end in prefill and one in decode, it gives what
+    the jnp form gives."""
+    import dataclasses
+
+    from aiko_services_tpu.ops.paged_attention import decode_attend_form
+    config = dataclasses.replace(CONFIG, d_model=128, n_heads=32,
+                                 n_kv_heads=32)
+    assert decode_attend_form(1, config.n_kv_heads, BLOCK) == "all_heads"
+    wide = evabyte.init_params(config, jax.random.PRNGKey(11))
+    tokens, prompt_len = _tokens(80), 37
+    got = {}
+    for mode in ("reference", "interpret"):
+        monkeypatch.setenv("AIKO_DECODE_ATTENTION", mode)
+        monkeypatch.setenv("AIKO_PREFILL_ATTENTION", "reference")
+        jax.clear_caches()
+        got[mode] = _through_the_cache(wide, tokens, prompt_len,
+                                       quantize_kv, 16, config)
+    jax.clear_caches()
+    assert np.abs(got["interpret"] - got["reference"]).max() < 2e-4
 
 
 # --- the engine ------------------------------------------------------------ #

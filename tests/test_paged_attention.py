@@ -200,6 +200,23 @@ def test_blocks_per_iteration_follows_block_size(bs):
     (4096, 5, 128), (4096, 40, 128)])
 def test_keys_per_iteration_follows_the_call(table_keys, kv, wide):
     assert pa.decode_keys_per_iteration(table_keys, 16, kv) == wide
+    assert pa.decode_keys_per_iteration(table_keys, 16, kv,
+                                        "per_head") == wide
+
+
+@pytest.mark.parametrize("table_keys,kv,bs,wide", [
+    # evabyte.files' table; fewer and more heads; a table of one block;
+    # the contiguous view; a block of 32 keys.
+    (2944, 32, 16, 128), (4096, 8, 16, 128), (64, 32, 16, 128),
+    (4096, 128, 16, 128), (2048, 16, 128, 128), (4096, 4, 32, 128)])
+def test_keys_per_iteration_of_the_all_heads_form(table_keys, kv, bs, wide):
+    """One query row a kv head: a pass is one group whatever the heads
+    (the attend takes a tile whole, and the tile holds only copied
+    keys)."""
+    assert pa.decode_attend_form(1, kv, bs) == "all_heads"
+    assert pa.decode_keys_per_iteration(table_keys, bs, kv,
+                                        "all_heads") == wide
+    assert pa.decode_tiles(bs, wide)[-1] == wide
 
 
 def test_prefill_kernel_keeps_a_group_of_keys_a_step():

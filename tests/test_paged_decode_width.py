@@ -28,6 +28,10 @@ WIDTH_CASES = {
     "contiguous_view_block128_bf16": ("bf16", 2, 2, 128, 512),
     "bf16_one_head": ("bf16", 1, 8, 16, 512),
     "f32_16_heads": ("f32", 16, 1, 16, 128),
+    # one query row a kv head, the all-heads form (evabyte.files' 32
+    # int8 heads among them)
+    "int8_32x1": ("int8", 32, 1, 16, 128),
+    "bf16_8x1": ("bf16", 8, 1, 16, 128),
 }
 
 
@@ -38,7 +42,9 @@ def _edge_case(name):
     dtype, kv, group, bs, wide = WIDTH_CASES[name]
     lengths = [wide - 1, wide, wide + 1, wide + 16, 2 * wide + 17, bs, 6]
     max_blocks = -(-max(lengths) // bs) + 1
-    assert pa.decode_keys_per_iteration(max_blocks * bs, bs, kv) == wide
+    assert pa.decode_keys_per_iteration(
+        max_blocks * bs, bs, kv,
+        pa.decode_attend_form(group, kv, bs)) == wide
     q, k, v, exact, tables, kv_args = _typed_case(
         dtype, bs, max_blocks, batch=len(lengths), kv=kv, group=group,
         hd=16)
@@ -55,7 +61,8 @@ def test_kernel_row_lengths_at_every_tile_edge(name):
 
 
 @pytest.mark.parametrize("name", ["f32", "sdar_bf16_4x32",
-                                  "mistral_int8_8x4", "int8_2x8"])
+                                  "mistral_int8_8x4", "int8_2x8",
+                                  "int8_32x1", "bf16_8x1"])
 @pytest.mark.parametrize("window", ["W-1", "W+24", 100])
 def test_kernel_window_at_every_tile_edge(name, window):
     """The same rows under sliding windows that end a band one key
@@ -69,7 +76,8 @@ def test_kernel_window_at_every_tile_edge(name, window):
 
 
 @pytest.mark.parametrize("name", ["f32", "sdar_bf16_4x32", "int8_2x8",
-                                  "contiguous_view_block128_bf16"])
+                                  "contiguous_view_block128_bf16",
+                                  "int8_32x1", "bf16_8x1", "f32_16_heads"])
 @pytest.mark.parametrize("tail_blocks", [9, 17, 31])
 def test_wide_tile_never_weighs_another_rows_values(name, tail_blocks):
     """A wide tile reaches past the groups its pass copied, into buffer
@@ -77,7 +85,10 @@ def test_wide_tile_never_weighs_another_rows_values(name, tail_blocks):
     are NaN and Inf here, in blocks it owns and attends over, and the
     rows after it, which end 9 to 31 blocks into a wide tile (of 32
     blocks, or of sdar's 16), must not see them (a masked key weighs
-    zero, and zero times NaN is NaN)."""
+    zero, and zero times NaN is NaN).  With one query row a kv head
+    (the all-heads form) a pass is one group, every key of which it
+    copied: the rows after it end inside a group whose clamped entries
+    re-copy their own last block."""
     dtype, kv, group, bs, wide = WIDTH_CASES[name]
     keys = tail_blocks * bs - 3 if bs < wide else 2 * bs - 3
     lengths = [wide, keys, 2 * wide, wide + keys, keys]
@@ -129,8 +140,11 @@ def test_bf16_queries_are_their_own_single_term():
 
 
 @pytest.mark.parametrize("window", [None, 200, 700])
-@pytest.mark.parametrize("kv", [2, 4, 8])
-def test_host_iteration_counts_are_the_kernels_loop_bounds(kv, window):
+@pytest.mark.parametrize("kv,form", [(2, "per_head"), (4, "per_head"),
+                                     (8, "per_head"), (8, "all_heads"),
+                                     (32, "all_heads")])
+def test_host_iteration_counts_are_the_kernels_loop_bounds(kv, form,
+                                                           window):
     """``decode_iterations`` / ``decode_wide_iterations`` as the host
     reckons them equal a walk of the kernel's own loop — its bounds,
     the live blocks each pass holds and the tile it takes — for a
@@ -139,7 +153,7 @@ def test_host_iteration_counts_are_the_kernels_loop_bounds(kv, window):
     bs, table_blocks = 16, 129
     positions = np.array([0, 5, 15, 16, 127, 128, 300, 511, 512, 513,
                           545, 639, 640, 1023, 1024, 1500, 2063])
-    wide = pa.decode_keys_per_iteration(table_blocks * bs, bs, kv)
+    wide = pa.decode_keys_per_iteration(table_blocks * bs, bs, kv, form)
     tiles = pa.decode_tiles(bs, wide)
     per_pass = wide // bs
     want_all = want_wide = 0
@@ -155,8 +169,118 @@ def test_host_iteration_counts_are_the_kernels_loop_bounds(kv, window):
                              == len(tiles) - 1)
     rows, wide_rows = pa.decode_iteration_counts(
         positions, block_size=bs, table_blocks=table_blocks, kv_heads=kv,
-        window=window)
+        window=window, form=form)
     assert (int(rows.sum()), int(wide_rows.sum())) == (want_all,
                                                        want_wide)
     assert rows.shape == wide_rows.shape == positions.shape
     assert (wide_rows <= rows).all() and wide_rows.sum() > 0
+
+
+# --------------------------------------------------------------------------- #
+# One query row a kv head: every head of a key at once, in the buffer's
+# own layout (``decode_attend_form`` == "all_heads").
+
+#: name -> (pool dtype, kv heads, block size)
+ALL_HEADS_CASES = {
+    "int8_32": ("int8", 32, 16),
+    "int8_8": ("int8", 8, 16),
+    "bf16_32": ("bf16", 32, 16),
+    "bf16_8": ("bf16", 8, 16),
+    "f32_32": ("f32", 32, 16),
+    "f32_8": ("f32", 8, 16),
+    "f32_4_block32": ("f32", 4, 32),
+    "int8_16_contiguous_view_block128": ("int8", 16, 128),
+}
+
+
+@pytest.mark.parametrize("q_dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("window", [None, 150])
+@pytest.mark.parametrize("name", sorted(ALL_HEADS_CASES))
+def test_all_heads_form_matches_the_reference(name, window, q_dtype):
+    """Rows that are idle (zero table, a scratch position), a lone
+    block, a ragged group, whole passes and several with a tail;
+    queries that are f32 values (three MXU terms against bf16 rows) and
+    bf16 values (their own one)."""
+    dtype, kv, bs = ALL_HEADS_CASES[name]
+    assert pa.decode_attend_form(1, kv, bs) == "all_heads"
+    wide = pa.decode_keys_per_iteration(4096, bs, kv, "all_heads")
+    assert wide == pa.KEYS_PER_GROUP
+    lengths = [7, bs, 100, wide - 1, wide, wide + 1, 2 * wide + 81, 3]
+    max_blocks = -(-max(lengths) // bs) + 1
+    q, k, v, exact, tables, kv_args = _typed_case(
+        dtype, bs, max_blocks, batch=len(lengths), kv=kv, group=1, hd=16)
+    tables = tables.at[len(lengths) - 1].set(0)
+    positions = jnp.asarray([length - 1 for length in lengths], jnp.int32)
+    q = q.astype(DTYPES[q_dtype])
+    out = pa.paged_decode_attention(q, k, v, tables, positions,
+                                    window=window, interpret=True,
+                                    **kv_args)
+    assert out.dtype == q.dtype
+    ref = pa.paged_decode_reference(
+        q.astype(jnp.float32), exact[0], exact[1], tables, positions,
+        window=window, **kv_args)
+    tol = {"f32": 1e-4 if dtype == "int8" else 2e-5, "bf16": 2e-2}[q_dtype]
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("group,kv,bs,form", [
+    # One query row a kv head and whole lane rows of (key, head) pairs.
+    (1, 32, 16, "all_heads"), (1, 8, 16, "all_heads"),
+    (1, 16, 16, "all_heads"), (1, 4, 32, "all_heads"),
+    (1, 1, 128, "all_heads"), (1, 128, 16, "all_heads"),
+    # The benchmark's other K/V cells: many rows a head.
+    (4, 8, 16, "per_head"), (16, 2, 16, "per_head"),
+    (32, 4, 16, "per_head"), (2, 32, 16, "per_head"),
+    # One row a head whose blocks fill no whole lane row.
+    (1, 4, 16, "per_head"), (1, 1, 16, "per_head"), (1, 3, 128, "per_head"),
+    (1, 40, 16, "per_head")])
+def test_attend_form_follows_the_geometry(group, kv, bs, form):
+    assert pa.decode_attend_form(group, kv, bs) == form
+
+
+@pytest.mark.parametrize("config_name,form", [
+    ("tiny", "per_head"), ("evabyte_tiny", "per_head"),
+    ("evabyte_32_heads", "all_heads")])
+def test_servers_attend_form_is_the_kernels(config_name, form):
+    """``stats()["decode_attend_form"]`` is the kernel's own deciding
+    function at the server's geometry (``evabyte_tiny``: one row a head,
+    but 4 heads of a 4-key block fill no lane row), and the host's
+    iteration counts take the width that form gets."""
+    import dataclasses
+
+    from aiko_services_tpu.models import evabyte, serving_model
+    from aiko_services_tpu.orchestration.continuous import DecodeRequest
+    from aiko_services_tpu.orchestration.paged import PagedContinuousServer
+    from aiko_services_tpu.orchestration.serving import serving_telemetry
+    if config_name == "evabyte_32_heads":
+        # EvaByte's own head count at width 128: a block of 4 keys is
+        # one lane row of (key, head) pairs.
+        evabyte.CONFIGS[config_name] = dataclasses.replace(
+            evabyte.CONFIGS["evabyte_tiny"], d_model=128, n_heads=32,
+            n_kv_heads=32)
+    _, config = serving_model(config_name)
+    block = 16 if config_name == "tiny" else config.chunk_size
+    try:
+        server = PagedContinuousServer(
+            config_name=config_name, slots=2, max_seq=128, chunk_steps=2,
+            block_size=block, chunk_prefill_tokens=16, total_blocks=40)
+    finally:
+        evabyte.CONFIGS.pop("evabyte_32_heads", None)
+    group = config.n_heads // config.n_kv_heads
+    assert server.decode_attend_form == pa.decode_attend_form(
+        group, config.n_kv_heads, block) == form
+    # Count as the chip's path does, at the width the form gets.
+    server.decode_attention_path = "kernel"
+    request = DecodeRequest(request_id="r", max_new_tokens=6,
+                            prompt=np.arange(1, 40, dtype=np.int32))
+    server.submit(request)
+    for _ in range(200):
+        if request.finished_ts is not None:
+            break
+        server.step()
+    assert request.error is None and len(request.tokens) == 6
+    stats = server.stats()
+    assert stats["decode_attend_form"] == form
+    assert serving_telemetry(stats)["decode_attend_form"] == form
+    assert stats["decode_iterations"] >= stats["decode_steps"] > 0

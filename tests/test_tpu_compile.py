@@ -150,8 +150,13 @@ DECODE_GEOMETRIES = {
                                               129, 128, 256),
     "f32_pool_2_heads": (8, 2, 16, jnp.float32, None, 64, 1025, 16, 512),
     "f32_pool_32_heads": (8, 32, 1, jnp.float32, None, 64, 1025, 16, 128),
+    # One query row a kv head: the all-heads form.
     "evabyte_files_int8_32x1": (8, 32, 1, jnp.int8, None, 184, 1537, 16,
                                 128),
+    "bf16_pool_8_heads_one_row_window": (8, 8, 1, jnp.bfloat16, 1024, 184,
+                                         1537, 16, 128),
+    "contiguous_view_block128_int8_16_heads_one_row": (
+        8, 16, 1, jnp.int8, None, 16, 129, 128, 128),
 }
 
 
@@ -167,7 +172,9 @@ def test_decode_kernel_compiles_for_v5e(name, one_chip, as_on_tpu):
     (slots, kv, group, pool_dt, window, table, n_blocks, bs,
      wide) = DECODE_GEOMETRIES[name]
     hd = 128
-    assert pa.decode_keys_per_iteration(table * bs, bs, kv) == wide
+    assert pa.decode_keys_per_iteration(
+        table * bs, bs, kv,
+        pa.decode_attend_form(group, kv, bs)) == wide
     S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     pool = S((n_blocks, bs, kv, hd), pool_dt)
     scales = S((n_blocks, bs, kv), jnp.float32) \
