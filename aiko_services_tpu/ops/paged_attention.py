@@ -40,10 +40,14 @@ costs time in proportion to them:
   to meet something finite).
 * online-softmax state for every head lives in VMEM scratch across the
   loop (flash-decoding style — running max ``m``, denominator ``l``,
-  accumulator ``acc`` in f32).  Heads are split in-kernel
-  (:func:`load_head_rows`): all ``group = n_heads // n_kv_heads`` query
-  heads of a kv head share one (group, head_dim) × (head_dim, keys)
-  matmul per iteration instead of ``group`` skinny dot products.
+  accumulator ``acc`` in f32).  Heads are split in-kernel — out of a
+  float pool's buffer as whole 32-bit word rows, one strided load a
+  vreg (:func:`load_word_head_rows`), out of an int8 pool's by a
+  sub-word read (:func:`load_head_rows`): :func:`decode_attend_form`,
+  ``"word_rows"`` and ``"per_head"`` — and all ``group = n_heads //
+  n_kv_heads`` query heads of a kv head share one (group, head_dim) ×
+  (head_dim, keys) matmul per iteration instead of ``group`` skinny dot
+  products.
   Contract precision is f32's: six MXU passes over f32 pool rows, ONE
   over rows that are bf16 values (bf16 pools, widened int8), with the
   f32 side's three bf16 terms stacked (:func:`_contract_terms`); the
@@ -96,6 +100,7 @@ __all__ = ["paged_decode_attention", "paged_decode_reference",
            "decode_kernel_mode",
            "decode_dispatch", "decode_attention_path",
            "contiguous_block_size", "kernel_serves", "load_head_rows",
+           "load_word_head_rows",
            "blocks_per_group", "decode_keys_per_iteration", "decode_tiles",
            "decode_tile_index", "decode_loop_bounds",
            "decode_iteration_counts", "decode_scale_row",
@@ -442,6 +447,43 @@ def load_head_rows(block_ref, head: int, dtype=jnp.float32):
     return block_ref[:, head, :].astype(dtype)
 
 
+def load_word_head_rows(words_ref, head: int, kv_heads: int, n_keys: int,
+                        pool_dtype, dtype=jnp.float32):
+    """One kv head of the first ``n_keys`` keys of a key buffer as
+    ``(n_keys, head_dim)`` rows in ``dtype``, read through the buffer's
+    32-bit words: ``words_ref`` is :func:`_word_rows_view` of a
+    ``(keys, kv_heads, head_dim)`` buffer of ``pool_dtype``.
+
+    A word row holds one lane row of ``packing`` neighbouring heads of
+    a key (one f32 head, two bf16 heads: the lower-numbered head in the
+    low half), and a key's word rows are ``kv_heads / packing`` apart,
+    so the heads of a word leave the buffer by ONE sublane-strided load
+    of whole 32-bit rows — a load a vreg of eight keys — where
+    :func:`load_head_rows`' sub-word read is a masked load, a shift and
+    a rotate a KEY (PERF.md section 6, PR 42 and PR 43).  A bf16 head
+    is then its half of the word moved to a float's high half: one
+    shift or one mask a vreg, exact."""
+    packing = 4 // jnp.dtype(pool_dtype).itemsize
+    words = words_ref[pl.ds(head // packing, n_keys,
+                            stride=kv_heads // packing), :]
+    if packing == 1:
+        return words.astype(dtype)
+    bits = (words << 16 if head % packing == 0
+            else words & jnp.uint32(0xFFFF0000))
+    return pltpu.bitcast(bits, jnp.float32).astype(dtype)
+
+
+def _word_rows_view(buf):
+    """A ``(2, keys, kv_heads, head_dim)`` key buffer seen as rows of
+    32-bit words ``(2, keys · kv_heads / packing, head_dim)``: the same
+    bytes (a packed tile's row pair IS a word row), no copy."""
+    slots, keys, kv_heads, head_dim = buf.shape
+    packing = 4 // jnp.dtype(buf.dtype).itemsize
+    if packing > 1:
+        buf = buf.bitcast(jnp.uint32)
+    return buf.reshape(slots, keys * kv_heads // packing, head_dim)
+
+
 #: Keys of one GROUP of block copies: a lane-wide score tile, what the
 #: prefill kernel's step covers, the least a decode iteration covers
 #: past a lone block, and the step its width grows in.
@@ -461,21 +503,38 @@ MAX_DECODE_KEYS_PER_ITERATION = 512
 DECODE_HEAD_TILES_PER_ITERATION = 8
 
 
-def decode_attend_form(group: int, kv_heads: int, block_size: int) -> str:
-    """Which of the decode kernel's two ``attend`` bodies a call takes,
+def decode_attend_form(group: int, kv_heads: int, block_size: int,
+                       pool_dtype) -> str:
+    """Which of the decode kernel's ``attend`` bodies a call takes,
     from what it can see: ``"all_heads"`` when a kv head carries ONE
     query row and a block's ``(key, head)`` pairs fill whole lane rows
     (:func:`decode_scale_row`: the layout the int8 scale planes already
-    ride in), else ``"per_head"``.
+    ride in); else ``"word_rows"`` over a float pool whose heads fill
+    whole 32-bit words; else ``"per_head"``.
 
-    Many rows a head want the MXU head by head (one strided head read
-    and one weight load serve ``group`` rows); one row a head has
-    nothing to amortise them over, and a key's ``(kv_heads, head_dim)``
-    tile has the queries' own shape, so every head of a key is attended
-    over at once, in the buffer's own layout (docs/KERNELS.md).  The
-    serving counter ``decode_attend_form`` is this same answer."""
+    Many rows a head want the MXU head by head (one head read and one
+    weight load serve ``group`` rows); one row a head has nothing to
+    amortise them over, and a key's ``(kv_heads, head_dim)`` tile has
+    the queries' own shape, so every head of a key is attended over at
+    once, in the buffer's own layout (docs/KERNELS.md).  Head by head,
+    ``word_rows`` and ``per_head`` differ in how a head's rows leave
+    the buffer: as whole 32-bit word rows by one strided load a vreg
+    (:func:`load_word_head_rows`) or by :func:`load_head_rows`'
+    sub-word read, a key at a time.  The first makes a 256-key attend
+    of 4 bf16 heads 742 bundles where the second makes it 2,073, reads
+    a third faster alone and gives the same result bit for bit (PERF.md
+    section 6, PR 43); it is taken where it was measured, on f32 and
+    bf16 pools; int8 pools
+    (four heads a word, scales beside them: 8 kv heads × 4 rows in the
+    chat cells, where two thirds of a call is idle slots' passes and a
+    larger kernel has cost a step more than it gained, PERF.md section
+    6, PR 40) keep ``per_head``.  The serving counter
+    ``decode_attend_form`` is this same answer."""
     if group == 1 and decode_scale_row(block_size, kv_heads):
         return "all_heads"
+    itemsize = jnp.dtype(pool_dtype).itemsize
+    if itemsize >= 2 and kv_heads % (4 // itemsize) == 0:
+        return "word_rows"
     return "per_head"
 
 
@@ -679,10 +738,14 @@ def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
     The queries' MXU terms are the program's, not an iteration's
     (``q_is_bf16``: the rows ARE bf16 values, their own single term).
 
-    Two ``attend`` bodies share the loop, the copies, the bounds and
-    the tiles (:func:`decode_attend_form`).  ``per_head``: a kv head
-    after another, its ``group`` query rows against its strided rows of
-    the tile, scratch one column a query head.  ``all_heads`` (one
+    The ``attend`` bodies share the loop, the copies, the bounds and
+    the tiles (:func:`decode_attend_form`).  ``per_head`` and
+    ``word_rows``: a kv head after another, its ``group`` query rows
+    against its rows of the tile — read key by key out of the
+    sub-word strided tile (:func:`load_head_rows`) or as whole 32-bit
+    word rows of a float pool's buffer, a strided load a vreg
+    (:func:`load_word_head_rows`); one chain after the read — scratch
+    one column a query head.  ``all_heads`` (one
     query row a kv head): every head of a key at once — the tile's
     ``(key, head)`` rows as they lie are the score matmul's transposed
     weights, the softmax runs on lane rows in the scale planes' own
@@ -782,8 +845,10 @@ def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
     # MXU as bf16: see _contract_terms.
     row_dtype = (jnp.float32 if k_buf.dtype == jnp.float32
                  else jnp.bfloat16)
-    all_heads = decode_attend_form(group, kv_heads,
-                                   block_size) == "all_heads"
+    form = decode_attend_form(group, kv_heads, block_size, k_buf.dtype)
+    all_heads = form == "all_heads"
+    if form == "word_rows":
+        k_words, v_words = _word_rows_view(k_buf), _word_rows_view(v_buf)
     if all_heads:
         head_dim = k_buf.shape[3]
         keys_per_row = LANES // kv_heads
@@ -853,10 +918,16 @@ def _paged_decode_kernel(tables_ref, positions_ref,   # scalar prefetch
                                   clear, 0)
             for head in range(kv_heads):
                 rows = slice(head * group, (head + 1) * group)
-                k = load_head_rows(k_buf.at[slot, :n_keys], head,
-                                   row_dtype)          # (n_keys, hd)
-                v = load_head_rows(v_buf.at[slot, :n_keys], head,
-                                   row_dtype)
+                if form == "word_rows":
+                    k, v = (load_word_head_rows(
+                        words.at[slot], head, kv_heads, n_keys,
+                        k_buf.dtype, row_dtype)
+                        for words in (k_words, v_words))
+                else:
+                    k = load_head_rows(k_buf.at[slot, :n_keys], head,
+                                       row_dtype)      # (n_keys, hd)
+                    v = load_head_rows(v_buf.at[slot, :n_keys], head,
+                                       row_dtype)
                 s = _contract_terms(q_terms[head], group, k,
                                     ((1,), (1,))) * sm_scale
                 if quantized:
@@ -1038,7 +1109,7 @@ def closed_call(q, k_pool, v_pool, tables, positions, ks, vs, *,
     tables = tables.astype(jnp.int32)
     positions = positions.astype(jnp.int32)
     heads = kv_heads * group
-    form = decode_attend_form(group, kv_heads, block_size)
+    form = decode_attend_form(group, kv_heads, block_size, k_pool.dtype)
     keys = decode_keys_per_iteration(tables.shape[1] * block_size,
                                      block_size, kv_heads, form)
 

@@ -874,13 +874,15 @@ class ContinuousBatchingServer:
         """Which ``attend`` body of the K/V decode kernel this
         geometry takes, by the kernel's own deciding function:
         ``"all_heads"`` (one query row a kv head: every head of a key
-        at once) or ``"per_head"``.  Static a server."""
+        at once), ``"word_rows"`` (head by head, a float pool's heads
+        read as whole 32-bit word rows) or ``"per_head"``.  Static a
+        server."""
         from ..ops.paged_attention import decode_attend_form
-        _, kv_heads, _ = self._kv_geometry()
+        _, kv_heads, kv_dtype = self._kv_geometry()
         query_rows = (self.config.n_heads // (kv_heads * self.tp_degree)
                       * max(self._block_length, 1))
         return decode_attend_form(query_rows, kv_heads,
-                                  self._attn_block_size)
+                                  self._attn_block_size, kv_dtype)
 
     def _note_decode_blocks(self, live, sched) -> None:
         """Estimate the KV blocks each dispatched decode step reads,

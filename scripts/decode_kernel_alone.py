@@ -11,7 +11,8 @@ tree's kernel has none).
     chiprun -- python scripts/decode_kernel_alone.py --against DIR
 
 A width is set for the sweep by the module's two constants, as no
-argument sets it in the program.  Prints one JSON line a build: ms a
+argument sets it in the program.  Prints one JSON line a build: the
+``attend`` body the build's ``decode_attend_form`` picks there, ms a
 call (median and least of 8 timings of ONE jit of ``--calls`` calls,
 each call with queries of its own: a Pallas call has no side effects,
 so XLA merges calls on the same operands and a jit of identical calls
@@ -135,6 +136,21 @@ def load(path, loaded={}):
     return loaded[path]
 
 
+def form_of(module, name):
+    """The ``attend`` body ``module``'s kernel takes at ``name``'s
+    geometry, by its own deciding function (a copy from before PR 43
+    is not handed the pool's dtype; one from before PR 42 has one
+    body)."""
+    _, kv, group, dtype, *_ = GEOMETRIES[name]
+    decide = getattr(module, "decode_attend_form", None)
+    if decide is None:
+        return "per_head"
+    try:
+        return decide(group, kv, BLOCK, dtype)
+    except TypeError:
+        return decide(group, kv, BLOCK)
+
+
 def timed(call, operands, calls, reps):
     """(result of the first call, median s a call, least s a call)."""
     q, rest = operands[0], operands[1:]
@@ -204,6 +220,7 @@ def main():
                     *((2, 1) if args.interpret else (args.calls, 8)))
             except Exception as error:       # a refused build is a finding
                 print(json.dumps(dict(shape=name, build=build,
+                                      form=form_of(module, name),
                                       refused=str(error)[:400])), flush=True)
                 continue
             finally:
@@ -212,7 +229,8 @@ def main():
             out = np.asarray(out.astype(jnp.float32))
             want = out if want is None else want
             print(json.dumps(dict(
-                shape=name, build=build, ms=round(seconds * 1e3, 4),
+                shape=name, build=build, form=form_of(module, name),
+                ms=round(seconds * 1e3, 4),
                 least_ms=round(least * 1e3, 4),
                 us_per_128_keys=round(seconds * 1e6 / made["groups"], 4),
                 roofline_pct=round(100 * floor_ms / (seconds * 1e3), 2),

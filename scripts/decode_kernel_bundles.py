@@ -30,11 +30,19 @@ BUNDLE = re.compile(r"\s*(?:0x[0-9a-f]+|\d+)\s+:\s*(>*)\s*\{(.*)\}")
 OPCODE = re.compile(r"=\s*([a-z_0-9.]+)")
 
 
+def _alone():
+    """``scripts/decode_kernel_alone.py``: the cells' geometries and
+    the loader of another copy of the kernel."""
+    if os.path.join(ROOT, "scripts") not in sys.path:
+        sys.path.insert(0, os.path.join(ROOT, "scripts"))
+    import decode_kernel_alone
+    return decode_kernel_alone
+
+
 def compile_one(name, path):
     """Child: lower and compile the kernel at ``name``'s geometry."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    sys.path.insert(0, os.path.join(ROOT, "scripts"))
-    import decode_kernel_alone as alone
+    alone = _alone()
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
@@ -61,6 +69,13 @@ def compile_one(name, path):
         shaped((slots, kv, group, alone.HEAD_DIM), jnp.bfloat16), pool,
         pool, shaped((slots, table), jnp.int32),
         shaped((slots,), jnp.int32), scales, scales).compile()
+
+
+def form_of(name, path):
+    """The ``attend`` body the build takes at ``name``'s geometry (the
+    parent stays off the TPU compiler: the children hold it)."""
+    alone = _alone()
+    return alone.form_of(alone.load(path) if path else alone.pa, name)
 
 
 def summary(dump):
@@ -112,7 +127,8 @@ def main():
                 else:
                     out = dict(refused=done.stderr[-600:])
                 print(json.dumps(dict(shape=name,
-                                      build=build or "this tree", **out)),
+                                      build=build or "this tree",
+                                      form=form_of(name, build), **out)),
                       flush=True)
 
 

@@ -198,7 +198,9 @@ def test_decode_at_32_heads_takes_the_all_heads_form(monkeypatch,
     from aiko_services_tpu.ops.paged_attention import decode_attend_form
     config = dataclasses.replace(CONFIG, d_model=128, n_heads=32,
                                  n_kv_heads=32)
-    assert decode_attend_form(1, config.n_kv_heads, BLOCK) == "all_heads"
+    assert decode_attend_form(1, config.n_kv_heads, BLOCK,
+                              jnp.int8 if quantize_kv
+                              else config.dtype) == "all_heads"
     wide = evabyte.init_params(config, jax.random.PRNGKey(11))
     tokens, prompt_len = _tokens(80), 37
     got = {}

@@ -200,8 +200,9 @@ def test_blocks_per_iteration_follows_block_size(bs):
     (4096, 5, 128), (4096, 40, 128)])
 def test_keys_per_iteration_follows_the_call(table_keys, kv, wide):
     assert pa.decode_keys_per_iteration(table_keys, 16, kv) == wide
-    assert pa.decode_keys_per_iteration(table_keys, 16, kv,
-                                        "per_head") == wide
+    for form in ("per_head", "word_rows"):
+        assert pa.decode_keys_per_iteration(table_keys, 16, kv,
+                                            form) == wide
 
 
 @pytest.mark.parametrize("table_keys,kv,bs,wide", [
@@ -213,7 +214,7 @@ def test_keys_per_iteration_of_the_all_heads_form(table_keys, kv, bs, wide):
     """One query row a kv head: a pass is one group whatever the heads
     (the attend takes a tile whole, and the tile holds only copied
     keys)."""
-    assert pa.decode_attend_form(1, kv, bs) == "all_heads"
+    assert pa.decode_attend_form(1, kv, bs, jnp.int8) == "all_heads"
     assert pa.decode_keys_per_iteration(table_keys, bs, kv,
                                         "all_heads") == wide
     assert pa.decode_tiles(bs, wide)[-1] == wide

@@ -44,7 +44,7 @@ def _edge_case(name):
     max_blocks = -(-max(lengths) // bs) + 1
     assert pa.decode_keys_per_iteration(
         max_blocks * bs, bs, kv,
-        pa.decode_attend_form(group, kv, bs)) == wide
+        pa.decode_attend_form(group, kv, bs, DTYPES[dtype])) == wide
     q, k, v, exact, tables, kv_args = _typed_case(
         dtype, bs, max_blocks, batch=len(lengths), kv=kv, group=group,
         hd=16)
@@ -142,7 +142,8 @@ def test_bf16_queries_are_their_own_single_term():
 @pytest.mark.parametrize("window", [None, 200, 700])
 @pytest.mark.parametrize("kv,form", [(2, "per_head"), (4, "per_head"),
                                      (8, "per_head"), (8, "all_heads"),
-                                     (32, "all_heads")])
+                                     (32, "all_heads"), (2, "word_rows"),
+                                     (4, "word_rows"), (8, "word_rows")])
 def test_host_iteration_counts_are_the_kernels_loop_bounds(kv, form,
                                                            window):
     """``decode_iterations`` / ``decode_wide_iterations`` as the host
@@ -202,7 +203,7 @@ def test_all_heads_form_matches_the_reference(name, window, q_dtype):
     queries that are f32 values (three MXU terms against bf16 rows) and
     bf16 values (their own one)."""
     dtype, kv, bs = ALL_HEADS_CASES[name]
-    assert pa.decode_attend_form(1, kv, bs) == "all_heads"
+    assert pa.decode_attend_form(1, kv, bs, DTYPES[dtype]) == "all_heads"
     wide = pa.decode_keys_per_iteration(4096, bs, kv, "all_heads")
     assert wide == pa.KEYS_PER_GROUP
     lengths = [7, bs, 100, wide - 1, wide, wide + 1, 2 * wide + 81, 3]
@@ -224,29 +225,118 @@ def test_all_heads_form_matches_the_reference(name, window, q_dtype):
                                np.asarray(ref), atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("group,kv,bs,form", [
-    # One query row a kv head and whole lane rows of (key, head) pairs.
-    (1, 32, 16, "all_heads"), (1, 8, 16, "all_heads"),
-    (1, 16, 16, "all_heads"), (1, 4, 32, "all_heads"),
-    (1, 1, 128, "all_heads"), (1, 128, 16, "all_heads"),
-    # The benchmark's other K/V cells: many rows a head.
-    (4, 8, 16, "per_head"), (16, 2, 16, "per_head"),
-    (32, 4, 16, "per_head"), (2, 32, 16, "per_head"),
-    # One row a head whose blocks fill no whole lane row.
-    (1, 4, 16, "per_head"), (1, 1, 16, "per_head"), (1, 3, 128, "per_head"),
-    (1, 40, 16, "per_head")])
-def test_attend_form_follows_the_geometry(group, kv, bs, form):
-    assert pa.decode_attend_form(group, kv, bs) == form
+# --------------------------------------------------------------------------- #
+# Float pools, head by head through the buffer's 32-bit word rows
+# (``decode_attend_form`` == "word_rows").
+
+#: name -> (pool dtype, kv heads, group, block size): sdar30b.fixedlen's
+#: and nemotron3super.reason's geometries, a word row eight heads wide
+#: (stride 4), f32 pools (a word a head), the contiguous view.
+WORD_ROWS_CASES = {
+    "sdar_bf16_4x32": ("bf16", 4, 32, 16),
+    "nemotron_bf16_2x16": ("bf16", 2, 16, 16),
+    "bf16_8x4": ("bf16", 8, 4, 16),
+    "f32_4x2": ("f32", 4, 2, 16),
+    "f32_1x8": ("f32", 1, 8, 16),
+    "bf16_2x2_contiguous_view_block128": ("bf16", 2, 2, 128),
+}
 
 
-@pytest.mark.parametrize("config_name,form", [
-    ("tiny", "per_head"), ("evabyte_tiny", "per_head"),
-    ("evabyte_32_heads", "all_heads")])
-def test_servers_attend_form_is_the_kernels(config_name, form):
+def _word_rows_case(name):
+    """Rows of unequal length: idle (zero table, a scratch position), a
+    lone block, one ragged group, a wide tile that reaches past the
+    groups its pass copied, whole passes, and several with a tail."""
+    dtype, kv, group, bs = WORD_ROWS_CASES[name]
+    assert pa.decode_attend_form(group, kv, bs,
+                                 DTYPES[dtype]) == "word_rows"
+    wide = pa.decode_keys_per_iteration(4096, bs, kv, "word_rows")
+    lengths = [7, bs, 100, 128 + 37, wide - 1, wide, wide + 1,
+               2 * wide + 81, 3]
+    max_blocks = -(-max(lengths) // bs) + 1
+    q, k, v, exact, tables, kv_args = _typed_case(
+        dtype, bs, max_blocks, batch=len(lengths), kv=kv, group=group,
+        hd=16)
+    tables = tables.at[len(lengths) - 1].set(0)
+    positions = jnp.asarray([length - 1 for length in lengths], jnp.int32)
+    return dtype, q, k, v, exact, tables, positions, kv_args
+
+
+@pytest.mark.parametrize("q_dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("window", [None, 150])
+@pytest.mark.parametrize("name", sorted(WORD_ROWS_CASES))
+def test_word_rows_form_matches_the_reference(name, window, q_dtype):
+    """Queries that are f32 values (three MXU terms against bf16 rows)
+    and bf16 values (their own one), with and without a window."""
+    dtype, q, k, v, exact, tables, positions, kv_args = \
+        _word_rows_case(name)
+    q = q.astype(DTYPES[q_dtype])
+    out = pa.paged_decode_attention(q, k, v, tables, positions,
+                                    window=window, interpret=True,
+                                    **kv_args)
+    assert out.dtype == q.dtype
+    ref = pa.paged_decode_reference(
+        q.astype(jnp.float32), exact[0], exact[1], tables, positions,
+        window=window, **kv_args)
+    tol = {"f32": 2e-5, "bf16": 2e-2}[q_dtype]
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("window", [None, 150])
+@pytest.mark.parametrize("name", sorted(WORD_ROWS_CASES))
+def test_word_rows_are_the_per_head_reads_rows(monkeypatch, name, window):
+    """A head's rows through the word rows are the rows the sub-word
+    read gives: the two bodies share everything after the read, so
+    their results agree bit for bit."""
+    _, q, k, v, _, tables, positions, _ = _word_rows_case(name)
+
+    def call():
+        return np.asarray(pa.closed_call.__wrapped__(
+            q, k, v, tables, positions, None, None, window=window,
+            sm_scale=0.25, interpret=True))
+    got = call()
+    monkeypatch.setattr(pa, "decode_attend_form", lambda *_: "per_head")
+    np.testing.assert_array_equal(got, call())
+
+
+@pytest.mark.parametrize("group,kv,bs,dtype,form", [
+    # One query row a kv head and whole lane rows of (key, head) pairs
+    # (evabyte.files' 32 int8 heads first).
+    (1, 32, 16, "int8", "all_heads"), (1, 8, 16, "bf16", "all_heads"),
+    (1, 16, 16, "f32", "all_heads"), (1, 4, 32, "int8", "all_heads"),
+    (1, 1, 128, "bf16", "all_heads"), (1, 128, 16, "int8", "all_heads"),
+    # Float pools whose heads fill whole words, head by head through
+    # the buffer's word rows: sdar30b.fixedlen, nemotron3super.reason,
+    # f32 pools, a bf16 pool at Mistral's heads, and one row a head
+    # whose blocks fill no whole lane row.
+    (32, 4, 16, "bf16", "word_rows"), (16, 2, 16, "bf16", "word_rows"),
+    (2, 2, 16, "f32", "word_rows"), (8, 1, 16, "f32", "word_rows"),
+    (4, 8, 16, "bf16", "word_rows"), (2, 32, 16, "bf16", "word_rows"),
+    (1, 4, 16, "bf16", "word_rows"), (1, 3, 128, "f32", "word_rows"),
+    # int8 pools keep the sub-word head read: mistral7b.chat and
+    # mixtral8x7b.chat (8 heads x 4 rows), others, and one row a head
+    # without whole lane rows; so does a bf16 pool whose heads fill no
+    # whole word.
+    (4, 8, 16, "int8", "per_head"), (16, 2, 16, "int8", "per_head"),
+    (32, 4, 16, "int8", "per_head"), (2, 32, 16, "int8", "per_head"),
+    (1, 4, 16, "int8", "per_head"), (1, 40, 16, "int8", "per_head"),
+    (8, 1, 16, "bf16", "per_head"), (1, 3, 128, "bf16", "per_head")])
+def test_attend_form_follows_the_geometry(group, kv, bs, dtype, form):
+    assert pa.decode_attend_form(group, kv, bs, DTYPES[dtype]) == form
+
+
+@pytest.mark.parametrize("config_name,quantize_kv,form", [
+    ("tiny", False, "word_rows"), ("tiny", True, "per_head"),
+    ("evabyte_tiny", False, "word_rows"),
+    ("evabyte_tiny", True, "per_head"),
+    ("evabyte_32_heads", False, "all_heads")])
+def test_servers_attend_form_is_the_kernels(config_name, quantize_kv,
+                                            form):
     """``stats()["decode_attend_form"]`` is the kernel's own deciding
-    function at the server's geometry (``evabyte_tiny``: one row a head,
-    but 4 heads of a 4-key block fill no lane row), and the host's
-    iteration counts take the width that form gets."""
+    function at the server's geometry and pool dtype (``evabyte_tiny``:
+    one row a head, but 4 heads of a 4-key block fill no lane row; an
+    int8 pool keeps the sub-word head read), and the host's iteration
+    counts take the width that form gets."""
     import dataclasses
 
     from aiko_services_tpu.models import evabyte, serving_model
@@ -264,12 +354,14 @@ def test_servers_attend_form_is_the_kernels(config_name, form):
     try:
         server = PagedContinuousServer(
             config_name=config_name, slots=2, max_seq=128, chunk_steps=2,
-            block_size=block, chunk_prefill_tokens=16, total_blocks=40)
+            block_size=block, chunk_prefill_tokens=16, total_blocks=40,
+            quantize_kv=quantize_kv)
     finally:
         evabyte.CONFIGS.pop("evabyte_32_heads", None)
     group = config.n_heads // config.n_kv_heads
     assert server.decode_attend_form == pa.decode_attend_form(
-        group, config.n_kv_heads, block) == form
+        group, config.n_kv_heads, block,
+        jnp.int8 if quantize_kv else config.dtype) == form
     # Count as the chip's path does, at the width the form gets.
     server.decode_attention_path = "kernel"
     request = DecodeRequest(request_id="r", max_new_tokens=6,

@@ -150,6 +150,12 @@ DECODE_GEOMETRIES = {
                                               129, 128, 256),
     "f32_pool_2_heads": (8, 2, 16, jnp.float32, None, 64, 1025, 16, 512),
     "f32_pool_32_heads": (8, 32, 1, jnp.float32, None, 64, 1025, 16, 128),
+    # Float pools through the buffer's word rows at a stride of 4 word
+    # rows (8 bf16 heads) and of 4 f32 heads, under a window.
+    "bf16_pool_8_heads_4_rows_window": (32, 8, 4, jnp.bfloat16, 4096, 160,
+                                        4609, 16, 128),
+    "f32_pool_4_heads_window": (8, 4, 8, jnp.float32, 512, 64, 1025, 16,
+                                256),
     # One query row a kv head: the all-heads form.
     "evabyte_files_int8_32x1": (8, 32, 1, jnp.int8, None, 184, 1537, 16,
                                 128),
@@ -157,6 +163,25 @@ DECODE_GEOMETRIES = {
                                          1537, 16, 128),
     "contiguous_view_block128_int8_16_heads_one_row": (
         8, 16, 1, jnp.int8, None, 16, 129, 128, 128),
+}
+
+
+#: The ``attend`` body each of them takes (``decode_attend_form``).
+DECODE_FORMS = {
+    "sdar30b_fixedlen_bf16_4x32": "word_rows",
+    "nemotron3super_bf16_2x16": "word_rows",
+    "contiguous_view_block128_bf16_2_heads": "word_rows",
+    "f32_pool_2_heads": "word_rows",
+    "bf16_pool_8_heads_4_rows_window": "word_rows",
+    "f32_pool_4_heads_window": "word_rows",
+    "mistral7b_chat_int8_8x4_window": "per_head",
+    "contiguous_view_block128_int8": "per_head",
+    "int8_pool_block32_4_heads": "per_head",
+    "contiguous_view_block128_int8_4_heads": "per_head",
+    "f32_pool_32_heads": "all_heads",
+    "evabyte_files_int8_32x1": "all_heads",
+    "bf16_pool_8_heads_one_row_window": "all_heads",
+    "contiguous_view_block128_int8_16_heads_one_row": "all_heads",
 }
 
 
@@ -172,9 +197,9 @@ def test_decode_kernel_compiles_for_v5e(name, one_chip, as_on_tpu):
     (slots, kv, group, pool_dt, window, table, n_blocks, bs,
      wide) = DECODE_GEOMETRIES[name]
     hd = 128
-    assert pa.decode_keys_per_iteration(
-        table * bs, bs, kv,
-        pa.decode_attend_form(group, kv, bs)) == wide
+    form = pa.decode_attend_form(group, kv, bs, pool_dt)
+    assert form == DECODE_FORMS[name]
+    assert pa.decode_keys_per_iteration(table * bs, bs, kv, form) == wide
     S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     pool = S((n_blocks, bs, kv, hd), pool_dt)
     scales = S((n_blocks, bs, kv), jnp.float32) \
